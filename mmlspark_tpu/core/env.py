@@ -134,3 +134,29 @@ class EnvironmentUtils:
             "processes": jax.process_count(),
             "device_kinds": EnvironmentUtils.device_kinds(),
         }
+
+
+def refuse_child_on_held_chip(what: str) -> None:
+    """One process per chip.  An accelerator belongs to the process that
+    first touched JAX; a child that needs it then fails or hangs.  Entry
+    points that start JAX-needing children call this first: it raises when
+    THIS process has already brought up a non-CPU backend (merely having
+    imported jax holds nothing)."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return
+    import jax
+    # no public "is a backend up?" query — jax.default_backend() itself
+    # would bring one up and take the chip
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{what}: this process already holds the "
+            f"{jax.default_backend()} device(s), so a child process that "
+            "needs them would fail or hang.  Start children from a parent "
+            "that never touches JAX (train and save in a child too), or "
+            "serve N in-process apps on N devices — see "
+            "mmlspark_tpu/serve/README.md, \"One process per chip\"."
+        )

@@ -2,49 +2,66 @@
 
 The reference has ZERO compile cost: LightGBM's C++ trains immediately
 (SURVEY.md §3.1), so every second XLA spends compiling is a real regression
-for a first-time user — the bench-shape training program costs ~11 s of
-compile on a v5e (first-ever factorized-kernel compile ~120 s).  JAX's
-persistent compilation cache eliminates this on every process AFTER the
-first on a machine, which matches how the reference's long-lived executors
-amortize JVM/native warmup — but it must be ON for library users, not just
-the benchmark (VERDICT r3 weak #2: the cache lived in bench.py only).
+for a first-time user.  JAX's persistent compilation cache eliminates this
+on every process AFTER the first on a machine, which matches how the
+reference's long-lived executors amortize JVM/native warmup — and it is ON
+for library users, not just the benchmark.
 
 Enabled automatically from :func:`mmlspark_tpu.engine.booster.train` (and
-therefore every estimator facade).  Controls:
+therefore every estimator facade).
+
+Directory contract (:func:`cache_dir`) — ONE rule for all three artifact
+kinds (jax's XLA entries, the ``aot-*``/``pft-*`` artifacts below, and
+``core/trace_cache``'s ``*.jaxexp`` blobs):
+
+- ``JAX_COMPILATION_CACHE_DIR`` set → everything lives in that directory
+  and this module never writes ``jax_compilation_cache_dir``;
+- else a ``jax_compilation_cache_dir`` the caller configured in code;
+- else ``<checkout>/.jax_cache`` next to the package (git-ignored) — a
+  fixed path derived from the package location, never the home
+  directory, a temp dir, a pid or a clock, so two processes started from
+  the same checkout always share entries.
+
+The same thresholds apply in every branch: every program is cached
+(``jax_persistent_cache_min_compile_time_secs=0`` — the scan-program zoo is
+many small programs and the write cost is trivial next to any compile), and
+the directory is pruned to a size cap at enable time.
+
+Controls:
 
 - ``MMLSPARK_TPU_NO_COMPILE_CACHE=1`` — opt out.
-- ``MMLSPARK_TPU_COMPILE_CACHE_DIR`` — override the default
-  ``~/.cache/mmlspark_tpu/jit`` (honors ``XDG_CACHE_HOME``).
-- ``MMLSPARK_TPU_COMPILE_CACHE_MAX_MB`` — size cap for best-effort
-  LRU pruning (default 2048).
+- ``MMLSPARK_TPU_COMPILE_CACHE_MAX_MB`` — size cap for the LRU prune
+  (default 128: the in-checkout directory is copied wherever the tree is).
 
-A user-set ``jax_compilation_cache_dir`` (jax config or ``JAX_COMPILATION_
-CACHE_DIR``) always wins — we never override an explicit choice.
+Hit/miss accounting rides jax's public ``jax.monitoring`` events
+(``/jax/compilation_cache/cache_hits`` / ``cache_misses``) into the
+``jit_cache.hit`` / ``jit_cache.miss`` obs counters.  Those events carry no
+cache key, so jax's own entries are NOT touched on a hit: their LRU order
+in :func:`prune_cache_dir` is the filesystem's atime/mtime (relatime-coarse).
+The artifacts this module reads itself are touched explicitly
+(:func:`record_cache_hit`).
 
 AOT artifacts (ISSUE 11 / ROADMAP item 3a)
 ------------------------------------------
 jax's persistent cache only skips the XLA *compile*; a fresh process
-still pays the full trace/lower before the cache is even consulted
-(~230 ms for the bench forest, on top of ~420 ms compile).  The
+still pays the full trace/lower before the cache is even consulted.  The
 ``aot-*`` artifact kind stores the WHOLE compiled executable
 (``jax.experimental.serialize_executable``), so a second process goes
-straight from disk bytes to a callable in low milliseconds.  The
-``pft-*`` kind stores the packed-forest host arrays (the Python
-per-tree pack loop is ~40 ms for 200 trees — real money against a
-millisecond cold-start budget).  Both kinds live in the SAME directory
-as jax's own cache entries and ride the SAME LRU prune/mtime machinery
-— :func:`prune_cache_dir` is kind-agnostic by construction (it orders
-every file by last access, whatever its prefix).
+straight from disk bytes to a callable.  The ``pft-*`` kind stores the
+packed-forest host arrays (the Python per-tree pack loop).  Both kinds ride
+the same LRU prune — :func:`prune_cache_dir` is kind-agnostic by
+construction (it orders every file by last access, whatever its prefix).
 
 Keys are content fingerprints (:func:`aot_fingerprint`): schema
 version, jax/jaxlib versions, backend platform + device kind + device
-count, ``XLA_FLAGS``, the caller's static meta (forest slice, bin
-config), and every argument leaf's shape/dtype.  Any drift — a jax
-upgrade, a different bucket shape, a retrained forest with a new tree
-count — lands on a different key; stale artifacts simply age out of
-the LRU.  A deserialize failure (e.g. an artifact from an incompatible
-jaxlib that collided on key) deletes the artifact and reports a miss,
-so the caller falls back to the trace path.
+count, ``XLA_FLAGS``, the ids of the devices the arguments live on (the
+executable is compiled FOR those devices and is loaded back onto exactly
+them — a one-device program stays one-device on a 4- or 8-device host),
+the caller's static meta (forest slice, bin config), and every argument
+leaf's shape/dtype.  Any drift lands on a different key; stale artifacts
+simply age out of the LRU.  An artifact whose bytes do not unpickle
+(truncated write, disk rot) is deleted and reported as a miss; any other
+load failure — an API mismatch with the installed jax — raises.
 
 obs: ``jit_cache.aot_serialize`` / ``jit_cache.aot_deserialize`` spans
 time the (de)serialization; ``jit_cache.aot_hits`` / ``aot_misses`` /
@@ -55,66 +72,76 @@ time the (de)serialization; ``jit_cache.aot_hits`` / ``aot_misses`` /
 from __future__ import annotations
 
 import os
+import pickle
 
 from mmlspark_tpu import obs
 
 _done = False
+_listening = False
 
-AOT_SCHEMA = 1  # bump to invalidate every serialized artifact at once
+AOT_SCHEMA = 2  # bump to invalidate every serialized artifact at once
+
+_DEFAULT_MAX_MB = 128.0
+
+# <checkout>/.jax_cache: the package's parent directory is the checkout
+# root for a source tree (the only way this repo is run).
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
-def default_cache_dir() -> str:
-    override = os.environ.get("MMLSPARK_TPU_COMPILE_CACHE_DIR")
-    if override:
-        return override
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
-    )
-    return os.path.join(base, "mmlspark_tpu", "jit")
+def cache_dir() -> str:
+    """The one directory every compiled artifact lives in (module
+    docstring: env var, else the caller's jax config, else the fixed
+    in-checkout path)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or _CHECKOUT_CACHE_DIR
 
 
 def enable_compile_cache() -> bool:
     """Idempotently point jax at the persistent compile cache.
 
-    Returns True when the cache is (now) enabled.  Never raises: a
-    read-only home or an old jax simply leaves caching off.
+    Returns True when the cache is (now) enabled, False on the opt-out or
+    when the directory cannot be created (read-only checkout).
     """
     global _done
     if _done:
         return True
     if os.environ.get("MMLSPARK_TPU_NO_COMPILE_CACHE"):
         return False
-    try:
-        import jax
+    import jax
 
-        if jax.config.jax_compilation_cache_dir or os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR"
-        ):
-            _done = True  # user already configured a cache — respect it
-            return True
-        path = default_cache_dir()
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Cache even fast compiles: the scan-program zoo is many small
-        # programs and the write cost is trivial next to any compile.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # Min-time-0 writes EVERY program, so the dir grows without bound
-        # across shapes/configs (r4 advisor low #5) — prune to a size cap,
-        # oldest-access first, at enable time (once per process).
-        prune_cache_dir(path)
-        _install_hit_recorder(path)
-        _done = True
-    except Exception:
-        return False
+    path = cache_dir()
     try:
-        # jax lazily imports etils.epath inside the FIRST compile's
-        # get_compile_options once a cache dir is set — ~75 ms of pure
-        # Python import that would otherwise land in the first predict's
-        # cold window.  Front-load it here, where enabling the cache is
-        # already declared process setup.
-        import etils.epath  # noqa: F401
-    except Exception:
-        pass
+        os.makedirs(path, exist_ok=True)
+    except OSError:
+        return False
+    if path == _CHECKOUT_CACHE_DIR:  # neither the env var nor the caller chose
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # Min-time-0 writes EVERY program, so the dir grows without bound
+    # across shapes/configs — prune to the size cap, oldest-access first,
+    # at enable time (once per process).
+    prune_cache_dir(path)
+    _listen_for_cache_events()
+    _done = True
+    # jax lazily imports etils.epath inside the FIRST compile's
+    # get_compile_options once a cache dir is set — ~75 ms of pure
+    # Python import that would otherwise land in the first predict's
+    # cold window.  Front-load it here, where enabling the cache is
+    # already declared process setup.
+    import etils.epath  # noqa: F401
+
     return True
 
 
@@ -132,46 +159,26 @@ def record_cache_hit(path: str) -> None:
         pass
 
 
-def _install_hit_recorder(cache_dir: str) -> None:
-    """Touch compile-cache entries when jax serves them (best-effort).
+def _on_cache_event(event: str, **_kwargs) -> None:
+    if event == _HIT_EVENT:
+        obs.inc("jit_cache.hit")
+    elif event == _MISS_EVENT:
+        obs.inc("jit_cache.miss")
+        # Unified compile-event ledger (obs/device.py): a cache miss here
+        # is exactly one XLA compile paid.
+        obs.device.compile_event("compile")
 
-    jax's persistent cache reads entries without updating any timestamp we
-    can rely on under relatime, so wrap its module-level getter and
-    :func:`record_cache_hit` the backing file(s) on every hit.  Layouts
-    differ across jax versions (``<key>`` flat files vs ``<key>-cache``
-    LRU entries), so any file beginning with the key is touched.  Any
-    internals mismatch leaves caching fully functional, just with the
-    weaker atime-based eviction order.
-    """
-    try:
-        import jax._src.compilation_cache as cc
 
-        if getattr(cc.get_executable_and_time, "_mmlspark_tpu_touch", False):
-            return
-        orig = cc.get_executable_and_time
+def _listen_for_cache_events() -> None:
+    """Feed jax's persistent-cache hit/miss events into the obs counters
+    (registered once per process)."""
+    global _listening
+    if _listening:
+        return
+    import jax.monitoring
 
-        def get_and_touch(cache_key, compile_options, backend):
-            result = orig(cache_key, compile_options, backend)
-            if result[0] is not None:
-                obs.inc("jit_cache.hit")
-                try:
-                    with os.scandir(cache_dir) as it:
-                        for e in it:
-                            if e.name.startswith(cache_key):
-                                record_cache_hit(e.path)
-                except OSError:
-                    pass
-            else:
-                obs.inc("jit_cache.miss")
-                # Unified compile-event ledger (obs/device.py): a cache
-                # miss here is exactly one XLA compile paid.
-                obs.device.compile_event("compile")
-            return result
-
-        get_and_touch._mmlspark_tpu_touch = True
-        cc.get_executable_and_time = get_and_touch
-    except Exception:
-        pass
+    jax.monitoring.register_event_listener(_on_cache_event)
+    _listening = True
 
 
 def cache_counters() -> dict:
@@ -194,18 +201,19 @@ def cache_counters() -> dict:
 # ---------------------------------------------------------------------------
 # AOT artifacts: serialized executables + packed-forest blobs
 # ---------------------------------------------------------------------------
-def artifact_dir() -> str:
-    """Directory AOT artifacts share with jax's persistent cache entries
-    (the user-configured jax cache dir when set, else our default)."""
-    try:
-        import jax
+def _arg_devices(args) -> list:
+    """The devices ``args``' array leaves live on, in id order — the
+    devices a program lowered from these arguments is compiled for
+    (the first device when no leaf is a device array)."""
+    import jax
 
-        configured = jax.config.jax_compilation_cache_dir
-        if configured:
-            return configured
-    except Exception:
-        pass
-    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or default_cache_dir()
+    devs = {
+        d.id: d
+        for leaf in jax.tree_util.tree_leaves(args)
+        if isinstance(leaf, jax.Array)
+        for d in leaf.devices()
+    }
+    return [devs[i] for i in sorted(devs)] or jax.devices()[:1]
 
 
 def aot_fingerprint(kind: str, meta: dict, args=()) -> str:
@@ -213,24 +221,20 @@ def aot_fingerprint(kind: str, meta: dict, args=()) -> str:
 
     Hashes everything that determines executable validity: schema
     version, jax + jaxlib versions, backend platform / device kind /
-    device count, ``XLA_FLAGS``, the caller's static ``meta`` (e.g.
-    forest slice T/K/depth, bin config, raw_score), and the
-    shape+dtype of every leaf in ``args`` (the bucket shape lives
-    here).  Model WEIGHTS are deliberately excluded for executables —
-    they are runtime arguments, so one artifact serves every model
-    version with the same shapes (a hot-swap warms for free).
+    device count, ``XLA_FLAGS``, the ids of the devices ``args`` live on
+    (:func:`load_aot` loads the executable back onto exactly those), the
+    caller's static ``meta`` (e.g. forest slice T/K/depth, bin config,
+    raw_score), and the shape+dtype of every leaf in ``args`` (the bucket
+    shape lives here).  Model WEIGHTS are deliberately excluded for
+    executables — they are runtime arguments, so one artifact serves
+    every model version with the same shapes (a hot-swap warms for free).
     """
     import hashlib
     import json
 
     import jax
+    import jaxlib
 
-    try:
-        import jaxlib
-
-        jaxlib_v = getattr(jaxlib, "__version__", "")
-    except Exception:
-        jaxlib_v = ""
     devs = jax.devices()
     spec = [
         (tuple(int(d) for d in getattr(leaf, "shape", ())),
@@ -242,10 +246,11 @@ def aot_fingerprint(kind: str, meta: dict, args=()) -> str:
             "schema": AOT_SCHEMA,
             "kind": kind,
             "jax": jax.__version__,
-            "jaxlib": jaxlib_v,
+            "jaxlib": jaxlib.__version__,
             "backend": jax.default_backend(),
-            "device_kind": getattr(devs[0], "device_kind", str(devs[0])),
+            "device_kind": devs[0].device_kind,
             "device_count": len(devs),
+            "devices": [d.id for d in _arg_devices(args)],
             "xla_flags": os.environ.get("XLA_FLAGS", ""),
             "meta": meta,
             "args": spec,
@@ -257,17 +262,18 @@ def aot_fingerprint(kind: str, meta: dict, args=()) -> str:
 
 
 def _artifact_path(kind: str, key: str) -> str:
-    return os.path.join(artifact_dir(), f"{kind}-{key}")
+    return os.path.join(cache_dir(), f"{kind}-{key}")
 
 
 def save_artifact(kind: str, key: str, data: bytes) -> bool:
     """Atomically write an artifact blob into the cache dir (tmp +
-    rename), then prune the dir to its LRU budget.  Never raises;
-    respects the ``MMLSPARK_TPU_NO_COMPILE_CACHE`` opt-out."""
+    rename), then prune the dir to its LRU budget.  Returns False when
+    the directory is not writable or caching is opted out
+    (``MMLSPARK_TPU_NO_COMPILE_CACHE``)."""
     if os.environ.get("MMLSPARK_TPU_NO_COMPILE_CACHE"):
         return False
     try:
-        d = artifact_dir()
+        d = cache_dir()
         os.makedirs(d, exist_ok=True)
         path = _artifact_path(kind, key)
         tmp = f"{path}.tmp.{os.getpid()}"
@@ -296,52 +302,50 @@ def load_artifact(kind: str, key: str):
 
 
 def save_aot(key: str, compiled) -> bool:
-    """Serialize a compiled executable under ``aot-<key>``.
+    """Serialize a compiled executable under ``aot-<key>``; False when the
+    artifact could not be written (see :func:`save_artifact`)."""
+    from jax.experimental import serialize_executable as se
 
-    Returns False (artifact simply not cached) on any failure — some
-    backends/executables don't support serialization.
-    """
-    try:
-        import pickle
-
-        from jax.experimental import serialize_executable as se
-
-        with obs.span("jit_cache.aot_serialize", key=key):
-            data = pickle.dumps(se.serialize(compiled))
-    except Exception:
-        return False
+    with obs.span("jit_cache.aot_serialize", key=key):
+        data = pickle.dumps(se.serialize(compiled))
     if save_artifact("aot", key, data):
         obs.inc("jit_cache.aot_bytes", float(len(data)))
         return True
     return False
 
 
-def load_aot(key: str):
-    """Deserialize the ``aot-<key>`` executable; ``None`` on miss.
+def load_aot(key: str, devices):
+    """Deserialize the ``aot-<key>`` executable onto ``devices`` (the
+    devices it was compiled for — part of the key, see
+    :func:`aot_fingerprint`); ``None`` on miss.
 
-    A present-but-undeserializable artifact (incompatible jaxlib bits
-    that collided on key) is deleted and reported as a miss, so the
-    caller's trace fallback replaces it.
+    jax's default (``execution_devices=None``) loads onto EVERY device of
+    the backend, which turns a one-device program into an N-shard one on
+    a multi-device host — hence the explicit list.  A present artifact
+    whose bytes do not unpickle is deleted and reported as a miss, so the
+    caller's trace path replaces it; anything else raises.
     """
     data = load_artifact("aot", key)
     if data is not None:
+        from jax.experimental import serialize_executable as se
+
         try:
-            import pickle
-
-            from jax.experimental import serialize_executable as se
-
+            payload = pickle.loads(data)
+        except (pickle.UnpicklingError, EOFError):
+            try:
+                os.remove(_artifact_path("aot", key))
+            except OSError:
+                pass
+        else:
             with obs.span("jit_cache.aot_deserialize", key=key):
-                exe = se.deserialize_and_load(*pickle.loads(data))
+                exe = se.deserialize_and_load(
+                    *payload, execution_devices=devices
+                )
             obs.inc("jit_cache.aot_hits")
             # Unified compile-event ledger (obs/device.py): an AOT load
             # replaces a compile with a deserialize.
             obs.device.compile_event("deserialize")
             return exe
-        except Exception:
-            try:
-                os.remove(_artifact_path("aot", key))
-            except OSError:
-                pass
     obs.inc("jit_cache.aot_misses")
     return None
 
@@ -351,24 +355,19 @@ def load_or_compile_aot(kind: str, meta: dict, args, lower):
     single-model serving program (``kind="packed_raw_rows"``, booster)
     and the co-resident super-table program
     (``kind="multi_packed_raw_rows"``, serve.coresident): fingerprint the
-    statics + arg shapes, try ``load_aot``, and only on a genuine miss
-    call ``lower()`` (returning a jax lowering), compile, and persist.
+    statics + arg shapes + arg devices, try ``load_aot``, and only on a
+    genuine miss call ``lower()`` (returning a jax lowering), compile,
+    and persist.
 
     Returns ``(executable, how)`` with ``how`` in ``{"from_disk",
-    "traced"}``.  Fingerprinting failures degrade to the trace path —
-    never raise over a cache.
+    "traced"}``.
     """
-    key = None
-    try:
-        key = aot_fingerprint(kind, meta, args)
-    except Exception:
-        pass
-    exe = load_aot(key) if key is not None else None
+    key = aot_fingerprint(kind, meta, args)
+    exe = load_aot(key, _arg_devices(args))
     if exe is not None:
         return exe, "from_disk"
     exe = lower().compile()
-    if key is not None:
-        save_aot(key, exe)
+    save_aot(key, exe)
     return exe, "traced"
 
 
@@ -397,19 +396,22 @@ def prune_cache_dir(path: str, max_mb: float | None = None) -> int:
     """Best-effort LRU prune of ``path`` to ``max_mb``; returns files removed.
 
     Eviction order is max(atime, mtime).  Relatime mounts refresh atime at
-    most once per 24 h, so hits are recorded explicitly by bumping mtime
-    (:func:`record_cache_hit`, wired into jax's cache getter by
-    :func:`_install_hit_recorder`) — a freshly-hit entry therefore always
-    outlives a stale one regardless of mount options.  Never raises;
-    concurrent processes racing on the same file just skip it.
+    most once per 24 h, so the artifacts this package reads itself record
+    their hits explicitly by bumping mtime (:func:`record_cache_hit`) — a
+    freshly-hit artifact outlives a stale one regardless of mount options;
+    jax's own entries are ordered by what the filesystem recorded (module
+    docstring).  Never raises; concurrent processes racing on the same
+    file just skip it.
     """
     if max_mb is None:
         try:
             max_mb = float(
-                os.environ.get("MMLSPARK_TPU_COMPILE_CACHE_MAX_MB", 2048)
+                os.environ.get(
+                    "MMLSPARK_TPU_COMPILE_CACHE_MAX_MB", _DEFAULT_MAX_MB
+                )
             )
         except ValueError:  # e.g. "2g" — keep the never-raises contract
-            max_mb = 2048.0
+            max_mb = _DEFAULT_MAX_MB
     budget = max_mb * (1 << 20)
     try:
         entries = []

@@ -48,9 +48,18 @@ dead participants; when the pod re-forms at full strength, the original
 blobs hit again unchanged.  Writes are tmp+rename atomic per process,
 so concurrent ranks racing the same digest never tear a reader.
 
-Opt out with ``MMLSPARK_TPU_NO_TRACE_CACHE=1``.  Any failure (old jax,
-unserializable graph, corrupt blob) silently falls back to the jitted
-callable.
+Blobs live in the one compiled-artifact directory
+(:func:`mmlspark_tpu.core.jit_cache.cache_dir`:
+``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``)
+beside the XLA entries and the ``aot-*``/``pft-*`` artifacts, and ride the
+same LRU prune.
+
+Opt out with ``MMLSPARK_TPU_NO_TRACE_CACHE=1``.  A blob that does not
+deserialize (truncated write, disk rot) is re-exported; a graph
+``jax.export`` rejects (``NotImplementedError``/``ValueError`` — an effect
+or a primitive without a serialization rule) turns the wrapper off for that
+program and counts ``trace_cache.off``.  Anything else — a kernel that
+fails to lower, an API mismatch — raises.
 """
 
 from __future__ import annotations
@@ -92,16 +101,6 @@ def _source_hash() -> str:
     return _SRC_HASH
 
 
-def cache_dir() -> str:
-    override = os.environ.get("MMLSPARK_TPU_TRACE_CACHE_DIR")
-    if override:
-        return override
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
-    )
-    return os.path.join(base, "mmlspark_tpu", "traces")
-
-
 def enabled() -> bool:
     return not os.environ.get("MMLSPARK_TPU_NO_TRACE_CACHE")
 
@@ -110,16 +109,13 @@ def _register_trees():
     global _REGISTERED
     if _REGISTERED:
         return
-    try:
-        from jax import export as jexport
+    from jax import export as jexport
 
-        from mmlspark_tpu.engine.tree import Tree
+    from mmlspark_tpu.engine.tree import Tree
 
-        jexport.register_namedtuple_serialization(
-            Tree, serialized_name="mmlspark_tpu.engine.tree.Tree"
-        )
-    except Exception:
-        pass
+    jexport.register_namedtuple_serialization(
+        Tree, serialized_name="mmlspark_tpu.engine.tree.Tree"
+    )
     _REGISTERED = True
 
 
@@ -186,6 +182,65 @@ def _all_processes_have(path: str, multi_controller: bool) -> bool:
     return _all_processes_ok(os.path.exists(path), multi_controller)
 
 
+def _load_or_export(jitted, args, digest: str, multi_controller: bool):
+    """The exported program for ``digest``: deserialized from the cache
+    directory when every participating process holds the blob, else
+    exported now (one trace — the price the plain jit path pays) and
+    written for later processes.  ``None`` when ``jax.export`` rejects
+    the graph (counted as ``trace_cache.off``)."""
+    import struct
+    import warnings
+
+    from jax import export as jexport
+
+    from mmlspark_tpu.core.jit_cache import cache_dir, record_cache_hit
+
+    path = os.path.join(cache_dir(), digest + ".jaxexp")
+    exp = None
+    # Every non-deterministic step below is COLLECTIVE-agreed under
+    # multiple controllers (blob existence, deserialize success), so all
+    # processes take the same branch and run byte-identical programs; an
+    # export rejection is a deterministic property of the program,
+    # failing identically on every process, so the per-process `off`
+    # fallback stays safe.
+    if _all_processes_have(path, multi_controller):
+        try:
+            with obs.span("trace_cache.load"), open(path, "rb") as f:
+                exp = jexport.deserialize(bytearray(f.read()))
+            record_cache_hit(path)
+        except (OSError, struct.error, ValueError):
+            exp = None  # torn/corrupt blob on SOME process
+        if not _all_processes_ok(exp is not None, multi_controller):
+            exp = None  # any process failed → everyone exports
+    if exp is not None:
+        obs.inc("trace_cache.hit")
+        return exp
+    obs.inc("trace_cache.miss")
+    # Unified compile-event ledger (obs/device.py): a trace-cache miss
+    # pays a Python re-trace.
+    obs.device.compile_event("trace")
+    try:
+        with obs.span("trace_cache.export"):
+            exp = jexport.export(jitted)(*args)
+            blob = exp.serialize()
+    except (NotImplementedError, ValueError) as e:
+        # jax.export's own refusals (an effect or custom call with no
+        # serialization guarantee).  A kernel that fails to lower fails
+        # again, loudly, in the plain jit call the caller falls back to.
+        obs.inc("trace_cache.off")
+        warnings.warn(f"trace cache off for this program: {e}")
+        return None
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        tmp = path + f".tmp{os.getpid()}"
+        with open(tmp, "wb") as f:
+            f.write(blob)
+        os.replace(tmp, path)
+    except OSError:
+        pass  # best-effort write; the export still serves
+    return exp
+
+
 def wrap_aot(
     jitted: Callable, key_material: str, multi_controller: bool = False
 ) -> Callable:
@@ -212,69 +267,31 @@ def wrap_aot(
         if exp is not None:
             obs.inc("trace_cache.memo_hit")
             return exp.call(*args)
-        try:
-            from jax import export as jexport
-
-            _register_trees()
-            digest = hashlib.sha256(
-                "\x1e".join(
-                    [
-                        key_material,
-                        sig,
-                        _source_hash(),
-                        jax.__version__,
-                        jax.default_backend(),
-                    ]
-                ).encode()
-            ).hexdigest()
-            exp = _EXP_MEMO.get(digest)
-            if exp is not None:
-                obs.inc("trace_cache.memo_hit")
-            else:
-                path = os.path.join(cache_dir(), digest + ".jaxexp")
-                # Every non-deterministic step below is COLLECTIVE-agreed
-                # under multiple controllers (blob existence, deserialize
-                # success), so all processes take the same branch and run
-                # byte-identical programs; the remaining failure modes
-                # (old jax, unserializable graph) are deterministic
-                # properties of the program, failing identically on every
-                # process, so the per-process `off` fallback stays safe.
-                if _all_processes_have(path, multi_controller):
-                    try:
-                        with obs.span("trace_cache.load"), open(path, "rb") as f:
-                            exp = jexport.deserialize(bytearray(f.read()))
-                    except Exception:
-                        exp = None  # corrupt blob on SOME process
-                    if not _all_processes_ok(exp is not None, multi_controller):
-                        exp = None  # any process failed → everyone exports
-                if exp is not None:
-                    obs.inc("trace_cache.hit")
-                else:
-                    obs.inc("trace_cache.miss")
-                    # Unified compile-event ledger (obs/device.py): a
-                    # trace-cache miss pays a Python re-trace.
-                    obs.device.compile_event("trace")
-                    with obs.span("trace_cache.export"):
-                        exp = jexport.export(jitted)(*args)
-                    try:
-                        os.makedirs(cache_dir(), exist_ok=True)
-                        tmp = path + f".tmp{os.getpid()}"
-                        with open(tmp, "wb") as f:
-                            f.write(exp.serialize())
-                        os.replace(tmp, path)
-                    except OSError:
-                        pass  # best-effort write; the export still serves
-                if len(_EXP_MEMO) >= _EXP_MEMO_MAX:
-                    _EXP_MEMO.pop(next(iter(_EXP_MEMO)))
-                _EXP_MEMO[digest] = exp
-            out = exp.call(*args)
-            state[sig] = exp
-            return out
-        except Exception:
-            # old jax / unserializable graph → plain jit (deterministic
-            # per-program, so every process lands here together)
-            state["off"] = True
-            obs.inc("trace_cache.off")
-            return jitted(*args)
+        _register_trees()
+        digest = hashlib.sha256(
+            "\x1e".join(
+                [
+                    key_material,
+                    sig,
+                    _source_hash(),
+                    jax.__version__,
+                    jax.default_backend(),
+                ]
+            ).encode()
+        ).hexdigest()
+        exp = _EXP_MEMO.get(digest)
+        if exp is not None:
+            obs.inc("trace_cache.memo_hit")
+        else:
+            exp = _load_or_export(jitted, args, digest, multi_controller)
+            if exp is None:
+                state["off"] = True
+                return jitted(*args)
+            if len(_EXP_MEMO) >= _EXP_MEMO_MAX:
+                _EXP_MEMO.pop(next(iter(_EXP_MEMO)))
+            _EXP_MEMO[digest] = exp
+        out = exp.call(*args)
+        state[sig] = exp
+        return out
 
     return call

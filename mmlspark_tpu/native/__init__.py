@@ -13,6 +13,8 @@ toolchain or the compiled library is unavailable (or when
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,10 +24,9 @@ from mmlspark_tpu import obs
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "binner.cpp")
-_SO = os.path.join(_HERE, "_binner.so")
 
 _lock = threading.Lock()
-_libs: dict = {}  # so-path -> _TimedLib | None (None = tried, unavailable)
+_libs: dict = {}  # source path -> _TimedLib | None (None = tried, unavailable)
 
 
 class _TimedLib:
@@ -68,49 +69,70 @@ class _TimedLib:
         return timed
 
 
-def load_native_lib(src: str, so: str, bind) -> "ctypes.CDLL | None":
-    """Shared compile-if-stale + CDLL + bind loader for the C++ components.
+def load_native_lib(src: str, bind) -> "ctypes.CDLL | None":
+    """Shared build-if-absent + CDLL + bind loader for the C++ components.
 
-    Compiles ``src`` to ``so`` with the local toolchain when the binary is
-    missing or older than the source (atomic tmp+replace, per-process tmp
-    name), loads it, and calls ``bind(lib)`` to set the ctypes signatures.
-    Returns None — the caller's numpy fallback — when the toolchain or the
-    library is unavailable, or when ``MMLSPARK_TPU_NO_NATIVE=1``.
+    The binary is named after its source — ``_<stem>-<sha12 of src>.so``
+    beside it — and built with the local toolchain when that name is
+    absent (atomic tmp+replace, per-process tmp name).  A copied or
+    unpacked tree resets mtimes, so freshness is never judged by them: an
+    edited source simply has another name, and binaries left from other
+    source versions are removed after a successful build.  Then the
+    library is loaded and ``bind(lib)`` sets the ctypes signatures.
+    Returns None — the caller's numpy fallback — when
+    ``MMLSPARK_TPU_NO_NATIVE=1``, when there is no toolchain, or when the
+    build fails (logged with the compiler's message).
     """
-    if so in _libs:
-        return _libs[so]
+    if src in _libs:
+        return _libs[src]
     with _lock:
-        if so in _libs:
-            return _libs[so]
+        if src in _libs:
+            return _libs[src]
         lib = None
         if not os.environ.get("MMLSPARK_TPU_NO_NATIVE"):
-            try:
-                fresh = os.path.exists(so) and (
-                    os.path.getmtime(so) >= os.path.getmtime(src)
-                )
-                if not fresh:
-                    tmp = so + f".tmp{os.getpid()}"
-                    try:
-                        subprocess.run(
-                            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                             "-pthread", src, "-o", tmp],
-                            check=True, capture_output=True, timeout=120,
-                        )
-                        os.replace(tmp, so)
-                        fresh = True
-                    except Exception:
-                        try:
-                            os.unlink(tmp)
-                        except OSError:
-                            pass
-                if fresh:
-                    lib = ctypes.CDLL(so)
-                    bind(lib)
-                    lib = _TimedLib(lib)
-            except Exception:
-                lib = None
-        _libs[so] = lib
+            so = _so_path(src)
+            if os.path.exists(so) or _build(src, so):
+                lib = ctypes.CDLL(so)
+                bind(lib)
+                lib = _TimedLib(lib)
+        _libs[src] = lib
         return lib
+
+
+def _so_path(src: str) -> str:
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    stem = os.path.splitext(os.path.basename(src))[0]
+    return os.path.join(os.path.dirname(src), f"_{stem}-{digest}.so")
+
+
+def _build(src: str, so: str) -> bool:
+    tmp = so + f".tmp{os.getpid()}"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+             "-pthread", src, "-o", tmp],
+            check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        obs.get_logger("mmlspark_tpu.native").warning(
+            "native build of %s failed (%r); using the numpy fallback\n%s",
+            os.path.basename(src), e, detail.decode(errors="replace")[-2000:],
+        )
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
+        return False
+    for stale in glob.glob(so.rsplit("-", 1)[0] + "-*.so"):
+        if stale != so:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    return True
 
 
 def _bind_binner(lib):
@@ -146,7 +168,7 @@ def _bind_binner(lib):
 
 def get_binner_lib():
     """The compiled binner library, or None (numpy fallback)."""
-    return load_native_lib(_SRC, _SO, _bind_binner)
+    return load_native_lib(_SRC, _bind_binner)
 
 
 def default_threads() -> int:

@@ -12,7 +12,8 @@
 // threshold; NaN on a categorical never matches the membership bitset;
 // leaf references are -(k+1).  Leaf values already include shrinkage.
 //
-// Build: g++ -O3 -shared -fPIC -std=c++17 predictor.cpp -o _predictor.so
+// Build: g++ -O3 -shared -fPIC -std=c++17 predictor.cpp -o _predictor-<sha12>.so
+// (native/__init__.py load_native_lib names the binary after this source)
 // (compiled on first use by mmlspark_tpu/native/__init__.py, ASAN pass in
 // tests/test_native_binner.py's harness pattern).
 

@@ -20,7 +20,6 @@ import numpy as np
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "predictor.cpp")
-_SO = os.path.join(_HERE, "_predictor.so")
 
 
 def _bind(lib):
@@ -42,7 +41,7 @@ def _bind(lib):
 def _get_lib():
     from mmlspark_tpu.native import load_native_lib
 
-    return load_native_lib(_SRC, _SO, _bind)
+    return load_native_lib(_SRC, _bind)
 
 
 class NativePredictor:

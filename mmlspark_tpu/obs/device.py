@@ -12,12 +12,9 @@ the common path:
 - ``device.live_buffer_bytes`` from ``jax.live_arrays()`` byte totals
   (the host-visible ledger of what obs-enabled code kept alive).
 
-Backends exposing NEITHER signal (no device ``memory_stats`` and no
-``jax.live_arrays`` attribute) degrade to a permanent no-op after the
-first probe — :func:`poll` then costs one boolean check.  A zero-byte
-``live_arrays`` total is a valid reading and never triggers the latch.
-jax is looked up in ``sys.modules`` only (the obs spine never imports
-it).
+Backends without device ``memory_stats`` (the CPU) still report the
+``live_arrays`` total; a zero-byte total is a valid reading.  jax is
+looked up in ``sys.modules`` only (the obs spine never imports it).
 
 Compile-event counters, unified with the jit_cache spans: the three
 places a program identity can cost wall time each bump
@@ -54,16 +51,14 @@ _POLL_EVERY = max(1, _env_int("MMLSPARK_TPU_OBS_DEVICE_POLL_EVERY", 4))
 
 _lock = threading.Lock()
 _poll_seq = 0
-_unsupported = False  # latched after the first stats-less probe
 _peak_seen = 0.0
 
 
 def reset() -> None:
-    """Re-arm the probe and drop the watermark (test isolation)."""
-    global _poll_seq, _unsupported, _peak_seen
+    """Re-arm the throttle and drop the watermark (test isolation)."""
+    global _poll_seq, _peak_seen
     with _lock:
         _poll_seq = 0
-        _unsupported = False
         _peak_seen = 0.0
 
 
@@ -77,9 +72,9 @@ def compile_event(kind: str) -> None:
 
 def poll(force: bool = False) -> Optional[dict]:
     """Sample device memory into gauges; returns the sample (or ``None``
-    when disabled, throttled, or the backend has no stats)."""
-    global _poll_seq, _unsupported, _peak_seen
-    if not _state.enabled or _unsupported:
+    when disabled or throttled)."""
+    global _poll_seq, _peak_seen
+    if not _state.enabled:
         return None
     with _lock:
         _poll_seq += 1
@@ -89,9 +84,6 @@ def poll(force: bool = False) -> Optional[dict]:
     if jax is None:
         return None
     sample: dict = {"devices": {}}
-    got_stats = False
-    live_supported = False  # the live_arrays SIGNAL exists (0.0 is a
-    # valid reading — never confuse value-is-zero with no-signal)
     try:
         for d in jax.local_devices():
             try:
@@ -107,34 +99,19 @@ def poll(force: bool = False) -> Optional[dict]:
             metrics.registry.gauge("device.hbm_in_use", in_use,
                                    device=label)
             metrics.registry.gauge("device.hbm_peak", peak, device=label)
-            got_stats = True
             with _lock:
                 if peak > _peak_seen:
                     _peak_seen = peak
                     metrics.registry.gauge("device.hbm_peak_seen", peak)
-        live = getattr(jax, "live_arrays", None)
-        if live is not None:
-            live_supported = True
-            nbytes = 0
-            for a in live():
-                try:
-                    nbytes += int(a.nbytes)
-                except Exception:
-                    continue
-            sample["live_buffer_bytes"] = float(nbytes)
-            metrics.registry.gauge(
-                "device.live_buffer_bytes", float(nbytes)
-            )
+        nbytes = 0
+        for a in jax.live_arrays():
+            try:
+                nbytes += int(a.nbytes)
+            except Exception:
+                continue
+        sample["live_buffer_bytes"] = float(nbytes)
+        metrics.registry.gauge("device.live_buffer_bytes", float(nbytes))
     except Exception:
-        return None
-    if not got_stats and not live_supported:
-        # NO measurement signal exists on this backend (no device
-        # memory_stats AND no jax.live_arrays attribute): latch off so
-        # the step-boundary call degrades to one boolean check.  A
-        # zero-byte live_arrays total is a real reading, not absence —
-        # it must NOT latch, or a first poll before any arrays exist
-        # would permanently disable accounting.
-        _unsupported = True
         return None
     return sample
 

@@ -74,8 +74,9 @@ def _bin_occ_kernel(
         # f64-exact "boundary < v" via the double-single pair
         below = (h < v) | ((h == v) & (l < 0))
         # exact-match hit anywhere ⟺ hit at the insertion point (sorted
-        # table); +inf pads can't hit a finite v
-        hit = hit | ((h == v) & (l == 0))
+        # table); +inf pads can't hit a finite v.  Carried as 0/1 int32:
+        # Mosaic cannot legalize an scf.for that carries a bool vector.
+        hit = hit | ((h == v) & (l == 0)).astype(jnp.int32)
         return pos + below.astype(jnp.int32), hit
 
     # headroom: pos counts boundaries below v, so it is bounded by
@@ -83,9 +84,9 @@ def _bin_occ_kernel(
     # quantize_wire_plan for the histogram-side int32 audit)
     pos, hit = jax.lax.fori_loop(
         0, n_bounds, p_body,
-        (jnp.zeros((bm, Fp), jnp.int32), jnp.zeros((bm, Fp), jnp.bool_)),
+        (jnp.zeros((bm, Fp), jnp.int32), jnp.zeros((bm, Fp), jnp.int32)),
     )
-    hit = hit & jnp.isfinite(v)
+    hit = (hit != 0) & jnp.isfinite(v)
     bins = jnp.where(ic, jnp.where(hit, pos, missing_bin), pos)
     bins = jnp.where(jnp.isnan(v_raw), missing_bin, bins)
     bins_ref[...] = bins.astype(jnp.uint8)

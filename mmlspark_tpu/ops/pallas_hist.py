@@ -47,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _PRECISIONS = {
     "highest": jax.lax.Precision.HIGHEST,
@@ -280,11 +281,33 @@ def _pallas_hist_by_leaf(
         out_shape=jax.ShapeDtypeStruct(
             (F // bf, num_leaves * 3, bf * num_bins), jnp.float32
         ),
+        compiler_params=_by_leaf_compiler_params(num_leaves, bf, num_bins),
         interpret=interpret,
     )(bins_t, vals, leaf_ids)
     # (F/bf, 3·L, bf·B) channel-major → (3, L, F, B)
     out = out.reshape(F // bf, 3, num_leaves, bf, num_bins)
     return out.transpose(1, 2, 0, 3, 4).reshape(3, num_leaves, F, num_bins)
+
+
+# Largest by-leaf accumulator (3·W · bf·B f32 elements) the v5e compiler
+# fits in its default 16 MiB of scoped VMEM next to the other tiles:
+# W=32, bf=48, B=256 (4.5 MiB) compiles with uint8 bins; one step past it
+# — the same tile with 4-byte bins, or B=512 at bf≥32 — is refused
+# ("Scoped allocation with size 16.41M and limit 16.00M exceeded scoped
+# vmem limit").
+_ACC_BUDGET_ELS = 3 * 32 * 48 * 256
+
+
+def _by_leaf_compiler_params(num_leaves: int, bf: int, num_bins: int):
+    """``None`` (the compiler's defaults) wherever the accumulator is
+    inside the budget ``_prep_by_leaf_chunk`` sizes ``bf`` to.  Only when
+    even the minimum block bf=8 is over it (W·B > 49,152: ≥249-leaf
+    depthwise windows at ≥512 bins) is the scoped-VMEM limit raised to
+    hold the tile's four live copies."""
+    acc_els = 3 * num_leaves * bf * num_bins
+    if acc_els <= _ACC_BUDGET_ELS:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=16 * acc_els + (8 << 20))
 
 
 def _prep_by_leaf_chunk(
@@ -318,12 +341,16 @@ def _prep_by_leaf_chunk(
     # Feature-block choice minimizes PADDED width: bf=32 on F=40 (the
     # criteo schema) tiles to 64 — 37.5% of every pass histogramming
     # padding; F=136 (the MSLR schema) tiles to 160 where bf=48 gives 144.
-    # Candidates stay ≤ 48 (inside the VMEM budget the bf-sweep
-    # established; 64 blew it); ties prefer the LARGER block (fewer grid
-    # steps amortize the per-block leaf-side rhs build better).
-    cands = sorted({bf, 24, 40, 48, max(8, min(48, _round_up(F, 8)))})
+    # Any multiple of 8 is a legal block height for uint8 as for int32
+    # bins (Mosaic pads the 8-bit (32, 128) tile itself).  The cap is
+    # VMEM: the grid-resident (3·W, bf·B) f32 accumulator, its loop carry
+    # and the double-buffered output block share the 16 MiB scoped default
+    # with the bins block — see _ACC_BUDGET_ELS.  Ties prefer the LARGER
+    # block (fewer grid steps amortize the per-block leaf-side rhs build).
+    cap = min(48, max(8, _ACC_BUDGET_ELS // (3 * num_leaves * num_bins) // 8 * 8))
+    cands = {bf, 24, 40, 48, max(8, min(48, _round_up(F, 8)))}
     bf = min(
-        (c for c in cands if c <= 48),
+        {c for c in cands if c <= cap} or {cap},
         key=lambda c: (_round_up(F, c), -c),
     )
     # VMEM guard: (num_bins, rm) one-hot tiles were swept at B=256.  rm
@@ -472,6 +499,9 @@ def _pallas_hist_by_leaf_nibble(
         ],
         out_specs=pl.BlockSpec((1, M, bf * _NIBBLE_LO), lambda j, i: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((F // bf, M, bf * _NIBBLE_LO), jnp.float32),
+        compiler_params=_by_leaf_compiler_params(
+            num_leaves, bf, H * _NIBBLE_LO
+        ),
         interpret=interpret,
     )(bins_t, vals, leaf_ids)
     # (F/bf, 3·W·H, bf·LO) → (3, W, F, H·LO) → slice the real bin range
@@ -689,6 +719,7 @@ def _pallas_hist_by_leaf_int(
         out_shape=jax.ShapeDtypeStruct(
             (F // bf, num_leaves * 3, bf * num_bins), jnp.int32
         ),
+        compiler_params=_by_leaf_compiler_params(num_leaves, bf, num_bins),
         interpret=interpret,
     )(bins_t, vals, leaf_ids)
     out = out.reshape(F // bf, 3, num_leaves, bf, num_bins)

@@ -52,10 +52,18 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# SMEM is ~a few hundred KB/core: four (TT, S) int32 tables + weights
-# must fit with headroom.  Above this entry count the resolver falls
-# back to the lax packed path.
-SMEM_ENTRY_BUDGET = 64 * 1024
+# SMEM is 1 MiB per core on v5e and the compiler keeps ~1 KiB for itself
+# (XLA:TPU's words at the edge: "Used 1.00M of 1.00M smem. Exceeded smem
+# capacity by 1.1K").  A 2-D 32-bit SMEM array is padded to (8, 128)
+# words, so a (TT, S) table costs far more than TT·S entries — the
+# budget is on the PADDED bytes of every scalar table together.  Above
+# it the resolver falls back to the lax packed path.
+SMEM_BUDGET_BYTES = (1 << 20) - 16 * 1024
+
+
+def _smem_table_bytes(rows: int, cols: int) -> int:
+    """Bytes one (rows, cols) 32-bit table occupies in SMEM."""
+    return _round_up(max(rows, 1), 8) * _round_up(max(cols, 1), 128) * 4
 
 
 class PallasForest(NamedTuple):
@@ -76,10 +84,11 @@ class PallasForest(NamedTuple):
 
 def pallas_supported(num_trees: int, num_class: int, num_steps: int,
                      has_cats: bool) -> bool:
-    """Can this forest run on the kernel?  (numeric-only + SMEM budget)"""
-    return (not has_cats) and (
-        num_trees * num_class * num_steps <= SMEM_ENTRY_BUDGET
-    )
+    """Can this forest run on the kernel?  (numeric-only + SMEM budget:
+    four (TT, S) split tables and the (TT, 1) weights)"""
+    tt = num_trees * num_class
+    smem = 4 * _smem_table_bytes(tt, num_steps) + _smem_table_bytes(tt, 1)
+    return (not has_cats) and smem <= SMEM_BUDGET_BYTES
 
 
 def build_pallas_forest(host_trees, tree_weights, T: int) -> PallasForest:
@@ -124,12 +133,17 @@ def _predict_kernel(bins_ref, leafv_ref, feat_ref, thr_ref, sleaf_ref,
             thr = thr_ref[idx, s]
             dl = dleft_ref[idx, s]
             fcol = bins_ref[pl.ds(f, 1), :]          # (1, bm) int32
-            miss = fcol == num_bins - 1
-            go_left = jnp.where(miss, dl == 1, fcol <= thr)
+            # direction as an int32 select: Mosaic has no select over
+            # bool vectors ("Unsupported target bitwidth for truncation"
+            # i8 → i1 on v5e), so the missing-bin default (scalar 1 - dl)
+            # and the threshold compare meet as 0/1 integers
+            go_right = jnp.where(
+                fcol == num_bins - 1, 1 - dl, (fcol > thr).astype(jnp.int32)
+            )
             # rows sitting in the split leaf that go right take the new
             # leaf id s+1 (LightGBM leaf relabelling); inactive steps
             # have sleaf == -1 and never match
-            move = (leaf == sleaf) & (~go_left)
+            move = (leaf == sleaf) & (go_right == 1)
             return jnp.where(move, s + 1, leaf)
 
         leaf = lax.fori_loop(0, S, step_body, jnp.zeros((1, bm), jnp.int32))
@@ -230,12 +244,15 @@ class MultiPallasForest(NamedTuple):
 
 def multi_pallas_supported(parts) -> bool:
     """``parts`` = per-model (T, K, S, has_cats) tuples; the concatenated
-    tables must fit the same SMEM budget as the standalone kernel."""
+    tables — four (TTtot, Smax) split tables and four (TTtot, 1) columns
+    (weight, model id, class slot, bin count) — must fit the same SMEM
+    budget as the standalone kernel."""
     if any(p[3] for p in parts):
         return False
     s_max = max((p[2] for p in parts), default=0)
     tt_tot = sum(p[0] * p[1] for p in parts)
-    return tt_tot * s_max <= SMEM_ENTRY_BUDGET
+    smem = 4 * _smem_table_bytes(tt_tot, s_max) + 4 * _smem_table_bytes(tt_tot, 1)
+    return smem <= SMEM_BUDGET_BYTES
 
 
 def build_multi_pallas_forest(models) -> MultiPallasForest:
@@ -314,9 +331,11 @@ def _multi_predict_kernel(bins_ref, mid_ref, leafv_ref, feat_ref, thr_ref,
             thr = thr_ref[idx, s]
             dl = dleft_ref[idx, s]
             fcol = bins_ref[pl.ds(f, 1), :]          # (1, bm) int32
-            miss = fcol == nb - 1
-            go_left = jnp.where(miss, dl == 1, fcol <= thr)
-            move = (leaf == sleaf) & (~go_left)
+            # int32 select, not a bool one (see _predict_kernel)
+            go_right = jnp.where(
+                fcol == nb - 1, 1 - dl, (fcol > thr).astype(jnp.int32)
+            )
+            move = (leaf == sleaf) & (go_right == 1)
             return jnp.where(move, s + 1, leaf)
 
         leaf = lax.fori_loop(0, S, step_body, jnp.zeros((1, bm), jnp.int32))

@@ -133,15 +133,8 @@ def initialize_distributed(
         # Single process (or TPU-pod auto-detection handled by jax itself on
         # Cloud TPU VMs). Nothing to rendezvous.
         return False
-    # CPU pods (the 2-real-process smoke topology): the CPU backend only
-    # runs cross-process computations over its gloo collectives layer,
-    # which must be selected BEFORE the backend initializes.  Harmless on
-    # TPU (the knob only affects the CPU client); a no-op when this jax
-    # build predates the option or a backend is already up.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    # (CPU pods — the 2-real-process smoke topology — run cross-process
+    # computations over gloo, the installed jax's default CPU collectives.)
     jax.distributed.initialize(
         coordinator_address=ctx.coordinator_address,
         num_processes=ctx.num_processes,

@@ -114,24 +114,3 @@ def is_mesh_2d(mesh: Optional[Mesh]) -> bool:
         and DATA_AXIS in mesh.axis_names
         and FEATURE_AXIS in mesh.axis_names
     )
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; before that it
-    lived at ``jax.experimental.shard_map.shard_map`` with the same knob
-    named ``check_rep``.  Single call site for both so the engine never
-    version-sniffs inline.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )

@@ -153,7 +153,14 @@ class FleetRouter:
         The child gets ``MMLSPARK_TPU_REPLICA_ID=r<i>`` so its obs
         export/blackbox files are namespaced per replica (obs/_state.py)
         — N same-host replicas never clobber one another's telemetry.
+
+        The router process must not hold the accelerator itself (one
+        process per chip): a caller that trained its models in this
+        process on a TPU is refused — see serve/README.md.
         """
+        from mmlspark_tpu.core.env import refuse_child_on_held_chip
+
+        refuse_child_on_held_chip("FleetRouter.spawn_replica")
         with self._lock:
             replica_id = f"r{len(self.replicas)}"
         cmd = [sys.executable, "-m", "mmlspark_tpu.serve.replica",

@@ -7,8 +7,8 @@ Three contracts, cheap enough for every CI run:
 - the committed RATCHET.json still passes against the committed ledgers
   (re-blessing and ledger updates travel together);
 - the seeded-regression fixture (tests/fixtures/ratchet_regression —
-  BENCH_r05's steady step inflated past its band) makes the ratchet
-  exit 1, so the CI red path is itself tested.
+  the hierarchical merge's inter-host byte count inflated past its 5%
+  band) makes the ratchet exit 1, so the CI red path is itself tested.
 
 None of these run the benches — the smoke replay (``--smoke``) is the
 CI job's own leg.
@@ -34,8 +34,7 @@ class TestLedgerSchemas:
     def test_committed_ledgers_validate(self):
         ledgers, errors = br.load_ledgers(REPO)
         assert errors == []
-        # every schema found a ledger (BENCH_r*.json collapses to one)
-        assert set(ledgers) == set(br.LEDGER_SCHEMAS)
+        assert set(ledgers) == set(br.LEDGER_SCHEMAS)  # every schema found one
 
     def test_missing_key_is_an_error(self):
         obj = json.load(open(os.path.join(REPO, "PREDICT_BENCH.json")))
@@ -64,13 +63,13 @@ class TestRatchet:
     def test_seeded_regression_exits_nonzero(self):
         assert br.main(["--ledger-dir", FIXTURE]) == 1
 
-    def test_regression_is_the_train_gate(self):
+    def test_regression_is_the_comms_bytes_gate(self):
         ledgers, _ = br.load_ledgers(FIXTURE)
         with open(br.ratchet_path(FIXTURE)) as f:
             ratchet = json.load(f)
         bad = [r["id"] for r in br.evaluate(ledgers, ratchet)
                if not r["ok"] and r["enforced"]]
-        assert bad == ["train.steady_step_s"]
+        assert bad == ["comms.inter_host_bytes"]
 
     def test_update_is_idempotent_against_committed_ledgers(self):
         # RATCHET.json was produced by --update from these exact ledgers;

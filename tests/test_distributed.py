@@ -54,10 +54,8 @@ class TestShardedHistogram:
 
         ref = np.asarray(build_histogram(jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(mask), B))
 
-        from mmlspark_tpu.parallel.mesh import shard_map_compat
-
         mesh = default_mesh()
-        sharded = shard_map_compat(
+        sharded = jax.shard_map(
             lambda b, v, m: build_histogram(b, v, m, B, axis_name="data"),
             mesh=mesh,
             in_specs=(P("data", None), P(None, "data"), P("data")),

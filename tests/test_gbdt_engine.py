@@ -161,6 +161,40 @@ class TestByLeafKernels:
         b = np.asarray(pallas_hist_by_leaf_nibble_chunk(bins, vals, leaf, W, B))
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
+    @pytest.mark.parametrize("F", [39, 64, 136])
+    @pytest.mark.parametrize("dtype,B", [("uint8", 256), ("int32", 512)])
+    def test_block_shapes_obey_what_the_v5e_compile_taught(self, F, dtype, B):
+        """The block choice of ``_prep_by_leaf_chunk``, against the rules
+        the Mosaic compile for v5e established (PR 21): (a) a 1-byte bins
+        block needs no more than the 8-row alignment 4-byte bins need —
+        bf=24/40/48 all lower; (b) the limit is scoped VMEM, reached when
+        the (3·W, bf·B) accumulator passes ``_ACC_BUDGET_ELS``; (c) the
+        bins stay at their HBM width (the kernel widens in VMEM)."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops.pallas_hist import (
+            _ACC_BUDGET_ELS,
+            _prep_by_leaf_chunk,
+        )
+
+        n = 4096
+        for W in (8, 32):
+            bins_t, _, leaf_row, bm, bf, rm, F_out, _ = _prep_by_leaf_chunk(
+                jnp.zeros((F, n), dtype), jnp.zeros((3, n)),
+                jnp.zeros((n,), jnp.int32), W, B, 16384, 32, 1024, True,
+            )
+            Fp, n_pad = bins_t.shape
+            assert F_out == F and bins_t.dtype == jnp.dtype(dtype)
+            assert bf % 8 == 0 and 8 <= bf <= 48
+            assert Fp % bf == 0 and Fp - F < bf
+            assert 3 * W * bf * B <= _ACC_BUDGET_ELS
+            assert rm >= 256 and rm & (rm - 1) == 0
+            assert bm % rm == 0 and n_pad % bm == 0
+            assert leaf_row.shape == (1, n_pad)
+        if B == 256:
+            # the swept blocks of the benchmarked schemas are unchanged
+            assert bf == {39: 40, 64: 32, 136: 48}[F]
+
     def test_by_leaf_dispatch_through_build_histogram(self):
         """build_histogram_by_leaf's pallas dispatch (nibble for small W at
         B>128) must agree with the scatter reference backend."""

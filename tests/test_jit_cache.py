@@ -6,11 +6,15 @@ respected and an opt-out.
 """
 
 import os
+import sys
 
 import jax
 import pytest
 
 import mmlspark_tpu.core.jit_cache as jc
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -21,37 +25,93 @@ def _reset_state(monkeypatch):
     jax.config.update("jax_compilation_cache_dir", old)
 
 
-def test_default_dir_honors_xdg(monkeypatch):
-    monkeypatch.delenv("MMLSPARK_TPU_COMPILE_CACHE_DIR", raising=False)
-    monkeypatch.setenv("XDG_CACHE_HOME", "/tmp/xdgtest")
-    assert jc.default_cache_dir() == "/tmp/xdgtest/mmlspark_tpu/jit"
-    monkeypatch.setenv("MMLSPARK_TPU_COMPILE_CACHE_DIR", "/tmp/explicit")
-    assert jc.default_cache_dir() == "/tmp/explicit"
+def test_dir_is_env_else_fixed_in_checkout(monkeypatch):
+    """The directory contract: ``JAX_COMPILATION_CACHE_DIR`` when set,
+    else one fixed path inside the checkout — for all artifact kinds."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/explicit")
+    assert jc.cache_dir() == "/tmp/explicit"
+    assert jc._artifact_path("aot", "k") == "/tmp/explicit/aot-k"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    jax.config.update("jax_compilation_cache_dir", None)
+    assert jc.cache_dir() == os.path.join(REPO, ".jax_cache")
+    # nothing about the location depends on the home directory
+    monkeypatch.setenv("HOME", "/tmp/elsewhere")
+    assert jc.cache_dir() == os.path.join(REPO, ".jax_cache")
+
+
+def test_no_cache_path_from_tempfile_pid_or_clock():
+    """Grep-style, over the code (not the prose) of the two cache
+    modules: a location built from a temp dir, the home directory, a pid
+    or the clock never hits in the next process.  ``getpid`` may only
+    name the scratch file of an atomic tmp+rename write."""
+    import ast
+
+    for mod in ("jit_cache.py", "trace_cache.py"):
+        path = os.path.join(REPO, "mmlspark_tpu", "core", mod)
+        with open(path) as f:
+            src = f.read()
+        tree = ast.parse(src)
+        imported = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                imported |= {a.name.split(".")[0] for a in node.names}
+            elif isinstance(node, ast.ImportFrom):
+                imported.add((node.module or "").split(".")[0])
+        assert not imported & {"tempfile", "time", "datetime", "uuid"}, mod
+        for stmt in ast.walk(tree):
+            if not isinstance(stmt, ast.stmt) or isinstance(
+                stmt, (ast.FunctionDef, ast.ClassDef, ast.If, ast.Try,
+                       ast.With, ast.For, ast.While)
+            ):
+                continue
+            names = {
+                n.attr for n in ast.walk(stmt) if isinstance(n, ast.Attribute)
+            }
+            where = f"{mod}:{stmt.lineno}"
+            assert not names & {"expanduser", "gettempdir", "mkdtemp"}, where
+            if "getpid" in names:
+                assert "tmp" in ast.get_source_segment(src, stmt), where
 
 
 def test_opt_out(monkeypatch):
     monkeypatch.setenv("MMLSPARK_TPU_NO_COMPILE_CACHE", "1")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     jax.config.update("jax_compilation_cache_dir", None)
     assert jc.enable_compile_cache() is False
     assert jax.config.jax_compilation_cache_dir is None
 
 
-def test_enables_and_is_idempotent(monkeypatch, tmp_path):
+def test_env_dir_is_used_and_config_untouched(monkeypatch, tmp_path):
+    """Env set → that directory, and no ``jax.config`` write of any
+    directory; the cache-everything threshold applies all the same."""
     monkeypatch.delenv("MMLSPARK_TPU_NO_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    monkeypatch.setenv("MMLSPARK_TPU_COMPILE_CACHE_DIR", str(tmp_path / "jit"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jit"))
     jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     assert jc.enable_compile_cache() is True
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "jit")
+    assert jax.config.jax_compilation_cache_dir is None
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     assert os.path.isdir(tmp_path / "jit")
     assert jc.enable_compile_cache() is True  # second call no-ops
 
 
-def test_respects_user_configured_dir(monkeypatch):
+def test_unset_env_points_jax_at_the_checkout_dir(monkeypatch):
     monkeypatch.delenv("MMLSPARK_TPU_NO_COMPILE_CACHE", raising=False)
-    jax.config.update("jax_compilation_cache_dir", "/tmp/user_choice")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
     assert jc.enable_compile_cache() is True
-    assert jax.config.jax_compilation_cache_dir == "/tmp/user_choice"
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        REPO, ".jax_cache"
+    )
+
+
+def test_respects_user_configured_dir(monkeypatch, tmp_path):
+    monkeypatch.delenv("MMLSPARK_TPU_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "mine"))
+    assert jc.enable_compile_cache() is True
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "mine")
+    assert jc.cache_dir() == str(tmp_path / "mine")  # artifacts follow
 
 
 def test_train_enables_cache(monkeypatch, tmp_path):
@@ -61,14 +121,12 @@ def test_train_enables_cache(monkeypatch, tmp_path):
     from mmlspark_tpu.engine.booster import Dataset, train
 
     monkeypatch.delenv("MMLSPARK_TPU_NO_COMPILE_CACHE", raising=False)
-    monkeypatch.setenv("MMLSPARK_TPU_COMPILE_CACHE_DIR", str(tmp_path / "jc"))
-    jax.config.update("jax_compilation_cache_dir", None)
     rng = np.random.default_rng(0)
     X = rng.normal(size=(64, 3))
     y = (X[:, 0] > 0).astype(np.float64)
     train(dict(objective="binary", num_iterations=2, num_leaves=4,
                min_data_in_leaf=2, max_bin=15), Dataset(X, y))
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "jc")
+    assert jc._done
 
 
 def test_prune_cache_dir_lru(tmp_path):
@@ -127,40 +185,78 @@ def test_freshly_hit_entry_survives_eviction(tmp_path):
     record_cache_hit(str(d / "gone.bin"))
 
 
-def test_hit_recorder_wraps_jax_cache(monkeypatch, tmp_path):
-    """The hit hook is installed by enable_compile_cache and touches the
-    entry file when jax's getter reports a hit (idempotent wrap)."""
-    import jax._src.compilation_cache as cc
+def test_cache_events_feed_obs_counters():
+    """Hit/miss accounting rides jax's public monitoring events (no
+    private-module patching): one event, one counter tick."""
+    import jax.monitoring
 
-    import mmlspark_tpu.core.jit_cache as jc
+    from mmlspark_tpu import obs
 
-    monkeypatch.delenv("MMLSPARK_TPU_NO_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    cache_dir = tmp_path / "jit"
-    monkeypatch.setenv("MMLSPARK_TPU_COMPILE_CACHE_DIR", str(cache_dir))
-    jax.config.update("jax_compilation_cache_dir", None)
+    obs.enable()
+    try:
+        jc._listen_for_cache_events()
+        before = jc.cache_counters()
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        after = jc.cache_counters()
+    finally:
+        obs.disable()
+    assert after["hit"] - before["hit"] == 1
+    assert after["miss"] - before["miss"] == 2
+    with open(jc.__file__) as f:
+        assert "jax._src" not in f.read()
 
-    calls = []
 
-    def fake_get(cache_key, compile_options, backend):
-        calls.append(cache_key)
-        return object(), 1  # a "hit"
+_TWO_PROCESS_WORKER = """
+import json, sys, warnings
+sys.path.insert(0, {repo!r})
+warnings.simplefilter("always")
+import jax, jax.numpy as jnp
+from mmlspark_tpu import obs
+from mmlspark_tpu.core import jit_cache as jc
+obs.enable()
+assert jc.enable_compile_cache()
+with warnings.catch_warnings(record=True) as caught:
+    warnings.simplefilter("always")
+    out = jax.jit(lambda x: jnp.tanh(x) @ x.T + 3.0)(jnp.ones((64, 64)))
+    out.block_until_ready()
+print(json.dumps({{
+    "counters": jc.cache_counters(),
+    "warnings": [str(w.message) for w in caught],
+    "configured": jax.config.jax_compilation_cache_dir,
+}}))
+"""
 
-    monkeypatch.setattr(cc, "get_executable_and_time", fake_get)
-    assert jc.enable_compile_cache() is True
-    wrapped = cc.get_executable_and_time
-    assert getattr(wrapped, "_mmlspark_tpu_touch", False)
 
-    entry = cache_dir / "k123-cache"
-    entry.write_bytes(b"blob")
-    old = entry.stat().st_mtime - 500
-    os.utime(entry, (old, old))
-    exe, t = wrapped("k123", None, None)
-    assert exe is not None and calls == ["k123"]
-    assert entry.stat().st_mtime > old + 400  # touched on hit
-    # re-install is a no-op (no double wrap)
-    jc._install_hit_recorder(str(cache_dir))
-    assert cc.get_executable_and_time is wrapped
+def test_second_process_hits_the_shared_cache_dir(tmp_path):
+    """Two processes sharing one ``JAX_COMPILATION_CACHE_DIR``: the first
+    writes (miss), the second READS (hit > 0) — and jax reports no
+    "Error reading persistent compilation cache entry"."""
+    import json
+    import subprocess
+
+    script = tmp_path / "w.py"
+    script.write_text(_TWO_PROCESS_WORKER.format(repo=REPO))
+    env = dict(os.environ)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "shared")
+    env.pop("MMLSPARK_TPU_NO_COMPILE_CACHE", None)
+
+    def leg():
+        r = subprocess.run(
+            [sys.executable, str(script)], env=env, capture_output=True,
+            text=True, timeout=240,
+        )
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert "Error reading persistent compilation cache" not in r.stderr
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    a, b = leg(), leg()
+    for rep in (a, b):
+        assert rep["configured"] == str(tmp_path / "shared")
+        assert not [w for w in rep["warnings"] if "compilation cache" in w]
+    assert a["counters"]["miss"] > 0 and a["counters"]["hit"] == 0
+    assert b["counters"]["hit"] > 0 and b["counters"]["miss"] == 0
 
 
 def test_prune_evicts_oldest_across_artifact_kinds(tmp_path):
@@ -185,15 +281,17 @@ def test_prune_evicts_oldest_across_artifact_kinds(tmp_path):
     assert sorted(f.name for f in d.iterdir()) == ["aot-new", "jaxentry-cache"]
 
 
-def test_aot_roundtrip_across_process_boundary(tmp_path):
+@pytest.mark.parametrize("num_devices", [1, 8])
+def test_aot_roundtrip_across_process_boundary(tmp_path, num_devices):
     """The ISSUE 11 cold-start contract end to end: process A compiles a
     padded predict and persists the ``aot-*`` executable; process B —
     sharing only the cache DIR, not the process — deserializes it (AOT
-    hits, zero misses) and reproduces the scores bitwise."""
+    hits, zero misses), CALLS it, and reproduces the scores bitwise.  On
+    the 8-device host the one-device program must load onto the device
+    it was compiled for, not onto all eight."""
     import json
     import pickle
     import subprocess
-    import sys
 
     import numpy as np
 
@@ -210,11 +308,11 @@ def test_aot_roundtrip_across_process_boundary(tmp_path):
     pkl = tmp_path / "booster.pkl"
     pkl.write_bytes(pickle.dumps(booster))
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
-    env["MMLSPARK_TPU_COMPILE_CACHE_DIR"] = str(tmp_path / "jit")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jit")
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)  # children: plain single-device cpu
+    env["JAX_NUM_CPU_DEVICES"] = str(num_devices)
+    env.pop("XLA_FLAGS", None)
 
     def leg(name):
         out_npy = tmp_path / f"{name}.npy"
@@ -222,7 +320,7 @@ def test_aot_roundtrip_across_process_boundary(tmp_path):
             [sys.executable, "-m", "tools.bench_predict",
              "--cold-child", str(pkl), "--bucket", "8",
              "--out-npy", str(out_npy)],
-            cwd=repo, env=env, capture_output=True, text=True, timeout=300,
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
         )
         assert r.returncode == 0, r.stderr[-2000:]
         return json.loads(r.stdout.strip().splitlines()[-1]), np.load(out_npy)
@@ -235,3 +333,44 @@ def test_aot_roundtrip_across_process_boundary(tmp_path):
     b, out_b = leg("from_disk")
     assert b["aot_misses"] == 0 and b["aot_hits"] >= a["aot_misses"]
     np.testing.assert_array_equal(out_a, out_b)
+
+
+def test_torn_aot_artifact_is_deleted_and_missed(monkeypatch, tmp_path):
+    """A truncated ``aot-*`` blob (disk rot, a copy cut short) is a miss
+    that removes itself; it never raises into the serving path."""
+    import pickle
+
+    monkeypatch.delenv("MMLSPARK_TPU_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    blob = pickle.dumps((b"x" * 4096, None, None))
+    (tmp_path / "aot-torn").write_bytes(blob[: len(blob) // 2])
+    assert jc.load_aot("torn", jax.devices()[:1]) is None
+    assert not (tmp_path / "aot-torn").exists()
+
+
+def test_torn_trace_blob_is_reexported(monkeypatch, tmp_path):
+    import numpy as np
+
+    import mmlspark_tpu.engine.booster as bo
+    from mmlspark_tpu.core import trace_cache as tc
+
+    monkeypatch.delenv("MMLSPARK_TPU_NO_TRACE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(bo, "_TRACE_CACHE_MIN_WORK", 0)
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(256, 3))
+    y = (X[:, 0] > 0).astype(np.float64)
+    params = dict(objective="binary", num_iterations=2, num_leaves=4,
+                  min_data_in_leaf=2, max_bin=15)
+    p1 = bo.train(params, bo.Dataset(X, y)).predict(X)
+    (blob,) = tmp_path.glob("*.jaxexp")
+    good = blob.read_bytes()
+    blob.write_bytes(good[: len(good) // 2])
+    tc._EXP_MEMO.clear()
+    bo._SCAN_CACHE.clear()
+    p2 = bo.train(params, bo.Dataset(X, y)).predict(X)
+    np.testing.assert_array_equal(p1, p2)
+    # rewritten whole: the file on disk deserializes again
+    from jax import export
+
+    export.deserialize(bytearray(blob.read_bytes()))

@@ -270,3 +270,31 @@ class TestCatTransformEdgeCases:
         bm = BinMapper(max_bin=15, categorical_features=(-1,)).fit(X)
         nat, ref = self._both(bm, X)
         np.testing.assert_array_equal(nat, ref)
+
+
+class TestBuiltFromSource:
+    def test_binary_is_named_after_its_source(self, tmp_path, monkeypatch):
+        """A copied tree resets mtimes, so a stale binary must never be
+        trusted by age: the library is named after a hash of its source,
+        an edited source builds under another name, and the binary of the
+        old source is removed."""
+        import shutil
+
+        import mmlspark_tpu.native as native
+
+        if shutil.which("g++") is None:
+            pytest.skip("no g++ toolchain")
+        src = tmp_path / "binner.cpp"
+        shutil.copy(os.path.join(os.path.dirname(native.__file__), "binner.cpp"), src)
+        monkeypatch.delenv("MMLSPARK_TPU_NO_NATIVE", raising=False)
+        assert native.load_native_lib(str(src), native._bind_binner) is not None
+        first = sorted(p.name for p in tmp_path.glob("_binner-*.so"))
+        assert len(first) == 1
+        # a stale binary that is NEWER than an edited source is not used
+        src.write_text(src.read_text() + "\n// edited\n")
+        os.utime(src, (1, 1))
+        native._libs.pop(str(src))
+        assert native.load_native_lib(str(src), native._bind_binner) is not None
+        second = sorted(p.name for p in tmp_path.glob("_binner-*.so"))
+        assert len(second) == 1 and second != first
+        native._libs.pop(str(src))

@@ -22,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _train_once(monkeypatch, tmp_path, cache_dir):
     monkeypatch.delenv("MMLSPARK_TPU_NO_TRACE_CACHE", raising=False)
-    monkeypatch.setenv("MMLSPARK_TPU_TRACE_CACHE_DIR", str(cache_dir))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
     monkeypatch.setattr(bo, "_TRACE_CACHE_MIN_WORK", 0)
     rng = np.random.default_rng(0)
     X = rng.normal(size=(512, 4))
@@ -38,12 +38,13 @@ def test_export_written_and_replayed(monkeypatch, tmp_path):
     p1 = _train_once(monkeypatch, tmp_path, cache)
     blobs = list(cache.glob("*.jaxexp"))
     assert blobs, "no exported program written"
-    # memo cleared → the next fit must REPLAY the blob (mtime untouched)
+    # memo cleared → the next fit must REPLAY the blob (same inode: a
+    # re-export goes through tmp+rename and would replace the file)
     tc._EXP_MEMO.clear()
-    before = {b: b.stat().st_mtime_ns for b in blobs}
+    before = {b: b.stat().st_ino for b in blobs}
     p2 = _train_once(monkeypatch, tmp_path, cache)
     np.testing.assert_array_equal(p1, p2)
-    after = {b: b.stat().st_mtime_ns for b in cache.glob("*.jaxexp")}
+    after = {b: b.stat().st_ino for b in cache.glob("*.jaxexp")}
     assert before == after  # replayed, not re-exported
 
 
@@ -91,7 +92,7 @@ def test_fresh_process_replays_without_retracing(tmp_path):
     """))
     env = {"PATH": "/usr/bin:/bin:/usr/local/bin", "HOME": "/root",
            "JAX_PLATFORMS": "cpu", "PYTHONDONTWRITEBYTECODE": "1",
-           "MMLSPARK_TPU_TRACE_CACHE_DIR": str(cache),
+           "JAX_COMPILATION_CACHE_DIR": str(cache),
            "MMLSPARK_TPU_NO_COMPILE_CACHE": "1"}
     outs = []
     for _ in range(2):
@@ -105,7 +106,7 @@ def test_fresh_process_replays_without_retracing(tmp_path):
 
 def test_opt_out(monkeypatch, tmp_path):
     monkeypatch.setenv("MMLSPARK_TPU_NO_TRACE_CACHE", "1")
-    monkeypatch.setenv("MMLSPARK_TPU_TRACE_CACHE_DIR", str(tmp_path / "t2"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "t2"))
     monkeypatch.setattr(bo, "_TRACE_CACHE_MIN_WORK", 0)
     rng = np.random.default_rng(1)
     X = rng.normal(size=(256, 3))
@@ -121,7 +122,7 @@ def test_mesh_program_exports_and_replays(monkeypatch, tmp_path):
     replays the blob bit-identically."""
     cache = tmp_path / "traces_mesh"
     monkeypatch.delenv("MMLSPARK_TPU_NO_TRACE_CACHE", raising=False)
-    monkeypatch.setenv("MMLSPARK_TPU_TRACE_CACHE_DIR", str(cache))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
     monkeypatch.setattr(bo, "_TRACE_CACHE_MIN_WORK", 0)
     rng = np.random.default_rng(0)
     X = rng.normal(size=(1024, 6))
@@ -133,10 +134,10 @@ def test_mesh_program_exports_and_replays(monkeypatch, tmp_path):
     blobs = list(cache.glob("*.jaxexp"))
     assert blobs, "no exported program written for the mesh path"
     tc._EXP_MEMO.clear()
-    before = {b: b.stat().st_mtime_ns for b in blobs}
+    before = {b: b.stat().st_ino for b in blobs}
     p2 = bo.train(params, bo.Dataset(X, y)).predict(X)
     np.testing.assert_array_equal(p1, p2)
-    after = {b: b.stat().st_mtime_ns for b in cache.glob("*.jaxexp")}
+    after = {b: b.stat().st_ino for b in cache.glob("*.jaxexp")}
     assert before == after  # replayed, not re-exported
 
 
@@ -200,10 +201,10 @@ def test_process_local_trace_cache_two_process_bit_identity(tmp_path):
     script.write_text(_PL_TRACE_WORKER.format(repo=REPO))
     base_env = {"PATH": "/usr/bin:/bin:/usr/local/bin", "HOME": "/root",
                 "JAX_PLATFORMS": "cpu", "PYTHONDONTWRITEBYTECODE": "1",
-                "MMLSPARK_TPU_TRACE_CACHE_DIR": str(cache),
+                "JAX_COMPILATION_CACHE_DIR": str(cache),
                 "MMLSPARK_TPU_NO_COMPILE_CACHE": "1"}
     models = []
-    mtimes = []
+    inodes = []
     for round_i in range(2):
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
@@ -225,8 +226,8 @@ def test_process_local_trace_cache_two_process_bit_identity(tmp_path):
         models.append(outs[0]["model"])
         blobs = sorted(cache.glob("*.jaxexp"))
         assert blobs, "no exported sharded program written"
-        mtimes.append({b: b.stat().st_mtime_ns for b in blobs})
+        inodes.append({b: b.stat().st_ino for b in blobs})
     # warm round replayed the same blobs (no re-export) and trained the
     # bit-identical model
     assert models[0] == models[1]
-    assert mtimes[0] == mtimes[1]
+    assert inodes[0] == inodes[1]

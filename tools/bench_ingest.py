@@ -4,9 +4,9 @@ Prints ONE JSON line and (without ``--smoke``) writes it to
 ``INGEST_BENCH.json``:
     {"metric": ..., "value": N, "unit": "s", "host_total_s": N, ...}
 
-Shape: the BENCH_r05 all-numeric config — 262,144 rows x 64 f32 features,
-max_bin=255 — whose HOST binning cost the r5 bench reports as ~1.12 s
-(fit 0.73 + transform 0.39).  The streamed path replaces both with:
+Shape: the r5 (2026-07-30) all-numeric bench config — 262,144 rows x 64
+f32 features, max_bin=255 — whose HOST binning cost that run reported as
+~1.12 s (fit 0.73 + transform 0.39).  The streamed path replaces both with:
 
 - a chunked SKETCH pass (host, mergeable KLL — paid once per dataset,
   overlapped with shard I/O by the prefetch thread), and
@@ -63,7 +63,7 @@ N_ROWS = 262_144
 N_FEATURES = 64
 MAX_BIN = 255
 CHUNK_ROWS = 32_768
-R05_HOST_BINNING_S = 1.12  # BENCH_r05 numeric: fit 0.73 + transform 0.39
+R05_HOST_BINNING_S = 1.12  # r5 bench, numeric: fit 0.73 + transform 0.39
 # ISSUE-10 record for the same leg, for cross-run context: the host legs
 # (unchanged pure-numpy code) calibrate box drift between records.
 R10_STEADY_S = 2.52

@@ -152,8 +152,10 @@ def run(args) -> int:
     tmp = tempfile.mkdtemp(prefix="bench_loop_")
     os.environ["MMLSPARK_TPU_OBS_FLIGHT_DIR"] = os.path.join(tmp, "flight")
     os.environ["MMLSPARK_TPU_OBS_FLIGHT_MIN_INTERVAL_S"] = "0"
+    from tools import empty_cache_dir
+
     os.environ.setdefault(
-        "MMLSPARK_TPU_COMPILE_CACHE_DIR", os.path.join(tmp, "jit_cache")
+        "JAX_COMPILATION_CACHE_DIR", empty_cache_dir("jit_cache_loop")
     )
 
     from mmlspark_tpu import obs

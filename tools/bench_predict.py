@@ -159,13 +159,19 @@ def _cold_start_phase(booster, bucket: int):
     empty) jit-cache dir: leg "cleared" = cache-cleared cold (compiles +
     persists the AOT artifact), leg "from_disk" = a second process
     deserializing it.  Returns the PREDICT_BENCH ``cold_start`` cell."""
+    from mmlspark_tpu.core.env import refuse_child_on_held_chip
+    from tools import empty_cache_dir
+
+    # the steady phase ran in THIS process: on a chip it holds the device
+    # the cold children need
+    refuse_child_on_held_chip("bench_predict cold-start phase")
     cell = {"bucket": int(bucket), "backend": "packed"}
     with tempfile.TemporaryDirectory(prefix="bench_cold_") as td:
         pkl = os.path.join(td, "booster.pkl")
         with open(pkl, "wb") as fh:
             fh.write(pickle.dumps(booster))
         env = dict(os.environ)
-        env["MMLSPARK_TPU_COMPILE_CACHE_DIR"] = os.path.join(td, "jit")
+        env["JAX_COMPILATION_CACHE_DIR"] = empty_cache_dir("jit_cache_predict")
         outs = {}
         for leg in ("cleared", "from_disk"):
             out_npy = os.path.join(td, leg + ".npy")

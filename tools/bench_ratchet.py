@@ -1,10 +1,11 @@
 """CI perf ratchet: pin the committed bench ledgers to enforced floors.
 
-The repo's headline perf claims live in hand-regenerated ledgers at the
-repo root (``BENCH_r*.json``, ``PREDICT_BENCH.json``,
-``INGEST_BENCH.json``, ``MULTICHIP_COMMS.json``,
-``MULTI_TRAIN_BENCH.json``, ``LOOP_BENCH.json``,
-``BENCH_POD.json``).  Nothing in CI
+The repo's builder-run perf claims live in hand-regenerated ledgers at
+the repo root (``PREDICT_BENCH.json``, ``INGEST_BENCH.json``,
+``MULTICHIP_COMMS.json``, ``MULTI_TRAIN_BENCH.json``,
+``LOOP_BENCH.json``, ``BENCH_POD.json``) — all ``"backend": "cpu"``
+today; the chip's numbers are the driver's ``PERF_LEDGER.jsonl``, which
+this tool does not read.  Nothing in CI
 stopped a PR from silently regressing them — a bench rerun could write
 a worse number and the diff would merge green (ROADMAP item 5(b)).
 
@@ -50,7 +51,6 @@ Usage::
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import subprocess
@@ -66,14 +66,6 @@ BENCH_OUT = os.path.join(REPO, "bench_out")
 # ---------------------------------------------------------------------------
 
 LEDGER_SCHEMAS = {
-    "BENCH_r*.json": {
-        "n": int,
-        "cmd": str,
-        "rc": int,
-        "parsed.metric": str,
-        "parsed.value": (int, float),
-        "parsed.unit": str,
-    },
     "PREDICT_BENCH.json": {
         "bench": str,
         "config.iters": int,
@@ -180,20 +172,6 @@ LEDGER_SCHEMAS = {
 # ---------------------------------------------------------------------------
 
 GATES = [
-    {
-        "id": "train.steady_step_s",
-        "ledger": "BENCH_r*.json",
-        "path": "parsed.value",
-        "op": "<=",
-        "band": {"cpu": 0.15, "*": 0.10},
-    },
-    {
-        "id": "train.vs_baseline",
-        "ledger": "BENCH_r*.json",
-        "path": "parsed.vs_baseline",
-        "op": ">=",
-        "band": {"cpu": 0.15, "*": 0.10},
-    },
     {
         "id": "predict.p99_ms_bulk_packed",
         "ledger": "PREDICT_BENCH.json",
@@ -403,12 +381,8 @@ def discover_ledgers(ledger_dir: str) -> dict:
     must match at least one file (a vanished ledger is a schema error)."""
     out = {}
     for name in LEDGER_SCHEMAS:
-        if "*" in name:
-            paths = sorted(glob.glob(os.path.join(ledger_dir, name)))
-        else:
-            p = os.path.join(ledger_dir, name)
-            paths = [p] if os.path.isfile(p) else []
-        out[name] = paths
+        p = os.path.join(ledger_dir, name)
+        out[name] = [p] if os.path.isfile(p) else []
     return out
 
 
@@ -468,8 +442,7 @@ def validate_ledger(schema_name: str, obj: dict) -> list:
 
 
 def load_ledgers(ledger_dir: str):
-    """(ledgers, errors): schema-validated ledger objects by schema name.
-    ``BENCH_r*.json`` keeps the HIGHEST round (the live record)."""
+    """(ledgers, errors): schema-validated ledger objects by schema name."""
     errors = []
     ledgers = {}
     found = discover_ledgers(ledger_dir)
@@ -487,15 +460,14 @@ def load_ledgers(ledger_dir: str):
             errs = validate_ledger(name, obj)
             errors.extend(f"{os.path.basename(p)}: {e}" for e in errs)
             if not errs:
-                ledgers[name] = obj  # sorted order -> last = highest round
+                ledgers[name] = obj
     return ledgers, errors
 
 
 def _backend_of(name: str, ledgers: dict) -> str:
     led = ledgers.get(name, {})
-    for path in ("backend", "parsed.backend"):
-        for v in _walk(led, path):
-            return str(v)
+    for v in _walk(led, "backend"):
+        return str(v)
     return "cpu"
 
 

@@ -159,16 +159,7 @@ def _auc(y, p):
 
 
 def run_child(n_dev: int):
-    # Must run BEFORE jax initializes a backend: newer jax exposes the
-    # device count as a config option; older builds only honor the XLA
-    # host-platform flag (main() also sets it in the child env).
-    import re
-
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   os.environ.get("XLA_FLAGS", ""))
-    os.environ["XLA_FLAGS"] = (
-        f"{flags} --xla_force_host_platform_device_count={n_dev}".strip()
-    )
+    # The virtual device count must be set BEFORE jax initializes a backend.
     # The collective-bytes ledger reads the PYTHON trace — an AOT
     # trace-cache replay skips tracing and would record zero collectives,
     # so the bench always re-traces (the compile cache still applies).
@@ -176,10 +167,7 @@ def run_child(n_dev: int):
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_dev)
-    except AttributeError:
-        pass  # old jax: XLA_FLAGS above is the only knob
+    jax.config.update("jax_num_cpu_devices", n_dev)
     assert jax.device_count() == n_dev, jax.device_count()
 
     from mmlspark_tpu import obs
@@ -274,12 +262,10 @@ def run_child(n_dev: int):
                 jax.tree_util.tree_leaves(r)[0].block_until_ready()
             return (time.perf_counter() - t0) / 5
 
-        from mmlspark_tpu.parallel.mesh import shard_map_compat
-
-        psum_f = jax.jit(shard_map_compat(
+        psum_f = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x[0], "data"), mesh=mesh,
             in_specs=P("data"), out_specs=P()))
-        scat_f = jax.jit(shard_map_compat(
+        scat_f = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum_scatter(
                 x[0], "data", scatter_dimension=3, tiled=True),
             mesh=mesh, in_specs=P("data"), out_specs=P(None, None, None, "data")))

@@ -464,6 +464,11 @@ def _free_port() -> int:
 def _spawn_replica_leg(model_path: str, timeout_s: float = 180.0) -> dict:
     """Spawn one replica process and wait for /readyz 200; returns the
     leg record (ready walls + the replica's AOT counters)."""
+    from mmlspark_tpu.core.env import refuse_child_on_held_chip
+
+    # the parent trained the model in-process: fine on the CPU, but on a
+    # chip it would hold the device the replica needs
+    refuse_child_on_held_chip("bench_serving --cold")
     port = _free_port()
     t0 = time.perf_counter()
     proc = subprocess.Popen(
@@ -899,7 +904,11 @@ def main(argv=None) -> int:
 
     tmp = tempfile.mkdtemp(prefix="bench_serving_")
     # fresh compile cache so neither phase rides a previous run's warmth
-    os.environ["MMLSPARK_TPU_COMPILE_CACHE_DIR"] = os.path.join(tmp, "jit")
+    # (set before jax is imported; the replica children inherit it)
+    from tools import empty_cache_dir
+
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = empty_cache_dir(
+        "jit_cache_serving")
 
     from mmlspark_tpu import obs
     from mmlspark_tpu.serve import ServingApp

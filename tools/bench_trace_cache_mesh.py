@@ -22,6 +22,7 @@ import tempfile
 import textwrap
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)  # run as a script: make ``tools`` importable
 
 _WORKER = textwrap.dedent("""
     import json, sys, time
@@ -67,7 +68,7 @@ _WORKER = textwrap.dedent("""
 """)
 
 
-def run_round(cache_dir, compile_cache_dir):
+def run_round(cache_dir):
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
@@ -78,8 +79,7 @@ def run_round(cache_dir, compile_cache_dir):
         env = {
             "PATH": "/usr/bin:/bin:/usr/local/bin", "HOME": "/root",
             "JAX_PLATFORMS": "cpu", "PYTHONDONTWRITEBYTECODE": "1",
-            "MMLSPARK_TPU_TRACE_CACHE_DIR": cache_dir,
-            "MMLSPARK_TPU_COMPILE_CACHE_DIR": compile_cache_dir,
+            "JAX_COMPILATION_CACHE_DIR": cache_dir,
         }
         procs = [
             subprocess.Popen(
@@ -99,12 +99,12 @@ def run_round(cache_dir, compile_cache_dir):
 
 
 def main():
-    with tempfile.TemporaryDirectory() as caches:
-        tdir = os.path.join(caches, "traces")
-        cdir = os.path.join(caches, "jit")
-        r1 = run_round(tdir, cdir)  # cold caches: pays trace + compile
-        r2 = run_round(tdir, cdir)  # fresh processes, warm caches
-        r3 = run_round(tdir, cdir)  # repeat (cache-hit variance)
+    from tools import empty_cache_dir
+
+    cdir = empty_cache_dir("jit_cache_trace_mesh")  # traces + XLA entries
+    r1 = run_round(cdir)  # cold caches: pays trace + compile
+    r2 = run_round(cdir)  # fresh processes, warm caches
+    r3 = run_round(cdir)  # repeat (cache-hit variance)
     for tag, r in [("cold-caches", r1), ("warm-caches", r2),
                    ("warm-caches-2", r3)]:
         print(json.dumps({"round": tag, "per_process": r}))
