@@ -41,6 +41,14 @@ in :func:`prune_cache_dir` is the filesystem's atime/mtime (relatime-coarse).
 The artifacts this module reads itself are touched explicitly
 (:func:`record_cache_hit`).
 
+What jit itself costs rides jax's duration events the same way
+(:func:`_on_jit_duration`): ``jit.traces`` / ``jit.trace_s`` from
+``/jax/core/compile/jaxpr_trace_duration``, ``jit.lower_s`` from
+``/jax/core/compile/jaxpr_to_mlir_module_duration``, ``jit.backend_s``
+from ``/jax/core/compile/backend_compile_duration`` (the backend's compile
+OR its load from the persistent cache).  Listeners are registered whether
+or not the persistent cache is in use.
+
 AOT artifacts (ISSUE 11 / ROADMAP item 3a)
 ------------------------------------------
 jax's persistent cache only skips the XLA *compile*; a fresh process
@@ -73,6 +81,7 @@ from __future__ import annotations
 
 import os
 import pickle
+import threading
 
 from mmlspark_tpu import obs
 
@@ -94,6 +103,9 @@ _CHECKOUT_CACHE_DIR = os.path.join(
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def cache_dir() -> str:
@@ -117,6 +129,7 @@ def enable_compile_cache() -> bool:
     global _done
     if _done:
         return True
+    _listen_for_cache_events()  # retraces are counted with the cache off too
     if os.environ.get("MMLSPARK_TPU_NO_COMPILE_CACHE"):
         return False
     import jax
@@ -133,7 +146,6 @@ def enable_compile_cache() -> bool:
     # across shapes/configs — prune to the size cap, oldest-access first,
     # at enable time (once per process).
     prune_cache_dir(path)
-    _listen_for_cache_events()
     _done = True
     # jax lazily imports etils.epath inside the FIRST compile's
     # get_compile_options once a cache dir is set — ~75 ms of pure
@@ -169,15 +181,53 @@ def _on_cache_event(event: str, **_kwargs) -> None:
         obs.device.compile_event("compile")
 
 
+_trace_depth = threading.local()
+
+
+def _on_jit_scalar(event: str, _value, **_kwargs) -> None:
+    """jax announces a trace's START as a scalar event: the depth it keeps
+    lets :func:`_on_jit_duration` tell a jitted function traced inside
+    another's trace (its seconds are already in the outer one's) from a
+    top-level one."""
+    if event == _TRACE_EVENT:
+        _trace_depth.n = getattr(_trace_depth, "n", 0) + 1
+
+
+def _on_jit_duration(event: str, duration: float, **_kwargs) -> None:
+    """``jit.traces`` counts the top-level calls that left jit's C++ fast
+    path and went through its tracing path — every retrace, and also a
+    call that then hits jax's own trace cache, which costs microseconds —
+    and ``jit.trace_s`` their seconds; a retrace in a timed path (a new
+    ``jax.jit`` object around an old function) shows in both although the
+    persistent cache hits.  ``jit.lower_s`` is the lowering to MLIR,
+    ``jit.backend_s`` the backend's compile or its load from the cache."""
+    if event == _TRACE_EVENT:
+        n = _trace_depth.n = max(getattr(_trace_depth, "n", 1) - 1, 0)
+        if n == 0:
+            obs.inc("jit.traces")
+            obs.inc("jit.trace_s", duration)
+            # Unified compile-event ledger (obs/device.py): a Python
+            # (re-)trace, wherever it happens (a trace-cache miss's
+            # export included).
+            obs.device.compile_event("trace")
+    elif event == _LOWER_EVENT:
+        obs.inc("jit.lower_s", duration)
+    elif event == _BACKEND_EVENT:
+        obs.inc("jit.backend_s", duration)
+
+
 def _listen_for_cache_events() -> None:
-    """Feed jax's persistent-cache hit/miss events into the obs counters
-    (registered once per process)."""
+    """Feed jax's persistent-cache hit/miss events and its trace / lower /
+    backend durations into the obs counters (registered once per
+    process)."""
     global _listening
     if _listening:
         return
     import jax.monitoring
 
     jax.monitoring.register_event_listener(_on_cache_event)
+    jax.monitoring.register_scalar_listener(_on_jit_scalar)
+    jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
     _listening = True
 
 
@@ -402,6 +452,14 @@ def prune_cache_dir(path: str, max_mb: float | None = None) -> int:
     jax's own entries are ordered by what the filesystem recorded (module
     docstring).  Never raises; concurrent processes racing on the same
     file just skip it.
+
+    jax keeps one entry as two files, ``<key>-cache`` and (where its own
+    eviction is on, ``jax_compilation_cache_max_size``) ``<key>-atime``:
+    the pair goes or stays as ONE unit, and a ``-cache`` whose ``-atime``
+    is gone is removed whatever the budget — jax's eviction raises on
+    such an orphan, and from then on refuses every new entry that needs
+    room (seen on the chip: the 17-minute fit compiled again in every
+    process).
     """
     if max_mb is None:
         try:
@@ -414,27 +472,46 @@ def prune_cache_dir(path: str, max_mb: float | None = None) -> int:
             max_mb = _DEFAULT_MAX_MB
     budget = max_mb * (1 << 20)
     try:
-        entries = []
+        units: dict = {}  # entry -> [last access, bytes, its files]
         with os.scandir(path) as it:
             for e in it:
                 if e.is_file():
                     st = e.stat()
-                    entries.append((max(st.st_atime, st.st_mtime), st.st_size, e.path))
-        total = sum(s for _, s, _ in entries)
-        if total <= budget:
-            return 0
-        removed = 0
-        for _, size, p in sorted(entries):
-            try:
-                os.remove(p)
-                removed += 1
-                total -= size
-            except OSError:
-                continue
+                    unit = units.setdefault(
+                        e.name.removesuffix("-cache").removesuffix("-atime"),
+                        [0.0, 0, []],
+                    )
+                    unit[0] = max(unit[0], st.st_atime, st.st_mtime)
+                    unit[1] += st.st_size
+                    unit[2].append(e.path)
+        evicts = _jax_evicts()
+        victims = [
+            u for u in units.values()
+            if evicts and len(u[2]) == 1 and u[2][0].endswith("-cache")
+        ]  # orphans first, whatever the budget
+        total = sum(u[1] for u in units.values() if u not in victims)
+        for u in sorted(u for u in units.values() if u not in victims):
             if total <= budget:
                 break
+            victims.append(u)
+            total -= u[1]
+        removed = 0
+        for _, _, paths in victims:
+            for p in paths:
+                try:
+                    os.remove(p)
+                    removed += 1
+                except OSError:
+                    continue
         if removed:
             obs.inc("jit_cache.pruned", removed)
         return removed
     except OSError:
         return 0
+
+
+def _jax_evicts() -> bool:
+    """jax's own LRU eviction is on (it then keeps ``-atime`` files)."""
+    import jax
+
+    return jax.config.jax_compilation_cache_max_size != -1

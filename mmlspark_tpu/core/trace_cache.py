@@ -215,10 +215,9 @@ def _load_or_export(jitted, args, digest: str, multi_controller: bool):
     if exp is not None:
         obs.inc("trace_cache.hit")
         return exp
+    # the re-trace this miss pays is counted where it happens
+    # (jit_cache._on_jit_duration: device.compile_events{kind=trace})
     obs.inc("trace_cache.miss")
-    # Unified compile-event ledger (obs/device.py): a trace-cache miss
-    # pays a Python re-trace.
-    obs.device.compile_event("trace")
     try:
         with obs.span("trace_cache.export"):
             exp = jexport.export(jitted)(*args)

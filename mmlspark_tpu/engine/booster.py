@@ -548,6 +548,7 @@ class Booster:
     def _forest_fn(self, T: int, kind: str):
         key = (T, kind)
         if key not in self._predict_cache:
+            obs.inc("predict.scorer_builds")
             nb = self.bin_mapper.num_bins
 
             if kind == "raw":
@@ -664,6 +665,8 @@ class Booster:
         if pf is None:
             import pickle
 
+            obs.inc("predict.scorer_builds")
+
             key = _jc.aot_fingerprint(
                 "pft", {"model": self._model_fingerprint(T)}
             )
@@ -742,6 +745,7 @@ class Booster:
         if pf is None:
             from mmlspark_tpu.ops.pallas_predict import build_pallas_forest
 
+            obs.inc("predict.scorer_builds")
             pf = build_pallas_forest(self._host_trees(), self.tree_weights, T)
             self._pallas_forests[T] = pf
         return pf
@@ -829,7 +833,22 @@ class Booster:
         binning pass — used by warm start, which bins once for training and
         reuses the same matrix here)."""
         T = self._used_iters(num_iteration)
-        raw = self._raw_scores_dispatch(bins, T, self._resolved_predict_backend(T))
+        backend = self._resolved_predict_backend(T)
+        # `built`: this call has to make its scorer first (a new jitted
+        # function that traces again, or a forest to pack) — what
+        # `predict.scorer_builds` counts.  The call returns at dispatch, so
+        # the span is host time: build, trace, enqueue.
+        if backend == "scan":
+            built = (T, "raw") not in self._predict_cache
+        elif backend in ("pallas", "pallas_interpret"):
+            built = T not in self._pallas_forests
+        else:
+            built = T not in self._packed_forests
+        with obs.span(
+            "booster.score_binned", backend=backend,
+            rows=int(bins.shape[0]), trees=T, built=built,
+        ):
+            raw = self._raw_scores_dispatch(bins, T, backend)
         if self.average_output:
             raw = raw / max(T, 1)
         return raw
@@ -1596,15 +1615,21 @@ def train(
     so thresholds agree across processes.  Every process must call train()
     collectively (SPMD) and receives the identical replicated Booster.
     """
-    t0 = time.perf_counter()
-    with obs.span("booster.train", process_local=bool(process_local)):
-        booster = _train_impl(
-            params, train_set, valid_sets, valid_names,
-            bin_mapper, init_model, mesh, process_local,
-        )
+    phases = _Phases()
+    with obs.span(
+        "booster.train", process_local=bool(process_local)
+    ) as sp_train:
+        try:
+            booster = _train_impl(
+                params, train_set, valid_sets, valid_names,
+                bin_mapper, init_model, mesh, process_local, phases,
+            )
+        finally:
+            phases.close()
+    sp_last = sp_train
     if booster.quality_baseline is None:
         try:
-            with obs.span("booster.quality_baseline"):
+            with obs.span("booster.quality_baseline") as sp_last:
                 booster.quality_baseline = _capture_quality_baseline(
                     booster, train_set
                 )
@@ -1613,8 +1638,9 @@ def train(
                 "quality baseline capture failed; serving drift monitor "
                 "will run reference-less for this model", exc_info=True,
             )
-    if obs.enabled():
-        wall = time.perf_counter() - t0
+    if isinstance(sp_train, obs.Span):
+        # the spans' own stamps: the fit's start to the baseline's end
+        wall = (sp_last.end_ns - sp_train.start_ns) / 1e9
         obs.gauge("booster.train_wall_s", wall)
         try:
             # StreamedDataset has X=None by design; row count still exists
@@ -1634,21 +1660,63 @@ def train(
     return booster
 
 
+class _Phases:
+    """A fit's consecutive host phases as ``obs`` spans, all children of
+    ``booster.train`` (``booster.prepare``, ``.upload``, ``.program``,
+    ``.collect``; ``booster.scan_dispatch`` stands between the last two on
+    its own): entering one closes the one before it, so the phases tile
+    the fit's host time and an idle gap in a device trace lies in a named
+    one.  Host code only — never entered inside a traced function."""
+
+    def __init__(self):
+        self._open = None
+
+    def enter(self, name: str, **attrs):
+        self.close()
+        self._open = obs.span(name, **attrs)
+        return self._open.__enter__()
+
+    def close(self) -> None:
+        sp, self._open = self._open, None
+        if sp is not None:
+            sp.__exit__(None, None, None)
+
+
+class _Uploads:
+    """What a fit sends to the device, counted at the send: called on each
+    array on its way there, a host array's ``nbytes`` go into
+    ``train.upload_bytes`` (and ``bytes``, for ``booster.upload``'s attr);
+    one already resident counts 0."""
+
+    def __init__(self):
+        self.bytes = 0
+
+    def __call__(self, a):
+        if isinstance(a, np.ndarray):
+            self.bytes += a.nbytes
+            obs.inc("train.upload_bytes", float(a.nbytes))
+        return a
+
+
 def _train_impl(
     params: dict,
     train_set: Dataset,
-    valid_sets: Sequence[Dataset] = (),
-    valid_names: Optional[Sequence[str]] = None,
-    bin_mapper: Optional[BinMapper] = None,
-    init_model: Optional[Booster] = None,
-    mesh=None,
-    process_local: bool = False,
+    valid_sets: Sequence[Dataset],
+    valid_names: Optional[Sequence[str]],
+    bin_mapper: Optional[BinMapper],
+    init_model: Optional[Booster],
+    mesh,
+    process_local: bool,
+    phases: _Phases,
 ) -> Booster:
     """Body of :func:`train` — see its docstring.  Split out so the
-    ``booster.train`` obs span wraps every return path."""
+    ``booster.train`` obs span wraps every return path; ``phases`` are its
+    children (:class:`_Phases`), closed by the caller."""
     import warnings
 
     from mmlspark_tpu.core.jit_cache import enable_compile_cache
+
+    sp_prepare = phases.enter("booster.prepare")
 
     # Library-level persistent compile cache (SURVEY.md §3.1: the reference
     # has no compile step to beat — a user's FIRST fit must not pay full
@@ -1909,6 +1977,7 @@ def _train_impl(
         bins_np = train_set.binned(bin_mapper)
     n, F = bins_np.shape
     B = bin_mapper.num_bins
+    sp_prepare.set(rows=int(n), features=int(F))
 
     # ---- "auto" knob resolution ----------------------------------------
     # The resolved values live on cfg from here on (GrowConfig, the scan
@@ -2088,6 +2157,8 @@ def _train_impl(
         hierarchical,
     )
     bins_dev = train_set._dev_bins_cache.get(dev_key)
+    sp_upload = phases.enter("booster.upload", bins_cached=bins_dev is not None)
+    _sent = _Uploads()
     if feature_par:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
@@ -2095,11 +2166,13 @@ def _train_impl(
         col_sh = NamedSharding(mesh, P(None, DATA_AXIS))  # columns sharded
         rep = NamedSharding(mesh, P())  # rows replicated on every shard
         if bins_dev is None:
-            bins_dev = jax.device_put(bins_np, col_sh)
-        y_dev = jax.device_put(y.astype(np.float32), rep)
-        w_dev = None if w_np is None else jax.device_put(w_np.astype(np.float32), rep)
-        valid_mask = jax.device_put(valid_mask_np, rep)
-        init_scores_dev = jax.device_put(init_arr, rep)
+            bins_dev = jax.device_put(_sent(bins_np), col_sh)
+        y_dev = jax.device_put(_sent(y.astype(np.float32)), rep)
+        w_dev = None if w_np is None else jax.device_put(
+            _sent(w_np.astype(np.float32)), rep
+        )
+        valid_mask = jax.device_put(_sent(valid_mask_np), rep)
+        init_scores_dev = jax.device_put(_sent(init_arr), rep)
     elif process_local:
         # Multi-controller assembly: each process contributes ONLY its
         # (padded) partition; jax stitches the global sharded arrays from
@@ -2109,13 +2182,17 @@ def _train_impl(
         from mmlspark_tpu.parallel.distributed import make_global_array
 
         if bins_dev is None:
-            bins_dev = make_global_array(mesh, P(row_axes, None), bins_np)
-        y_dev = make_global_array(mesh, P(row_axes), y.astype(np.float32))
-        w_dev = None if w_np is None else make_global_array(
-            mesh, P(row_axes), w_np.astype(np.float32)
+            bins_dev = make_global_array(mesh, P(row_axes, None), _sent(bins_np))
+        y_dev = make_global_array(
+            mesh, P(row_axes), _sent(y.astype(np.float32))
         )
-        valid_mask = make_global_array(mesh, P(row_axes), valid_mask_np)
-        init_scores_dev = make_global_array(mesh, P(None, row_axes), init_arr)
+        w_dev = None if w_np is None else make_global_array(
+            mesh, P(row_axes), _sent(w_np.astype(np.float32))
+        )
+        valid_mask = make_global_array(mesh, P(row_axes), _sent(valid_mask_np))
+        init_scores_dev = make_global_array(
+            mesh, P(None, row_axes), _sent(init_arr)
+        )
     elif mesh is not None:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
@@ -2124,18 +2201,22 @@ def _train_impl(
         rowF_sh = NamedSharding(mesh, P(row_axes, None))
         krow_sh = NamedSharding(mesh, P(None, row_axes))
         if bins_dev is None:
-            bins_dev = jax.device_put(bins_np, rowF_sh)
-        y_dev = jax.device_put(y.astype(np.float32), row_sh)
-        w_dev = None if w_np is None else jax.device_put(w_np.astype(np.float32), row_sh)
-        valid_mask = jax.device_put(valid_mask_np, row_sh)
-        init_scores_dev = jax.device_put(init_arr, krow_sh)
+            bins_dev = jax.device_put(_sent(bins_np), rowF_sh)
+        y_dev = jax.device_put(_sent(y.astype(np.float32)), row_sh)
+        w_dev = None if w_np is None else jax.device_put(
+            _sent(w_np.astype(np.float32)), row_sh
+        )
+        valid_mask = jax.device_put(_sent(valid_mask_np), row_sh)
+        init_scores_dev = jax.device_put(_sent(init_arr), krow_sh)
     else:
         if bins_dev is None:
-            bins_dev = jnp.asarray(bins_np)
-        y_dev = jnp.asarray(y, dtype=jnp.float32)
-        w_dev = None if w_np is None else jnp.asarray(w_np, dtype=jnp.float32)
-        valid_mask = jnp.asarray(valid_mask_np)
-        init_scores_dev = jnp.asarray(init_arr)
+            bins_dev = jnp.asarray(_sent(bins_np))
+        y_dev = jnp.asarray(_sent(y.astype(np.float32)))
+        w_dev = None if w_np is None else jnp.asarray(
+            _sent(w_np.astype(np.float32))
+        )
+        valid_mask = jnp.asarray(_sent(valid_mask_np))
+        init_scores_dev = jnp.asarray(_sent(init_arr))
     # Size-1 like the host caches: each entry pins a full-matrix device
     # copy, and sweeps over mesh/chunk configs must not accumulate HBM.
     train_set._dev_bins_cache = {dev_key: bins_dev}
@@ -2344,6 +2425,7 @@ def _train_impl(
         and K * cfg.num_leaves * n <= _ONEHOT_BUDGET_ELS
     )
 
+    @jax.named_scope("leaf_delta")
     def _leaf_delta(tree, leaf_ids):
         # delta[k] = leaf_value[k][leaf_ids[k]] as a one-hot contraction:
         # the (n,)-gather-from-(L,) lowering cost ~2.1ms/tree at the bench
@@ -2442,19 +2524,21 @@ def _train_impl(
             nv_local = (int(vcounts.max()) + d_local - 1) // d_local
             v_pad = nv_local * d_local - vs.num_rows
             vb = make_global_array(
-                mesh, P(row_axes, None), _pad_rows(vbins_np, v_pad)
+                mesh, P(row_axes, None), _sent(_pad_rows(vbins_np, v_pad))
             )
             vy = make_global_array(
                 mesh, P(row_axes),
-                _pad_rows(vs.label, v_pad).astype(np.float32),
+                _sent(_pad_rows(vs.label, v_pad).astype(np.float32)),
             )
             vw = None if vs.weight is None else make_global_array(
                 mesh, P(row_axes),
-                _pad_rows(vs.weight, v_pad).astype(np.float32),
+                _sent(_pad_rows(vs.weight, v_pad).astype(np.float32)),
             )
             vvm = make_global_array(
                 mesh, P(row_axes),
-                np.concatenate([np.ones(vs.num_rows, bool), np.zeros(v_pad, bool)]),
+                _sent(np.concatenate(
+                    [np.ones(vs.num_rows, bool), np.zeros(v_pad, bool)]
+                )),
             )
             vscore_np = np.broadcast_to(
                 np.asarray(init, dtype=np.float32).reshape(-1, 1),
@@ -2464,7 +2548,9 @@ def _train_impl(
                 vscore_np = vscore_np + _pad_rows(
                     vs.init_score.astype(np.float32), v_pad
                 ).reshape(1, -1)
-            vscore = make_global_array(mesh, P(None, row_axes), vscore_np)
+            vscore = make_global_array(
+                mesh, P(None, row_axes), _sent(vscore_np)
+            )
             if init_model is not None:
                 vscore = vscore + init_model._raw_scores_binned(vb)
             vsets.append({
@@ -2473,7 +2559,7 @@ def _train_impl(
                 "row_offset": jax.process_index() * nv_local * d_local,
             })
             continue
-        vb = jnp.asarray(vbins_np)
+        vb = jnp.asarray(_sent(vbins_np))
         vscore = np.broadcast_to(
             np.asarray(init, dtype=np.float32).reshape(-1, 1), (K, vs.num_rows)
         ).copy()
@@ -2483,7 +2569,9 @@ def _train_impl(
             vscore = vscore + np.asarray(
                 init_model._raw_scores_binned(vb), dtype=np.float32
             )
-        vsets.append({"bins": vb, "scores": jnp.asarray(vscore), "data": vs})
+        vsets.append({
+            "bins": vb, "scores": jnp.asarray(_sent(vscore)), "data": vs,
+        })
 
     if cfg.is_provide_training_metric:
         # The training set joins the eval loop as a LAST pseudo-valid;
@@ -2500,6 +2588,8 @@ def _train_impl(
             ),
         })
 
+    sp_upload.set(bytes=_sent.bytes)
+    sp_program = phases.enter("booster.program")
     predict_v = jax.jit(
         lambda tree, vbins: jax.vmap(lambda t: predict_tree_binned(t, vbins, B))(tree)
     )
@@ -2746,6 +2836,7 @@ def _train_impl(
             key_start, total_keyed, n_iter,
         )
         xs_dev = _XS_CACHE.get(xs_key)
+        sp_program.set(xs_cache_hit=xs_dev is not None)
         if xs_dev is None:
             xs_packed = np.concatenate(
                 [
@@ -2917,6 +3008,7 @@ def _train_impl(
         # (LambdaRank's group matrix) participate only when their state
         # fingerprint is part of the key, and are rebuilt otherwise.
         state_key = obj.state_key() if obj.stateful else None
+        scan_cache_hit = False
         if device_eval and vsets:
             # Evaluator aux shapes and group-count constants are per-call
             # state; the distributed-eval program skips the cross-call
@@ -2935,6 +3027,7 @@ def _train_impl(
                 type(obj).__name__, state_key, gcfg, _delta_onehot,
             )
             scan_chunk = _SCAN_CACHE.get(cache_key)
+            scan_cache_hit = scan_chunk is not None
             if scan_chunk is None:
                 scan_chunk = _build_scan_chunk()
                 if len(_SCAN_CACHE) >= _SCAN_CACHE_MAX:
@@ -3103,6 +3196,8 @@ def _train_impl(
         n_done = 0
         stop_at: Optional[int] = None
         chunk_idx = 0
+        sp_program.set(scan_cache_hit=scan_cache_hit)
+        phases.close()  # the dispatches are booster.train's own children
         while n_done < n_iter and stop_at is None:
             t_chunk = time.perf_counter()
             step_t = obs.steps.begin()
@@ -3192,6 +3287,7 @@ def _train_impl(
             chunk_idx += 1
 
         kept = (stop_at + 1) if stop_at is not None else n_iter
+        phases.enter("booster.collect", iters=kept)
         if ckpt_path is None and init_model is None:
             # The forest STAYS device-resident: one jitted concat/slice/
             # bias-fold program instead of a packed fetch + 10 re-uploads
@@ -3266,6 +3362,7 @@ def _train_impl(
             return f
 
         _legacy_stats = [_make_stats_fn(vs["evaluators"]) for vs in vsets]
+    phases.close()  # the per-iteration spans are booster.train's own children
     for it in range(cfg.num_iterations):
         t_it = time.perf_counter()
         step_t = obs.steps.begin()
@@ -3374,6 +3471,7 @@ def _train_impl(
             break
 
     # ---- stack trees (legacy/DART path) --------------------------------
+    phases.enter("booster.collect", iters=len(trees_host))
     # Stack on DEVICE in ONE jitted program, then one host transfer per
     # field: pulling each tree's 8 small arrays separately costs a full
     # dispatch round-trip per pull (~0.5s each through a remote-dispatch

@@ -546,6 +546,7 @@ def _cat_feat_mask(cfg: GrowConfig, F: int) -> np.ndarray:
     return m
 
 
+@jax.named_scope("split_scan")
 def _candidate_matrix(cfg: GrowConfig, hists, leaf_stats, feat_mask):
     """Best candidate per (leaf, feature): (gain, t, d) each (L, F).
 
@@ -701,6 +702,7 @@ def _local_cat_mask(cfg: GrowConfig, F_local: int):
     return m
 
 
+@jax.named_scope("split_scan")
 def _local_candidate_matrix(cfg: GrowConfig, hists, leaf_stats, feat_mask, cmask):
     """(L, F_local) candidate matrices over a LOCAL column block with a
     RUNTIME categorical mask: numeric and sorted-category candidates are
@@ -865,6 +867,7 @@ def grow_tree(
     else:
         qvals, hq = vals, None
 
+    @jax.named_scope("hist_build")
     def hist(mask):
         return build_histogram(
             bins_t, qvals, mask, B,
@@ -1062,6 +1065,7 @@ def grow_tree_depthwise(
     else:
         qvals, hq = vals, None
 
+    @jax.named_scope("hist_build")
     def window_hist(win_leaf):
         return build_histogram_by_leaf(
             bins_t, qvals, win_leaf, W, B,
@@ -1471,20 +1475,21 @@ def grow_tree_depthwise(
     # Final per-leaf (G, H, count): one-hot contraction when the (L, n)
     # operand fits the budget (~0.2ms vs ~1.8ms for the scatter-add at
     # 262k rows), exact either way.
-    if cfg.onehot_stats:
-        leaf_oh = (
-            leaf_ids[None, :] == jnp.arange(L, dtype=jnp.int32)[:, None]
-        ).astype(jnp.float32)  # (L, n)
-        leaf_stats = jax.lax.dot_general(
-            vals, leaf_oh, dimension_numbers=(((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-        )  # (3, L)
-    else:
-        leaf_stats = jax.vmap(
-            lambda v: jnp.zeros(L, jnp.float32).at[leaf_ids].add(
-                v, mode="drop"
-            )
-        )(vals)  # (3, L)
+    with jax.named_scope("leaf_stats"):
+        if cfg.onehot_stats:
+            leaf_oh = (
+                leaf_ids[None, :] == jnp.arange(L, dtype=jnp.int32)[:, None]
+            ).astype(jnp.float32)  # (L, n)
+            leaf_stats = jax.lax.dot_general(
+                vals, leaf_oh, dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+            )  # (3, L)
+        else:
+            leaf_stats = jax.vmap(
+                lambda v: jnp.zeros(L, jnp.float32).at[leaf_ids].add(
+                    v, mode="drop"
+                )
+            )(vals)  # (3, L)
     if cfg.axis_name is not None and not cfg.feature_parallel_active:
         # Row-sharded modes sum partial stats; feature-parallel replicates
         # rows, so the local sum is already the global sum.  psum_axes
@@ -1549,7 +1554,8 @@ def _replay_leaf_ids(tree: Tree, bins: jnp.ndarray, num_bins: int) -> jnp.ndarra
         move = active & (leaf_ids == tree.split_leaf[s]) & ~goes_left
         return jnp.where(move, s + 1, leaf_ids)
 
-    return lax.fori_loop(0, S, step, jnp.zeros(n, jnp.int32))
+    with jax.named_scope("replay_step"):
+        return lax.fori_loop(0, S, step, jnp.zeros(n, jnp.int32))
 
 
 def predict_tree_binned(tree: Tree, bins: jnp.ndarray, num_bins: int) -> jnp.ndarray:

@@ -84,6 +84,9 @@ class _NullSpan:
 
     __slots__ = ()
 
+    def set(self, **attrs) -> None:
+        pass
+
     def __enter__(self):
         return self
 
@@ -131,7 +134,16 @@ def span(name: str, **attrs):
     ``jax.profiler.TraceAnnotation`` pass-through.  When disabled, the
     flight recorder still rings a begin/end event pair (bounded memory,
     no I/O — the blackbox contract), unless flight is disarmed too, in
-    which case the shared zero-allocation null context returns."""
+    which case the shared zero-allocation null context returns.
+
+    The record is ``(name, start_ns, end_ns, parent, attrs)`` on the
+    ``monotonic_ns`` clock (read them back with ``obs.flight.spans()``);
+    where :func:`bind_trace` has bound a request, its ids join the attrs,
+    so the spans of one request share an identifier.  ``.set(**attrs)`` on
+    the returned span adds what is known only at the end."""
+    ids = trace_attrs()
+    if ids:
+        attrs = {**ids, **attrs}
     if _state.enabled:
         return Span(name, attrs)
     if flight._armed:

@@ -21,7 +21,10 @@ places a program identity can cost wall time each bump
 ``device.compile_events{kind=}`` at the exact site that already carries
 the matching span/counter —
 
-- ``kind=trace``       — a Python re-trace (``trace_cache.miss``);
+- ``kind=trace``       — a Python (re-)trace: every top-level trip
+  through jit's tracing path, from jax's own trace-duration event
+  (``core/jit_cache._on_jit_duration``, beside ``jit.traces``), a
+  ``trace_cache.miss``'s export and a plain ``jax.jit`` retrace alike;
 - ``kind=compile``     — an XLA compile paid (``jit_cache.miss``);
 - ``kind=deserialize`` — an AOT executable loaded from disk instead
   (``jit_cache.aot_deserialize`` span / ``aot_hits`` counter).

@@ -33,6 +33,13 @@ connection) share one overflow ring — ``deque.append`` is thread-safe, so
 sharing costs nothing on the hot path; rings of dead threads are evicted
 when a new thread registers.
 
+The rings are also the ONE in-memory span log: :func:`spans` pairs the
+begin/end events still in them into ``(name, start_ns, end_ns, parent,
+attrs)`` records on this module's ``monotonic_ns`` clock, for code that
+measures the process it runs in (a benchmark's per-layer metrics), and
+:func:`pair_spans` is the same pairing for events read back from a dump
+(``python -m tools.obs timeline`` calls it).
+
 Each dump appends a ``flight_header`` record carrying a paired
 ``(ts, mono_ns)`` wall/monotonic anchor; events carry raw
 ``monotonic_ns`` stamps.  The reader (``python -m tools.obs timeline``)
@@ -128,15 +135,17 @@ def _new_ring() -> collections.deque:
     return ring
 
 
-def record(kind: str, name: str, detail=None) -> None:
+def record(kind: str, name: str, detail=None, t_ns: Optional[int] = None) -> None:
     """Append one event to this thread's ring.  The hot path: one
-    monotonic read + one bounded deque append; no locks, no I/O."""
+    monotonic read + one bounded deque append; no locks, no I/O.
+    ``t_ns`` is a ``monotonic_ns`` stamp the caller already took (an
+    enabled ``Span`` stamps once, for the ring and for its own record)."""
     if not _armed:
         return
     ring = getattr(_tls, "ring", None)
     if ring is None or getattr(_tls, "gen", -1) != _gen:
         ring = _new_ring()
-    ring.append((time.monotonic_ns(), kind, name, detail))
+    ring.append((t_ns or time.monotonic_ns(), kind, name, detail))
 
 
 class FlightSpan:
@@ -145,19 +154,89 @@ class FlightSpan:
     Returned by ``obs.span`` when metrics are disabled but the flight
     recorder is armed."""
 
-    __slots__ = ("name", "attrs")
+    __slots__ = ("name", "attrs", "_late")
 
     def __init__(self, name: str, attrs: Optional[dict]):
         self.name = name
         self.attrs = attrs
+        self._late = None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (bytes sent, a
+        cache hit): they ride the end event."""
+        self._late = {**self._late, **attrs} if self._late else attrs
 
     def __enter__(self):
         record("sb", self.name, self.attrs or None)
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        record("se", self.name, None)
+        record("se", self.name, self._late)
         return False
+
+
+# ---------------------------------------------------------------- reader
+
+
+def pair_spans(events) -> "list[dict]":
+    """THE place a span's begin meets its end.  ``events`` are
+    ``(t_ns, kind, name, detail, thread)`` in time order (``thread`` is any
+    hashable that keeps one thread's events apart: the live reader passes
+    the ring's thread name, ``tools/obs`` a ``(rank, thread)`` pair); the
+    result is one record per completed span::
+
+        {"id", "name", "start_ns", "end_ns", "parent", "parent_id",
+         "thread", "attrs"}
+
+    in order of completion.  ``sb``/``se`` pair stack-wise per thread, by
+    name, so a child lies inside its parent; ``parent`` is the enclosing
+    open span's name on that thread and ``parent_id`` its record's ``id``
+    (ids count begins).  ``attrs`` are the begin event's detail plus
+    whatever the end event carries (``set``).  A pre-measured ``span``
+    event (``obs.record_span`` with metrics off) ends at its stamp and
+    starts ``dur_s`` before it.  An ``se`` whose ``sb`` the ring has
+    already dropped, and an ``sb`` still open, give no record."""
+    out: "list[dict]" = []
+    stacks: "dict[object, list]" = {}  # per thread: open (id, name, t0, detail)
+    next_id = 0
+
+    def done(sid, name, t0, t1, up, thread, attrs):
+        out.append({
+            "id": sid, "name": name, "start_ns": t0, "end_ns": t1,
+            "parent": up[1] if up else None,
+            "parent_id": up[0] if up else None,
+            "thread": thread, "attrs": attrs,
+        })
+
+    for t, kind, name, detail, thread in events:
+        if kind == "sb":
+            stacks.setdefault(thread, []).append((next_id, name, t, detail))
+            next_id += 1
+        elif kind == "se":
+            stack = stacks.get(thread, ())
+            for i in range(len(stack) - 1, -1, -1):
+                if stack[i][1] == name:
+                    sid, _, t0, begun = stack.pop(i)
+                    done(sid, name, t0, t, stack[i - 1] if i else None,
+                         thread, {**(begun or {}), **(detail or {})})
+                    break
+        elif kind == "span":
+            attrs = dict(detail or {})
+            dur_ns = int(float(attrs.pop("dur_s", 0.0) or 0.0) * 1e9)
+            stack = stacks.get(thread, ())
+            done(next_id, name, t - dur_ns, t, stack[-1] if stack else None,
+                 thread, attrs)
+            next_id += 1
+    return out
+
+
+def spans(name: Optional[str] = None) -> "list[dict]":
+    """The completed spans still in this process's rings, as
+    :func:`pair_spans` records on the ``monotonic_ns`` clock — the public
+    reader for code that runs in the process it measures (a benchmark's
+    per-layer metrics).  ``name`` keeps only spans of that name."""
+    recs = pair_spans(_all_events())
+    return [r for r in recs if r["name"] == name] if name else recs
 
 
 # ------------------------------------------------------------------ dump
@@ -206,6 +285,17 @@ def _snapshot_rings() -> "list[tuple[str, list]]":
     return out
 
 
+def _all_events() -> "list[tuple]":
+    """Every ring's events as ``(t_ns, kind, name, detail, thread name)``
+    in time order (each ring's own order kept among equal stamps)."""
+    events = []
+    for tname, ring in _snapshot_rings():
+        events.extend((t, kind, name, detail, tname)
+                      for (t, kind, name, detail) in ring)
+    events.sort(key=lambda e: e[0])
+    return events
+
+
 def dump(reason: str, directory: Optional[str] = None) -> Optional[str]:
     """Flush every thread's ring to ``blackbox.rank<R>.jsonl`` (appended,
     so a bark followed by a crash leaves two anchored segments).  Returns
@@ -215,11 +305,7 @@ def dump(reason: str, directory: Optional[str] = None) -> Optional[str]:
         path = blackbox_path(directory)
         if path is None or not _armed:
             return None
-        events = []
-        for tname, ring in _snapshot_rings():
-            events.extend((t, kind, name, detail, tname)
-                          for (t, kind, name, detail) in ring)
-        events.sort(key=lambda e: e[0])
+        events = _all_events()
         rank = _state.process_index()
         rid = _state.replica_id()
         pi = _state.jax_process_index()

@@ -1,18 +1,25 @@
 """mmlspark_tpu.obs.tracing — spans, the JSONL exporter, and the library
 logger.
 
-Spans are monotonic (``time.perf_counter_ns``) wall-time measurements with
-nesting tracked per thread.  Each completed span is (a) aggregated into the
-metric registry's span table and (b) appended as one JSON line to the
-export file when ``MMLSPARK_TPU_OBS=path`` (or ``obs.enable(path=...)``)
-is active.  When jax is already imported, spans also enter a
-``jax.profiler.TraceAnnotation`` so they show up in XLA device profiles —
-jax is never imported from here (obs stays dependency-free).
+A span is ``(name, start_ns, end_ns, parent, attrs)`` on ONE clock,
+``time.monotonic_ns`` — the flight ring's (``obs/flight.py``; on Linux
+``perf_counter_ns`` reads the same clock).  An enabled span stamps its
+begin and its end once each: the stamp goes into the ring (whose public
+reader, ``obs.flight.spans()``, pairs them back into such records) and
+into the span's own record, which is (a) aggregated into the metric
+registry's span table and (b) appended as one JSON line to the export
+file when ``MMLSPARK_TPU_OBS=path`` (or ``obs.enable(path=...)``) is
+active.  When jax is already imported, spans also enter a
+``jax.profiler.TraceAnnotation`` so they show up in XLA device profiles,
+on the profiler's own clock — jax is never imported from here (obs stays
+dependency-free).
 
-JSONL record shapes::
+JSONL record shapes (``ts`` is the wall clock at the write, for merging
+ranks; ``start_ns``/``end_ns`` are this process's ``monotonic_ns``)::
 
     {"kind": "span", "ts": <unix>, "rank": R, "name": ..., "dur_s": ...,
-     "depth": D, "parent": <name|null>, "attrs": {...}}
+     "start_ns": ..., "end_ns": ..., "depth": D, "parent": <name|null>,
+     "attrs": {...}}
     {"kind": "snapshot", "ts": <unix>, "rank": R, "snapshot": {...}}
 
 Under multiple processes every rank writes its own file
@@ -107,11 +114,18 @@ class Span:
     ``obs.span(name, **attrs)`` — which returns a shared null context when
     obs is disabled, so this class only ever runs enabled."""
 
-    __slots__ = ("name", "attrs", "_t0", "_ta", "_depth", "_parent")
+    __slots__ = ("name", "attrs", "start_ns", "end_ns", "_late", "_ta",
+                 "_depth", "_parent")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
+        self._late = None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (bytes sent, a
+        cache hit): they ride the ring's end event and the record."""
+        self._late = {**self._late, **attrs} if self._late else attrs
 
     def __enter__(self):
         stack = _stack()
@@ -122,12 +136,15 @@ class Span:
         self._ta = ta_cls(self.name) if ta_cls else None
         if self._ta is not None:
             self._ta.__enter__()
-        flight.record("sb", self.name, self.attrs or None)
-        self._t0 = time.perf_counter_ns()
+        self.start_ns = time.monotonic_ns()
+        flight.record("sb", self.name, self.attrs or None, self.start_ns)
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur_s = (time.perf_counter_ns() - self._t0) / 1e9
+        self.end_ns = time.monotonic_ns()
+        flight.record("se", self.name, self._late, self.end_ns)
+        if self._late:
+            self.attrs.update(self._late)
         if self._ta is not None:
             try:
                 self._ta.__exit__(exc_type, exc, tb)
@@ -136,9 +153,10 @@ class Span:
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
-        flight.record("se", self.name, None)
         record_span(
-            self.name, dur_s, self.attrs, depth=self._depth, parent=self._parent
+            self.name, (self.end_ns - self.start_ns) / 1e9, self.attrs,
+            depth=self._depth, parent=self._parent,
+            start_ns=self.start_ns, end_ns=self.end_ns,
         )
         return False
 
@@ -149,11 +167,17 @@ def record_span(
     attrs: Optional[dict] = None,
     depth: int = 0,
     parent: Optional[str] = None,
+    start_ns: Optional[int] = None,
+    end_ns: Optional[int] = None,
 ) -> None:
-    """Record a completed (pre-measured) span: aggregate + export."""
+    """Record a completed span: aggregate + export.  A pre-measured one
+    (no stamps given) ends now and started ``dur_s`` ago."""
     metrics.registry.observe_span(name, dur_s)
     exp = _EXPORTER
     if exp is not None:
+        if end_ns is None:
+            end_ns = time.monotonic_ns()
+            start_ns = end_ns - int(dur_s * 1e9)
         exp.write(
             {
                 "kind": "span",
@@ -161,6 +185,8 @@ def record_span(
                 "rank": _state.process_index(),
                 "name": name,
                 "dur_s": dur_s,
+                "start_ns": start_ns,
+                "end_ns": end_ns,
                 "depth": depth,
                 "parent": parent,
                 "attrs": attrs or {},

@@ -374,3 +374,62 @@ def test_torn_trace_blob_is_reexported(monkeypatch, tmp_path):
     from jax import export
 
     export.deserialize(bytearray(blob.read_bytes()))
+
+
+def _jax_entry(d, key, age_s, size=1024, atime=True):
+    """One entry as jax's LRU cache keeps it: ``<key>-cache`` + ``<key>-atime``."""
+    import os
+    import time
+
+    t = time.time() - age_s
+    files = [(d / f"{key}-cache", b"x" * size)]
+    if atime:
+        files.append((d / f"{key}-atime", (0).to_bytes(8, "little")))
+    for p, data in files:
+        p.write_bytes(data)
+        os.utime(p, (t, t))
+
+
+@pytest.mark.parametrize("evicting", [True, False])
+def test_prune_keeps_jax_cache_and_atime_files_together(tmp_path, evicting):
+    """jax's own eviction reads ``<key>-atime`` for every ``<key>-cache`` and
+    raises where one is missing, after which it refuses every entry that
+    needs room (the chip's cache: the fit compiled anew in every process).
+    The prune takes or leaves the pair as one unit."""
+    import jax
+
+    from mmlspark_tpu.core.jit_cache import prune_cache_dir
+
+    for i, age in enumerate((400, 300, 200, 100)):
+        _jax_entry(tmp_path, f"jit_prog{i}-{'ab' * 8}", age)
+    old = jax.config.jax_compilation_cache_max_size
+    jax.config.update("jax_compilation_cache_max_size", 1 << 20 if evicting else -1)
+    try:
+        # room for two whole entries and a bit: the two oldest go, whole
+        removed = prune_cache_dir(str(tmp_path), max_mb=(2 * 1032 + 500) / (1 << 20))
+    finally:
+        jax.config.update("jax_compilation_cache_max_size", old)
+    assert removed == 4
+    left = sorted(f.name for f in tmp_path.iterdir())
+    assert left == sorted(
+        f"jit_prog{i}-{'ab' * 8}-{kind}" for i in (2, 3) for kind in ("atime", "cache")
+    )
+
+
+def test_prune_removes_a_cache_file_whose_atime_is_gone_whatever_the_budget(tmp_path):
+    import jax
+
+    from mmlspark_tpu.core.jit_cache import prune_cache_dir
+
+    _jax_entry(tmp_path, "jit_whole-00", 300)
+    _jax_entry(tmp_path, "jit_orphan-11", 10, atime=False)
+    (tmp_path / "aot-ours").write_bytes(b"y" * 64)  # this package's own kinds have no pair
+    old = jax.config.jax_compilation_cache_max_size
+    try:
+        jax.config.update("jax_compilation_cache_max_size", -1)
+        assert prune_cache_dir(str(tmp_path), max_mb=1.0) == 0  # no eviction by jax: no -atime files, no orphans
+        jax.config.update("jax_compilation_cache_max_size", 1 << 20)
+        assert prune_cache_dir(str(tmp_path), max_mb=1.0) == 1
+    finally:
+        jax.config.update("jax_compilation_cache_max_size", old)
+    assert sorted(f.name for f in tmp_path.iterdir()) == ["aot-ours", "jit_whole-00-atime", "jit_whole-00-cache"]

@@ -223,8 +223,11 @@ class TestDisabledOverhead:
         _tiny_train()
         snap = obs.snapshot()
         events = sum(s["count"] for s in snap["spans"].values())
+        # counters that carry a quantity (nanoseconds, bytes sent, seconds
+        # of tracing) are one event per `inc`, not `value` events
         events += sum(
-            v for k, v in snap["counters"].items() if ".ns" not in k
+            1 if k.endswith(("_bytes", "_s")) else v
+            for k, v in snap["counters"].items() if ".ns" not in k
         )
         # step telemetry rides the same budget: every histogram sample
         # (train.step_*_s et al) is one more enabled-mode event, and the

@@ -22,7 +22,8 @@ and ``blackbox.rank<R>.jsonl`` flight-recorder dumps.
   alarms, PSI gauges, burn rates) from any snapshot-bearing file, or
   pulls a live app's ``GET /driftz`` for full per-feature detail.
 
-Pure stdlib — usable on a machine without jax installed.
+Stdlib plus this repo's own ``mmlspark_tpu.obs.flight`` (the one place a
+span's begin meets its end) — usable on a machine without jax installed.
 """
 
 from __future__ import annotations
@@ -388,33 +389,27 @@ def _gather_timeline_events(paths: List[str]):
 
 
 def _pair_flight_spans(events: List[dict]) -> List[dict]:
-    """Match ``sb``/``se`` ring events into completed spans (per
-    rank+thread, stack-wise, by name) and pass through pre-measured
-    ``span`` events; returns span dicts with start/dur/attrs."""
-    spans: List[dict] = []
-    stacks: Dict[tuple, list] = {}
+    """Completed spans of a merged event list: ``sb``/``se`` pairs and
+    pre-measured ``span`` events through the program's own reader
+    (``mmlspark_tpu.obs.flight.pair_spans``: per rank+thread, stack-wise,
+    by name), plus one ``collective.<name>`` span per watchdog
+    ``collective_end``; returns span dicts with start/dur/attrs on the
+    reconstructed wall clock."""
+    from mmlspark_tpu.obs.flight import pair_spans
+
+    spans = [
+        {"rank": r["thread"][0], "name": r["name"],
+         "start": r["start_ns"] / 1e9,
+         "dur_s": max(0.0, (r["end_ns"] - r["start_ns"]) / 1e9),
+         "attrs": r["attrs"]}
+        for r in pair_spans(
+            (int(round(e["wall"] * 1e9)), e["ev"], e["name"], e["detail"],
+             (e["rank"], e["thread"]))
+            for e in events
+        )
+    ]
     for e in events:
-        if e["ev"] == "span":
-            d = dict(e["detail"] or {})
-            dur = float(d.pop("dur_s", 0.0) or 0.0)
-            spans.append({"rank": e["rank"], "name": e["name"],
-                          "start": e["wall"] - dur, "dur_s": dur,
-                          "attrs": d})
-        elif e["ev"] == "sb":
-            stacks.setdefault((e["rank"], e["thread"]), []).append(e)
-        elif e["ev"] == "se":
-            stack = stacks.get((e["rank"], e["thread"]), [])
-            for i in range(len(stack) - 1, -1, -1):
-                if stack[i]["name"] == e["name"]:
-                    sb = stack.pop(i)
-                    spans.append({
-                        "rank": e["rank"], "name": e["name"],
-                        "start": sb["wall"],
-                        "dur_s": max(0.0, e["wall"] - sb["wall"]),
-                        "attrs": dict(sb["detail"] or {}),
-                    })
-                    break
-        elif e["ev"] == "collective_end":
+        if e["ev"] == "collective_end":
             d = dict(e["detail"] or {})
             dur = float(d.pop("dur_s", 0.0) or 0.0)
             spans.append({"rank": e["rank"],
