@@ -116,8 +116,10 @@ class TestBitwiseParity:
         raw = scan.predict(X, raw_score=True)
         assert np.array_equal(raw, packed.predict(X, raw_score=True))
 
-    def test_categorical(self, cat_booster):
+    @pytest.mark.parametrize("entry", ["predict", "raw_scores_binned"])
+    def test_categorical(self, cat_booster, entry):
         booster, X = cat_booster
+        assert bool(np.asarray(booster.trees.split_cat).any())
         scan = _clone(booster, "scan")
         packed = _clone(booster, "packed")
         probe = np.concatenate(
@@ -126,7 +128,17 @@ class TestBitwiseParity:
                           [np.nan, 3.0, 1.0, -1.0, 0.5]])],
             axis=0,
         )
-        assert np.array_equal(scan.predict(probe), packed.predict(probe))
+        if entry == "predict":
+            assert np.array_equal(scan.predict(probe), packed.predict(probe))
+            return
+        # the entry warm start and a holdout evaluation score through;
+        # pallas_interpret is not admitted for membership splits and
+        # resolves to packed (next test)
+        bins = booster.bin_mapper.transform(probe)
+        ref = np.asarray(scan._raw_scores_binned(bins))
+        assert ref.shape == (1, probe.shape[0])
+        for other in (packed, _clone(booster, "pallas_interpret")):
+            assert np.array_equal(ref, np.asarray(other._raw_scores_binned(bins)))
 
     def test_categorical_forces_packed_over_pallas(self, cat_booster):
         booster, _ = cat_booster
