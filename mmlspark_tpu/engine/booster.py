@@ -846,7 +846,7 @@ class Booster:
             built = T not in self._packed_forests
         with obs.span(
             "booster.score_binned", backend=backend,
-            rows=int(bins.shape[0]), trees=T, built=built,
+            rows=int(bins.shape[0]), trees=T, built=built, **_placement(bins),
         ):
             raw = self._raw_scores_dispatch(bins, T, backend)
         if self.average_output:
@@ -1167,11 +1167,11 @@ _PARALLEL_LEARNERS = (
     "feature", "feature_parallel",
 )
 
-# Jitted whole-run scan programs cached ACROSS train() calls (bounded FIFO).
-# jax.jit caches per function object; without this, every fit (each AutoML
-# candidate, each CV fold, the bench's steady-state run) re-traces the scan
-# body — seconds of pure Python/tracing overhead per call.
-_SCAN_CACHE: Dict[Tuple, callable] = {}
+# Jitted whole-run scan programs cached ACROSS train() calls (bounded FIFO):
+# jax.jit caches per function object, so without this every fit (AutoML
+# candidate, CV fold, steady-state run) re-traces the scan body for seconds.
+# An entry is ``(program, notes)``; ``notes["merge_ledger"]``: _grow_ledger.
+_SCAN_CACHE: Dict[Tuple, tuple] = {}
 _SCAN_CACHE_MAX = 16
 
 # Device copies of the packed per-iteration xs (keys/bag-keys/iteration
@@ -2027,7 +2027,7 @@ def _train_impl(
         # never renumbers real columns, so the global indices stay valid.
         f_pad = (-F) % D
         if f_pad:
-            bins_np = _pad_cols(bins_np, f_pad)
+            bins_np = _pad_cols(bins_np, f_pad if feature_par else 0)  # reduce_scatter pads its histogram, not the rows' matrix
             F += f_pad
     elif hierarchical:
         f_pad = (-F) % d_feat
@@ -2588,7 +2588,7 @@ def _train_impl(
             ),
         })
 
-    sp_upload.set(bytes=_sent.bytes)
+    sp_upload.set(bytes=_sent.bytes, **_placement(bins_dev))
     sp_program = phases.enter("booster.program")
     predict_v = jax.jit(
         lambda tree, vbins: jax.vmap(lambda t: predict_tree_binned(t, vbins, B))(tree)
@@ -3013,9 +3013,9 @@ def _train_impl(
             # Evaluator aux shapes and group-count constants are per-call
             # state; the distributed-eval program skips the cross-call
             # cache (jit still reuses compiles across this run's chunks).
-            scan_chunk = _build_scan_chunk()
+            scan_chunk, program_notes = _build_scan_chunk(), {}
         elif obj.stateful and state_key is None:
-            scan_chunk = _build_scan_chunk()
+            scan_chunk, program_notes = _build_scan_chunk(), {}
         else:
             # gcfg carries every data-derived static baked into the traced
             # program (cat_value_bins from the bin mapper, onehot_stats from
@@ -3026,13 +3026,25 @@ def _train_impl(
                 _cfg_cache_key(cfg), K, F, F_real, B, _mesh_cache_key(mesh),
                 type(obj).__name__, state_key, gcfg, _delta_onehot,
             )
-            scan_chunk = _SCAN_CACHE.get(cache_key)
-            scan_cache_hit = scan_chunk is not None
-            if scan_chunk is None:
-                scan_chunk = _build_scan_chunk()
+            entry = _SCAN_CACHE.get(cache_key)
+            scan_cache_hit = entry is not None
+            if entry is None:
+                entry = (_build_scan_chunk(), {})
                 if len(_SCAN_CACHE) >= _SCAN_CACHE_MAX:
                     _SCAN_CACHE.pop(next(iter(_SCAN_CACHE)))
-                _SCAN_CACHE[cache_key] = scan_chunk
+                _SCAN_CACHE[cache_key] = entry
+            scan_chunk, program_notes = entry
+        merge_ledger = None
+        if mesh is not None and D > 1 and obs.enabled():
+            # what one iteration's collectives bring each device, read off
+            # the grower's jaxpr once a program and kept with it (a program
+            # that is not cached across calls reads it anew each fit): the
+            # dispatches below count it once for every iteration that RAN
+            if "merge_ledger" not in program_notes:
+                program_notes["merge_ledger"] = _grow_ledger(
+                    grow, gcfg, K, bins_dev, F, quantize_on
+                )
+            merge_ledger = program_notes["merge_ledger"]
 
         if (
             n * n_iter >= _TRACE_CACHE_MIN_WORK
@@ -3196,7 +3208,10 @@ def _train_impl(
         n_done = 0
         stop_at: Optional[int] = None
         chunk_idx = 0
-        sp_program.set(scan_cache_hit=scan_cache_hit)
+        sp_program.set(
+            scan_cache_hit=scan_cache_hit, devices=D,
+            hist_merge=gcfg.hist_merge if mesh is not None and D > 1 else "none",
+        )
         phases.close()  # the dispatches are booster.train's own children
         while n_done < n_iter and stop_at is None:
             t_chunk = time.perf_counter()
@@ -3221,6 +3236,9 @@ def _train_impl(
                     if c < n_iter else xs_dev,
                     *dart_xs,
                 )
+            for op, (calls, nbytes) in (merge_ledger or {}).items():
+                obs.inc("train.merge_calls", float(calls * c), op=op)
+                obs.inc("train.merge_bytes", float(nbytes * c), op=op)
             if quantize_on:
                 trees_c, vsnap_c, qsc_c = scan_ys
             else:
@@ -3486,6 +3504,49 @@ def _train_impl(
         stacked, weights, bin_mapper, cfg, init_model, evals_result,
         best_iter if cfg.early_stopping_round > 0 else -1,
     )
+
+
+def _placement(arr) -> dict:
+    """Span attributes that say where a matrix lives: how many devices hold
+    it and whether they hold shards of it or copies."""
+    sh = getattr(arr, "sharding", None)
+    if sh is None:
+        return {"devices": 0, "sharded": False}
+    return {
+        "devices": len(sh.device_set),
+        "sharded": not sh.is_fully_replicated,
+    }
+
+
+def _grow_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
+                 quantized: bool) -> dict:
+    """The bytes each device receives in ONE boosting iteration's
+    collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`
+    over the sharded grower's jaxpr: an abstract trace, made once a
+    program, with recording off so the trace-time ``collective.*`` counters
+    do not tick for it).  The windowed grower's loop counts as the passes
+    of a full tree (``full_tree_passes``; ``tests/test_dp_resident.py``
+    holds it to the loop's own trips): the program does not carry its trip
+    count out, so a tree that runs out of valid splits early is counted
+    high, and ``train.merge_bytes`` is the bytes of full trees."""
+    from mmlspark_tpu.engine.tree import full_tree_passes
+    from mmlspark_tpu.obs import _state as obs_state
+    from mmlspark_tpu.parallel.distributed import collective_ledger
+
+    n = bins_dev.shape[0]
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    args = [
+        jax.ShapeDtypeStruct(bins_dev.shape, bins_dev.dtype), f32(K, n),
+        f32(K, n), f32(n), jax.ShapeDtypeStruct((K, F_mask), jnp.bool_),
+    ]
+    if quantized:
+        args += [jax.ShapeDtypeStruct((K, 2), jnp.uint32), f32(K, 2)]
+    was, obs_state.enabled = obs_state.enabled, False
+    try:
+        jaxpr = jax.make_jaxpr(grow)(*args)
+    finally:
+        obs_state.enabled = was
+    return collective_ledger(jaxpr, while_trips=full_tree_passes(gcfg))
 
 
 def _fold_bias(stacked: Tree, init) -> Tree:

@@ -1083,8 +1083,8 @@ def grow_tree_depthwise(
     root_hist = window_hist(jnp.zeros(n, jnp.int32))[:, 0]  # (3, F_loc, B)
     # Under reduce_scatter the merged buffer holds only THIS shard's
     # contiguous feature slice: F_loc = F/D is STATIC at trace time
-    # (psum_scatter's result shape; the booster pads F to a multiple of
-    # the axis size).  Every other mode has F_loc == F.
+    # (psum_scatter's result shape; device_psum_scatter pads the histogram
+    # to a multiple of the axis size).  Every other mode has F_loc == F.
     F_loc = root_hist.shape[1]
     hists0 = jnp.zeros((3, LB, F_loc, B), jnp.float32).at[:, 0].set(root_hist)
 
@@ -1603,3 +1603,19 @@ def predict_forest_binned(trees: Tree, bins: jnp.ndarray, num_bins: int) -> jnp.
     init = jnp.zeros(bins.shape[0], jnp.float32)
     out, _ = lax.scan(body, init, trees)
     return out
+
+
+def full_tree_passes(cfg: GrowConfig) -> int:
+    """Trips of the windowed grower's ``while_loop`` for a tree that splits
+    every leaf it may: a pass splits at most ``split_batch`` (where set)
+    and ``level_window`` of the leaves it holds, up to the leaf budget.  The
+    count the program itself does not carry (the loop stops on data); a
+    tree whose leaves run out of valid splits makes other passes."""
+    leaves, passes = 1, 0
+    while leaves < cfg.num_leaves:
+        k = min(leaves, cfg.num_leaves - leaves, cfg.level_window)
+        if cfg.split_batch > 0:
+            k = min(k, cfg.split_batch)
+        leaves += k
+        passes += 1
+    return passes

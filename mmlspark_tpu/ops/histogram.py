@@ -160,8 +160,8 @@ def merge_shard_histograms(
       Reduce-Scatter merge (Ke et al., NeurIPS 2017): split finding then
       runs per-slice and a tiny per-leaf winner all-gather elects the
       global best, cutting received bytes per device per pass from
-      ``3·F·B`` floats to ``3·F·B/D``.  The ``feature_axis`` size must be
-      a multiple of the mesh axis size (the booster right-pads columns).
+      ``3·F·B`` floats to ``3·F·B/D``.  A ``feature_axis`` the mesh axis
+      size does not divide is zero-padded by ``device_psum_scatter``.
     - ``"hierarchical"`` (ISSUE 14, 2D pod mesh): ``axis_name`` is the
       ``(slow, fast)`` axis tuple and the merge psum_scatters over the
       FAST intra-host axis ONLY — each device receives its host's merged

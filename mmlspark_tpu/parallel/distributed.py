@@ -212,7 +212,10 @@ def _leaf_nbytes(x) -> int:
 # call) and rides the collective watchdog, which — when obs is enabled —
 # emits ``collective.calls`` / ``collective.bytes`` counters labeled by op
 # (psum, reduce_scatter, all_gather).  The counters are TRACE-TIME
-# accounting: one increment per traced call site, with nbytes = the bytes
+# accounting (a window of fits that run a cached program reads 0; what RAN
+# is ``train.merge_bytes`` / ``train.merge_calls``, which the booster adds
+# at each ``booster.scan_dispatch`` from :func:`collective_ledger`): one
+# increment per traced call site, with nbytes = the bytes
 # each device RECEIVES per execution of that site (psum: the full reduced
 # array; reduce_scatter: the 1/D slice; all_gather: the D-fold result) —
 # i.e. per-pass wire volume, the quantity the MULTICHIP comms ledger and
@@ -224,6 +227,69 @@ def _leaf_nbytes(x) -> int:
 # at these helpers; COL007 flags full-histogram operands whose axis
 # argument crosses the inter-host axis.
 # ---------------------------------------------------------------------------
+
+
+# The scope every wire op of the merge runs under, beside the grower's
+# ``hist_build`` and ``split_scan``: the device trace names the ops by it.
+MERGE_SCOPE = "hist_merge"
+
+_COLLECTIVE_PRIMS = {
+    "psum": "psum", "psum2": "psum", "psum_invariant": "psum",
+    "pmax": "pmax", "pmin": "pmin",
+    "reduce_scatter": "reduce_scatter",
+    "all_gather": "all_gather", "all_gather_invariant": "all_gather",
+    "ppermute": "ppermute", "all_to_all": "all_to_all",
+}
+
+
+def collective_ledger(jaxpr, while_trips: int = 1) -> dict:
+    """``{op: (calls, bytes)}`` one execution of a traced program makes:
+    for each collective in ``jaxpr`` (a ``ClosedJaxpr`` or ``Jaxpr``, read
+    through ``shard_map``, ``scan``, ``cond`` and calls) the bytes each
+    device RECEIVES, by the convention of the wrappers below (the result's
+    bytes), times how often its site runs.  A ``scan`` multiplies by its
+    length.  A ``while`` loop's count is not in the program: ``while_trips``
+    stands for it (the booster passes the passes of a full tree,
+    ``engine.tree.full_tree_passes``), so a tree that stops early is counted
+    high.  ``cond`` counts its largest branch.  This is what the EXECUTED
+    counters ``train.merge_bytes`` / ``train.merge_calls`` are made from;
+    the wrappers' own ``collective.*`` counters tick once a traced site."""
+    out: dict = {}
+
+    def add(dst, op, calls, nbytes):
+        c, b = dst.get(op, (0, 0))
+        dst[op] = (c + calls, b + nbytes)
+
+    def walk(jp, mult, dst):
+        for eqn in getattr(jp, "jaxpr", jp).eqns:
+            name = eqn.primitive.name
+            op = _COLLECTIVE_PRIMS.get(name)
+            if op is not None:
+                nbytes = _leaf_nbytes([v.aval for v in eqn.outvars])
+                add(dst, op, mult, mult * nbytes)
+                continue
+            inner = mult
+            if name == "scan":
+                inner = mult * int(eqn.params["length"])
+            elif name == "while":
+                inner = mult * int(while_trips)
+            if name == "cond":
+                branches = [{} for _ in eqn.params["branches"]]
+                for br, cur in zip(eqn.params["branches"], branches):
+                    walk(br, mult, cur)
+                best = max(branches, key=lambda d: sum(b for _, b in d.values()))
+                for op_, (c, b) in best.items():
+                    add(dst, op_, c, b)
+                continue
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        # a while's cond_jaxpr runs once more than its body:
+                        # it holds no collective here, and counts as the body
+                        walk(sub, inner, dst)
+
+    walk(jaxpr, 1, out)
+    return out
 
 
 def axis_scope(axis_name) -> str:
@@ -296,10 +362,12 @@ def device_psum(x, axis_name):
     float paths use :func:`psum_axes` instead); the bytes land on the
     slowest tier any named axis touches.
     """
+    import jax
     from jax import lax
 
     with obs.collective_watchdog("psum", **obs.trace_attrs()) as wd:
-        x = lax.psum(x, axis_name)
+        with jax.named_scope(MERGE_SCOPE):
+            x = lax.psum(x, axis_name)
         wd.attrs["nbytes"] = _leaf_nbytes(x)
         obs.inc("collective.axis_bytes", wd.attrs["nbytes"],
                 name="psum", axis=axis_scope(axis_name))
@@ -312,6 +380,7 @@ def device_psum_exact(x, axis_name):
     against ITS link tier as ``all_gather`` — because that IS the wire
     op.  Non-float or single-axis operands fall through to the ordinary
     ledgered :func:`device_psum` (already order-exact)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -326,7 +395,8 @@ def device_psum_exact(x, axis_name):
     with obs.collective_watchdog("all_gather", **obs.trace_attrs()) as wd:
         total = 0
         for ax in reversed(axes):  # fast (intra-host) axis first
-            g = lax.all_gather(x, ax)
+            with jax.named_scope(MERGE_SCOPE):
+                g = lax.all_gather(x, ax)
             nb = _leaf_nbytes(g)
             total += nb
             obs.inc("collective.axis_bytes", nb,
@@ -336,19 +406,40 @@ def device_psum_exact(x, axis_name):
     return x
 
 
+def _pad_to_axis(x, axis_name, dimension: int):
+    """``x`` zero-padded along ``dimension`` to a multiple of the mesh axis
+    size (static under ``shard_map``), which ``psum_scatter`` asks for.  The
+    histogram is padded, a few KB a pass, never the binned matrix: 39
+    columns on 4 chips scatter as 40, and the grower masks the slot that
+    no column fills out of every candidate search."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    pad = (-x.shape[dimension]) % lax.psum(1, axis_name)
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[dimension] = (0, pad)
+    return jnp.pad(x, widths)
+
+
 def device_psum_scatter(x, axis_name, scatter_dimension: int = 0,
                         tiled: bool = True):
     """``lax.psum_scatter``: reduce + scatter contiguous blocks of
     ``scatter_dimension`` over the mesh axis — each device receives the
     fully-reduced values for its 1/D block (``tiled=True`` keeps the axis
-    in place at size/D).  The block size must divide the axis size; callers
-    pad (the booster right-pads feature columns)."""
+    in place at size/D).  A dimension the axis size does not divide is
+    zero-padded up to it here (:func:`_pad_to_axis`): the caller masks what
+    the padding adds (the grower: the feature slots past the last column)."""
+    import jax
     from jax import lax
 
+    x = _pad_to_axis(x, axis_name, scatter_dimension)
     with obs.collective_watchdog("reduce_scatter", **obs.trace_attrs()) as wd:
-        out = lax.psum_scatter(
-            x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled
-        )
+        with jax.named_scope(MERGE_SCOPE):
+            out = lax.psum_scatter(
+                x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled
+            )
         wd.attrs["nbytes"] = _leaf_nbytes(out)
         obs.inc("collective.axis_bytes", wd.attrs["nbytes"],
                 name="reduce_scatter", axis=axis_scope(axis_name))
@@ -357,10 +448,12 @@ def device_psum_scatter(x, axis_name, scatter_dimension: int = 0,
 
 def device_all_gather(x, axis_name, **kw):
     """``lax.all_gather`` under the collective watchdog + byte accounting."""
+    import jax
     from jax import lax
 
     with obs.collective_watchdog("all_gather", **obs.trace_attrs()) as wd:
-        out = lax.all_gather(x, axis_name, **kw)
+        with jax.named_scope(MERGE_SCOPE):
+            out = lax.all_gather(x, axis_name, **kw)
         wd.attrs["nbytes"] = _leaf_nbytes(out)
         obs.inc("collective.axis_bytes", wd.attrs["nbytes"],
                 name="all_gather", axis=axis_scope(axis_name))
@@ -390,11 +483,13 @@ def device_psum_int(x, axis_name):
     makes the integer sum overflow-safe, so a float sneaking in here
     means the plan was bypassed.
     """
+    import jax
     from jax import lax
 
     _require_int_wire(x, "device_psum_int")
     with obs.collective_watchdog("psum", **obs.trace_attrs()) as wd:
-        x = lax.psum(x, axis_name)  # integer sum: order-exact
+        with jax.named_scope(MERGE_SCOPE):
+            x = lax.psum(x, axis_name)  # integer sum: order-exact
         wd.attrs["nbytes"] = _leaf_nbytes(x)
         obs.inc("collective.axis_bytes", wd.attrs["nbytes"],
                 name="psum", axis=axis_scope(axis_name))
@@ -405,13 +500,16 @@ def device_psum_int(x, axis_name):
 def device_psum_scatter_int(x, axis_name, scatter_dimension: int = 0,
                             tiled: bool = True):
     """Integer-wire ``lax.psum_scatter`` (see :func:`device_psum_int`)."""
+    import jax
     from jax import lax
 
     _require_int_wire(x, "device_psum_scatter_int")
+    x = _pad_to_axis(x, axis_name, scatter_dimension)
     with obs.collective_watchdog("reduce_scatter", **obs.trace_attrs()) as wd:
-        out = lax.psum_scatter(
-            x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled
-        )
+        with jax.named_scope(MERGE_SCOPE):
+            out = lax.psum_scatter(
+                x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled
+            )
         nbytes = _leaf_nbytes(out)
         wd.attrs["nbytes"] = nbytes
         obs.inc("hist.quantized_bytes", nbytes)

@@ -206,7 +206,8 @@ def test_a_fit_leaves_one_of_each_phase_under_one_train_span(mode, path, monkeyp
     assert got["booster.upload"]["bins_cached"] is False and got["booster.upload"]["bytes"] > 0
     assert got["booster.collect"] == {"iters": 3}
     if path == "fused":
-        assert set(got["booster.program"]) == {"xs_cache_hit", "scan_cache_hit"}
+        assert set(got["booster.program"]) == {"xs_cache_hit", "scan_cache_hit", "devices", "hist_merge"}
+        assert (got["booster.program"]["devices"], got["booster.program"]["hist_merge"]) == (1, "none")  # no mesh
     # binning stays, as the prepare phase's child
     (binning,) = [r for r in recs if r["name"] == "booster.binning"]
     assert binning["parent"] == "booster.prepare"
@@ -235,11 +236,12 @@ def test_upload_bytes_by_hand_and_less_the_matrix_on_a_second_fit():
     valid_rows = vbins.nbytes + 64 * 4  # its binned matrix and its float32 scores
     assert first == bins.nbytes + rows + valid_rows
     (up,) = flight.spans("booster.upload")
-    assert up["attrs"] == {"bins_cached": False, "bytes": first}
+    one_device = {"devices": 1, "sharded": False}  # where the binned matrix lives (PR 27)
+    assert up["attrs"] == {"bins_cached": False, "bytes": first, **one_device}
 
     train(_params(), ds, valid_sets=[valid])  # the same Dataset: its matrix is resident
     assert sent() - first == first - bins.nbytes
-    assert flight.spans("booster.upload")[-1]["attrs"] == {"bins_cached": True, "bytes": first - bins.nbytes}
+    assert flight.spans("booster.upload")[-1]["attrs"] == {"bins_cached": True, "bytes": first - bins.nbytes, **one_device}
 
 
 def test_scorer_builds_once_for_a_new_booster(mode):
@@ -264,7 +266,7 @@ def test_scorer_builds_once_for_a_new_booster(mode):
     if mode == "enabled":
         assert (first, second) == (1, 0)
     one, two = flight.spans("booster.score_binned")
-    assert one["attrs"] == {"backend": "scan", "rows": 256, "trees": 3, "built": True}
+    assert one["attrs"] == {"backend": "scan", "rows": 256, "trees": 3, "built": True, "devices": 1, "sharded": False}
     assert two["attrs"]["built"] is False
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
