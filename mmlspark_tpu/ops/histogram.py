@@ -28,6 +28,11 @@ Backends:
 
 All are row-chunked with ``lax.scan`` so peak memory is bounded by the chunk,
 not the dataset (HBM holds only the uint8 binned matrix — SURVEY.md §7.2).
+The scan walks the chunk INDEX and its body slices chunk ``i`` out of the
+bins, ``vals`` and leaf ids where they lie (``_row_chunk``), whatever the
+layout.  Nothing is re-laid-out to put chunks on a leading axis: for the
+growers' ``(F, n)`` matrix that is a copy of the whole data set on every
+histogram pass, and a compile that follows the row count (PERF.md §6 PR 28).
 """
 
 from __future__ import annotations
@@ -41,6 +46,12 @@ from jax import lax
 
 # Default rows per scan chunk; callers pad row counts to a multiple.
 DEFAULT_CHUNK = 16_384
+
+
+def _row_chunk(x, i, size: int, axis: int):
+    """Rows ``[i·size, (i+1)·size)`` of ``x`` along its row ``axis``."""
+    return lax.dynamic_slice_in_dim(x, i * size, size, axis=axis)
+
 
 # ---------------------------------------------------------------------------
 # Quantized accumulation (ISSUE 9 — LightGBM quantized training,
@@ -404,7 +415,9 @@ def build_histogram(
     past it — growers hoist the transpose out of their per-pass loop
     (pallas wants rows on the lane axis and widens per VMEM block; the
     scatter/onehot fallbacks transpose back and widen per chunk, they
-    are the small-scale/test paths).
+    are the small-scale/test paths).  A chunk is the (F, chunk) column
+    slice of that matrix, taken inside the scan; the matrix itself is
+    never reshaped or transposed.
 
     When ``axis_name`` is set (running inside ``shard_map`` over row shards),
     the result is ``psum``-med across the mesh axis — this single line is the
@@ -481,27 +494,20 @@ def build_histogram(
     else:
         if n % chunk != 0:
             raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
-        if packed:
-            if chunk % 2:
-                raise ValueError(
-                    f"packed bins need an even chunk, got {chunk}"
-                )
-            # two logical rows per packed row: unpack happens per-chunk in
-            # the body, so peak unpacked residency is ONE chunk
-            bc = bins.reshape(n // chunk, chunk // 2, F)
-        elif transposed:
-            bc = bins.reshape(F, n // chunk, chunk).transpose(1, 0, 2)
-        else:
-            bc = bins.reshape(n // chunk, chunk, F)
-        vc = vals.reshape(3, n // chunk, chunk).transpose(1, 0, 2)
+        if packed and chunk % 2:
+            raise ValueError(f"packed bins need an even chunk, got {chunk}")
+        # two logical rows per packed row: unpack happens per-chunk in
+        # the body, so peak unpacked residency is ONE chunk
+        bin_rows = chunk // 2 if packed else chunk
+        bin_axis = 1 if transposed else 0
 
-        def body(acc, xs):
-            b, v = xs
+        def body(acc, i):
+            b = _row_chunk(bins, i, bin_rows, bin_axis)
             if packed:
                 b = unpack_rows(b, chunk)
-            return acc + fn(b, v, num_bins), None
+            return acc + fn(b, _row_chunk(vals, i, chunk, 1), num_bins), None
 
-        hist, _ = lax.scan(body, acc0, (bc, vc))
+        hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
     if axis_name is not None:
         if quant:
             hist = merge_shard_histograms_quantized(
@@ -587,7 +593,8 @@ def build_histogram_by_leaf(
     pass, which passes ``leaf_ids - base``) must arrive with ``leaf_ids``
     outside ``[0, num_leaves)`` (any parked value, including negatives) or
     zeroed ``vals``.  ``transposed=True``: bins arrive as (F, n) integer —
-    uint8 through the byte tier (see :func:`build_histogram`).  With
+    uint8 through the byte tier — and each chunk is its (F, chunk) column
+    slice, taken inside the scan (see :func:`build_histogram`).  With
     ``axis_name``, the result is psum-med
     across the mesh — the same single-collective structure as
     :func:`build_histogram`.
@@ -651,18 +658,15 @@ def build_histogram_by_leaf(
     else:
         if n % chunk != 0:
             raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
-        if transposed:
-            bc = bins.reshape(F, n // chunk, chunk).transpose(1, 0, 2)
-        else:
-            bc = bins.reshape(n // chunk, chunk, F)
-        vc = vals.reshape(3, n // chunk, chunk).transpose(1, 0, 2)
-        lc = leaf_ids.reshape(n // chunk, chunk)
+        bin_axis = 1 if transposed else 0
 
-        def body(acc, xs):
-            b, v, l = xs
+        def body(acc, i):
+            b = _row_chunk(bins, i, chunk, bin_axis)
+            v = _row_chunk(vals, i, chunk, 1)
+            l = _row_chunk(leaf_ids, i, chunk, 0)
             return acc + fn(b, v, l, num_leaves, num_bins), None
 
-        hist, _ = lax.scan(body, acc0, (bc, vc, lc))
+        hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
     if axis_name is not None:
         if quant:
             hist = merge_shard_histograms_quantized(

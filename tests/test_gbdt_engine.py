@@ -118,6 +118,102 @@ class TestHistogram:
         h2 = build_histogram(bins, vals, mask, B, chunk=1024)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-4, atol=1e-4)
 
+    # -- the chunk loop over the growers' (F, n) matrix: n = 4 chunks of 256 --
+    _N, _CHUNK, _B, _W = 1024, 256, 256, 8
+    _chunk_cases = pytest.mark.parametrize(
+        "kind,backend,F",
+        [(k, b, F) for k in ("by_leaf", "plain") for b in ("scatter", "pallas") for F in (5, 39)],
+    )
+
+    def _chunk_inputs(self, F, seed):
+        """uint8 bins (n, F), vals (3, n), leaf ids parked on both sides of
+        ``[0, W)``, a row mask; chunk 1 is all parked, chunk 2 all masked."""
+        rng = np.random.default_rng(seed)
+        n, c = self._N, self._CHUNK
+        bins = rng.integers(0, self._B, size=(n, F)).astype(np.uint8)
+        vals = rng.normal(size=(3, n)).astype(np.float32)
+        leaf = rng.integers(-2, self._W + 2, size=n).astype(np.int32)
+        leaf[c:2 * c] = np.where(rng.random(c) < 0.5, -1, self._W)
+        mask = rng.random(n) > 0.3
+        mask[2 * c:3 * c] = False
+        return bins, vals, leaf, mask
+
+    def _chunk_build(self, kind, bins, vals, leaf, mask, **kw):
+        """Either builder on the same rows; by-leaf takes the mask as zeroed ``vals``."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops.histogram import build_histogram, build_histogram_by_leaf
+
+        if kind == "plain":
+            return build_histogram(jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(mask), self._B, **kw)
+        return build_histogram_by_leaf(
+            jnp.asarray(bins), jnp.asarray(np.where(mask[None, :], vals, 0)), jnp.asarray(leaf),
+            self._W, self._B, **kw,
+        )
+
+    @_chunk_cases
+    def test_transposed_chunks_are_the_row_major_chunks(self, kind, backend, F):
+        """A chunk sliced out of (F, n) inside the scan is the chunk the
+        row-major split hands the same kernel: equal sums bit for bit, and
+        the unchunked call's to float tolerance."""
+        bins, *rest = self._chunk_inputs(F, seed=F)
+        kw = dict(backend=backend, chunk=self._CHUNK)
+        t = np.asarray(self._chunk_build(kind, bins.T, *rest, transposed=True, **kw))
+        r = np.asarray(self._chunk_build(kind, bins, *rest, **kw))
+        u = np.asarray(self._chunk_build(kind, bins.T, *rest, transposed=True, backend=backend, chunk=self._N))
+        np.testing.assert_array_equal(t, r)
+        np.testing.assert_allclose(t, u, rtol=1e-5, atol=1e-4)
+
+    @_chunk_cases
+    def test_transposed_chunks_drop_parked_and_masked_rows(self, kind, backend, F):
+        """Parked leaf ids and masked rows drop out in every chunk: the sums
+        are numpy's over the kept rows, whatever the dropped rows hold."""
+        bins, vals, leaf, mask = self._chunk_inputs(F, seed=100 + F)
+        kw = dict(backend=backend, chunk=self._CHUNK, transposed=True)
+        got = np.asarray(self._chunk_build(kind, bins.T, vals, leaf, mask, **kw))
+        kept = mask if kind == "plain" else mask & (leaf >= 0) & (leaf < self._W)
+        rows = np.flatnonzero(kept)
+        want = np.zeros(got.shape, np.float64)
+        for c in range(3):
+            for f in range(F):
+                if kind == "plain":
+                    np.add.at(want[c, f], bins[rows, f], vals[c, rows])
+                else:
+                    np.add.at(want[c, :, f], (leaf[rows], bins[rows, f]), vals[c, rows])
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        scrambled = np.where(kept[:, None], bins, np.uint8(255) - bins)
+        again = np.asarray(self._chunk_build(kind, scrambled.T, vals, leaf, mask, **kw))
+        np.testing.assert_array_equal(got, again)
+
+    @_chunk_cases
+    def test_transposed_chunk_loop_relays_no_whole_array(self, kind, backend, F):
+        """The relayout cannot come back unseen on a CPU: the chunked
+        transposed call's jaxpr holds no transpose or reshape of the whole
+        integer bins matrix and no transpose of the whole ``vals``; the scan's
+        body slices the matrix itself."""
+        import jax
+
+        bins, *rest = self._chunk_inputs(F, seed=0)
+        jaxpr = jax.make_jaxpr(
+            lambda b: self._chunk_build(kind, b, *rest, backend=backend, chunk=self._CHUNK, transposed=True)
+        )(bins.T)
+        whole_bins, whole_vals, sliced = self._N * F, 3 * self._N, []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                name = eqn.primitive.name
+                for a in (v.aval for v in eqn.invars[:1]):  # the operand, where there is one
+                    if np.issubdtype(a.dtype, np.integer) and a.size == whole_bins:
+                        assert name not in ("transpose", "reshape"), eqn
+                        if name == "dynamic_slice":
+                            sliced.append(a.shape)
+                    assert not (name == "transpose" and a.size == whole_vals), eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        assert sliced == [(F, self._N)]
+
     def test_pallas_matches_scatter(self):
         import jax.numpy as jnp
 
