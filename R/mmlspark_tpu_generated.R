@@ -1883,7 +1883,7 @@ ml_k_n_n_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2053,7 +2053,7 @@ ml_light_g_b_m_classification_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2223,7 +2223,7 @@ ml_light_g_b_m_classifier <- function(
 #' @param group_col Query group column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2396,7 +2396,7 @@ ml_light_g_b_m_ranker <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2558,7 +2558,7 @@ ml_light_g_b_m_ranker_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2720,7 +2720,7 @@ ml_light_g_b_m_regression_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels

@@ -405,27 +405,24 @@ def kernels_phase(sz: dict, booster, X) -> dict:
         leaf = jnp.asarray(rng.integers(-1, W + 1, size=n), jnp.int32)
         got, ref = (
             H.build_histogram_by_leaf(bins_t, vals, leaf, W, B, backend=be,
-                                      chunk=n, transposed=True)
+                                      chunk=n)
             for be in ("pallas", "scatter")
         )
         close(got, ref, name, 1e-5)  # test_gbdt_engine.py TestByLeafKernels
         equal(
-            PH.pallas_hist_by_leaf_chunk_int(
-                bins_t, qvals, leaf, W, B, precision="default",
-                transposed=True),
-            H._scatter_hist_by_leaf_chunk_int(bins_t.T, qvals, leaf, W, B),
+            PH.pallas_hist_by_leaf_chunk(
+                bins_t, qvals, leaf, W, B, precision="default"),
+            H._scatter_hist_by_leaf_chunk(bins_t, qvals, leaf, W, B),
             f"by_leaf_int_W{W}",
         )
     got, ref = (
-        H.build_histogram(bins_t, vals, mask, B, backend=be, chunk=n,
-                          transposed=True)
+        H.build_histogram(bins_t, vals, mask, B, backend=be, chunk=n)
         for be in ("pallas", "scatter")
     )
     close(got, ref, "hist", 1e-4)  # ...::test_pallas_matches_scatter
     equal(
-        PH.pallas_hist_chunk_int(bins_t, qvals, B, precision="default",
-                                 transposed=True),
-        H._scatter_hist_chunk_int(bins_t.T, qvals, B),
+        PH.pallas_hist_chunk(bins_t, qvals, B, precision="default"),
+        H._scatter_hist_chunk(bins_t, qvals, B),
         "hist_int",
     )
 

@@ -138,7 +138,7 @@ class TrainConfig:
     predict_backend: str = "auto"
     # 0 = auto: one chunk (the whole padded row count, capped) under the
     # pallas backend — fewer scan steps; DEFAULT_CHUNK for the
-    # memory-bound scatter/onehot builders.
+    # memory-bound scatter builder.
     hist_chunk: int = 0
     # Histogram / leaf-delta contraction precision: "highest" = f32 MXU
     # passes (scatter-add-exact numerics), "default" = bf16 multiplies with
@@ -149,9 +149,6 @@ class TrainConfig:
     # f32 everywhere else (CPU dots are f32 regardless; keeping "highest"
     # there preserves scatter-exact parity in the test oracles).
     hist_precision: str = "auto"
-    # Wire dtype of the cross-shard histogram allreduce: float32 | bfloat16
-    # (halves the dominant data-parallel collective; see GrowConfig)
-    hist_psum_dtype: str = "float32"
     # Cross-shard histogram merge strategy for the data-parallel learner:
     # "allreduce" (every device receives all F×B histogram floats per
     # node — SURVEY §3.1 direct allreduce), "reduce_scatter" (each device
@@ -173,8 +170,7 @@ class TrainConfig:
     # row-count headroom — ops.histogram.quantize_wire_plan picks the
     # pre-wire shift; int sums are associative, so allreduce and
     # reduce_scatter merges agree bit-for-bit).  "on" = resolved to
-    # "int16" by resolve_auto_config.  Supersedes hist_psum_dtype on this
-    # path: explicit bfloat16 + quantize is rejected (one coherent wire).
+    # "int16" by resolve_auto_config.
     # Winning splits get an f32 refinement pass, and leaf values come
     # from exact f32 sums, so AUC holds parity with the f32 path.
     hist_quantize: str = "off"
@@ -1342,17 +1338,6 @@ def resolve_auto_config(
             f"{cfg.hist_quantize!r}"
         )
     if cfg.hist_quantize != "off":
-        if cfg.hist_psum_dtype not in ("float32",):
-            # ONE coherent wire: quantized merges travel as integers, so a
-            # float wire dtype request on the same path is a contradiction,
-            # not a preference to silently override.
-            raise ValueError(
-                "hist_quantize and hist_psum_dtype="
-                f"{cfg.hist_psum_dtype!r} both rewire the histogram merge; "
-                "pick ONE wire — quantized histograms already merge over "
-                "the int16/int32 wire (strictly less traffic than bf16), "
-                "so drop hist_psum_dtype or set hist_quantize='off'"
-            )
         if cfg.tree_learner in (
             "voting", "voting_parallel", "feature", "feature_parallel"
         ):
@@ -2279,7 +2264,6 @@ def _train_impl(
         hist_backend=cfg.hist_backend,
         hist_chunk=chunk,
         hist_precision=cfg.hist_precision,
-        hist_psum_dtype=cfg.hist_psum_dtype,
         hist_merge=(
             "hierarchical" if hierarchical
             else "reduce_scatter" if reduce_scatter

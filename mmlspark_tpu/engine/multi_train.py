@@ -551,7 +551,6 @@ def multi_train(
         hist_backend=cfg0.hist_backend,
         hist_chunk=chunk,
         hist_precision=cfg0.hist_precision,
-        hist_psum_dtype=cfg0.hist_psum_dtype,
         hist_merge="allreduce",
         hist_quantize=cfg0.hist_quantize,
         quantize_shift=0,

@@ -80,12 +80,6 @@ class GrowConfig:
     # f32 — validate AUC before enabling on a new workload).
     hist_precision: str = "highest"
     axis_name: Optional[str] = None  # set under shard_map for psum
-    # Wire dtype for the histogram allreduce: "float32" (exact) or
-    # "bfloat16" — halves the dominant data-parallel collective (3·L·F·B
-    # floats/pass) at ~2^-8 relative rounding on the cross-shard SUM only
-    # (per-shard accumulation stays f32).  Quality-gate with AUC before
-    # enabling (tools/bench_scaling.py measures both).
-    hist_psum_dtype: str = "float32"
     # Cross-shard histogram merge of the data-parallel learner (depthwise/
     # windowed grower only).  "allreduce": every device receives ALL F
     # features' merged bins per pass (the reference's socket allreduce).
@@ -172,7 +166,7 @@ class GrowConfig:
     # refinement re-accumulation, and final leaf values are always
     # computed from raw f32 grad/hess.  resolve_auto_config validates
     # the value ("on" → "int16") and rejects voting/feature-parallel
-    # and bf16-wire combinations before a GrowConfig is ever built.
+    # learners before a GrowConfig is ever built.
     hist_quantize: str = "off"
     # Static pre-wire right-shift from ops.histogram.quantize_wire_plan
     # (0 when the worst-case global bin total already fits the wire).
@@ -872,8 +866,7 @@ def grow_tree(
         return build_histogram(
             bins_t, qvals, mask, B,
             backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=cfg.axis_name,
-            psum_dtype=cfg.hist_psum_dtype,
-            precision=cfg.hist_precision, transposed=True,
+            precision=cfg.hist_precision,
             quantize=hq,
         )
 
@@ -903,8 +896,7 @@ def grow_tree(
             ref = build_histogram(
                 wcol, vals, leaf_ids == l, B,
                 backend=cfg.hist_backend, chunk=cfg.hist_chunk,
-                axis_name=cfg.axis_name, psum_dtype="float32",
-                precision=cfg.hist_precision, transposed=True,
+                axis_name=cfg.axis_name, precision=cfg.hist_precision,
                 merge="allreduce_exact",  # recorded gains: layout-invariant
             )[:, None]  # (3, 1, 1, B)
             ref_col = ref[:, 0, 0]  # (3, B) exact winner column
@@ -1070,8 +1062,7 @@ def grow_tree_depthwise(
         return build_histogram_by_leaf(
             bins_t, qvals, win_leaf, W, B,
             backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=hist_axis,
-            psum_dtype=cfg.hist_psum_dtype,
-            precision=cfg.hist_precision, transposed=True,
+            precision=cfg.hist_precision,
             merge=merge_mode,
             quantize=hq,
         )
@@ -1277,8 +1268,7 @@ def grow_tree_depthwise(
             ref_hist = build_histogram_by_leaf(
                 win_col[None, :], vals, row_slot, W, B,
                 backend=cfg.hist_backend, chunk=cfg.hist_chunk,
-                axis_name=hist_axis, psum_dtype="float32",
-                precision=cfg.hist_precision, transposed=True,
+                axis_name=hist_axis, precision=cfg.hist_precision,
                 # exact AND process-layout-invariant: the refined
                 # gains/thresholds are recorded in the model, so their
                 # f32 sum order must not depend on how many processes

@@ -1156,7 +1156,7 @@ class LightGBMClassificationModel(_LightGBMClassificationModel):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1220,7 +1220,7 @@ class LightGBMClassifier(_LightGBMClassifier):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1286,7 +1286,7 @@ class LightGBMRanker(_LightGBMRanker):
       groupCol: Query group column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1351,7 +1351,7 @@ class LightGBMRankerModel(_LightGBMRankerModel):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1413,7 +1413,7 @@ class LightGBMRegressionModel(_LightGBMRegressionModel):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1475,7 +1475,7 @@ class LightGBMRegressor(_LightGBMRegressor):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity); mutually exclusive with hist_psum_dtype=bfloat16
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels

@@ -85,7 +85,7 @@ class _LightGBMExecutionParams(Params):
         "per-row grad/hess to ±127 buckets with seeded stochastic "
         "rounding, accumulates int32 histograms and merges shards over an "
         "integer collective wire (f32 winner refinement keeps AUC "
-        "parity); mutually exclusive with hist_psum_dtype=bfloat16",
+        "parity)",
         default="off", dtype=str,
         validator=ParamValidators.inList(["off", "on", "int16", "int32"]),
     )

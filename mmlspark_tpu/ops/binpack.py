@@ -7,10 +7,8 @@ largest training-resident array.  Two lossless packing tiers:
   fits 4 bits — packing consecutive ROW pairs of a column into one byte
   halves the binned cache's HBM/upload bytes.  Row-pair (not
   column-pair) packing keeps the feature axis intact, so per-feature
-  metadata (categorical masks, bounds) is untouched and the histogram
-  kernels can consume the packed layout directly, unpacking per scan
-  chunk (``build_histogram(..., packed=True)``) — peak unpacked
-  residency stays one chunk, never the full matrix.
+  metadata (categorical masks, bounds) is untouched.  The streamed
+  cache (``data/streaming.py``) unpacks it once, before a fit.
 
 - **Byte tier** (``16 < num_bins ≤ 256``, i.e. through the default
   ``max_bin=255``): every index fits ONE byte, so the packed form is
@@ -21,7 +19,7 @@ largest training-resident array.  Two lossless packing tiers:
   int32 (4 bytes/index) for the histogram kernels.
   :func:`hist_transpose` is the single authority for that layout: it
   keeps the transposed matrix uint8 whenever the byte tier applies and
-  the Pallas/scatter/onehot kernels widen per block/chunk INSIDE their
+  the Pallas/scatter kernels widen per block/chunk INSIDE their
   bodies, so HBM holds (and every hist pass DMAs) 1-byte indices — a 4×
   cut in the hist-pass working set at 255 bins.
 
@@ -35,8 +33,8 @@ split selection from a packed cache is bitwise-identical — tested in
 ``tests/test_streaming.py`` and ``tests/test_binpack_bytes.py``.
 
 All helpers are dual-backend: they use only ufunc-style operators, so
-numpy arrays stay numpy and jax arrays trace/jit (the unpack runs
-inside the histogram scan body on device).
+numpy arrays stay numpy and jax arrays trace/jit (the streamed cache
+unpacks on the device).
 """
 
 from __future__ import annotations

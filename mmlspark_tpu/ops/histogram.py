@@ -4,7 +4,7 @@ TPU-native replacement for LightGBM's histogram construction (reference
 native component N1, SURVEY.md §2.9: upstream C++ ``src/treelearner/*`` and
 its CUDA kernels, shipped prebuilt in the ``lightgbmlib`` jar — [REF-EMPTY]).
 
-Three interchangeable backends build the same CHANNEL-MAJOR histogram of
+Two interchangeable backends build the same CHANNEL-MAJOR histogram of
 ``(Σgrad, Σhess, Σcount)``:
 
 - ``build_histogram``          → ``(3, F, B)``
@@ -14,25 +14,29 @@ Channel-major layout is a TPU tiling decision: every downstream consumer
 (cumsums, split gains) then operates on arrays whose MINOR axis is the
 bin axis (lane-sized), instead of a trailing size-3 channel axis that
 wastes ~97% of each 8×128 vector tile.  ``vals`` arrives as ``(3, n)`` for
-the same reason.
+the same reason, and the bins as the growers' ``(F, n)`` matrix — uint8
+through the byte tier (``num_bins ≤ 256``, ``ops/binpack.py``), int32
+past it: rows on the lane axis, which is what the kernels read.
 
 Backends:
 
 - ``scatter``  — ``jnp...at[].add`` scatter-add.  Reference semantics; the
-  backend used on the CPU test mesh.
-- ``onehot``   — blocked one-hot × values matmul: the contraction lands on
-  the MXU, with feature-blocking to bound the materialized one-hot tile.
-  This is the jit-only TPU path.
-- ``pallas``   — Pallas kernel (``mmlspark_tpu.ops.pallas_hist``) doing the
-  one-hot-matmul trick with the one-hot tile living in VMEM only.
+  backend used on the CPU test mesh (it transposes and widens per chunk).
+- ``pallas``   — Pallas kernels (``mmlspark_tpu.ops.pallas_hist``): the
+  histogram as a one-hot × values matmul on the MXU, with the one-hot
+  tile living in VMEM only.  The chip's path; interpreted on a CPU.
 
-All are row-chunked with ``lax.scan`` so peak memory is bounded by the chunk,
+Every chunk body sums in the accumulator its ``vals`` ask for, read from
+their dtype at trace time: float32, or int32 for the int16 buckets of
+quantized training.
+
+Both are row-chunked with ``lax.scan`` so peak memory is bounded by the chunk,
 not the dataset (HBM holds only the uint8 binned matrix — SURVEY.md §7.2).
 The scan walks the chunk INDEX and its body slices chunk ``i`` out of the
-bins, ``vals`` and leaf ids where they lie (``_row_chunk``), whatever the
-layout.  Nothing is re-laid-out to put chunks on a leading axis: for the
-growers' ``(F, n)`` matrix that is a copy of the whole data set on every
-histogram pass, and a compile that follows the row count (PERF.md §6 PR 28).
+bins, ``vals`` and leaf ids where they lie (``_row_chunk``).  Nothing is
+re-laid-out to put chunks on a leading axis: for the ``(F, n)`` matrix
+that is a copy of the whole data set on every histogram pass, and a
+compile that follows the row count (PERF.md §6 PR 28).
 """
 
 from __future__ import annotations
@@ -158,7 +162,6 @@ def merge_shard_histograms(
     hist: jnp.ndarray,
     axis_name: str,
     merge: str = "allreduce",
-    psum_dtype: str = "float32",
     feature_axis: int = 1,
 ) -> jnp.ndarray:
     """Cross-shard histogram merge — the one collective of the
@@ -191,8 +194,6 @@ def merge_shard_histograms(
       recorded in the model (the multihost bitwise-parity gate,
       tools/multihost_smoke.py).
 
-    ``psum_dtype="bfloat16"`` halves the wire for any strategy: local
-    f32 partial sums are cast down for the cross-shard reduction only.
     All delegate to the watchdog-wrapped device collectives in
     :mod:`mmlspark_tpu.parallel.distributed`, so call counts and received
     bytes land in the obs ``collective.*`` ledger (split per axis tier
@@ -232,10 +233,6 @@ def merge_shard_histograms(
             f"unknown hist_merge {merge!r}; expected "
             "allreduce|allreduce_exact|reduce_scatter|hierarchical"
         )
-    if psum_dtype == "bfloat16":
-        # halve the wire: per-shard sums stay f32; only the cross-shard
-        # reduction rides bf16 (tools/bench_scaling.py gates it)
-        return op(hist.astype(jnp.bfloat16)).astype(jnp.float32)
     return op(hist)
 
 
@@ -319,71 +316,99 @@ def merge_shard_histograms_quantized(
     return merged * jnp.exp2(s.astype(jnp.float32))
 
 
+def _is_bucket(vals_dtype) -> bool:
+    """True for integer (quantized bucket) row values: int32 accumulation."""
+    return jnp.issubdtype(vals_dtype, jnp.integer)
+
+
 def _scatter_hist_chunk(bins_c, vals_c, num_bins: int):
-    """(C, F) int bins, (3, C) vals → (3, F, B) via scatter-add."""
-    C, F = bins_c.shape
-    idx = bins_c.astype(jnp.int32) + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
+    """(F, C) int bins, (3, C) vals → (3, F, B) via scatter-add: float32
+    sums, or int32 sums of int16 bucket ``vals_c``.  headroom: |bucket| ≤
+    QMAX, so C·QMAX row sums fit int32 for any chunk ≤ 16.9M rows
+    (quantize_wire_plan)."""
+    F, C = bins_c.shape
+    acc = jnp.int32 if _is_bucket(vals_c.dtype) else jnp.float32
+    idx = bins_c.T.astype(jnp.int32) + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
     flat = jax.vmap(
-        lambda v: jnp.zeros(F * num_bins, jnp.float32).at[idx.reshape(-1)].add(
-            jnp.broadcast_to(v[:, None], (C, F)).reshape(-1)
+        lambda v: jnp.zeros(F * num_bins, acc).at[idx.reshape(-1)].add(
+            jnp.broadcast_to(v.astype(acc)[:, None], (C, F)).reshape(-1)
         )
     )(vals_c)
     return flat.reshape(3, F, num_bins)
 
 
-def _onehot_hist_chunk(bins_c, vals_c, num_bins: int, feat_block: int = 8):
-    """Same contraction as ``_scatter_hist_chunk`` but as MXU matmuls."""
-    C, F = bins_c.shape
-    bins_c = bins_c.astype(jnp.int32)  # uint8 arrivals widen per chunk
-    pad_f = (-F) % feat_block
-    if pad_f:
-        # Padded features all hit bin 0 with zero value — harmless.
-        bins_c = jnp.pad(bins_c, ((0, 0), (0, pad_f)))
-    Fp = F + pad_f
-    blocks = bins_c.reshape(C, Fp // feat_block, feat_block).transpose(1, 0, 2)
+def _scatter_hist_by_leaf_chunk(bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int):
+    """(F, C) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B) scatter-add:
+    float32 sums, or int32 sums of int16 bucket ``vals_c``.  headroom:
+    |bucket| ≤ QMAX keeps C·QMAX sums inside int32 (quantize_wire_plan).
 
-    def block_hist(bl):  # (C, feat_block)
-        oh = (bl[:, :, None] == jnp.arange(num_bins, dtype=bl.dtype)[None, None, :])
-        oh = oh.astype(jnp.float32).reshape(C, feat_block * num_bins)
-        return (vals_c @ oh).reshape(3, feat_block, num_bins)
-
-    hist = lax.map(block_hist, blocks)  # (Fp/fb, 3, fb, B)
-    return hist.transpose(1, 0, 2, 3).reshape(3, Fp, num_bins)[:, :F]
-
-
-def _scatter_hist_chunk_int(bins_c, vals_c, num_bins: int):
-    """Quantized twin of ``_scatter_hist_chunk``: (3, C) int16 vals →
-    (3, F, B) int32 scatter-add.  headroom: |val| ≤ QMAX, so C·QMAX row
-    sums fit int32 for any chunk ≤ 16.9M rows (quantize_wire_plan)."""
-    C, F = bins_c.shape
-    idx = bins_c.astype(jnp.int32) + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
+    Rows parked outside ``[0, num_leaves)`` (including NEGATIVE ids from the
+    windowed depthwise pass) are routed to a scratch slot and sliced off —
+    negative flat indices would otherwise WRAP in ``.at[].add``.
+    """
+    F, C = bins_c.shape
+    acc = jnp.int32 if _is_bucket(vals_c.dtype) else jnp.float32
+    leaf_c = leaf_c.astype(jnp.int32)
+    parked = (leaf_c < 0) | (leaf_c >= num_leaves)
+    leaf_c = jnp.where(parked, num_leaves, leaf_c)
+    base = leaf_c[:, None] * (F * num_bins)
+    idx = base + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins + bins_c.T.astype(jnp.int32)
     flat = jax.vmap(
-        lambda v: jnp.zeros(F * num_bins, jnp.int32).at[idx.reshape(-1)].add(
-            jnp.broadcast_to(v.astype(jnp.int32)[:, None], (C, F)).reshape(-1)
-        )
+        lambda v: jnp.zeros((num_leaves + 1) * F * num_bins, acc)
+        .at[idx.reshape(-1)]
+        .add(jnp.broadcast_to(v.astype(acc)[:, None], (C, F)).reshape(-1))
     )(vals_c)
-    return flat.reshape(3, F, num_bins)
+    return flat.reshape(3, num_leaves + 1, F, num_bins)[:, :num_leaves]
 
 
-def _onehot_hist_chunk_int(bins_c, vals_c, num_bins: int, feat_block: int = 8):
-    """Quantized twin of ``_onehot_hist_chunk``: int32 matmul accumulation.
-    headroom: per-chunk sums ≤ C·QMAX ≪ 2³¹ (quantize_wire_plan)."""
-    C, F = bins_c.shape
-    bins_c = bins_c.astype(jnp.int32)  # uint8 arrivals widen per chunk
-    pad_f = (-F) % feat_block
-    if pad_f:
-        bins_c = jnp.pad(bins_c, ((0, 0), (0, pad_f)))
-    Fp = F + pad_f
-    blocks = bins_c.reshape(C, Fp // feat_block, feat_block).transpose(1, 0, 2)
-    vals_i = vals_c.astype(jnp.int32)
+def _chunked_hist(fn, acc0, bins, rows, chunk: int, axis_name, merge: str,
+                  quantize: Optional[HistQuantize]):
+    """What both builders do with their chunk function ``fn(bins_chunk,
+    *row_chunks)``: sum it over the row chunks of the (F, n) ``bins``
+    into ``acc0``, merge the sum across shards, dequantize.
 
-    def block_hist(bl):  # (C, feat_block)
-        oh = (bl[:, :, None] == jnp.arange(num_bins, dtype=bl.dtype)[None, None, :])
-        oh = oh.astype(jnp.int32).reshape(C, feat_block * num_bins)
-        return (vals_i @ oh).reshape(3, feat_block, num_bins)
+    ``rows`` holds the per-row arrays as ``(array, row axis)`` pairs, in
+    ``fn``'s argument order.  A chunk is the (F, chunk) column slice of
+    ``bins`` and the matching slice of each, taken inside the scan; the
+    matrix itself is never reshaped or transposed.
+    """
+    n = bins.shape[1]
+    if quantize is not None and not _is_bucket(acc0.dtype):
+        raise ValueError(
+            "quantize needs the int16 buckets of quantize_hist_vals as vals"
+        )
+    if n <= chunk:
+        hist = fn(bins, *(x for x, _ in rows))
+    else:
+        if n % chunk != 0:
+            raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
 
-    hist = lax.map(block_hist, blocks)  # (Fp/fb, 3, fb, B)
-    return hist.transpose(1, 0, 2, 3).reshape(3, Fp, num_bins)[:, :F]
+        def body(acc, i):
+            part = fn(
+                _row_chunk(bins, i, chunk, 1),
+                *(_row_chunk(x, i, chunk, axis) for x, axis in rows),
+            )
+            return acc + part, None
+
+        hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
+    feature_axis = acc0.ndim - 2  # (3, F, B) | (3, L, F, B)
+    if axis_name is not None:
+        if quantize is not None:
+            hist = merge_shard_histograms_quantized(
+                hist, axis_name, merge=merge, wire=quantize.wire,
+                shift=quantize.shift, feature_axis=feature_axis,
+            )
+        else:
+            hist = merge_shard_histograms(
+                hist, axis_name, merge=merge, feature_axis=feature_axis,
+            )
+    if quantize is not None:
+        # dequantize ONCE post-merge (the merge already folded back its
+        # dynamic wire shift; serial hists are plain int32 sums)
+        hist = hist.astype(jnp.float32) * quantize.scales.reshape(
+            (3,) + (1,) * (acc0.ndim - 1)
+        )
+    return hist
 
 
 def build_histogram(
@@ -395,175 +420,53 @@ def build_histogram(
     chunk: int = DEFAULT_CHUNK,
     axis_name: Optional[str] = None,
     precision: str = "highest",
-    transposed: bool = False,
-    psum_dtype: str = "float32",
     merge: str = "allreduce",
     quantize: Optional[HistQuantize] = None,
-    packed: bool = False,
 ) -> jnp.ndarray:
-    """Histogram of ``vals`` (3, n) over (feature, bin), rows gated by
-    ``mask``; returns (3, F, B) — or (3, F/D, B), this shard's merged
-    feature slice, under ``merge="reduce_scatter"``.
+    """Histogram of ``vals`` (3, n) over (feature, bin) of the (F, n)
+    ``bins``, rows gated by ``mask``; returns (3, F, B) — or (3, F/D, B),
+    this shard's merged feature slice, under ``merge="reduce_scatter"``.
 
     With ``quantize`` set, ``vals`` must arrive as int16 buckets from
     :func:`quantize_hist_vals`; accumulation is int32, the cross-shard
     merge rides the integer wire, and the returned histogram is
     DEQUANTIZED f32 — downstream gain math is unchanged.
 
-    ``transposed=True`` means ``bins`` arrives as (F, n) integer — uint8
-    through the byte tier (``num_bins ≤ 256``, ``ops/binpack.py``), int32
-    past it — growers hoist the transpose out of their per-pass loop
-    (pallas wants rows on the lane axis and widens per VMEM block; the
-    scatter/onehot fallbacks transpose back and widen per chunk, they
-    are the small-scale/test paths).  A chunk is the (F, chunk) column
-    slice of that matrix, taken inside the scan; the matrix itself is
-    never reshaped or transposed.
+    ``bins`` is the growers' (F, n) integer matrix — uint8 through the
+    byte tier (``num_bins ≤ 256``, ``ops/binpack.py``), int32 past it:
+    growers hoist the transpose out of their per-pass loop (pallas wants
+    rows on the lane axis and widens per VMEM block; the scatter fallback
+    transposes back and widens per chunk, it is the small-scale/test
+    path).
 
     When ``axis_name`` is set (running inside ``shard_map`` over row shards),
     the result is ``psum``-med across the mesh axis — this single line is the
     replacement for LightGBM's socket allreduce of histograms
     (``LGBM_NetworkInit`` + recursive-halving allreduce; SURVEY.md §3.1,
     §5.8 native component N2).
-
-    ``packed=True`` means ``bins`` arrives NIBBLE-PACKED — (⌈n/2⌉, F)
-    uint8 with two row indices per byte (``ops/binpack.py``; requires
-    ``num_bins ≤ 16`` and row-major layout, so it excludes
-    ``transposed``).  The scan unpacks per chunk inside the body, so the
-    full-size uint8 matrix never materializes: HBM holds the packed half
-    plus one unpacked chunk.  ``n``/``mask``/``vals`` keep LOGICAL row
-    semantics; odd ``n`` is handled by the pack's phantom zero row, whose
-    mask slot must be False (standard row padding already guarantees it).
     """
-    if packed:
-        if transposed:
-            raise ValueError("packed bins are row-major; transposed "
-                             "input is not supported")
-        from mmlspark_tpu.ops.binpack import PACK_MAX_BINS, unpack_rows
-
-        if num_bins > PACK_MAX_BINS:
-            raise ValueError(
-                f"packed bins need num_bins <= {PACK_MAX_BINS}, got {num_bins}"
-            )
-        n = vals.shape[1]
-        F = bins.shape[1]
-        if bins.shape[0] != (n + 1) // 2:
-            raise ValueError(
-                f"packed bins rows {bins.shape[0]} != ceil({n}/2)"
-            )
-    elif transposed:
-        F, n = bins.shape
-    else:
-        n, F = bins.shape
-    quant = quantize is not None
+    F = bins.shape[0]
     if backend == "pallas":
-        from mmlspark_tpu.ops.pallas_hist import (
-            pallas_hist_chunk,
-            pallas_hist_chunk_int,
-        )
+        from mmlspark_tpu.ops.pallas_hist import pallas_hist_chunk
 
-        fn = functools.partial(
-            pallas_hist_chunk_int if quant else pallas_hist_chunk,
-            precision=precision, transposed=transposed,
-        )
-    elif backend == "onehot":
-        base = _onehot_hist_chunk_int if quant else _onehot_hist_chunk
-        fn = base if not transposed else (
-            lambda b, v, nb, _f=base: _f(b.T, v, nb)
-        )
+        fn = functools.partial(pallas_hist_chunk, num_bins=num_bins, precision=precision)
     elif backend == "scatter":
-        base = _scatter_hist_chunk_int if quant else _scatter_hist_chunk
-        fn = base if not transposed else (
-            lambda b, v, nb, _f=base: _f(b.T, v, nb)
-        )
+        fn = functools.partial(_scatter_hist_chunk, num_bins=num_bins)
     else:
         raise ValueError(
-            f"unknown hist backend {backend!r}; expected scatter|onehot|pallas"
+            f"unknown hist backend {backend!r}; expected scatter|pallas"
         )
-    if quant:
-        vals = jnp.where(mask[None, :], vals, jnp.int16(0))
-        # headroom: n·QMAX bin sums fit the int32 accumulator for any
-        # n ≤ 16.9M rows/shard — guarded statically by quantize_wire_plan
-        acc0 = jnp.zeros((3, F, num_bins), jnp.int32)
-    else:
-        vals = jnp.where(mask[None, :], vals, 0.0).astype(jnp.float32)
-        acc0 = jnp.zeros((3, F, num_bins), jnp.float32)
-    if n <= chunk:
-        if packed:
-            bins = unpack_rows(bins, n)
-        hist = fn(bins, vals, num_bins)
-    else:
-        if n % chunk != 0:
-            raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
-        if packed and chunk % 2:
-            raise ValueError(f"packed bins need an even chunk, got {chunk}")
-        # two logical rows per packed row: unpack happens per-chunk in
-        # the body, so peak unpacked residency is ONE chunk
-        bin_rows = chunk // 2 if packed else chunk
-        bin_axis = 1 if transposed else 0
-
-        def body(acc, i):
-            b = _row_chunk(bins, i, bin_rows, bin_axis)
-            if packed:
-                b = unpack_rows(b, chunk)
-            return acc + fn(b, _row_chunk(vals, i, chunk, 1), num_bins), None
-
-        hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
-    if axis_name is not None:
-        if quant:
-            hist = merge_shard_histograms_quantized(
-                hist, axis_name, merge=merge, wire=quantize.wire,
-                shift=quantize.shift, feature_axis=1,
-            )
-        else:
-            hist = merge_shard_histograms(
-                hist, axis_name, merge=merge, psum_dtype=psum_dtype,
-                feature_axis=1,
-            )
-    if quant:
-        # dequantize ONCE post-merge (the merge already folded back its
-        # dynamic wire shift; serial hists are plain int32 sums)
-        hist = hist.astype(jnp.float32) * quantize.scales[:, None, None]
-    return hist
-
-
-def _scatter_hist_by_leaf_chunk(bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int):
-    """(C, F) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B) scatter-add.
-
-    Rows parked outside ``[0, num_leaves)`` (including NEGATIVE ids from the
-    windowed depthwise pass) are routed to a scratch slot and sliced off —
-    negative flat indices would otherwise WRAP in ``.at[].add``.
-    """
-    C, F = bins_c.shape
-    leaf_c = leaf_c.astype(jnp.int32)
-    parked = (leaf_c < 0) | (leaf_c >= num_leaves)
-    leaf_c = jnp.where(parked, num_leaves, leaf_c)
-    base = leaf_c[:, None] * (F * num_bins)
-    idx = base + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins + bins_c.astype(jnp.int32)
-    flat = jax.vmap(
-        lambda v: jnp.zeros((num_leaves + 1) * F * num_bins, jnp.float32)
-        .at[idx.reshape(-1)]
-        .add(jnp.broadcast_to(v[:, None], (C, F)).reshape(-1))
-    )(vals_c)
-    return flat.reshape(3, num_leaves + 1, F, num_bins)[:, :num_leaves]
-
-
-def _scatter_hist_by_leaf_chunk_int(bins_c, vals_c, leaf_c, num_leaves: int,
-                                    num_bins: int):
-    """Quantized twin of ``_scatter_hist_by_leaf_chunk``: int16 vals →
-    (3, L, F, B) int32 scatter-add.  headroom: |val| ≤ QMAX keeps C·QMAX
-    sums inside int32 (quantize_wire_plan)."""
-    C, F = bins_c.shape
-    leaf_c = leaf_c.astype(jnp.int32)
-    parked = (leaf_c < 0) | (leaf_c >= num_leaves)
-    leaf_c = jnp.where(parked, num_leaves, leaf_c)
-    base = leaf_c[:, None] * (F * num_bins)
-    idx = base + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins + bins_c.astype(jnp.int32)
-    flat = jax.vmap(
-        lambda v: jnp.zeros((num_leaves + 1) * F * num_bins, jnp.int32)
-        .at[idx.reshape(-1)]
-        .add(jnp.broadcast_to(v.astype(jnp.int32)[:, None], (C, F)).reshape(-1))
-    )(vals_c)
-    return flat.reshape(3, num_leaves + 1, F, num_bins)[:, :num_leaves]
+    if quantize is None:
+        vals = vals.astype(jnp.float32)
+    vals = jnp.where(mask[None, :], vals, vals.dtype.type(0))
+    # headroom: n·QMAX bin sums of buckets fit the int32 accumulator for
+    # any n ≤ 16.9M rows/shard — guarded statically by quantize_wire_plan
+    acc0 = jnp.zeros(
+        (3, F, num_bins), jnp.int32 if _is_bucket(vals.dtype) else jnp.float32
+    )
+    return _chunked_hist(
+        fn, acc0, bins, [(vals, 1)], chunk, axis_name, merge, quantize
+    )
 
 
 def build_histogram_by_leaf(
@@ -576,108 +479,61 @@ def build_histogram_by_leaf(
     chunk: int = DEFAULT_CHUNK,
     axis_name: Optional[str] = None,
     precision: str = "highest",
-    transposed: bool = False,
-    psum_dtype: str = "float32",
     merge: str = "allreduce",
     quantize: Optional[HistQuantize] = None,
 ) -> jnp.ndarray:
-    """Per-leaf histograms in ONE pass over the data: (3, L, F, B) — or
-    (3, L, F/D, B), this shard's merged feature slice, under
-    ``merge="reduce_scatter"``.  With ``quantize`` set, ``vals`` must be
-    int16 buckets; the result is the DEQUANTIZED f32 histogram (see
-    :func:`build_histogram`).
+    """Per-leaf histograms in ONE pass over the (F, n) ``bins``:
+    (3, L, F, B) — or (3, L, F/D, B), this shard's merged feature slice,
+    under ``merge="reduce_scatter"``.  With ``quantize`` set, ``vals``
+    must be int16 buckets; the result is the DEQUANTIZED f32 histogram
+    (see :func:`build_histogram`, also for the layout of ``bins``).
 
     The depthwise grower's workhorse (SURVEY.md §7.4.2): one pass histograms
     every leaf slot in ``[0, num_leaves)`` together.  Rows to exclude
     (out of bag / padding / other leaves — e.g. the windowed new-children
     pass, which passes ``leaf_ids - base``) must arrive with ``leaf_ids``
     outside ``[0, num_leaves)`` (any parked value, including negatives) or
-    zeroed ``vals``.  ``transposed=True``: bins arrive as (F, n) integer —
-    uint8 through the byte tier — and each chunk is its (F, chunk) column
-    slice, taken inside the scan (see :func:`build_histogram`).  With
-    ``axis_name``, the result is psum-med
+    zeroed ``vals``.  With ``axis_name``, the result is psum-med
     across the mesh — the same single-collective structure as
     :func:`build_histogram`.
     """
-    if transposed:
-        F, n = bins.shape
-    else:
-        n, F = bins.shape
-    quant = quantize is not None
-    if not quant:
+    F = bins.shape[0]
+    if quantize is None:
         vals = vals.astype(jnp.float32)
+    quant = _is_bucket(vals.dtype)
     if backend == "pallas":
         from mmlspark_tpu.ops.pallas_hist import (
             pallas_hist_by_leaf_chunk,
-            pallas_hist_by_leaf_chunk_int,
             pallas_hist_by_leaf_nibble_chunk,
         )
 
         # Small windows starve the plain kernel's matmul M = 3·W; the
         # factorized hi/lo variant doubles M (same results to float-summation
         # ulps — parity tested) and wins measurably up to M ≈ 128 (W≤21 at B=256:
-        # 7.5 → 4.9 ms/pass at W=12, 262k×64 on v5e).
+        # 7.5 → 4.9 ms/pass at W=12, 262k×64 on v5e).  Bucket builds take
+        # the plain kernel whatever the window: the nibble factorization's
+        # hi/lo recombination is a float trick with no int32 twin (and the
+        # int path is already exact, so there is nothing for it to tighten).
         h = (num_bins + 127) // 128
-        if quant:
-            # quantized builds route to the plain int-accumulator kernel
-            # only: the nibble factorization's hi/lo recombination is a
-            # float trick with no int32 twin (and the int path is already
-            # exact, so there is nothing for it to tighten)
-            fn = functools.partial(
-                pallas_hist_by_leaf_chunk_int, precision=precision,
-                transposed=transposed,
-            )
-        elif num_bins > 128 and 3 * num_leaves * h <= 128:
-            fn = functools.partial(
-                pallas_hist_by_leaf_nibble_chunk, precision=precision,
-                transposed=transposed,
-            )
-        else:
-            fn = functools.partial(
-                pallas_hist_by_leaf_chunk, precision=precision,
-                transposed=transposed,
-            )
-    elif backend in ("scatter", "onehot"):
-        base = (_scatter_hist_by_leaf_chunk_int if quant
-                else _scatter_hist_by_leaf_chunk)
-        fn = base if not transposed else (
-            lambda b, v, l, nl, nb, _f=base: _f(b.T, v, l, nl, nb)
+        nibble = not quant and num_bins > 128 and 3 * num_leaves * h <= 128
+        fn = functools.partial(
+            pallas_hist_by_leaf_nibble_chunk if nibble else pallas_hist_by_leaf_chunk,
+            num_leaves=num_leaves, num_bins=num_bins, precision=precision,
+        )
+    elif backend == "scatter":
+        fn = functools.partial(
+            _scatter_hist_by_leaf_chunk, num_leaves=num_leaves, num_bins=num_bins
         )
     else:
         raise ValueError(
-            f"unknown hist backend {backend!r}; expected scatter|onehot|pallas"
+            f"unknown hist backend {backend!r}; expected scatter|pallas"
         )
-    if quant:
-        # headroom: n·QMAX bin sums fit the int32 accumulator for any
-        # n ≤ 16.9M rows/shard — guarded statically by quantize_wire_plan
-        acc0 = jnp.zeros((3, num_leaves, F, num_bins), jnp.int32)
-    else:
-        acc0 = jnp.zeros((3, num_leaves, F, num_bins), jnp.float32)
-    if n <= chunk:
-        hist = fn(bins, vals, leaf_ids, num_leaves, num_bins)
-    else:
-        if n % chunk != 0:
-            raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
-        bin_axis = 1 if transposed else 0
-
-        def body(acc, i):
-            b = _row_chunk(bins, i, chunk, bin_axis)
-            v = _row_chunk(vals, i, chunk, 1)
-            l = _row_chunk(leaf_ids, i, chunk, 0)
-            return acc + fn(b, v, l, num_leaves, num_bins), None
-
-        hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
-    if axis_name is not None:
-        if quant:
-            hist = merge_shard_histograms_quantized(
-                hist, axis_name, merge=merge, wire=quantize.wire,
-                shift=quantize.shift, feature_axis=2,
-            )
-        else:
-            hist = merge_shard_histograms(
-                hist, axis_name, merge=merge, psum_dtype=psum_dtype,
-                feature_axis=2,
-            )
-    if quant:
-        hist = hist.astype(jnp.float32) * quantize.scales[:, None, None, None]
-    return hist
+    # headroom: n·QMAX bin sums of buckets fit the int32 accumulator for
+    # any n ≤ 16.9M rows/shard — guarded statically by quantize_wire_plan
+    acc0 = jnp.zeros(
+        (3, num_leaves, F, num_bins), jnp.int32 if quant else jnp.float32
+    )
+    return _chunked_hist(
+        fn, acc0, bins, [(vals, 1), (leaf_ids, 0)], chunk, axis_name, merge,
+        quantize,
+    )

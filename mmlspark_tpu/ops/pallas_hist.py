@@ -21,7 +21,8 @@ Layout choices (TPU tiling wants the last dim lane-sized):
   (``num_bins ≤ 256``, ``ops/binpack.py``) and every kernel widens to
   int32 immediately after the block load — 1-byte indices in HBM and on
   the DMA, int32 only in VMEM;
-- vals arrive channel-major (3, rows) — rows on lanes;
+- vals arrive channel-major (3, rows) — rows on lanes — float32, or the
+  int16 buckets of quantized training (see "Value dtype" below);
 - bin one-hots are built PER FEATURE as clean 2-D (B, rows) iota-compares:
   a fused (bf, B, rows)→(bf·B, rows) one-hot needs a Mosaic lane relayout
   that traced at ~10x the matmul cost;
@@ -49,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mmlspark_tpu.ops.histogram import _is_bucket
+
 _PRECISIONS = {
     "highest": jax.lax.Precision.HIGHEST,
     "default": jax.lax.Precision.DEFAULT,
@@ -63,13 +66,42 @@ def _pow2_floor(x: int) -> int:
     return 1 << (max(x, 1).bit_length() - 1)
 
 
+# ---------------------------------------------------------------------------
+# Value dtype (ISSUE 9 — quantized training).  One body per kernel serves
+# both: the dtype of ``vals`` is static at trace time and picks the
+# accumulator, so the float build's traced body holds no integer op.
+#
+# Integer ``vals`` are the int16 buckets of ops.histogram.quantize_hist_vals.
+# Layout note, int accumulator tile: the row values arrive as an int16
+# (3, bm) tile (sublane-padded to 16; HALF the per-row-block DMA of the
+# f32 build) and the grid-resident output tile is **int32** with the
+# same (3·L on sublanes, bf·B on lanes) orientation as the float build.
+# The per-row-block contraction itself stays an f32 MXU matmul — there is
+# no native int32 MXU path to lower to, and none is needed for exactness:
+# both operands are small integers (one-hot ∈ {0,1}, |vals| ≤ QMAX = 127,
+# exact even as bf16 under precision="default"), so every partial sum is
+# an integer ≤ bm·QMAX ≈ 2.1M ≪ 2²⁴, exactly representable in the f32
+# accumulator; the cast to int32 after each row block is therefore exact,
+# and int32 grid accumulation across row blocks is associative — the
+# whole build is bit-reproducible regardless of precision mode, chunking,
+# or merge order.  headroom: n·QMAX ≤ 2³¹ per shard is attested
+# statically by ops.histogram.quantize_wire_plan before any kernel runs.
+# ---------------------------------------------------------------------------
+def _tile_dtype(vals_dtype):
+    """The dtype row values cross the DMA in: int16 buckets, else f32."""
+    return jnp.int16 if _is_bucket(vals_dtype) else jnp.float32
+
+
 def _hist_kernel(bins_ref, vals_ref, out_ref, *, num_bins: int, precision):
     """One (feature-block j, row-block i) cell: out[j] += vals·onehotᵀ."""
     i = pl.program_id(1)  # row block (innermost → accumulation is safe)
+    quant = _is_bucket(vals_ref.dtype)
     # bins arrive uint8 at ≤256 bins (byte tier, ops/binpack.py) — the
     # HBM→VMEM DMA moves 1 byte/index; widen to int32 IN VMEM only.
     bins = bins_ref[...].astype(jnp.int32)  # (bf, bm)
-    vals = vals_ref[...]  # (3, bm) f32
+    vals = vals_ref[...]  # (3, bm) f32 | int16 buckets
+    if quant:
+        vals = vals.astype(jnp.float32)
     bf, bm = bins.shape
     # Per-feature 2-D one-hot over bins, rows on lanes — VMEM only.
     # Precision: HIGHEST = f32 passes (scatter-add-exact numerics — the
@@ -87,9 +119,11 @@ def _hist_kernel(bins_ref, vals_ref, out_ref, *, num_bins: int, precision):
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=precision,
-            )  # (3, B)
+            )  # (3, B) — of buckets: integer-valued, exact in f32
         )
     part = jnp.concatenate(parts, axis=1)  # (3, bf·B)
+    if quant:
+        part = part.astype(jnp.int32)  # exact (see "Value dtype")
 
     @pl.when(i == 0)
     def _init():
@@ -121,37 +155,36 @@ def _pallas_hist(
         # shape's last two dims (3, bf·B) satisfy TPU tiling by equalling
         # the array dims; the bin unflatten happens outside the kernel.
         out_specs=pl.BlockSpec((1, 3, bf * num_bins), lambda j, i: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F // bf, 3, bf * num_bins), jnp.float32),
+        # headroom: the int32 grid accumulator of a bucket build — n·QMAX
+        # per shard is attested statically by
+        # ops.histogram.quantize_wire_plan before kernels run
+        out_shape=jax.ShapeDtypeStruct(
+            (F // bf, 3, bf * num_bins),
+            jnp.int32 if _is_bucket(vals.dtype) else jnp.float32,
+        ),
         interpret=interpret,
     )(bins_t, vals)
     return out.transpose(1, 0, 2).reshape(3, F, num_bins)
 
 
 def pallas_hist_chunk(
-    bins_c, vals_c, num_bins: int, bm: int = 4096, bf: int = 32,
-    precision: str = "highest", transposed: bool = False,
+    bins_t, vals_c, num_bins: int, bm: int = 4096, bf: int = 32,
+    precision: str = "highest",
 ) -> jnp.ndarray:
-    """(C, F) int bins + (3, C) vals → (3, F, B), same contract as the
-    scatter/onehot chunk builders in :mod:`mmlspark_tpu.ops.histogram`.
+    """(F, C) int bins + (3, C) vals → (3, F, B), same contract as the
+    scatter chunk builder in :mod:`mmlspark_tpu.ops.histogram`: float32
+    sums of float ``vals_c``, int32 sums of int16 bucket ``vals_c``.
 
-    ``transposed=True`` means ``bins_c`` arrives PRE-transposed as (F, C)
-    integer — uint8 through the byte tier (``num_bins ≤ 256``), int32
-    past it — the grower hoists the 10s-of-MB transpose out of the
-    per-pass path (it is invariant across a tree's passes).  The kernel
-    widens per VMEM block, so uint8 input quarters the per-pass bins DMA.
+    ``bins_t`` is a column slice of the growers' (F, n) matrix — uint8
+    through the byte tier (``num_bins ≤ 256``), int32 past it.  The
+    kernel widens per VMEM block, so uint8 input quarters the per-pass
+    bins DMA.
 
     Pads rows/features up to block multiples (padded rows carry zero vals,
     padded features are sliced off).
     """
-    from mmlspark_tpu.ops.binpack import hist_transpose
-
-    if transposed:
-        bins_t = bins_c  # (F, C) integer already
-        F, C = bins_t.shape
-    else:
-        C, F = bins_c.shape
-        bins_t = hist_transpose(bins_c, num_bins)  # (F, C): rows on lanes
-    vals_c = vals_c.astype(jnp.float32)
+    F, C = bins_t.shape
+    vals_c = vals_c.astype(_tile_dtype(vals_c.dtype))
     # VMEM guard: the kernel's iota/one-hot tiles are (num_bins, bm); the
     # defaults were swept at B=256, so scale bm down for bigger bin counts.
     # Powers of two / 128-multiples only: Pallas requires 128-aligned
@@ -204,12 +237,15 @@ def _hist_leaf_kernel(
     """
     i = pl.program_id(1)  # row block, innermost → accumulation is safe
     bf, bm = bins_ref.shape
+    quant = _is_bucket(vals_ref.dtype)
 
     def sub(s, acc):
         sl = pl.ds(s * rm, rm)
         # uint8 at ≤256 bins: 1-byte DMA, widened in VMEM (see _hist_kernel)
         bins = bins_ref[:, sl].astype(jnp.int32)  # (bf, rm)
-        vals = vals_ref[:, sl]  # (3, rm) f32
+        vals = vals_ref[:, sl]  # (3, rm) f32 | int16 buckets
+        if quant:
+            vals = vals.astype(jnp.float32)
         leaf = leaf_ref[0, sl]  # (rm,) int32
         # Leaf-masked values, channel-major columns: rhs[r, c·L + l] =
         # vals[c, r] · (leaf[r] == l).  Three lane-dim concats because
@@ -237,11 +273,20 @@ def _hist_leaf_kernel(
         # multiple of 8) and the big bf·B axis on lanes — the transposed
         # orientation padded 3·L up to 256 lanes and blew the 16M VMEM
         # budget through the grid-resident accumulator tile.
-        return acc + jnp.concatenate(parts, axis=1)  # (3·L, bf·B)
+        part = jnp.concatenate(parts, axis=1)  # (3·L, bf·B)
+        if quant:
+            # integer-valued f32 partial sums ≤ rm·QMAX ≪ 2²⁴ → exact cast
+            part = part.astype(jnp.int32)
+        return acc + part
 
     part = jax.lax.fori_loop(
         0, bm // rm, sub,
-        jnp.zeros((3 * num_leaves, bf * num_bins), jnp.float32),
+        # headroom: bm·QMAX ≪ 2³¹ per block of buckets; the cross-block
+        # int32 total is bounded by quantize_wire_plan's static n·QMAX check
+        jnp.zeros(
+            (3 * num_leaves, bf * num_bins),
+            jnp.int32 if quant else jnp.float32,
+        ),
     )
 
     @pl.when(i == 0)
@@ -278,8 +323,12 @@ def _pallas_hist_by_leaf(
         out_specs=pl.BlockSpec(
             (1, num_leaves * 3, bf * num_bins), lambda j, i: (j, 0, 0)
         ),
+        # headroom: the int32 grid accumulator of a bucket build — n·QMAX
+        # per shard is attested statically by
+        # ops.histogram.quantize_wire_plan before kernels run
         out_shape=jax.ShapeDtypeStruct(
-            (F // bf, num_leaves * 3, bf * num_bins), jnp.float32
+            (F // bf, num_leaves * 3, bf * num_bins),
+            jnp.int32 if _is_bucket(vals.dtype) else jnp.float32,
         ),
         compiler_params=_by_leaf_compiler_params(num_leaves, bf, num_bins),
         interpret=interpret,
@@ -311,15 +360,14 @@ def _by_leaf_compiler_params(num_leaves: int, bf: int, num_bins: int):
 
 
 def _prep_by_leaf_chunk(
-    bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int,
-    bm: int, bf: int, rm: int, transposed: bool,
-    val_dtype=jnp.float32,
+    bins_t, vals_c, leaf_c, num_leaves: int, num_bins: int,
+    bm: int, bf: int, rm: int,
 ):
-    """Shared wrapper prep for the by-leaf kernels: backend check,
-    transpose, block clamps, padding.  Returns
-    (bins_t, vals, leaf_row, bm, bf, rm, F, interpret).  ``val_dtype``
-    is f32 for the float kernels, int16 for the quantized kernel (the
-    row values DMA at half width)."""
+    """Shared wrapper prep for the by-leaf kernels: backend check, block
+    clamps, padding of the (F, C) bins slice.  Returns
+    (bins_t, vals, leaf_row, bm, bf, rm, F, interpret).  Float ``vals_c``
+    go in as f32, integer buckets as int16 (the row values DMA at half
+    width)."""
     import jax as _jax
 
     backend = _jax.default_backend()
@@ -327,15 +375,8 @@ def _prep_by_leaf_chunk(
         raise NotImplementedError(
             f"hist_backend='pallas' supports tpu/cpu backends, not {backend!r}"
         )
-    from mmlspark_tpu.ops.binpack import hist_transpose
-
-    if transposed:
-        bins_t = bins_c  # (F, C) integer (uint8 through the byte tier)
-        F, C = bins_t.shape
-    else:
-        C, F = bins_c.shape
-        bins_t = hist_transpose(bins_c, num_bins)
-    vals_c = vals_c.astype(val_dtype)
+    F, C = bins_t.shape  # integer, uint8 through the byte tier
+    vals_c = vals_c.astype(_tile_dtype(vals_c.dtype))
     leaf_row = leaf_c.astype(jnp.int32)[None, :]  # (1, C): lane-friendly
     bf = min(bf, max(8, _round_up(F, 8)))  # don't pad tiny feature counts 4x
     # Feature-block choice minimizes PADDED width: bf=32 on F=40 (the
@@ -373,13 +414,11 @@ def _prep_by_leaf_chunk(
 
 
 def pallas_hist_by_leaf_chunk(
-    bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int,
+    bins_t, vals_c, leaf_c, num_leaves: int, num_bins: int,
     bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
-    transposed: bool = False,
 ) -> jnp.ndarray:
-    """(C, F) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B).
-
-    ``transposed=True``: bins arrive pre-transposed (F, C) int32 (see
+    """(F, C) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B): float32
+    sums of float ``vals_c``, int32 sums of int16 bucket ``vals_c`` (see
     :func:`pallas_hist_chunk`).
 
     ``rm`` bounds the VMEM one-hot tile AND sets the matmul contraction
@@ -389,7 +428,7 @@ def pallas_hist_by_leaf_chunk(
     bf=64 and bm=32k×rm=2k blow the remote-compile VMEM budget.
     """
     bins_t, vals_c, leaf_row, bm, bf, rm, F, interp = _prep_by_leaf_chunk(
-        bins_c, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm, transposed
+        bins_t, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm
     )
     out = _pallas_hist_by_leaf(
         bins_t, vals_c, leaf_row, num_leaves, num_bins, bm, bf, rm,
@@ -513,232 +552,18 @@ def _pallas_hist_by_leaf_nibble(
 
 
 def pallas_hist_by_leaf_nibble_chunk(
-    bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int,
+    bins_t, vals_c, leaf_c, num_leaves: int, num_bins: int,
     bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
-    transposed: bool = False,
 ) -> jnp.ndarray:
     """Factorized-bin variant of :func:`pallas_hist_by_leaf_chunk` — same
-    contract, intended for small windows (see module comment above)."""
+    contract for float ``vals_c``, intended for small windows (see module
+    comment above).  The hi/lo recombination is a float trick with no
+    integer form: ops/histogram.py sends bucket builds to the plain
+    kernel, whose int32 sums are exact already."""
     bins_t, vals_c, leaf_row, bm, bf, rm, F, interp = _prep_by_leaf_chunk(
-        bins_c, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm, transposed
+        bins_t, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm
     )
     out = _pallas_hist_by_leaf_nibble(
-        bins_t, vals_c, leaf_row, num_leaves, num_bins, bm, bf, rm,
-        interp, precision,
-    )
-    return out[:, :, :F]
-
-
-# ---------------------------------------------------------------------------
-# Integer-accumulator variants (ISSUE 9 — quantized training).
-#
-# Layout note, int accumulator tile: the row values arrive as an int16
-# (3, bm) tile (sublane-padded to 16; HALF the per-row-block DMA of the
-# f32 kernels) and the grid-resident output tile is **int32** with the
-# same (3·L on sublanes, bf·B on lanes) orientation as the float kernels.
-# The per-row-block contraction itself stays an f32 MXU matmul — there is
-# no native int32 MXU path to lower to, and none is needed for exactness:
-# both operands are small integers (one-hot ∈ {0,1}, |vals| ≤ QMAX = 127,
-# exact even as bf16 under precision="default"), so every partial sum is
-# an integer ≤ bm·QMAX ≈ 2.1M ≪ 2²⁴, exactly representable in the f32
-# accumulator; the cast to int32 after each row block is therefore exact,
-# and int32 grid accumulation across row blocks is associative — the
-# whole build is bit-reproducible regardless of precision mode, chunking,
-# or merge order.  headroom: n·QMAX ≤ 2³¹ per shard is attested
-# statically by ops.histogram.quantize_wire_plan before any kernel runs.
-# ---------------------------------------------------------------------------
-def _hist_kernel_int(bins_ref, vals_ref, out_ref, *, num_bins: int, precision):
-    """Quantized twin of ``_hist_kernel``: int16 vals in, int32 out."""
-    i = pl.program_id(1)  # row block (innermost → accumulation is safe)
-    bins = bins_ref[...].astype(jnp.int32)  # (bf, bm); uint8 DMA at ≤256 bins
-    vals = vals_ref[...].astype(jnp.float32)  # (3, bm) int16 buckets
-    bf, bm = bins.shape
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (num_bins, bm), 0)
-    parts = []
-    for f in range(bf):
-        oh_f = (iota_b == bins[f, :][None, :]).astype(jnp.float32)
-        parts.append(
-            jax.lax.dot_general(
-                vals, oh_f,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=precision,
-            )  # (3, B) — integer-valued, exact in f32 (see layout note)
-        )
-    part = jnp.concatenate(parts, axis=1).astype(jnp.int32)  # (3, bf·B)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = part[None, :, :]
-
-    @pl.when(i > 0)
-    def _acc():
-        out_ref[...] += part[None, :, :]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_bins", "bm", "bf", "interpret", "precision")
-)
-def _pallas_hist_int(
-    bins_t, vals, num_bins: int, bm: int, bf: int, interpret: bool, precision: str
-):
-    F, n = bins_t.shape
-    kernel = functools.partial(
-        _hist_kernel_int, num_bins=num_bins, precision=_PRECISIONS[precision]
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(F // bf, n // bm),
-        in_specs=[
-            pl.BlockSpec((bf, bm), lambda j, i: (j, i)),
-            pl.BlockSpec((3, bm), lambda j, i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, 3, bf * num_bins), lambda j, i: (j, 0, 0)),
-        # headroom: int32 grid accumulator — n·QMAX per shard is attested
-        # statically by ops.histogram.quantize_wire_plan before kernels run
-        out_shape=jax.ShapeDtypeStruct((F // bf, 3, bf * num_bins), jnp.int32),
-        interpret=interpret,
-    )(bins_t, vals)
-    return out.transpose(1, 0, 2).reshape(3, F, num_bins)
-
-
-def pallas_hist_chunk_int(
-    bins_c, vals_c, num_bins: int, bm: int = 4096, bf: int = 32,
-    precision: str = "highest", transposed: bool = False,
-) -> jnp.ndarray:
-    """Quantized twin of :func:`pallas_hist_chunk`: (3, C) int16 bucket
-    vals → (3, F, B) int32, same padding/blocking rules."""
-    from mmlspark_tpu.ops.binpack import hist_transpose
-
-    if transposed:
-        bins_t = bins_c  # (F, C) integer (uint8 through the byte tier)
-        F, C = bins_t.shape
-    else:
-        C, F = bins_c.shape
-        bins_t = hist_transpose(bins_c, num_bins)
-    vals_c = vals_c.astype(jnp.int16)
-    bm = min(bm, _pow2_floor(max(512, bm * 256 // num_bins)))
-    bm = min(bm, _round_up(C, 128))
-    bf = min(bf, max(8, _round_up(F, 8)))
-    pad_r = (-C) % bm
-    pad_f = (-F) % bf
-    if pad_r:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad_r)))
-        vals_c = jnp.pad(vals_c, ((0, 0), (0, pad_r)))
-    if pad_f:
-        bins_t = jnp.pad(bins_t, ((0, pad_f), (0, 0)))
-    backend = jax.default_backend()
-    if backend not in ("cpu", "tpu"):
-        raise NotImplementedError(
-            f"hist_backend='pallas' supports tpu (compiled) and cpu "
-            f"(interpret) backends, not {backend!r}; use 'scatter'"
-        )
-    out = _pallas_hist_int(
-        bins_t, vals_c, num_bins, bm, bf, backend == "cpu", precision
-    )
-    return out[:, :F, :]  # (3, F, B) int32
-
-
-def _hist_leaf_kernel_int(
-    bins_ref, vals_ref, leaf_ref, out_ref, *,
-    num_bins: int, num_leaves: int, rm: int, precision,
-):
-    """Quantized twin of ``_hist_leaf_kernel`` (see the layout note above):
-    per-sub-block f32 contraction, exact cast, int32 accumulation."""
-    i = pl.program_id(1)  # row block, innermost → accumulation is safe
-    bf, bm = bins_ref.shape
-
-    def sub(s, acc):
-        sl = pl.ds(s * rm, rm)
-        bins = bins_ref[:, sl].astype(jnp.int32)  # (bf, rm); uint8 DMA ≤256 bins
-        vals = vals_ref[:, sl].astype(jnp.float32)  # (3, rm) int16 buckets
-        leaf = leaf_ref[0, sl]  # (rm,) int32
-        iota_l = jax.lax.broadcasted_iota(jnp.int32, (rm, num_leaves), 1)
-        oh_leaf = (iota_l == leaf[:, None]).astype(jnp.float32)
-        rhs = jnp.concatenate(
-            [oh_leaf * vals[c, :][:, None] for c in range(3)], axis=1
-        )  # (rm, 3·L)
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (num_bins, rm), 0)
-        parts = []
-        for f in range(bf):
-            oh_f = (iota_b == bins[f, :][None, :]).astype(jnp.float32)
-            parts.append(
-                jax.lax.dot_general(
-                    rhs, oh_f,
-                    dimension_numbers=(((0,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=precision,
-                )  # (3·L, B)
-            )
-        # integer-valued f32 partial sums ≤ rm·QMAX ≪ 2²⁴ → exact cast
-        return acc + jnp.concatenate(parts, axis=1).astype(jnp.int32)
-
-    part = jax.lax.fori_loop(
-        0, bm // rm, sub,
-        # headroom: bm·QMAX ≪ 2³¹ per block; the cross-block int32 total
-        # is bounded by quantize_wire_plan's static n·QMAX check
-        jnp.zeros((3 * num_leaves, bf * num_bins), jnp.int32),
-    )
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = part[None]
-
-    @pl.when(i > 0)
-    def _acc():
-        out_ref[...] += part[None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "num_leaves", "num_bins", "bm", "bf", "rm", "interpret", "precision"
-    ),
-)
-def _pallas_hist_by_leaf_int(
-    bins_t, vals, leaf_ids, num_leaves, num_bins, bm, bf, rm, interpret, precision
-):
-    F, n = bins_t.shape
-    kernel = functools.partial(
-        _hist_leaf_kernel_int, num_bins=num_bins, num_leaves=num_leaves,
-        rm=rm, precision=_PRECISIONS[precision],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(F // bf, n // bm),
-        in_specs=[
-            pl.BlockSpec((bf, bm), lambda j, i: (j, i)),
-            pl.BlockSpec((3, bm), lambda j, i: (0, i)),
-            pl.BlockSpec((1, bm), lambda j, i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, num_leaves * 3, bf * num_bins), lambda j, i: (j, 0, 0)
-        ),
-        # headroom: int32 grid accumulator — n·QMAX per shard is attested
-        # statically by ops.histogram.quantize_wire_plan before kernels run
-        out_shape=jax.ShapeDtypeStruct(
-            (F // bf, num_leaves * 3, bf * num_bins), jnp.int32
-        ),
-        compiler_params=_by_leaf_compiler_params(num_leaves, bf, num_bins),
-        interpret=interpret,
-    )(bins_t, vals, leaf_ids)
-    out = out.reshape(F // bf, 3, num_leaves, bf, num_bins)
-    return out.transpose(1, 2, 0, 3, 4).reshape(3, num_leaves, F, num_bins)
-
-
-def pallas_hist_by_leaf_chunk_int(
-    bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int,
-    bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
-    transposed: bool = False,
-) -> jnp.ndarray:
-    """Quantized twin of :func:`pallas_hist_by_leaf_chunk`: int16 bucket
-    vals → (3, L, F, B) int32.  The nibble factorization has no int twin
-    (ops/histogram.py routes quantized builds here unconditionally)."""
-    bins_t, vals_c, leaf_row, bm, bf, rm, F, interp = _prep_by_leaf_chunk(
-        bins_c, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm, transposed,
-        val_dtype=jnp.int16,
-    )
-    out = _pallas_hist_by_leaf_int(
         bins_t, vals_c, leaf_row, num_leaves, num_bins, bm, bf, rm,
         interp, precision,
     )

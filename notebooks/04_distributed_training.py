@@ -58,14 +58,12 @@ print("final valid AUC:", booster.evals_result["valid_0"]["auc"][-1])
 # ## 2. Bandwidth-reduced modes
 #
 # `voting` elects top-k features per leaf and psums only the elected
-# histogram slices (LightGBM's parallel voting); `hist_psum_dtype=
-# "bfloat16"` halves the wire instead.  `feature` shards COLUMNS and
-# exchanges only per-leaf winners (categoricals included).
+# histogram slices (LightGBM's parallel voting).  `feature` shards
+# COLUMNS and exchanges only per-leaf winners (categoricals included).
 
 # %%
 for mode, extra in [
     ("voting", dict(tree_learner="voting", top_k=6)),
-    ("bf16-wire", dict(tree_learner="data", hist_psum_dtype="bfloat16")),
     ("feature", dict(tree_learner="feature")),
 ]:
     b = train(dict(params, early_stopping_round=0, num_iterations=20, **extra),

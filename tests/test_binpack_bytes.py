@@ -65,7 +65,7 @@ class TestByteTier:
         assert byte.dtype == jnp.uint8 and byte.shape == (3, 5)
         assert wide.dtype == jnp.int32 and wide.shape == (3, 5)
 
-    @pytest.mark.parametrize("backend", ["scatter", "onehot"])
+    @pytest.mark.parametrize("backend", ["scatter", "pallas"])
     def test_hist_bitwise_uint8_vs_int32_working_set(self, backend):
         rng = np.random.default_rng(1)
         n, F, B = 257, 5, 255
@@ -76,10 +76,8 @@ class TestByteTier:
         byte_t = hist_transpose(jnp.asarray(bins), B)
         int_t = jnp.asarray(bins, jnp.int32).T
         assert byte_t.dtype == jnp.uint8
-        h8 = build_histogram(
-            byte_t, vals, mask, B, backend=backend, transposed=True)
-        h32 = build_histogram(
-            int_t, vals, mask, B, backend=backend, transposed=True)
+        h8 = build_histogram(byte_t, vals, mask, B, backend=backend)
+        h32 = build_histogram(int_t, vals, mask, B, backend=backend)
         np.testing.assert_array_equal(np.asarray(h8), np.asarray(h32))
 
 

@@ -148,7 +148,7 @@ def test_shard_histograms_add_up_to_the_serial_histogram(data, mesh):
     leaf = rng.integers(-2, W, size=N).astype(np.int32)  # some rows parked outside the window
     vals = np.stack([y - 0.5, np.full(N, 0.25), np.ones(N)]).astype(np.float32)
     hist = lambda b, v, l, **kw: build_histogram_by_leaf(  # noqa: E731
-        jnp.asarray(b.T), jnp.asarray(v), jnp.asarray(l), W, B, backend="scatter", transposed=True, **kw
+        jnp.asarray(b.T), jnp.asarray(v), jnp.asarray(l), W, B, backend="scatter", **kw
     )
     whole = np.asarray(hist(bins, vals, leaf))
     rows = N // D
@@ -161,7 +161,7 @@ def test_shard_histograms_add_up_to_the_serial_histogram(data, mesh):
     # slice of the whole, 39 columns scattered as 40 with the last slot empty
     merged = jax.shard_map(
         lambda b, v, l: build_histogram_by_leaf(
-            b.T, v, l, W, B, backend="scatter", transposed=True, axis_name=DATA_AXIS, merge="reduce_scatter",
+            b.T, v, l, W, B, backend="scatter", axis_name=DATA_AXIS, merge="reduce_scatter",
         ),
         mesh=mesh, in_specs=(P(DATA_AXIS, None), P(None, DATA_AXIS), P(DATA_AXIS)), out_specs=P(None, None, DATA_AXIS, None),
         check_vma=False,

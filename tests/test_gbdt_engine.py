@@ -68,6 +68,32 @@ class TestBinning:
         np.testing.assert_array_equal(bm.transform(X), bm2.transform(X))
 
 
+def _hist_chunk_fn(kind, backend, nibble=False):
+    """The chunk function a builder hands its chunk loop."""
+    from mmlspark_tpu.ops import histogram as H
+    from mmlspark_tpu.ops import pallas_hist as PH
+
+    return {
+        ("plain", "scatter"): H._scatter_hist_chunk,
+        ("plain", "pallas"): PH.pallas_hist_chunk,
+        ("by_leaf", "scatter"): H._scatter_hist_by_leaf_chunk,
+        ("by_leaf", "pallas"): PH.pallas_hist_by_leaf_nibble_chunk if nibble else PH.pallas_hist_by_leaf_chunk,
+    }[kind, backend]
+
+
+def _numpy_hist(kind, bins_t, vals, leaf, rows, W, B):
+    """float64 sums over ``rows`` of (F, n) bins: (3, F, B), or (3, W, F, B) by leaf."""
+    F = bins_t.shape[0]
+    want = np.zeros((3, F, B) if kind == "plain" else (3, W, F, B), np.float64)
+    for c in range(3):
+        for f in range(F):
+            if kind == "plain":
+                np.add.at(want[c, f], bins_t[f, rows], vals[c, rows])
+            else:
+                np.add.at(want[c, :, f], (leaf[rows], bins_t[f, rows]), vals[c, rows])
+    return want
+
+
 class TestHistogram:
     def test_scatter_matches_numpy(self):
         import jax.numpy as jnp
@@ -82,27 +108,13 @@ class TestHistogram:
         mask = rng.random(n) > 0.3
         vals = np.stack([grad, hess, np.ones(n)], 0)  # (3, n) channel-major
         hist = np.asarray(
-            build_histogram(jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(mask), B)
+            build_histogram(jnp.asarray(bins.T), jnp.asarray(vals), jnp.asarray(mask), B)
         )  # (3, F, B)
         for f in range(F):
             for b in range(B):
                 sel = (bins[:, f] == b) & mask
                 np.testing.assert_allclose(hist[0, f, b], grad[sel].sum(), rtol=1e-5, atol=1e-5)
                 np.testing.assert_allclose(hist[2, f, b], sel.sum(), rtol=1e-6)
-
-    def test_onehot_matches_scatter(self):
-        import jax.numpy as jnp
-
-        from mmlspark_tpu.ops.histogram import build_histogram
-
-        rng = np.random.default_rng(2)
-        n, F, B = 128, 7, 12
-        bins = jnp.asarray(rng.integers(0, B, size=(n, F)))
-        vals = jnp.asarray(rng.normal(size=(3, n)))
-        mask = jnp.ones(n, bool)
-        h1 = build_histogram(bins, vals, mask, B, backend="scatter")
-        h2 = build_histogram(bins, vals, mask, B, backend="onehot")
-        np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-4, atol=1e-4)
 
     def test_chunked_matches_unchunked(self):
         import jax.numpy as jnp
@@ -111,7 +123,7 @@ class TestHistogram:
 
         rng = np.random.default_rng(3)
         n, F, B = 512, 3, 8
-        bins = jnp.asarray(rng.integers(0, B, size=(n, F)))
+        bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
         vals = jnp.asarray(rng.normal(size=(3, n)))
         mask = jnp.ones(n, bool)
         h1 = build_histogram(bins, vals, mask, B, chunk=128)
@@ -152,50 +164,52 @@ class TestHistogram:
         )
 
     @_chunk_cases
-    def test_transposed_chunks_are_the_row_major_chunks(self, kind, backend, F):
-        """A chunk sliced out of (F, n) inside the scan is the chunk the
-        row-major split hands the same kernel: equal sums bit for bit, and
-        the unchunked call's to float tolerance."""
-        bins, *rest = self._chunk_inputs(F, seed=F)
+    def test_scan_sums_are_the_chunk_function_on_host_slices(self, kind, backend, F):
+        """A chunk sliced out of (F, n) inside the scan is the chunk a host
+        slice hands the same chunk function: the scan's sums equal theirs,
+        added in order, bit for bit, and the unchunked call's to float
+        tolerance."""
+        import jax.numpy as jnp
+
+        bins, vals, leaf, mask = self._chunk_inputs(F, seed=F)
         kw = dict(backend=backend, chunk=self._CHUNK)
-        t = np.asarray(self._chunk_build(kind, bins.T, *rest, transposed=True, **kw))
-        r = np.asarray(self._chunk_build(kind, bins, *rest, **kw))
-        u = np.asarray(self._chunk_build(kind, bins.T, *rest, transposed=True, backend=backend, chunk=self._N))
-        np.testing.assert_array_equal(t, r)
+        t = np.asarray(self._chunk_build(kind, bins.T, vals, leaf, mask, **kw))
+        u = np.asarray(self._chunk_build(kind, bins.T, vals, leaf, mask, backend=backend, chunk=self._N))
+        fn = _hist_chunk_fn(kind, backend, nibble=True)  # W = 8 at 256 bins: the router's choice
+        per_row = [np.where(mask[None, :], vals, 0).astype(np.float32)] + ([leaf] if kind == "by_leaf" else [])
+        static = (self._W, self._B) if kind == "by_leaf" else (self._B,)
+        want = jnp.zeros(t.shape, jnp.float32)
+        for i in range(self._N // self._CHUNK):
+            sl = slice(i * self._CHUNK, (i + 1) * self._CHUNK)
+            want = want + fn(jnp.asarray(bins.T[:, sl]), *(jnp.asarray(x[..., sl]) for x in per_row), *static)
+        np.testing.assert_array_equal(t, np.asarray(want))
         np.testing.assert_allclose(t, u, rtol=1e-5, atol=1e-4)
 
     @_chunk_cases
-    def test_transposed_chunks_drop_parked_and_masked_rows(self, kind, backend, F):
+    def test_chunks_drop_parked_and_masked_rows(self, kind, backend, F):
         """Parked leaf ids and masked rows drop out in every chunk: the sums
         are numpy's over the kept rows, whatever the dropped rows hold."""
         bins, vals, leaf, mask = self._chunk_inputs(F, seed=100 + F)
-        kw = dict(backend=backend, chunk=self._CHUNK, transposed=True)
+        kw = dict(backend=backend, chunk=self._CHUNK)
         got = np.asarray(self._chunk_build(kind, bins.T, vals, leaf, mask, **kw))
         kept = mask if kind == "plain" else mask & (leaf >= 0) & (leaf < self._W)
-        rows = np.flatnonzero(kept)
-        want = np.zeros(got.shape, np.float64)
-        for c in range(3):
-            for f in range(F):
-                if kind == "plain":
-                    np.add.at(want[c, f], bins[rows, f], vals[c, rows])
-                else:
-                    np.add.at(want[c, :, f], (leaf[rows], bins[rows, f]), vals[c, rows])
+        want = _numpy_hist(kind, bins.T, vals, leaf, np.flatnonzero(kept), self._W, self._B)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
         scrambled = np.where(kept[:, None], bins, np.uint8(255) - bins)
         again = np.asarray(self._chunk_build(kind, scrambled.T, vals, leaf, mask, **kw))
         np.testing.assert_array_equal(got, again)
 
     @_chunk_cases
-    def test_transposed_chunk_loop_relays_no_whole_array(self, kind, backend, F):
+    def test_chunk_loop_relays_no_whole_array(self, kind, backend, F):
         """The relayout cannot come back unseen on a CPU: the chunked
-        transposed call's jaxpr holds no transpose or reshape of the whole
+        call's jaxpr holds no transpose or reshape of the whole
         integer bins matrix and no transpose of the whole ``vals``; the scan's
         body slices the matrix itself."""
         import jax
 
         bins, *rest = self._chunk_inputs(F, seed=0)
         jaxpr = jax.make_jaxpr(
-            lambda b: self._chunk_build(kind, b, *rest, backend=backend, chunk=self._CHUNK, transposed=True)
+            lambda b: self._chunk_build(kind, b, *rest, backend=backend, chunk=self._CHUNK)
         )(bins.T)
         whole_bins, whole_vals, sliced = self._N * F, 3 * self._N, []
 
@@ -221,13 +235,144 @@ class TestHistogram:
 
         rng = np.random.default_rng(5)
         for (n, F, B) in [(257, 5, 16), (1024, 9, 64)]:
-            bins = jnp.asarray(rng.integers(0, B, size=(n, F)))
+            bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
             vals = jnp.asarray(rng.normal(size=(3, n)))
             mask = jnp.asarray(rng.random(n) > 0.3)
             h1 = build_histogram(bins, vals, mask, B, backend="scatter")
             h2 = build_histogram(bins, vals, mask, B, backend="pallas")
             np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-4, atol=1e-4)
 
+
+
+class TestSharedChunkBodies:
+    """One body per kernel whatever the value dtype, one layout, two backends."""
+
+    _N, _F, _B, _W = 1024, 5, 256, 8
+
+    @staticmethod
+    def _kernels_reached(fn, *args):
+        """Names of the jitted Pallas wrappers in ``fn``'s jaxpr."""
+        import jax
+
+        found = set()
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                name = eqn.params.get("name", "")
+                if eqn.primitive.name in ("jit", "pjit") and name.startswith("_pallas_hist"):
+                    found.add(name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return found
+
+    @pytest.mark.parametrize("dtype", ["float32", "int16"])
+    @pytest.mark.parametrize("backend", ["scatter", "pallas"])
+    @pytest.mark.parametrize("kind", ["plain", "by_leaf"])
+    def test_body_sums_in_the_accumulator_its_vals_ask_for(self, kind, backend, dtype):
+        """The shared chunk body gives numpy's sums: int16 buckets exactly
+        and as int32, float32 values to 1e-4 and as float32.  Through the
+        builder, a bucket by-leaf build at 256 bins, W = 8 stays off the
+        float-only nibble kernel that the same float build takes."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops import histogram as H
+
+        rng = np.random.default_rng(29)
+        n, F, B, W = self._N, self._F, self._B, self._W
+        bins_t = rng.integers(0, B, size=(F, n)).astype(np.uint8)
+        leaf = rng.integers(-2, W + 2, size=n).astype(np.int32)
+        if dtype == "int16":
+            vals = rng.integers(-H.QMAX, H.QMAX + 1, size=(3, n)).astype(np.int16)
+        else:
+            vals = rng.normal(size=(3, n)).astype(np.float32)
+        fn = _hist_chunk_fn(kind, backend)
+        per_row = [vals] if kind == "plain" else [vals, leaf, W]
+        got = fn(jnp.asarray(bins_t), *per_row, B)
+        rows = np.flatnonzero((leaf >= 0) & (leaf < W)) if kind == "by_leaf" else np.arange(n)
+        want = _numpy_hist(kind, bins_t, vals, leaf, rows, W, B)
+        if dtype == "int16":
+            assert got.dtype == jnp.int32
+            np.testing.assert_array_equal(np.asarray(got), want.astype(np.int32))
+        else:
+            assert got.dtype == jnp.float32
+            np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+        if (kind, backend) == ("by_leaf", "pallas"):
+            hq = H.HistQuantize("int32", 0, jnp.ones(3, jnp.float32)) if dtype == "int16" else None
+            reached = self._kernels_reached(
+                lambda b, v, l: H.build_histogram_by_leaf(b, v, l, W, B, backend="pallas", quantize=hq),
+                bins_t, vals, leaf,
+            )
+            assert reached == ({"_pallas_hist_by_leaf"} if dtype == "int16" else {"_pallas_hist_by_leaf_nibble"})
+
+    @pytest.mark.parametrize("kernel", ["_pallas_hist", "_pallas_hist_by_leaf", "_pallas_hist_by_leaf_nibble"])
+    def test_float_kernel_bodies_hold_no_int32_accumulator(self, kernel):
+        """The dtype branch is a Python ``if``: a float build's traced
+        kernel casts no float to int32 and holds no int32 array as wide as
+        an accumulator tile, where the bucket build of the same body (the
+        nibble kernel has none) holds both."""
+        import jax
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops import pallas_hist as PH
+
+        n, F, B, W, bf = 1536, 8, 256, 8, 8
+        acc_lanes = {bf * B, bf * PH._NIBBLE_LO}  # no other array here is this wide
+        static = dict(num_bins=B, bm=512, bf=bf, interpret=True, precision="highest")
+        rows = []
+        if kernel != "_pallas_hist":
+            rows = [jnp.zeros((1, n), jnp.int32)]
+            static.update(num_leaves=W, rm=256)
+
+        def int32_accumulation(val_dtype):
+            jaxpr = jax.make_jaxpr(lambda *a: getattr(PH, kernel)(*a, **static))(
+                jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), val_dtype), *rows
+            )
+            hits, seen = [], set()
+
+            def walk(jp):
+                for eqn in jp.eqns:
+                    seen.add(eqn.primitive.name)
+                    for aval in (v.aval for v in eqn.outvars):
+                        if getattr(aval, "dtype", None) == jnp.int32 and aval.shape[-1:] and aval.shape[-1] in acc_lanes:
+                            hits.append(eqn)
+                    if eqn.primitive.name == "convert_element_type" and eqn.params["new_dtype"] == jnp.int32:
+                        if jnp.issubdtype(eqn.invars[0].aval.dtype, jnp.floating):
+                            hits.append(eqn)
+                    for sub in jax.core.jaxprs_in_params(eqn.params):
+                        walk(sub)
+
+            walk(jaxpr.jaxpr)
+            assert {"pallas_call", "dot_general"} <= seen
+            return hits, jaxpr.out_avals[0].dtype
+
+        hits, out = int32_accumulation(jnp.float32)
+        assert not hits and out == jnp.float32, hits[:1]
+        if kernel != "_pallas_hist_by_leaf_nibble":
+            hits, out = int32_accumulation(jnp.int16)
+            assert hits and out == jnp.int32
+
+    @pytest.mark.parametrize(
+        "builder,rows",
+        [("build_histogram", ["mask"]), ("build_histogram_by_leaf", ["leaf_ids", "num_leaves"])],
+    )
+    def test_builders_take_one_layout_two_backends_no_wire(self, builder, rows):
+        """Ten and eleven parameters: no layout flag, no wire dtype; the
+        third backend's name is unknown."""
+        import inspect
+
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops import histogram as H
+
+        fn = getattr(H, builder)
+        assert list(inspect.signature(fn).parameters) == (
+            ["bins", "vals", *rows, "num_bins", "backend", "chunk", "axis_name", "precision", "merge", "quantize"]
+        )
+        per_row = (jnp.ones(4, bool),) if builder == "build_histogram" else (jnp.zeros(4, jnp.int32), 2)
+        with pytest.raises(ValueError, match="unknown hist backend"):
+            fn(jnp.zeros((2, 4), jnp.uint8), jnp.zeros((3, 4)), *per_row, 4, backend="onehot")
 
 
 class TestByLeafKernels:
@@ -249,7 +394,7 @@ class TestByLeafKernels:
         n, F = 2048, 9
         # inclusive of bin B-1: the top bin exercises the nibble kernel's
         # hi plane and the H*128 -> num_bins slice at non-power-of-two B
-        bins = jnp.asarray(rng.integers(0, B, size=(n, F)))
+        bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
         vals = jnp.asarray(rng.normal(size=(3, n)), dtype=jnp.float32)
         # parked ids on both sides of the window range
         leaf = jnp.asarray(rng.integers(-3, W + 2, size=(n,)), dtype=jnp.int32)
@@ -277,7 +422,7 @@ class TestByLeafKernels:
         for W in (8, 32):
             bins_t, _, leaf_row, bm, bf, rm, F_out, _ = _prep_by_leaf_chunk(
                 jnp.zeros((F, n), dtype), jnp.zeros((3, n)),
-                jnp.zeros((n,), jnp.int32), W, B, 16384, 32, 1024, True,
+                jnp.zeros((n,), jnp.int32), W, B, 16384, 32, 1024,
             )
             Fp, n_pad = bins_t.shape
             assert F_out == F and bins_t.dtype == jnp.dtype(dtype)
@@ -300,7 +445,7 @@ class TestByLeafKernels:
 
         rng = np.random.default_rng(7)
         n, F, B, W = 1024, 6, 256, 8
-        bins = jnp.asarray(rng.integers(0, B, size=(n, F)))
+        bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
         vals = jnp.asarray(rng.normal(size=(3, n)), dtype=jnp.float32)
         leaf = jnp.asarray(rng.integers(-1, W + 1, size=(n,)), dtype=jnp.int32)
         ref = np.asarray(build_histogram_by_leaf(bins, vals, leaf, W, B,
@@ -596,7 +741,7 @@ class TestWarmStartAndGuards:
         import pytest
 
         with pytest.raises(ValueError, match="hist backend"):
-            build_histogram(jnp.zeros((4, 2), jnp.int32), jnp.zeros((3, 4)),
+            build_histogram(jnp.zeros((2, 4), jnp.int32), jnp.zeros((3, 4)),
                             jnp.ones(4, bool), 4, backend="one_hot")
 
 
@@ -664,10 +809,10 @@ class TestAutoBackendResolution:
         X = rng.normal(size=(200, 3))
         y = (X[:, 0] > 0).astype(np.float64)
         b = train({"objective": "binary", "num_iterations": 2,
-                   "num_leaves": 4, "hist_backend": "onehot",
+                   "num_leaves": 4, "hist_backend": "scatter",
                    "hist_chunk": 256, "min_data_in_leaf": 5},
                   Dataset(X, y))
-        assert b.config.hist_backend == "onehot"
+        assert b.config.hist_backend == "scatter"
         assert b.config.hist_chunk == 256
 
 

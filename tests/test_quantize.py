@@ -4,8 +4,8 @@ histogram accumulation, integer-wire merge, f32 winner refinement.
 Layers:
 1. wire-plan unit tests — shift sizing and the overflow guard,
 2. quantization primitives — SR exactness, determinism, bounds,
-3. resolve_auto_config — every hist_psum_dtype × hist_merge ×
-   hist_quantize combination (the coherent-wire rules),
+3. resolve_auto_config — every hist_merge × hist_quantize
+   combination,
 4. end-to-end training — AUC parity vs f32, bitwise run-to-run
    determinism, categoricals, adversarial gradient magnitudes, and
    reduce_scatter-vs-allreduce consistency on the 8-device mesh.
@@ -144,7 +144,7 @@ class TestStochasticRounding:
         # bin sums of the SAME buckets — no hidden float accumulation
         rng = np.random.default_rng(5)
         n, F, B = 512, 4, 16
-        bins = jnp.asarray(rng.integers(0, B, size=(n, F)), jnp.int32)
+        bins = jnp.asarray(rng.integers(0, B, size=(F, n)), jnp.int32)
         vals = jnp.asarray(rng.normal(size=(3, n)), jnp.float32)
         scales = jnp.asarray([0.02, 0.02, COUNT_SCALE], jnp.float32)
         key = jax.random.PRNGKey(11)
@@ -156,7 +156,7 @@ class TestStochasticRounding:
         bn = np.asarray(bins)
         for f in range(F):
             for c in range(3):
-                np.add.at(manual[c, f], bn[:, f], qn[c])
+                np.add.at(manual[c, f], bn[f], qn[c])
         # dequantization is int32 total × f32 scale — mirror it exactly
         np.testing.assert_array_equal(
             np.asarray(out),
@@ -177,22 +177,14 @@ class TestResolveRules:
         )
 
     def test_every_wire_combination(self):
-        # hist_psum_dtype × hist_merge × hist_quantize: the two wire
-        # rewrites are mutually exclusive; everything else resolves
+        # hist_merge × hist_quantize: every pair resolves
         for merge in ("auto", "allreduce", "reduce_scatter"):
             for quant in ("off", "on", "int16", "int32"):
-                for dtype in ("float32", "bfloat16"):
-                    kw = dict(hist_merge=merge, hist_quantize=quant,
-                              hist_psum_dtype=dtype)
-                    if quant != "off" and dtype == "bfloat16":
-                        with pytest.raises(ValueError, match="ONE wire"):
-                            self._resolve(**kw)
-                        continue
-                    r = self._resolve(**kw)
-                    expect = "int16" if quant == "on" else quant
-                    assert r.hist_quantize == expect
-                    if merge != "auto":
-                        assert r.hist_merge == merge
+                r = self._resolve(hist_merge=merge, hist_quantize=quant)
+                expect = "int16" if quant == "on" else quant
+                assert r.hist_quantize == expect
+                if merge != "auto":
+                    assert r.hist_merge == merge
 
     def test_on_resolves_to_int16(self):
         assert self._resolve(hist_quantize="on").hist_quantize == "int16"
@@ -208,10 +200,8 @@ class TestResolveRules:
                 resolve_auto_config(cfg, n=1000, backend="cpu",
                                     num_devices=8, num_features=64)
 
-    def test_off_stays_off_and_bf16_still_works(self):
-        r = self._resolve(hist_quantize="off", hist_psum_dtype="bfloat16")
-        assert r.hist_quantize == "off"
-        assert r.hist_psum_dtype == "bfloat16"
+    def test_off_stays_off(self):
+        assert self._resolve(hist_quantize="off").hist_quantize == "off"
 
 
 # --------------------------------------------------- end-to-end training

@@ -176,49 +176,6 @@ class TestNibblePacking:
         out = np.asarray(unpack_rows(pack_rows(jnp.asarray(b)), 9))
         np.testing.assert_array_equal(out, b)
 
-    def test_packed_histogram_bitwise_matches_unpacked(self):
-        import jax.numpy as jnp
-
-        from mmlspark_tpu.ops.binpack import pack_rows
-        from mmlspark_tpu.ops.histogram import build_histogram
-
-        rng = np.random.default_rng(5)
-        n, F, B = 2048, 4, 16
-        bins = rng.integers(0, B, size=(n, F)).astype(np.uint8)
-        vals = rng.normal(size=(3, n)).astype(np.float32)
-        mask = rng.random(n) < 0.8
-        packed = jnp.asarray(pack_rows(bins))
-        for chunk in (512, 4096):  # scan path and single-shot path
-            plain = build_histogram(
-                jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(mask),
-                B, chunk=chunk)
-            pk = build_histogram(
-                packed, jnp.asarray(vals), jnp.asarray(mask),
-                B, chunk=chunk, packed=True)
-            np.testing.assert_array_equal(np.asarray(plain), np.asarray(pk))
-
-    def test_packed_input_validation(self):
-        import jax.numpy as jnp
-
-        from mmlspark_tpu.ops.binpack import pack_rows
-        from mmlspark_tpu.ops.histogram import build_histogram
-
-        rng = np.random.default_rng(6)
-        bins = rng.integers(0, 16, size=(64, 2)).astype(np.uint8)
-        vals = jnp.zeros((3, 64), jnp.float32)
-        mask = jnp.ones(64, bool)
-        packed = jnp.asarray(pack_rows(bins))
-        with pytest.raises(ValueError, match="num_bins"):
-            build_histogram(packed, vals, mask, 64, packed=True)
-        with pytest.raises(ValueError, match="transposed"):
-            build_histogram(packed, vals, mask, 16, packed=True,
-                            transposed=True)
-        bins93 = rng.integers(0, 16, size=(93, 2)).astype(np.uint8)
-        with pytest.raises(ValueError, match="even chunk"):
-            build_histogram(
-                jnp.asarray(pack_rows(bins93)), jnp.zeros((3, 93),
-                jnp.float32), jnp.ones(93, bool), 16, chunk=31, packed=True)
-
 
 # ------------------------------------------------- streamed training
 

@@ -180,9 +180,7 @@ def main(argv=None):
         rmask = jnp.ones(n_rows, bool)
 
         def hist_once():
-            build_histogram(
-                bins_t, vals, rmask, B, transposed=True
-            ).block_until_ready()
+            build_histogram(bins_t, vals, rmask, B).block_until_ready()
 
         hist_once()  # warm the jit
         with obs.span("ingest.hist", rows=n_rows, features=n_feat):

@@ -5,7 +5,7 @@ scale.  This tool produces the table BASELINE.md commits:
 
 1. **Weak scaling** over 1→8 virtual CPU devices (fixed rows/device):
    steady train wall for ``tree_learner=data`` vs ``voting`` vs data with
-   the bf16 histogram wire (``hist_psum_dtype="bfloat16"``), plus AUC so
+   the quantized integer wire (``hist_quantize``), plus AUC so
    wire-precision tradeoffs are quality-gated.  Virtual CPU devices share
    one core, so WALL numbers measure collective/overhead growth (the
    shape of the curve), not real ICI speedup — the BYTES are the part
@@ -199,9 +199,6 @@ def run_child(n_dev: int):
     modes = [("data", dict(tree_learner="data"), None),
              ("data_allreduce", dict(tree_learner="data",
                                      hist_merge="allreduce"), None),
-             ("data_bf16wire", dict(tree_learner="data",
-                                    hist_merge="allreduce",
-                                    hist_psum_dtype="bfloat16"), None),
              # ISSUE 9: int16 gradient buckets + integer merge wire — the
              # recorder shows the hist merge riding int16 (half the f32
              # bytes) and the AUC column quality-gates the quantization

@@ -54,7 +54,7 @@ def compact_then_hist(bins_t, vals, leaf, n_buf: int):
     vals_c = jnp.take(vals, take, axis=1, fill_value=0.0, mode="fill")
     leaf_c = jnp.where(take < n, jnp.take(leaf, jnp.minimum(take, n - 1)), W)
     return pallas_hist_by_leaf_nibble_chunk(
-        bins_c, vals_c, leaf_c, W, B, precision="default", transposed=True
+        bins_c, vals_c, leaf_c, W, B, precision="default"
     )
 
 
@@ -66,7 +66,7 @@ def main():
 
     full = jax.jit(
         lambda b, v, l: pallas_hist_by_leaf_nibble_chunk(
-            b, v, l, W, B, precision="default", transposed=True
+            b, v, l, W, B, precision="default"
         )
     )
 

@@ -40,7 +40,7 @@ def main():
     for name, kw in configs:
         try:
             fn = jax.jit(lambda b, v, l, kw=kw: pallas_hist_by_leaf_chunk(
-                b, v, l, W, B, precision="default", transposed=True, **kw))
+                b, v, l, W, B, precision="default", **kw))
             out = fn(bins_t, vals, leaf)
             np.asarray(out[:1, :1, :1, :1])  # compile+run once
             t0 = time.perf_counter()
@@ -67,7 +67,7 @@ def nibble():
     ]:
         try:
             fn = jax.jit(lambda b, v, l, kw=kw: pallas_hist_by_leaf_nibble_chunk(
-                b, v, l, W, B, precision="default", transposed=True, **kw))
+                b, v, l, W, B, precision="default", **kw))
             out = fn(bins_t, vals, leaf)
             np.asarray(out[:1, :1, :1, :1])
             t0 = time.perf_counter()
