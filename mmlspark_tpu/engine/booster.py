@@ -34,6 +34,7 @@ from mmlspark_tpu.engine import eval_metrics
 from mmlspark_tpu.engine.tree import (
     GrowConfig,
     Tree,
+    _leaf_lookup,
     grow_tree_auto,
     predict_tree_binned,
     predict_tree_leaf_binned,
@@ -1086,6 +1087,15 @@ def _feature_mask(key, F: int, fraction: float):
     order = jnp.argsort(-u)
     rank = jnp.argsort(order)
     return rank < k
+
+
+@jax.named_scope("leaf_delta")
+def _leaf_delta(tree: Tree, leaf_ids: jnp.ndarray) -> jnp.ndarray:
+    """delta[k] = leaf_value[k][leaf_ids[k]] for the (K, L) leaf values
+    and (K, n) leaf ids of one iteration's trees: the float32 the stored
+    model holds, on every backend and at every ``n`` (the gather lowering
+    cost 8.0ns a row on v5e, ``_leaf_lookup``'s select form ~0.5ns)."""
+    return jax.vmap(_leaf_lookup)(tree.leaf_value, leaf_ids)
 
 
 # ---------------------------------------------------------------------------
@@ -2393,46 +2403,6 @@ def _train_impl(
             m = jnp.pad(m, (0, F - F_real))
         return m
 
-    _delta_precision = (
-        jax.lax.Precision.DEFAULT
-        if cfg.hist_precision == "default"
-        else jax.lax.Precision.HIGHEST
-    )
-    # The one-hot delta is vmapped over classes, so its operand is
-    # (K, L, n) f32 — fall back to the gather when that blows the budget
-    # (the gather needs only the (K, n) output).  TPU-only for the same
-    # layout-parity reason as onehot_stats above: training scores feed the
-    # next tree's gradients, so a thread-count-dependent gemm order on CPU
-    # would diverge the whole forest between process layouts.
-    _delta_onehot = (
-        jax.default_backend() == "tpu"
-        and K * cfg.num_leaves * n <= _ONEHOT_BUDGET_ELS
-    )
-
-    @jax.named_scope("leaf_delta")
-    def _leaf_delta(tree, leaf_ids):
-        # delta[k] = leaf_value[k][leaf_ids[k]] as a one-hot contraction:
-        # the (n,)-gather-from-(L,) lowering cost ~2.1ms/tree at the bench
-        # shape vs ~0.2ms for the compare+dot.  Precision follows
-        # cfg.hist_precision (same contract as the histogram kernels): the
-        # one-hot operand is exact either way; "default" rounds the f32
-        # leaf value to bf16 (~2^-9 relative) in the TRAINING-score
-        # accumulation only — the stored model keeps f32 leaf values, and
-        # "highest" makes training scores replay-exact against them.
-        if not _delta_onehot:
-            return jax.vmap(lambda lv, li: lv[li])(tree.leaf_value, leaf_ids)
-        return jax.vmap(
-            lambda lv, li: jax.lax.dot_general(
-                lv[None, :],
-                (
-                    li[None, :]
-                    == jnp.arange(lv.shape[0], dtype=li.dtype)[:, None]
-                ).astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                precision=_delta_precision,
-            )[0]
-        )(tree.leaf_value, leaf_ids)
-
     def _quantize_inputs(grad, hess, bag, key):
         # Per-iteration channel scales over the GLOBAL bagged batch —
         # grad/hess are still the full (sharded) arrays here, outside
@@ -3008,7 +2978,7 @@ def _train_impl(
             # added, instead of re-enumerating cfg fields that feed it.
             cache_key = (
                 _cfg_cache_key(cfg), K, F, F_real, B, _mesh_cache_key(mesh),
-                type(obj).__name__, state_key, gcfg, _delta_onehot,
+                type(obj).__name__, state_key, gcfg,
             )
             entry = _SCAN_CACHE.get(cache_key)
             scan_cache_hit = entry is not None
@@ -3061,7 +3031,6 @@ def _train_impl(
                         len(vsets), cfg.is_provide_training_metric,
                         tuple(metric_names) if device_eval else None,
                         gcfg,  # data-derived statics (cat_value_bins, ...)
-                        _delta_onehot,
                         mesh_trace_key(mesh), process_local, feature_par,
                     )),
                     # Load-vs-export agreement only for programs every rank

@@ -65,6 +65,7 @@ from mmlspark_tpu.engine.booster import (
     _fetch_tree_chunks,
     _finalize_booster,
     _fold_bias,
+    _leaf_delta,
     _pad_rows,
     resolve_auto_config,
 )
@@ -220,7 +221,7 @@ def _grow_classes(gcfg_):
     return grow_all
 
 
-def _build_multi_program(cfg, gcfg, obj, Kc, F, delta_onehot, has_w):
+def _build_multi_program(cfg, gcfg, obj, Kc, F, has_w):
     """The ONE jitted program: lax.map over the model axis of the
     standalone fused-scan body.  Every statement inside ``body`` is the
     standalone ``scan_chunk`` body's no-bagging/no-dart/no-valid leg,
@@ -229,27 +230,6 @@ def _build_multi_program(cfg, gcfg, obj, Kc, F, delta_onehot, has_w):
 
     def _fmask_one(key):
         return _feature_mask(key, F, cfg.feature_fraction)
-
-    _delta_precision = (
-        jax.lax.Precision.DEFAULT
-        if cfg.hist_precision == "default"
-        else jax.lax.Precision.HIGHEST
-    )
-
-    def _leaf_delta(tree, leaf_ids):
-        if not delta_onehot:
-            return jax.vmap(lambda lv, li: lv[li])(tree.leaf_value, leaf_ids)
-        return jax.vmap(
-            lambda lv, li: jax.lax.dot_general(
-                lv[None, :],
-                (
-                    li[None, :]
-                    == jnp.arange(lv.shape[0], dtype=li.dtype)[:, None]
-                ).astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                precision=_delta_precision,
-            )[0]
-        )(tree.leaf_value, leaf_ids)
 
     def one_model(args):
         if has_w:
@@ -407,23 +387,19 @@ def multi_train(
             f"histogram chunk ({chunk}); train() handles the large case"
         )
 
-    # onehot algorithm choices are made from each model's UNPADDED row
-    # count (exactly what its standalone run resolves) and must agree
+    # the one-hot leaf statistics are chosen from each model's UNPADDED
+    # row count (exactly what its standalone run resolves) and must agree
     # across the stack — the shared program bakes ONE choice in.
     on_tpu = jax.default_backend() == "tpu"  # layout-parity: see _train_impl
     oh_flags = {
-        (
-            on_tpu and cfg0.num_leaves * n <= _ONEHOT_BUDGET_ELS,
-            on_tpu and Kc * cfg0.num_leaves * n <= _ONEHOT_BUDGET_ELS,
-        )
-        for n in n_list
+        on_tpu and cfg0.num_leaves * n <= _ONEHOT_BUDGET_ELS for n in n_list
     }
     if len(oh_flags) != 1:
         raise ValueError(
             "stacked jobs straddle the one-hot stats budget "
             "(_ONEHOT_BUDGET_ELS); split the batch by row count"
         )
-    onehot_stats, delta_onehot = next(iter(oh_flags))
+    onehot_stats = next(iter(oh_flags))
 
     # ---- per-model tensors, padded to (N rows, T_max iterations) -------
     T_list = [cfg.num_iterations for cfg in cfgs]
@@ -584,14 +560,11 @@ def multi_train(
     cache_key = (
         tuple(kv for kv in _cfg_cache_key(cfg0)
               if kv[0] not in _PER_MODEL_FIELDS),
-        Kc, F, B, type(obj).__name__, gcfg,
-        delta_onehot, has_w,
+        Kc, F, B, type(obj).__name__, gcfg, has_w,
     )
     program = _MULTI_CACHE.get(cache_key)
     if program is None:
-        program = _build_multi_program(
-            cfg0, gcfg, obj, Kc, F, delta_onehot, has_w
-        )
+        program = _build_multi_program(cfg0, gcfg, obj, Kc, F, has_w)
         if len(_MULTI_CACHE) >= _MULTI_CACHE_MAX:
             _MULTI_CACHE.pop(next(iter(_MULTI_CACHE)))
         _MULTI_CACHE[cache_key] = program

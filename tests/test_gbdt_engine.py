@@ -953,6 +953,30 @@ class TestMultiMetric:
                   Dataset(X, y), valid_sets=[Dataset(Xv, yv)])
 
 
+class TestLeafTableAccess:
+    """The fit reads its (K, L) leaf table with no per-row gather."""
+
+    @pytest.mark.parametrize("L", [7, 63, 255])
+    @pytest.mark.parametrize("K", [1, 3])
+    def test_leaf_delta_is_the_gather_to_the_bit(self, K, L):
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.engine.booster import _leaf_delta
+        from mmlspark_tpu.engine.tree import _empty_tree
+
+        rng = np.random.default_rng(100 * K + L)
+        leaf_value = rng.normal(size=(K, L)).astype(np.float32)
+        leaf_value[:, ::5] *= 1e-30  # denormal-range and tiny values too
+        leaf_ids = rng.integers(0, L, size=(K, 5000)).astype(np.int32)
+        tree = _empty_tree(L - 1, L, 4)._replace(
+            leaf_value=jnp.asarray(leaf_value)
+        )
+        got = np.asarray(_leaf_delta(tree, jnp.asarray(leaf_ids)))
+        want = np.take_along_axis(leaf_value, leaf_ids, axis=1)
+        assert got.dtype == np.float32
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
 class TestOnehotBudgetCrossover:
     def test_gather_fallback_matches_onehot_path(self, monkeypatch):
         """HBM-budget guard (BASELINE.md r5 row-scaling envelope): past
