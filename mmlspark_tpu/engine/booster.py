@@ -1193,13 +1193,6 @@ _XS_CACHE_MAX = 8
 # per-iteration loop (tests monkeypatch this to force the legacy path).
 _DART_SCAN_MAX_ELS = 128_000_000
 
-# HBM-budget guard for the one-hot leaf-stat/leaf-delta contractions: the
-# (L, n) / (K, L, n) f32 operands buy MXU throughput below this element
-# count and blow HBM above it (a gather serves instead).  At 63 leaves the
-# crossover is n ≈ 2.03M rows/chip — measured in BASELINE.md's r5
-# row-scaling envelope; tests cross it by monkeypatching this constant.
-_ONEHOT_BUDGET_ELS = 128_000_000
-
 # The AOT trace cache engages only for programs big enough that tracing
 # hurts (rows × iterations): exporting costs one extra serialize per
 # first-ever program, which would tax small fits/test suites for no win.
@@ -2300,18 +2293,13 @@ def _train_impl(
         ),
         voting=voting,
         top_k=cfg.top_k,
-        # classes grow sequentially (lax.map below), so the grower's
-        # one-hot stats operand is (L, n) f32 for ONE class at a time.
         # TPU-only: the MXU contraction is shape-deterministic, while
         # XLA:CPU threads the gemm by the host's device count, so the
         # f32 sum order differs between process layouts of the same mesh
         # and the recorded leaf values lose bitwise layout-parity
         # (tools/bench_pod.py gate); the scatter path accumulates in row
         # order on every layout.
-        onehot_stats=(
-            jax.default_backend() == "tpu"
-            and cfg.num_leaves * n <= _ONEHOT_BUDGET_ELS
-        ),
+        onehot_stats=jax.default_backend() == "tpu",
     )
 
     def _grow_classes(gcfg_):
@@ -2973,7 +2961,7 @@ def _train_impl(
         else:
             # gcfg carries every data-derived static baked into the traced
             # program (cat_value_bins from the bin mapper, onehot_stats from
-            # n, resolved split_batch/grow_policy, hist_chunk) — keying on
+            # the backend, resolved split_batch/grow_policy, hist_chunk) — keying on
             # the whole frozen dataclass keeps the key honest as fields are
             # added, instead of re-enumerating cfg fields that feed it.
             cache_key = (

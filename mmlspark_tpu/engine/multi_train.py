@@ -54,7 +54,6 @@ import numpy as np
 
 from mmlspark_tpu import obs
 from mmlspark_tpu.engine.booster import (
-    _ONEHOT_BUDGET_ELS,
     _PARALLEL_LEARNERS,
     Booster,
     Dataset,
@@ -387,20 +386,6 @@ def multi_train(
             f"histogram chunk ({chunk}); train() handles the large case"
         )
 
-    # the one-hot leaf statistics are chosen from each model's UNPADDED
-    # row count (exactly what its standalone run resolves) and must agree
-    # across the stack — the shared program bakes ONE choice in.
-    on_tpu = jax.default_backend() == "tpu"  # layout-parity: see _train_impl
-    oh_flags = {
-        on_tpu and cfg0.num_leaves * n <= _ONEHOT_BUDGET_ELS for n in n_list
-    }
-    if len(oh_flags) != 1:
-        raise ValueError(
-            "stacked jobs straddle the one-hot stats budget "
-            "(_ONEHOT_BUDGET_ELS); split the batch by row count"
-        )
-    onehot_stats = next(iter(oh_flags))
-
     # ---- per-model tensors, padded to (N rows, T_max iterations) -------
     T_list = [cfg.num_iterations for cfg in cfgs]
     T_max = max(T_list)
@@ -550,7 +535,7 @@ def multi_train(
         ),
         voting=False,
         top_k=cfg0.top_k,
-        onehot_stats=onehot_stats,
+        onehot_stats=jax.default_backend() == "tpu",  # see _train_impl
     )
 
     # Per-model fields ride as runtime data (seeds through the xs

@@ -64,6 +64,7 @@ def fits(data, mesh):
     """One serial fit, then two data-parallel fits of one resident sharded
     data set (the second runs the cached program), with what each counted."""
     obs.reset()
+    obs.flight.reset()  # the ring keeps spans while obs is off: earlier tests' fits are in it
     obs.enable()
     try:
         counters = [dict(obs.snapshot()["counters"])]

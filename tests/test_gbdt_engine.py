@@ -977,31 +977,164 @@ class TestLeafTableAccess:
         np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
 
 
-class TestOnehotBudgetCrossover:
-    def test_gather_fallback_matches_onehot_path(self, monkeypatch):
-        """HBM-budget guard (BASELINE.md r5 row-scaling envelope): past
-        num_leaves*n = _ONEHOT_BUDGET_ELS the (L, n) one-hot leaf-stat /
-        leaf-delta contractions fall back to gathers.  Both sides of the
-        crossover must train the same model at this (small, fixed
-        summation order) scale — the budget is a memory trade, not a
-        semantics change.  At millions of rows f32 summation-order
-        reassociation can flip near-tie splits, so the large-n gate is
-        quality (AUC gap ~1e-6 measured at 1M rows on TPU — BASELINE.md
-        r5 envelope), like the feature-parallel caveat."""
-        import mmlspark_tpu.engine.booster as bo
+    @staticmethod
+    def _totals_case(n, L, seed):
+        rng = np.random.default_rng(seed)
+        vals = rng.normal(size=(3, n)).astype(np.float32)
+        vals[2] = 1.0  # the count channel: in-bag rows ...
+        vals[:, rng.random(n) < 0.2] = 0.0  # ... zero-weight rows are all 0
+        leaf_ids = rng.integers(0, L + 3, size=n).astype(np.int32)  # ids >= L drop
+        return vals, leaf_ids
+
+    @pytest.mark.parametrize("rows", ["below", "equal", "ragged", "two_and_ragged"])
+    def test_chunked_leaf_totals_match_the_scatter(self, rows):
+        import jax
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.engine import tree as tr
+
+        c, L = tr._LEAF_TOTALS_CHUNK, 63
+        n = {"below": 5000, "equal": c, "ragged": c + 777,
+             "two_and_ragged": 2 * c + 12345}[rows]
+        vals, leaf_ids = self._totals_case(n, L, seed=n % 1000)
+        totals = jax.jit(tr._leaf_totals, static_argnums=(2, 3))
+        got = np.asarray(totals(jnp.asarray(vals), jnp.asarray(leaf_ids), L, True))
+        want = np.asarray(totals(jnp.asarray(vals), jnp.asarray(leaf_ids), L, False))
+        keep = leaf_ids < L
+        exact = np.stack([
+            np.bincount(leaf_ids[keep], weights=v[keep].astype(np.float64),
+                        minlength=L)
+            for v in vals
+        ])
+        np.testing.assert_array_equal(got[2], want[2])  # counts: exact
+        np.testing.assert_array_equal(got[2], exact[2])
+        scale = np.abs(vals[:2]).sum(axis=1, keepdims=True) / L
+        assert np.max(np.abs(got[:2] - want[:2]) / scale) < 1e-6
+        assert np.max(np.abs(got[:2] - exact[:2]) / scale) < 1e-6
+
+    @staticmethod
+    def _row_ops(jaxpr, n):
+        """Names of the gather / scatter-add equations, at any depth, one of
+        whose operands has ``n`` rows."""
+        import jax
+
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("gather", "scatter-add", "scatter_add") and any(
+                n in getattr(v.aval, "shape", ()) for v in eqn.invars
+            ):
+                found.append(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += TestLeafTableAccess._row_ops(sub, n)
+        return found
+
+    @pytest.mark.parametrize("chunks", [1, 3])
+    def test_tpu_resolved_tail_and_delta_index_no_table_per_row(self, chunks):
+        import jax
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.engine import tree as tr
+        from mmlspark_tpu.engine.booster import _leaf_delta
+
+        n, F, B, L = chunks * tr._LEAF_TOTALS_CHUNK, 4, 17, 63
+        tail = jax.make_jaxpr(
+            lambda v, i: tr._leaf_totals(v, i, L, onehot=True)
+        )(jnp.zeros((3, n), jnp.float32), jnp.zeros(n, jnp.int32))
+        assert self._row_ops(tail.jaxpr, n) == []
+        # the control: the CPU's form of the same sums is the scatter-add
+        scatter = jax.make_jaxpr(
+            lambda v, i: tr._leaf_totals(v, i, L, onehot=False)
+        )(jnp.zeros((3, n), jnp.float32), jnp.zeros(n, jnp.int32))
+        assert set(self._row_ops(scatter.jaxpr, n)) == {"scatter-add"}
+        delta = jax.make_jaxpr(_leaf_delta)(
+            tr._empty_tree(L - 1, L, B)._replace(
+                leaf_value=jnp.zeros((2, L), jnp.float32)
+            ),
+            jnp.zeros((2, n), jnp.int32),
+        )
+        assert self._row_ops(delta.jaxpr, n) == []
+        # and the whole windowed grower, as the booster resolves it on a TPU
+        def grower_ops(onehot):
+            cfg = tr.GrowConfig(num_bins=B, num_leaves=L, split_batch=8,
+                                hist_backend="pallas", onehot_stats=onehot)
+            return self._row_ops(jax.make_jaxpr(
+                lambda *a: tr.grow_tree_depthwise(cfg, *a)
+            )(jnp.zeros((n, F), jnp.uint8), jnp.zeros(n, jnp.float32),
+              jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32),
+              jnp.ones(F, bool)).jaxpr, n)
+
+        assert grower_ops(True) == []
+        assert grower_ops(False) == ["scatter-add"]
+
+    def test_backend_switch_trains_the_same_model(self):
+        """``onehot_stats`` is a backend switch, not a semantics change:
+        the windowed grower's leaf values and counts under the contraction
+        are the scatter's at this (small, fixed summation order) scale."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.engine import tree as tr
 
         rng = np.random.default_rng(5)
-        X = rng.normal(size=(1500, 6))
-        y = (X[:, 0] - 0.5 * X[:, 1] > 0).astype(np.float64)
-        params = dict(objective="binary", num_iterations=8, num_leaves=15,
-                      min_data_in_leaf=5, max_bin=63)
-        p_onehot = bo.train(params, bo.Dataset(X, y)).predict(X)
-        assert 15 * 1500 <= bo._ONEHOT_BUDGET_ELS  # sanity: was one-hot
-        monkeypatch.setattr(bo, "_ONEHOT_BUDGET_ELS", 0)  # force gathers
-        bo._SCAN_CACHE.clear()
-        p_gather = bo.train(params, bo.Dataset(X, y)).predict(X)
-        bo._SCAN_CACHE.clear()
-        np.testing.assert_allclose(p_onehot, p_gather, rtol=1e-6, atol=1e-7)
+        n, F, B = 1500, 6, 33
+        args = (jnp.asarray(rng.integers(0, B - 1, size=(n, F))),
+                jnp.asarray(rng.normal(size=n).astype(np.float32)),
+                jnp.ones(n, jnp.float32),
+                jnp.asarray((rng.random(n) < 0.8).astype(np.float32)),
+                jnp.ones(F, bool))
+        common = dict(num_bins=B, num_leaves=15, min_data_in_leaf=5,
+                      split_batch=8)
+        t_oh, ids_oh = tr.grow_tree_depthwise(
+            tr.GrowConfig(**common, onehot_stats=True), *args)
+        t_sc, ids_sc = tr.grow_tree_depthwise(
+            tr.GrowConfig(**common, onehot_stats=False), *args)
+        np.testing.assert_array_equal(np.asarray(ids_oh), np.asarray(ids_sc))
+        np.testing.assert_array_equal(
+            np.asarray(t_oh.leaf_count), np.asarray(t_sc.leaf_count))
+        np.testing.assert_allclose(
+            np.asarray(t_oh.leaf_value), np.asarray(t_sc.leaf_value),
+            rtol=1e-5, atol=1e-7)
+
+    def test_sharded_contraction_sums_across_shards(self):
+        """Under ``reduce_scatter`` on the CPU's fake devices each shard
+        contracts its own rows and ``psum_axes`` adds the partials: the
+        tree's counts are the whole set's."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        from mmlspark_tpu.engine import tree as tr
+        from mmlspark_tpu.parallel.mesh import DATA_AXIS
+
+        D = 4
+        rng = np.random.default_rng(9)
+        n, F, B, L = 4096, 8, 33, 15
+        bins = rng.integers(0, B - 1, size=(n, F)).astype(np.uint8)
+        grad = rng.normal(size=n).astype(np.float32)
+        hess = np.ones(n, np.float32)
+        bag = (rng.random(n) < 0.9).astype(np.float32)
+        common = dict(num_bins=B, num_leaves=L, min_data_in_leaf=5,
+                      split_batch=1, onehot_stats=True)
+        mesh = Mesh(np.asarray(jax.devices()[:D]), (DATA_AXIS,))
+        spec = tr.Tree(*([P()] * len(tr.Tree._fields)))
+        cfg = tr.GrowConfig(**common, axis_name=DATA_AXIS,
+                            hist_merge="reduce_scatter")
+        sharded = jax.jit(jax.shard_map(
+            lambda *a: tr.grow_tree_depthwise(cfg, *a), mesh=mesh,
+            in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS),
+                      P(DATA_AXIS), P(None)),
+            out_specs=(spec, P(DATA_AXIS)), check_vma=False,
+        ))
+        args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+                jnp.asarray(bag), jnp.ones(F, bool))
+        t_sh, ids_sh = sharded(*args)
+        t_1, ids_1 = tr.grow_tree_depthwise(tr.GrowConfig(**common), *args)
+        assert float(np.asarray(t_sh.leaf_count).sum()) == float(bag.sum())
+        np.testing.assert_array_equal(np.asarray(ids_sh), np.asarray(ids_1))
+        np.testing.assert_array_equal(
+            np.asarray(t_sh.leaf_count), np.asarray(t_1.leaf_count))
+        np.testing.assert_allclose(
+            np.asarray(t_sh.leaf_value), np.asarray(t_1.leaf_value),
+            rtol=1e-5, atol=1e-7)
 
 
 class TestScanDispatchIters:

@@ -3,10 +3,10 @@
 The north star is Criteo-1TB on v5e-32 — O(100M) rows per chip — but
 nothing had ever measured training beyond 262k rows.  This sweeps the
 criteo-schema shape at 1M/2M/4M rows on the real chip at ENGINE DEFAULTS,
-reporting steady s/iter, device peak memory, and which static fallbacks
-engaged (the (L, n) one-hot leaf-stat operands cap at 128M elements —
-`GrowConfig.onehot_stats` / `_delta_onehot` switch to gathers past
-n = 128e6/num_leaves ≈ 2.03M rows at 63 leaves).
+reporting steady s/iter, device peak memory, and the resolved statics
+(no form of the fit changes with the rows since PR 30: the leaf delta is
+`_leaf_lookup`'s select form and the leaf statistics a one-hot
+contraction over `tree._LEAF_TOTALS_CHUNK` rows at a time, at every n).
 
 Each cell runs in its own subprocess, one after the other (a cell that
 runs out of device memory takes only its own process down; the parent never
@@ -80,7 +80,7 @@ print(json.dumps(dict(
     rows=N, iters=ITERS, bin_s=round(bin_s, 2),
     steady_s=round(min(walls), 3),
     s_per_iter=round(min(walls) / ITERS, 4),
-    onehot_stats=bool(63 * (N if N % (1 << 20) == 0 else N) <= 128_000_000),
+    onehot_stats=jax.default_backend() == "tpu",
     hist_chunk=rc.hist_chunk, split_batch=rc.split_batch,
     mem=mem,
 )))
