@@ -2960,8 +2960,8 @@ def _train_impl(
             scan_chunk, program_notes = _build_scan_chunk(), {}
         else:
             # gcfg carries every data-derived static baked into the traced
-            # program (cat_value_bins from the bin mapper, onehot_stats from
-            # the backend, resolved split_batch/grow_policy, hist_chunk) — keying on
+            # program (cat_value_bins from the bin mapper, onehot_stats from the
+            # backend, resolved split_batch/grow_policy, hist_chunk) — keying on
             # the whole frozen dataclass keeps the key honest as fields are
             # added, instead of re-enumerating cfg fields that feed it.
             cache_key = (

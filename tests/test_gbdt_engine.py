@@ -976,7 +976,6 @@ class TestLeafTableAccess:
         assert got.dtype == np.float32
         np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
 
-
     @staticmethod
     def _totals_case(n, L, seed):
         rng = np.random.default_rng(seed)
@@ -1020,7 +1019,7 @@ class TestLeafTableAccess:
 
         found = []
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name in ("gather", "scatter-add", "scatter_add") and any(
+            if eqn.primitive.name in ("gather", "scatter-add") and any(
                 n in getattr(v.aval, "shape", ()) for v in eqn.invars
             ):
                 found.append(eqn.primitive.name)
