@@ -201,6 +201,7 @@ class StreamedDataset:
         num_features: int,
         label: Optional[np.ndarray] = None,
         weight: Optional[np.ndarray] = None,
+        group: Optional[np.ndarray] = None,
         occupancy: Optional[np.ndarray] = None,
         sample: Optional[np.ndarray] = None,
         ingest_stats: Optional[dict] = None,
@@ -213,7 +214,7 @@ class StreamedDataset:
         self.X = None  # the whole point: raw features never fully on host
         self.label = None if label is None else np.asarray(label, np.float64)
         self.weight = None if weight is None else np.asarray(weight, np.float64)
-        self.group = None
+        self.group = None if group is None else np.asarray(group, np.int64)
         self.init_score = None
         self._occupancy = occupancy  # (F, B) int64 exact bin occupancy
         self._sample = sample        # (≤cap, F) uint8 host quality sample
@@ -224,6 +225,7 @@ class StreamedDataset:
         self._mapper_cache = {}
         self._bins_cache = {}
         self._dev_bins_cache = {}
+        self._rank_plan_cache = {}
         self._cache_refs = []
 
     @property

@@ -297,6 +297,7 @@ class Dataset:
         self._mapper_cache: Dict[Tuple, BinMapper] = {}
         self._bins_cache: Dict[int, np.ndarray] = {}
         self._dev_bins_cache: Dict[Tuple, object] = {}  # padded device copies
+        self._rank_plan_cache: Dict[Tuple, object] = {}  # (RankPlan, device arrays)
         self._cache_refs: List[BinMapper] = []  # pin ids used as cache keys
 
     def __getstate__(self):
@@ -308,6 +309,7 @@ class Dataset:
         state["_mapper_cache"] = {}
         state["_bins_cache"] = {}
         state["_dev_bins_cache"] = {}
+        state["_rank_plan_cache"] = {}
         state["_cache_refs"] = []
         return state
 
@@ -2047,7 +2049,6 @@ def _train_impl(
         if n_local > chunk:
             n_local = ((n_local + chunk - 1) // chunk) * chunk
         n_pad = n_local * D_rows - n
-    bins_np = _pad_rows(bins_np, n_pad)
     y = _pad_rows(train_set.label, n_pad)
     valid_mask_np = np.concatenate([np.ones(n, bool), np.zeros(n_pad, bool)])
 
@@ -2076,13 +2077,17 @@ def _train_impl(
             w = np.where(train_set.label > 0, base * spw, base)
     w_np = None if w is None else _pad_rows(np.asarray(w, dtype=np.float64), n_pad)
 
-    # Process-aligned ranking groups (distributed lambdarank): every
-    # process's queries live wholly inside its own row block, the padded
-    # (G, M) index matrices are assembled GLOBALLY from allgathered group
-    # metadata (engine/dist_metrics.assemble_global_groups), and the
-    # pairwise lambda computation runs unchanged over the globally sharded
-    # scores — the score[idx] gather is the one collective.
+    # Ranking queries: the plan (ops/rank_plan: queries bucketed by length)
+    # is built once for a data set from its group sizes and kept on the
+    # device with it, like the binned matrix; a later fit on the same set
+    # builds and sends nothing.  Process-aligned groups (distributed
+    # lambdarank): every process's queries live wholly inside its own row
+    # block, the plan is built GLOBALLY from allgathered group metadata
+    # (engine/dist_metrics.assemble_global_groups) and replicated, and the
+    # pairwise computation runs unchanged over the globally sharded scores
+    # — gathering each query's scores is the one collective.
     train_groups_host = None
+    rank_counts = None
     if isinstance(obj, LambdaRank):
         if train_set.group is None:
             raise ValueError("lambdarank requires group sizes")
@@ -2091,22 +2096,44 @@ def _train_impl(
                 "group sizes must sum to this dataset's row count "
                 f"({int(np.sum(train_set.group))} != {n})"
             )
-        if process_local:
-            from jax.sharding import PartitionSpec as P
+        with obs.span("booster.rank_plan") as sp_plan:
+            from mmlspark_tpu.ops.rank_plan import build_rank_plan, plan_from_matrix
 
-            from mmlspark_tpu.engine.dist_metrics import assemble_global_groups
-            from mmlspark_tpu.parallel.distributed import make_global_array
+            group = np.asarray(train_set.group, np.int64)
+            plan_key = (hash(group.tobytes()), _mesh_cache_key(mesh))
+            held = None if process_local else train_set._rank_plan_cache.get(plan_key)
+            sp_plan.set(cache_hit=held is not None)
+            if process_local:
+                # never cached: assembling the groups is a collective that
+                # every process must enter in every fit
+                from jax.sharding import PartitionSpec as P
 
-            row_off = jax.process_index() * n_local * d_local
-            idx_g, valid_g = assemble_global_groups(train_set.group, row_off)
-            train_groups_host = (idx_g, valid_g)
-            obj.set_group_matrix(
-                make_global_array(mesh, P(), idx_g),
-                make_global_array(mesh, P(), valid_g),
-                state_key=hash(idx_g.tobytes() + valid_g.tobytes()),
+                from mmlspark_tpu.engine.dist_metrics import assemble_global_groups
+                from mmlspark_tpu.parallel.distributed import make_global_array
+
+                row_off = jax.process_index() * n_local * d_local
+                train_groups_host = assemble_global_groups(group, row_off)
+                plan = plan_from_matrix(*train_groups_host)
+                held = (plan, plan.device_arrays(lambda a: make_global_array(mesh, P(), a)))
+            elif held is None:
+                plan = build_rank_plan(group)
+                obs.inc(
+                    "train.upload_bytes",
+                    float(sum(a.nbytes for a in jax.tree_util.tree_leaves(plan.host_arrays()))),
+                )
+                held = (plan, plan.device_arrays())
+                train_set._rank_plan_cache = {plan_key: held}  # size 1, like the matrix's
+            plan = held[0]
+            obj.set_plan(*held)
+            rank_counts = {
+                "rank.queries": plan.queries,
+                "rank.pair_slots": plan.pair_slots(obj.max_position),
+                "rank.pair_terms": plan.pair_terms(obj.max_position),
+            }
+            sp_plan.set(
+                queries=plan.queries, buckets=len(plan.buckets), rows=int(n + n_pad),
+                shapes=" ".join(f"{g}x{m}" for g, m in plan.shape_key[0]),
             )
-        else:
-            obj.set_groups(train_set.group)
 
     # ---- init score ----------------------------------------------------
     # dart (tree rescaling would corrupt the folded bias) and rf (averaged
@@ -2145,6 +2172,10 @@ def _train_impl(
         hierarchical,
     )
     bins_dev = train_set._dev_bins_cache.get(dev_key)
+    if bins_dev is None:
+        # only now: a resident matrix (StreamedDataset) is padded on the
+        # device, and a fit that finds the padded copy must not make another
+        bins_np = _pad_rows(bins_np, n_pad)
     sp_upload = phases.enter("booster.upload", bins_cached=bins_dev is not None)
     _sent = _Uploads()
     if feature_par:
@@ -2410,8 +2441,10 @@ def _train_impl(
     # minutes constant-folding through the 10s-of-MB binned matrix (75s →
     # 8s compile observed at 262k×64).
     @jax.jit
-    def iteration(bins_a, y_a, w_a, vmask_a, scores, key, bag_in):
-        grad, hess = obj.grad_hess(scores if K > 1 else scores[0], y_a, w_a)
+    def iteration(bins_a, y_a, w_a, vmask_a, ostate_a, scores, key, bag_in):
+        grad, hess = obj.grad_hess_from(
+            ostate_a, scores if K > 1 else scores[0], y_a, w_a
+        )
         if K == 1:
             grad, hess = grad[None, :], hess[None, :]
         gkey, fkey = jax.random.split(key)
@@ -2803,8 +2836,8 @@ def _train_impl(
             _rep = NamedSharding(mesh, _PS()) if mesh is not None else None
 
             def scan_chunk(
-                bins_a, y_a, w_a, vmask_a, init_scores_a, vbins_a, vaux_a,
-                carry, xs_c, *dart_xs,
+                bins_a, y_a, w_a, vmask_a, ostate_a, init_scores_a, vbins_a,
+                vaux_a, carry, xs_c, *dart_xs,
             ):
                 def body(car, xs):
                     if dart_scan:
@@ -2829,8 +2862,9 @@ def _train_impl(
                         train_scores = (
                             init_scores_a if cfg.boosting == "rf" else scores_c
                         )
-                    grad, hess = obj.grad_hess(
-                        train_scores if K > 1 else train_scores[0], y_a, w_a
+                    grad, hess = obj.grad_hess_from(
+                        ostate_a,
+                        train_scores if K > 1 else train_scores[0], y_a, w_a,
                     )
                     if K == 1:
                         grad, hess = grad[None, :], hess[None, :]
@@ -2947,8 +2981,10 @@ def _train_impl(
         # closes over can differ.  The cached program closes over the FIRST
         # call's objective instance, which is sound because objectives are
         # stateless-by-construction (Objective.stateful) — stateful ones
-        # (LambdaRank's group matrix) participate only when their state
-        # fingerprint is part of the key, and are rebuilt otherwise.
+        # (LambdaRank's query plan) hand their device state in as an
+        # ARGUMENT (``device_state``), and participate with the SHAPES the
+        # program was traced over as their key: another data set's plan of
+        # the same bucket shapes reuses the program.
         state_key = obj.state_key() if obj.stateful else None
         scan_cache_hit = False
         if device_eval and vsets:
@@ -3171,8 +3207,8 @@ def _train_impl(
                 chunk=chunk_idx, iters=c, cold=(chunk_idx == 0),
             ):
                 carry, scan_ys = scan_chunk(
-                    bins_dev, y_dev, w_dev, valid_mask, init_scores_dev,
-                    vbins_t, vaux_t, carry,
+                    bins_dev, y_dev, w_dev, valid_mask, obj.device_state(),
+                    init_scores_dev, vbins_t, vaux_t, carry,
                     jax.lax.slice(xs_dev, (n_done, 0), (n_done + c, 5))
                     if c < n_iter else xs_dev,
                     *dart_xs,
@@ -3180,6 +3216,8 @@ def _train_impl(
             for op, (calls, nbytes) in (merge_ledger or {}).items():
                 obs.inc("train.merge_calls", float(calls * c), op=op)
                 obs.inc("train.merge_bytes", float(nbytes * c), op=op)
+            for name, per_iter in (rank_counts or {}).items():
+                obs.inc(name, float(per_iter * c))
             if quantize_on:
                 trees_c, vsnap_c, qsc_c = scan_ys
             else:
@@ -3346,7 +3384,8 @@ def _train_impl(
             train_scores = scores
 
         tree, delta, qsc = iteration(
-            bins_dev, y_dev, w_dev, valid_mask, train_scores, sub, current_bag
+            bins_dev, y_dev, w_dev, valid_mask, obj.device_state(),
+            train_scores, sub, current_bag,
         )
         if qsc is not None and obs.enabled():
             qsc_np = np.asarray(qsc)  # (K, 2)

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from mmlspark_tpu.ops.rank_plan import build_rank_plan, ndcg_sum, plan_from_matrix
+
 _AUC_BINS = 4096
 
 
@@ -207,34 +209,26 @@ class _BinnedAUC(DeviceMetric):
 
 
 class _GroupedNDCG(DeviceMetric):
-    """NDCG@k over a padded (G, M) group-index matrix (process-aligned)."""
+    """NDCG@k by query over a query plan (ops/rank_plan: queries bucketed by
+    length), built from a padded (G, M) group-index matrix
+    (process-aligned) or from the query sizes."""
 
     higher_better = True
 
-    def __init__(self, k: int, group_idx: np.ndarray, group_valid: np.ndarray):
+    def __init__(self, k: int, plan):
         self.k = k
-        self._idx = np.asarray(group_idx, np.int32)
-        self._valid = np.asarray(group_valid, bool)
+        self.plan = plan
 
     def aux_host(self):
-        return (self._idx, self._valid)
+        buckets, inv = self.plan.host_arrays()
+        return tuple(a for bucket in buckets for a in bucket) + (inv,)
 
-    def stats(self, score_kn, y, w, mask, idx, valid):
-        s = jnp.where(valid, score_kn[0][idx], -jnp.inf)
-        lbl = jnp.where(valid, y[idx], 0.0)
-        gains = jnp.where(valid, 2.0 ** lbl - 1.0, 0.0)
-        pos = jnp.arange(s.shape[1])
-        disc = jnp.where(pos < self.k, 1.0 / jnp.log2(pos + 2.0), 0.0)
-        # argsort is stable (mergesort semantics), matching the host metric's
-        # tie ordering over the same group layout.
-        order = jnp.argsort(-s, axis=1)
-        dcg = jnp.sum(jnp.take_along_axis(gains, order, axis=1) * disc, axis=1)
-        ideal = jnp.sort(gains, axis=1)[:, ::-1]
-        idcg = jnp.sum(ideal * disc, axis=1)
-        ndcg = jnp.where(idcg > 0, dcg / jnp.maximum(idcg, 1e-300), 1.0)
-        return jnp.stack(
-            [jnp.sum(ndcg), jnp.asarray(float(self._idx.shape[0]), jnp.float32)]
-        )
+    def stats(self, score_kn, y, w, mask, *flat):
+        plan_arrays = (tuple(flat[i : i + 3] for i in range(0, len(flat) - 1, 3)), flat[-1])
+        return jnp.stack([
+            ndcg_sum(plan_arrays, score_kn[0], y, self.k),
+            jnp.asarray(float(self.plan.queries), jnp.float32),
+        ])
 
     def finalize(self, s):
         return float(s[0]) / max(float(s[1]), 1e-300)
@@ -248,17 +242,23 @@ def get_device_metric(
     auc_eval_bins: int = _AUC_BINS,
     group_idx: Optional[np.ndarray] = None,
     group_valid: Optional[np.ndarray] = None,
+    group_sizes: Optional[np.ndarray] = None,
 ) -> DeviceMetric:
     """The device evaluator for an ``eval_metrics`` name.
 
-    ``group_idx``/``group_valid``: padded global group matrices, required
-    for ndcg (built process-aligned by the booster's ingestion path)."""
+    ndcg needs the queries: ``group_idx``/``group_valid``, padded global
+    group matrices (built process-aligned by the booster's ingestion path),
+    or ``group_sizes`` of queries that tile the rows in order."""
     name = name.lower()
     if name.startswith("ndcg") or name == "lambdarank":
-        if group_idx is None:
+        if group_idx is None and group_sizes is None:
             raise ValueError("ndcg needs process-aligned group matrices")
         k = int(name.split("@", 1)[1]) if "@" in name else 5
-        return _GroupedNDCG(k, group_idx, group_valid)
+        plan = (
+            build_rank_plan(group_sizes) if group_idx is None
+            else plan_from_matrix(group_idx, group_valid)
+        )
+        return _GroupedNDCG(k, plan)
     table = {
         "auc": lambda: _BinnedAUC(int(auc_eval_bins)),
         "binary_logloss": lambda: _Pointwise(_binary_logloss),
@@ -313,14 +313,10 @@ def global_group_matrix(
     row block starts in the global sharded array; ``max_size`` the global
     max group size (host-allgathered so every process pads identically)."""
     sizes = np.asarray(local_sizes, np.int64)
-    G = len(sizes)
-    idx = np.zeros((G, max_size), np.int32)
-    valid = np.zeros((G, max_size), bool)
-    start = row_offset
-    for g, s in enumerate(sizes):
-        idx[g, :s] = np.arange(start, start + s)
-        valid[g, :s] = True
-        start += s
+    pos = np.arange(max_size)
+    valid = pos[None, :] < sizes[:, None]
+    first = row_offset + np.cumsum(sizes) - sizes
+    idx = np.where(valid, first[:, None] + pos[None, :], 0).astype(np.int32)
     return idx, valid
 
 

@@ -21,6 +21,9 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from mmlspark_tpu.ops.rank_plan import build_rank_plan, by_bucket, score_key, to_rows
 
 
 class Objective:
@@ -77,6 +80,16 @@ class Objective:
         self, score: jnp.ndarray, y: jnp.ndarray, w: Optional[jnp.ndarray]
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         raise NotImplementedError
+
+    def device_state(self):
+        """Device arrays the gradient reads beside score, label and weight
+        (a pytree; empty for a stateless objective).  A jitted caller passes
+        them as an ARGUMENT to :meth:`grad_hess_from`, so nothing large
+        becomes a constant of its program."""
+        return ()
+
+    def grad_hess_from(self, state, score, y, w):
+        return self.grad_hess(score, y, w)
 
     def transform(self, score: jnp.ndarray) -> jnp.ndarray:
         """Raw score → user-facing prediction (link function)."""
@@ -344,14 +357,17 @@ class LambdaRank(Objective):
 
     Reference parity: LightGBM ``lambdarank`` (upstream
     ``src/objective/rank_objective.hpp`` — [REF-EMPTY]) as surfaced by
-    ``LightGBMRanker`` (SURVEY.md §2.3).  Groups are carried as a padded
-    (num_groups, max_group_size) index matrix so the pairwise loop is
-    shape-static and vmap-able on TPU.
+    ``LightGBMRanker`` (SURVEY.md §2.3).  Queries are carried as a
+    :class:`~mmlspark_tpu.ops.rank_plan.RankPlan` (buckets by length), and
+    the pairwise terms of a bucket are formed for its top ``max_position``
+    rows by score against all of its rows: a pair with both members below
+    the cut has equal (zero) discounts and so a zero delta-NDCG, which
+    leaves ``(G_b, K, M_b)`` terms where all pairs are ``(G, M, M)``.
     """
 
     name = "lambdarank"
     default_metric = "ndcg"
-    stateful = True  # set_groups() stores per-dataset group indices
+    stateful = True  # set_plan() stores the data set's query plan
 
     def __init__(self, **params):
         super().__init__(**params)
@@ -360,45 +376,23 @@ class LambdaRank(Objective):
         self.max_position = int(params.get("max_position", 20) or 20)
 
     def set_groups(self, group_sizes: np.ndarray):
-        """Precompute padded group index matrix from per-query sizes."""
-        # lazy import: ops must not import engine at module load
-        from mmlspark_tpu.engine.dist_metrics import global_group_matrix
+        """Plan and upload the queries of ``group_sizes`` rows each."""
+        return self.set_plan(build_rank_plan(group_sizes))
 
-        sizes = np.asarray(group_sizes, dtype=np.int64)
-        M = max(int(sizes.max()) if len(sizes) else 1, 1)
-        idx, valid = global_group_matrix(sizes, 0, M)
-        return self.set_group_matrix(idx, valid)
-
-    def set_group_matrix(self, idx, valid, state_key=None):
-        """Install a PREBUILT padded (G, M) group matrix.
-
-        The distributed path assembles this globally (process-aligned
-        groups with global row offsets — engine/dist_metrics
-        ``assemble_global_groups``) so the pairwise lambda computation runs
-        unchanged over the globally sharded score vector: the ``score[idx]``
-        gather is the one collective (an allgather of the (n,) scores, the
-        same wire class as a histogram psum), everything after is local.
-        ``idx``/``valid`` may be host numpy or device arrays; device
-        placement (replicated global arrays under a multi-process mesh) is
-        the caller's choice.  Pass ``state_key`` (hash of the HOST
-        matrices) alongside device arrays — otherwise fingerprinting pulls
-        them back to host.
-        """
-        self._group_idx = idx if hasattr(idx, "sharding") else jnp.asarray(
-            np.asarray(idx)
-        )
-        self._group_valid = (
-            valid if hasattr(valid, "sharding") else jnp.asarray(np.asarray(valid))
-        )
-        if state_key is None:
-            state_key = hash(
-                np.asarray(idx).tobytes() + np.asarray(valid).tobytes()
-            )
-        self._state_key = state_key
+    def set_plan(self, plan, arrays=None):
+        """Install a query plan and its device arrays (``arrays``: the
+        caller's placement, e.g. replicated over a multi-process mesh, or
+        the copy kept with a resident data set; default: uploaded here)."""
+        self._plan = plan
+        self._plan_arrays = plan.device_arrays() if arrays is None else arrays
         return self
 
     def state_key(self):
-        return getattr(self, "_state_key", None)
+        plan = getattr(self, "_plan", None)
+        return None if plan is None else plan.shape_key
+
+    def device_state(self):
+        return self._plan_arrays
 
     def _gains(self, labels):
         if self.label_gain is not None:
@@ -406,47 +400,54 @@ class LambdaRank(Objective):
             return table[labels.astype(jnp.int32)]
         return 2.0 ** labels.astype(jnp.float32) - 1.0
 
-    def grad_hess(self, score, y, w):
-        idx, valid = self._group_idx, self._group_valid
-        s = score[idx]  # (G, M)
-        lbl = y[idx]
-        gain = self._gains(lbl) * valid
-
-        # Ideal DCG per group for normalization.
-        order_ideal = jnp.argsort(jnp.where(valid, -gain, jnp.inf), axis=1)
-        sorted_gain = jnp.take_along_axis(gain, order_ideal, axis=1)
-        pos = jnp.arange(gain.shape[1])
-        disc = 1.0 / jnp.log2(pos + 2.0)
-        topk = pos < self.max_position
-        idcg = jnp.sum(sorted_gain * disc * topk, axis=1, keepdims=True)
+    def _bucket_lambdas(self, s, gain, valid, pos):
+        """Gradient and hessian ``(2, G, M)`` of one bucket's rows, zero
+        where ``valid`` is not."""
+        K = min(self.max_position, pos.shape[0])
+        top = jnp.arange(K)
+        disc = 1.0 / jnp.log2(top + 2.0)
+        gain = jnp.where(valid, gain, 0.0)
+        idcg = jnp.sum(lax.top_k(gain, K)[0] * disc, axis=1)
         inv_idcg = jnp.where(idcg > 0, 1.0 / jnp.maximum(idcg, 1e-12), 0.0)
-
-        # Current ranks by score (descending).
-        order = jnp.argsort(jnp.where(valid, -s, jnp.inf), axis=1)
-        ranks = jnp.argsort(order, axis=1)  # rank of each item
-        item_disc = jnp.where(ranks < self.max_position, disc[ranks], 0.0)
-
-        # Pairwise (i, j): label_i > label_j.
-        sd = s[:, :, None] - s[:, None, :]
-        gd = gain[:, :, None] - gain[:, None, :]
-        dd = item_disc[:, :, None] - item_disc[:, None, :]
-        pair_valid = valid[:, :, None] & valid[:, None, :] & (gd > 0)
-        delta_ndcg = jnp.abs(gd * dd) * inv_idcg[:, :, None]
-        sig = jax.nn.sigmoid(-self.sigmoid * sd)
-        lam = -self.sigmoid * sig * delta_ndcg * pair_valid
-        hs = self.sigmoid**2 * sig * (1.0 - sig) * delta_ndcg * pair_valid
-
-        g_item = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
-        h_item = jnp.sum(hs, axis=2) + jnp.sum(hs, axis=1)
-
-        n = score.shape[0]
-        grad = jnp.zeros(n, score.dtype).at[idx.reshape(-1)].add(
-            jnp.where(valid, g_item, 0.0).reshape(-1)
+        # the K best rows by score (ties in row order, padding last) are the
+        # pairs' one side (axis a), all M rows the other; lax.top_k puts the
+        # lower index first among equals, and a full sort of four operands
+        # compiles for 15-27 s a bucket where this takes one
+        s_a, row_a = lax.top_k(score_key(s, valid), K)
+        valid_a = top[None, :] < jnp.sum(valid, axis=1, keepdims=True)
+        s_a = jnp.where(valid_a, s_a, 0.0)
+        is_a = row_a[:, :, None] == pos[None, None, :]  # (G, K, M): row m holds rank a
+        gain_a = jnp.sum(jnp.where(is_a, gain[:, None, :], 0.0), axis=2)
+        disc_m = jnp.sum(jnp.where(is_a, disc[None, :, None], 0.0), axis=1)
+        rank_m = jnp.sum(jnp.where(is_a, top[None, :, None], 0), axis=1) + jnp.where(is_a.any(axis=1), 0, K)
+        gd = gain_a[:, :, None] - gain[:, None, :]
+        sgn = jnp.sign(gd)
+        # a pair of two top rows appears at (a, m) and at (m's rank, a's row): kept where a is the better rank
+        pair = (
+            valid_a[:, :, None] & valid[:, None, :] & (gd != 0)
+            & (rank_m[:, None, :] > top[None, :, None])
         )
-        hess = jnp.zeros(n, score.dtype).at[idx.reshape(-1)].add(
-            jnp.where(valid, h_item, 0.0).reshape(-1)
-        )
-        hess = jnp.maximum(hess, 1e-9)
+        delta = jnp.abs(gd) * jnp.abs(disc[None, :, None] - disc_m[:, None, :]) * inv_idcg[:, None, None]
+        rho = jax.nn.sigmoid(-self.sigmoid * sgn * (s_a[:, :, None] - s[:, None, :]))
+        lam = jnp.where(pair, -self.sigmoid * rho * delta * sgn, 0.0)  # to row a; minus it to row m
+        hs = jnp.where(pair, self.sigmoid**2 * rho * (1.0 - rho) * delta, 0.0)
+        # each row's sum over the pairs it is in, on either side
+        grad_a, hess_a = jnp.sum(lam, axis=2), jnp.sum(hs, axis=2)  # (G, K)
+        grad = jnp.sum(jnp.where(is_a, grad_a[:, :, None], 0.0) - lam, axis=1)
+        hess = jnp.sum(jnp.where(is_a, hess_a[:, :, None], 0.0) + hs, axis=1)
+        return jnp.stack([grad, hess])
+
+    def grad_hess(self, score, y, w):
+        return self.grad_hess_from(self._plan_arrays, score, y, w)
+
+    def grad_hess_from(self, state, score, y, w):
+        with jax.named_scope("rank_grad"):
+            per_bucket = [
+                self._bucket_lambdas(s, self._gains(lbl), valid, pos)
+                for (s, lbl), valid, pos in by_bucket(state, (score, y))
+            ]
+            grad, hess = to_rows(state, per_bucket, score.shape[0])
+            hess = jnp.maximum(hess, 1e-9)
         if w is not None:
             grad, hess = grad * w, hess * w
         return grad, hess
