@@ -189,6 +189,21 @@ class StreamedDataset:
     plus ``quality_feature_specs`` / ``quality_binned_sample``, the
     streamed substitutes the quality-baseline capture uses instead of
     materializing the full binned matrix on host.
+
+    **Held on the device**: the binned matrix for the data set's whole
+    life, and between fits, one entry each, what ``Dataset`` holds: the
+    padded copy of the matrix where the rows are not whole histogram
+    chunks (``_dev_bins_cache``), a ranking fit's query plan
+    (``_rank_plan_cache``) and the fit's per-row state
+    (``_row_state_cache``: labels, weights, row mask, init scores), so a
+    second ``train()`` on this set under the same objective settings and
+    placement computes and sends nothing that follows the rows.  An entry
+    is replaced when a fit's key differs and freed with the data set (which
+    cannot be pickled at all).  As with ``Dataset``, the row state is keyed
+    by the identity of ``label`` / ``weight`` / ``init_score``, and storing
+    it makes those arrays read-only: assigning a new array is seen, a write
+    into one raises ``ValueError``, and only a write through another view
+    of the same memory goes unseen.
     """
 
     def __init__(
@@ -226,6 +241,7 @@ class StreamedDataset:
         self._bins_cache = {}
         self._dev_bins_cache = {}
         self._rank_plan_cache = {}
+        self._row_state_cache = {}
         self._cache_refs = []
 
     @property

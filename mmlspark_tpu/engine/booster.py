@@ -23,7 +23,7 @@ import math
 import os
 import time
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -276,6 +276,22 @@ class Dataset:
     this container caches the fitted :class:`BinMapper` (per bin-config) and
     the binned matrix (per mapper), so repeated ``train()`` calls on the
     same Dataset skip the host binning pass entirely.
+
+    **Held on the device** between fits, one entry each, replaced and never
+    added to: the padded binned matrix (``_dev_bins_cache``, keyed by the
+    mapper, the padding and the placement), a ranking fit's query plan
+    (``_rank_plan_cache``), and the fit's per-row state
+    (``_row_state_cache``: labels, weights, row mask and init scores, made
+    by ``_fit_row_state``), so a second ``train()`` under the same objective
+    settings and placement computes and sends nothing that follows the
+    rows.  All three go with the data set, and none enters a pickle.  The
+    row state is keyed by the IDENTITY of ``label``, ``weight`` and
+    ``init_score``: assigning a new array is seen.  A write into one of
+    them cannot be seen, so it is refused: storing the entry makes the three
+    arrays read-only (``setflags(write=False)``: a later write raises
+    ``ValueError``; a caller who passed float64 arrays handed over those
+    very arrays).  What stays unseen: a write through another view of the
+    same memory, such as the array ``label`` was sliced from.
     """
 
     def __init__(
@@ -298,6 +314,7 @@ class Dataset:
         self._bins_cache: Dict[int, np.ndarray] = {}
         self._dev_bins_cache: Dict[Tuple, object] = {}  # padded device copies
         self._rank_plan_cache: Dict[Tuple, object] = {}  # (RankPlan, device arrays)
+        self._row_state_cache: Dict[Tuple, object] = {}  # (host arrays keyed on, _RowState)
         self._cache_refs: List[BinMapper] = []  # pin ids used as cache keys
 
     def __getstate__(self):
@@ -310,6 +327,7 @@ class Dataset:
         state["_bins_cache"] = {}
         state["_dev_bins_cache"] = {}
         state["_rank_plan_cache"] = {}
+        state["_row_state_cache"] = {}
         state["_cache_refs"] = []
         return state
 
@@ -1688,6 +1706,114 @@ class _Uploads:
         return a
 
 
+def _placer(mesh, process_local: bool):
+    """``put(array, spec)``: how this fit places an array on its devices.
+    Off a mesh an uncommitted array on the default device; under a mesh a
+    ``NamedSharding`` of ``spec``; multi-controller, the global array
+    stitched from each process's (padded) partition, so no host ever sees
+    another's rows."""
+    if mesh is None:
+        return lambda a, spec: jnp.asarray(a)
+    if process_local:
+        from mmlspark_tpu.parallel.distributed import make_global_array
+
+        return lambda a, spec: make_global_array(mesh, spec, a)
+    from jax.sharding import NamedSharding
+
+    return lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
+
+
+class _RowState(NamedTuple):
+    """What a fit holds for each training row beside the binned matrix, on
+    the device, and the objective's init score it was made with."""
+
+    y: object  # (n + n_pad,) float32 labels
+    w: object  # (n + n_pad,) float32 weights after is_unbalance / scale_pos_weight, or None
+    valid_mask: object  # (n + n_pad,) bool, False on padding
+    init_scores: object  # (K, n + n_pad) float32: init score (+ the set's init_score), before any init_model
+    init: object  # the init score itself: a float, or (K,) for K > 1
+
+
+def _fit_row_state(
+    train_set, obj, cfg: TrainConfig, *, K: int, n: int, n_pad: int,
+    use_bfa: bool, process_local: bool, placement: Tuple, put, row_spec,
+    krow_spec, sent: "_Uploads",
+) -> Tuple[_RowState, bool]:
+    """``(row state, found)`` for a fit of ``train_set``: made once for a
+    data set and kept on the device with its binned matrix
+    (``_row_state_cache``, one entry), so a later fit on the same host
+    arrays, placement and objective settings touches no array of ``n``
+    elements and sends nothing.  The key holds what the arrays depend on;
+    the entry pins the host arrays it was made from, which keeps their ids
+    from being recycled, and makes them read-only, so a write into them
+    between fits raises instead of being trained past (see ``Dataset``).
+    Nothing donates these buffers; a fit that did would need a copy here.
+
+    Multi-controller fits always build: their label statistics are
+    collectives that every process must enter in every fit."""
+    label, weight, init_score = srcs = (train_set.label, train_set.weight, train_set.init_score)
+    key = (
+        tuple(id(a) for a in srcs), n, n_pad, placement, K, cfg.objective,
+        tuple((k, _hashable(v)) for k, v in cfg.objective_params().items()),
+        # what enters the weights, and what decides the init score
+        (cfg.is_unbalance, cfg.scale_pos_weight) if cfg.objective == "binary" else None,
+        use_bfa,
+    )
+    held = None if process_local else train_set._row_state_cache.get(key)
+    obs.inc("train.row_state", 1.0, result="miss" if held is None else "hit")
+    if held is not None:
+        return held[1], True
+    train_set._row_state_cache = {}  # the old entry's arrays go before the new ones come
+
+    y = _pad_rows(label, n_pad)
+    valid_mask_np = np.concatenate([np.ones(n, bool), np.zeros(n_pad, bool)])
+
+    # ---- weights (is_unbalance / scale_pos_weight) ---------------------
+    w = weight
+    if cfg.objective == "binary":
+        pn = np.asarray([float((label > 0).sum()), float((label <= 0).sum())])
+        if process_local:
+            from mmlspark_tpu.parallel.distributed import host_allgather
+
+            pn = host_allgather(pn).sum(axis=0)
+        pos, neg = max(pn[0], 1.0), max(pn[1], 1.0)
+        spw = neg / pos if cfg.is_unbalance else cfg.scale_pos_weight
+        if spw != 1.0:
+            base = np.ones(n) if w is None else np.asarray(w, dtype=np.float64)
+            w = np.where(label > 0, base * spw, base)
+    w_np = None if w is None else _pad_rows(np.asarray(w, dtype=np.float64), n_pad)
+
+    # ---- init score ----------------------------------------------------
+    if use_bfa and process_local:
+        # Seed from SUMMED sufficient statistics (one tiny allgather) —
+        # the global label vector never exists on any host.
+        from mmlspark_tpu.parallel.distributed import host_allgather
+
+        stats = host_allgather(obj.init_score_stats(label, weight)).sum(axis=0)
+        init = obj.init_score_from_stats(stats)
+    elif use_bfa:
+        init = obj.init_score(label, weight)
+    else:
+        init = np.zeros(K) if K > 1 else 0.0
+    init_arr = np.broadcast_to(np.asarray(init, dtype=np.float32).reshape(-1, 1), (K, n + n_pad)).copy()
+    if init_score is not None:
+        init_arr = init_arr + _pad_rows(init_score.astype(np.float32), n_pad).reshape(1, -1)
+
+    state = _RowState(
+        y=put(sent(y.astype(np.float32)), row_spec),
+        w=None if w_np is None else put(sent(w_np.astype(np.float32)), row_spec),
+        valid_mask=put(sent(valid_mask_np), row_spec),
+        init_scores=put(sent(init_arr), krow_spec),
+        init=init,
+    )
+    if not process_local:
+        train_set._row_state_cache = {key: (srcs, state)}
+        for a in srcs:
+            if isinstance(a, np.ndarray):
+                a.setflags(write=False)
+    return state, False
+
+
 def _train_impl(
     params: dict,
     train_set: Dataset,
@@ -2049,34 +2175,6 @@ def _train_impl(
         if n_local > chunk:
             n_local = ((n_local + chunk - 1) // chunk) * chunk
         n_pad = n_local * D_rows - n
-    y = _pad_rows(train_set.label, n_pad)
-    valid_mask_np = np.concatenate([np.ones(n, bool), np.zeros(n_pad, bool)])
-
-    # ---- weights (is_unbalance / scale_pos_weight) ---------------------
-    w = train_set.weight
-    if cfg.objective == "binary":
-        if process_local:
-            from mmlspark_tpu.parallel.distributed import host_allgather
-
-            pn = host_allgather(
-                np.asarray([
-                    float((train_set.label > 0).sum()),
-                    float((train_set.label <= 0).sum()),
-                ])
-            ).sum(axis=0)
-            pos, neg = max(pn[0], 1.0), max(pn[1], 1.0)
-        else:
-            pos = max(float((train_set.label > 0).sum()), 1.0)
-            neg = max(float((train_set.label <= 0).sum()), 1.0)
-        if cfg.is_unbalance:
-            spw = neg / pos
-        else:
-            spw = cfg.scale_pos_weight
-        if spw != 1.0:
-            base = np.ones(n) if w is None else np.asarray(w, dtype=np.float64)
-            w = np.where(train_set.label > 0, base * spw, base)
-    w_np = None if w is None else _pad_rows(np.asarray(w, dtype=np.float64), n_pad)
-
     # Ranking queries: the plan (ops/rank_plan: queries bucketed by length)
     # is built once for a data set from its group sizes and kept on the
     # device with it, like the binned matrix; a later fit on the same set
@@ -2144,98 +2242,35 @@ def _train_impl(
         and train_set.init_score is None
         and init_model is None  # the old forest already embeds its bias
     )
-    if use_bfa and process_local:
-        # Seed from SUMMED sufficient statistics (one tiny allgather) —
-        # the global label vector never exists on any host.
-        from mmlspark_tpu.parallel.distributed import host_allgather
-
-        stats = host_allgather(
-            obj.init_score_stats(train_set.label, train_set.weight)
-        ).sum(axis=0)
-        init = obj.init_score_from_stats(stats)
-    elif use_bfa:
-        init = obj.init_score(train_set.label, train_set.weight)
-    else:
-        init = np.zeros(K) if K > 1 else 0.0
-    init_arr = np.broadcast_to(np.asarray(init, dtype=np.float32).reshape(-1, 1), (K, n + n_pad)).copy()
-    if train_set.init_score is not None:
-        init_arr = init_arr + _pad_rows(
-            train_set.init_score.astype(np.float32), n_pad
-        ).reshape(1, -1)
 
     # ---- device-resident data ------------------------------------------
     # Under a mesh, rows are sharded over the data axis up front so the
     # binned matrix lives partitioned in HBM (SURVEY.md §7.2) and per-
-    # iteration programs never reshuffle it.
-    dev_key = (
-        id(bin_mapper), n_pad, _mesh_cache_key(mesh), process_local, feature_par,
-        hierarchical,
+    # iteration programs never reshuffle it.  Feature-parallel shards the
+    # columns and replicates everything that follows the rows.
+    from jax.sharding import PartitionSpec as P
+
+    if feature_par:
+        bins_spec, row_spec, krow_spec = P(None, DATA_AXIS), P(), P()
+    else:
+        bins_spec, row_spec, krow_spec = P(row_axes, None), P(row_axes), P(None, row_axes)
+    put = _placer(mesh, process_local)
+    placement = (_mesh_cache_key(mesh), process_local, feature_par, hierarchical)
+    _sent = _Uploads()
+    (y_dev, w_dev, valid_mask, init_scores_dev, init), rows_cached = _fit_row_state(
+        train_set, obj, cfg, K=K, n=n, n_pad=n_pad, use_bfa=use_bfa,
+        process_local=process_local, placement=placement, put=put,
+        row_spec=row_spec, krow_spec=krow_spec, sent=_sent,
     )
+    dev_key = (id(bin_mapper), n_pad, *placement)
     bins_dev = train_set._dev_bins_cache.get(dev_key)
+    sp_upload = phases.enter(
+        "booster.upload", bins_cached=bins_dev is not None, rows_cached=rows_cached
+    )
     if bins_dev is None:
         # only now: a resident matrix (StreamedDataset) is padded on the
         # device, and a fit that finds the padded copy must not make another
-        bins_np = _pad_rows(bins_np, n_pad)
-    sp_upload = phases.enter("booster.upload", bins_cached=bins_dev is not None)
-    _sent = _Uploads()
-    if feature_par:
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        col_sh = NamedSharding(mesh, P(None, DATA_AXIS))  # columns sharded
-        rep = NamedSharding(mesh, P())  # rows replicated on every shard
-        if bins_dev is None:
-            bins_dev = jax.device_put(_sent(bins_np), col_sh)
-        y_dev = jax.device_put(_sent(y.astype(np.float32)), rep)
-        w_dev = None if w_np is None else jax.device_put(
-            _sent(w_np.astype(np.float32)), rep
-        )
-        valid_mask = jax.device_put(_sent(valid_mask_np), rep)
-        init_scores_dev = jax.device_put(_sent(init_arr), rep)
-    elif process_local:
-        # Multi-controller assembly: each process contributes ONLY its
-        # (padded) partition; jax stitches the global sharded arrays from
-        # the per-process pieces.  No host ever sees another's rows.
-        from jax.sharding import PartitionSpec as P
-
-        from mmlspark_tpu.parallel.distributed import make_global_array
-
-        if bins_dev is None:
-            bins_dev = make_global_array(mesh, P(row_axes, None), _sent(bins_np))
-        y_dev = make_global_array(
-            mesh, P(row_axes), _sent(y.astype(np.float32))
-        )
-        w_dev = None if w_np is None else make_global_array(
-            mesh, P(row_axes), _sent(w_np.astype(np.float32))
-        )
-        valid_mask = make_global_array(mesh, P(row_axes), _sent(valid_mask_np))
-        init_scores_dev = make_global_array(
-            mesh, P(None, row_axes), _sent(init_arr)
-        )
-    elif mesh is not None:
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        row_sh = NamedSharding(mesh, P(row_axes))
-        rowF_sh = NamedSharding(mesh, P(row_axes, None))
-        krow_sh = NamedSharding(mesh, P(None, row_axes))
-        if bins_dev is None:
-            bins_dev = jax.device_put(_sent(bins_np), rowF_sh)
-        y_dev = jax.device_put(_sent(y.astype(np.float32)), row_sh)
-        w_dev = None if w_np is None else jax.device_put(
-            _sent(w_np.astype(np.float32)), row_sh
-        )
-        valid_mask = jax.device_put(_sent(valid_mask_np), row_sh)
-        init_scores_dev = jax.device_put(_sent(init_arr), krow_sh)
-    else:
-        if bins_dev is None:
-            bins_dev = jnp.asarray(_sent(bins_np))
-        y_dev = jnp.asarray(_sent(y.astype(np.float32)))
-        w_dev = None if w_np is None else jnp.asarray(
-            _sent(w_np.astype(np.float32))
-        )
-        valid_mask = jnp.asarray(_sent(valid_mask_np))
-        init_scores_dev = jnp.asarray(_sent(init_arr))
+        bins_dev = put(_sent(_pad_rows(bins_np, n_pad)), bins_spec)
     # Size-1 like the host caches: each entry pins a full-matrix device
     # copy, and sweeps over mesh/chunk configs must not accumulate HBM.
     train_set._dev_bins_cache = {dev_key: bins_dev}

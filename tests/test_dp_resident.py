@@ -121,9 +121,11 @@ def test_leaf_values_and_predictions_within_the_reduce_scatter_tolerance(fits, d
 
 
 def test_no_binned_byte_leaves_the_host(fits):
-    for rise in fits["rises"][1:]:
-        # labels and init scores as float32, the mask as bool: nine bytes a row
-        assert rise["train.upload_bytes"] == N * (4 + 4 + 1)
+    # labels and init scores as float32, the mask as bool: nine bytes a row,
+    # sent by the first fit of a data set and kept on the devices with it
+    assert fits["rises"][1]["train.upload_bytes"] == N * (4 + 4 + 1)
+    assert "train.upload_bytes" not in fits["rises"][2]  # the second fit sends nothing at all
+    assert fits["rises"][1]["train.row_state{result=miss}"] == 1 and fits["rises"][2].get("train.row_state{result=hit}") == 1
     ds = fits["ds"]
     (kept,) = ds._dev_bins_cache.values()
     assert kept is ds._binned_dev  # neither fetched, padded nor placed again

@@ -237,11 +237,13 @@ def test_upload_bytes_by_hand_and_less_the_matrix_on_a_second_fit():
     assert first == bins.nbytes + rows + valid_rows
     (up,) = flight.spans("booster.upload")
     one_device = {"devices": 1, "sharded": False}  # where the binned matrix lives (PR 27)
-    assert up["attrs"] == {"bins_cached": False, "bytes": first, **one_device}
+    assert up["attrs"] == {"bins_cached": False, "rows_cached": False, "bytes": first, **one_device}
 
-    train(_params(), ds, valid_sets=[valid])  # the same Dataset: its matrix is resident
-    assert sent() - first == first - bins.nbytes
-    assert flight.spans("booster.upload")[-1]["attrs"] == {"bins_cached": True, "bytes": first - bins.nbytes, **one_device}
+    train(_params(), ds, valid_sets=[valid])  # the same Dataset: its matrix and its row state are resident
+    assert sent() - first == valid_rows
+    assert flight.spans("booster.upload")[-1]["attrs"] == {
+        "bins_cached": True, "rows_cached": True, "bytes": valid_rows, **one_device,
+    }
 
 
 def test_scorer_builds_once_for_a_new_booster(mode):
