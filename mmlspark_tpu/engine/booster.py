@@ -1735,9 +1735,9 @@ class _RowState(NamedTuple):
 
 
 def _fit_row_state(
-    train_set, obj, cfg: TrainConfig, *, K: int, n: int, n_pad: int,
-    use_bfa: bool, process_local: bool, placement: Tuple, put, row_spec,
-    krow_spec, sent: "_Uploads",
+    train_set, obj, cfg: TrainConfig, *, n: int, n_pad: int, use_bfa: bool,
+    process_local: bool, placement: Tuple, put, row_spec, krow_spec,
+    sent: "_Uploads",
 ) -> Tuple[_RowState, bool]:
     """``(row state, found)`` for a fit of ``train_set``: made once for a
     data set and kept on the device with its binned matrix
@@ -1752,6 +1752,7 @@ def _fit_row_state(
     Multi-controller fits always build: their label statistics are
     collectives that every process must enter in every fit."""
     label, weight, init_score = srcs = (train_set.label, train_set.weight, train_set.init_score)
+    K = obj.num_model_per_iteration
     key = (
         tuple(id(a) for a in srcs), n, n_pad, placement, K, cfg.objective,
         tuple((k, _hashable(v)) for k, v in cfg.objective_params().items()),
@@ -2258,7 +2259,7 @@ def _train_impl(
     placement = (_mesh_cache_key(mesh), process_local, feature_par, hierarchical)
     _sent = _Uploads()
     (y_dev, w_dev, valid_mask, init_scores_dev, init), rows_cached = _fit_row_state(
-        train_set, obj, cfg, K=K, n=n, n_pad=n_pad, use_bfa=use_bfa,
+        train_set, obj, cfg, n=n, n_pad=n_pad, use_bfa=use_bfa,
         process_local=process_local, placement=placement, put=put,
         row_spec=row_spec, krow_spec=krow_spec, sent=_sent,
     )

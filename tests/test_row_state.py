@@ -137,7 +137,7 @@ def test_a_fit_that_depends_on_something_else_misses(counted, what):
     assert len(ds._row_state_cache) == 1  # the old entry went: the device holds one copy
     fresh = train(params, _plain(**{**base, **arrays}), mesh=mesh)
     assert got.save_model_string() == fresh.save_model_string()
-    # and not what the first fit's arrays would have given
+    # the entry now stands for the new arrays and settings: the next such fit finds it
     _, c2 = counted(params, ds, mesh=mesh)
     assert (c2["hit"], c2["sent"]) == (1, 0)
 
