@@ -1,0 +1,109 @@
+"""What the quantized-training readers share: whether the window's fits were
+quantized, the seconds of their bucket builds, of the float32 refinement and
+of the rounding, and the least work a tree's bucket histograms need.
+
+A reader gets seconds by op name only, and a name there is the op's HLO name
+and the shape it produces (``fusion.9 s16[3,39845888]``).  The three parts are
+told apart by what they PRODUCE, which follows the arithmetic and not the
+implementation:
+
+- a bucket build is a histogram kernel (``_hist.KERNELS``: any kernel of
+  ``ops/pallas_hist.py``, whatever its body) whose result is integer
+  (``s32[...]``); with it count the chunk loop's slices of the integer row
+  values (``s16[3,chunk]`` or ``s8[3,chunk]``: only bucket builds read them);
+- the refinement is every histogram kernel with a float result in a fit that
+  is quantized (every full pass of such a fit is a bucket build, so the float
+  kernels that are left are the winners' columns), with the slices of the
+  composed winner column (``[1,chunk]``) and of the float32 row values
+  (``f32[3,chunk]``) that only it reads;
+- the rounding is whatever produces an array of the row values' shape
+  ``[3,rows]`` in an integer or ``u32`` type (the buckets and the draw's
+  bits).  Not found this way, so not counted: the two reductions of the
+  scales (scalars), and a draw that the compiler fuses into another op's
+  result; the share reads a little low, never high.
+
+Whether a fit was quantized is read from the program's counter
+``train.quant_levels``; a program without it gives ``None`` everywhere.
+"""
+
+import re
+
+from benchmark.metrics import _hist, _program
+
+LEVELS = "train.quant_levels"
+_SHAPE = re.compile(r" ([a-z]+[0-9]*)\[([0-9,]*)\]")
+
+
+def levels(ctx):
+    """``{channel: largest bucket}`` of the window's fits, from the counter's
+    rise over the window / the fits; ``None`` where no fit was quantized."""
+    fits = ctx["window"].get("attempted")
+    out = {}
+    for k in ctx["window_counters"]:
+        if k.startswith(LEVELS + "{"):
+            rise = _program.window_count(ctx, k)
+            if rise and fits:
+                out[k[len(LEVELS):].strip("{}").replace("channel=", "")] = rise / fits
+    return out or None
+
+
+def _produces(name: str):
+    """``(dtype, dims)`` of the shape an op's name says it produces."""
+    m = _SHAPE.search(name)
+    return (m.group(1), tuple(int(d) for d in m.group(2).split(",") if d)) if m else (None, ())
+
+
+def _is_kernel(name: str) -> bool:
+    return any(k in name for k in _hist.KERNELS)
+
+
+def _integer(dtype) -> bool:
+    return dtype is not None and dtype[0] in "su" and dtype != "u32"
+
+
+def split_seconds(ctx):
+    """``{"bucket", "refine", "round"}`` seconds of the traced window, ``None``
+    where there is no trace or no quantized fit in it."""
+    tr = ctx.get("trace")
+    if not tr or not levels(ctx):
+        return None
+    rows, chunk = int(ctx["rows"]), int(ctx["cfg"]["chunk_rows"])
+    out = {"bucket": 0.0, "refine": 0.0, "round": 0.0}
+    for name, s in tr["op_s"].items():
+        dtype, dims = _produces(name)
+        if _is_kernel(name):
+            out["bucket" if dtype == "s32" else "refine"] += s
+        elif dims == (3, chunk) and _integer(dtype):
+            out["bucket"] += s
+        elif dims in ((3, chunk), (1, chunk)) and (dtype == "f32" or dims[0] == 1):
+            out["refine"] += s
+        elif dims == (3, rows) and (_integer(dtype) or dtype == "u32"):
+            out["round"] += s
+    return out
+
+
+def value_bytes(ctx) -> int:
+    """Bytes of one bucket as the program keeps it: the width of the integer
+    row values in the trace (``s8`` 1, else ``s16``'s 2)."""
+    rows = int(ctx["rows"])
+    for name in ctx["trace"]["op_s"]:
+        dtype, dims = _produces(name)
+        if dims == (3, rows) and dtype == "s8":
+            return 1
+    return 2
+
+
+def least_work(rows: int, cols: int, bucket_bytes: int) -> dict:
+    """One tree's bucket histograms: every binned byte and each row's three
+    buckets (gradient, hessian, count, at the bucket's width) read once --
+    the root histogram, which no implementation avoids -- and two integer adds
+    a row-column, counted as ``peaks.hist_least_work`` counts the float one."""
+    return {"bytes": rows * cols + rows * 3 * bucket_bytes, "ops": 2 * rows * cols}
+
+
+def floor_seconds(work: dict, peak: dict) -> tuple:
+    """``(least seconds, which bound binds)``: bytes over the HBM's rate, or
+    integer operations over the chip's int8 rate."""
+    by_bytes = work["bytes"] / peak["hbm_bytes_per_s"]
+    by_ops = work["ops"] / peak["int8_ops_per_s"]
+    return (by_bytes, "hbm_bytes") if by_bytes >= by_ops else (by_ops, "int8_ops")

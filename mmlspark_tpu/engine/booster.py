@@ -43,6 +43,7 @@ from mmlspark_tpu.ops.binning import BinMapper
 from mmlspark_tpu.ops.histogram import (
     DEFAULT_CHUNK,
     quantize_channel_scales,
+    quantize_levels,
     quantize_wire_plan,
 )
 from mmlspark_tpu.ops.objectives import LambdaRank, Objective, get_objective
@@ -162,19 +163,37 @@ class TrainConfig:
     # to shard, allreduce otherwise).  Ignored by the voting and
     # feature-parallel learners, which have their own comm patterns.
     hist_merge: str = "auto"
-    # Quantized training (ISSUE 9; NeurIPS'22 LightGBM quantized-training
-    # lineage): "off" (default — bitwise-identical to the pre-quantize
-    # path), "int16"/"int32" = quantize per-row grad/hess to ±127 buckets
-    # with per-iteration max-abs scales and seeded stochastic rounding,
+    # Quantized training (ISSUE 9; Shi et al., "Quantized Training of
+    # Gradient Boosting Decision Trees", NeurIPS 2022; LightGBM 4's
+    # use_quantized_grad): "off" (default — bitwise-identical to the
+    # pre-quantize path), "int16"/"int32" = round each row's gradient,
+    # hessian and in-bag count (1[w > 0]) to integer buckets with
+    # per-iteration max-abs scales and seeded stochastic rounding,
     # accumulate histograms as int32, and merge shards over an INTEGER
     # psum/psum_scatter wire of this dtype ("int16" needs attested
     # row-count headroom — ops.histogram.quantize_wire_plan picks the
-    # pre-wire shift; int sums are associative, so allreduce and
+    # pre-wire shift and refuses a fit whose rows × a channel's largest
+    # bucket reach 2³¹; int sums are associative, so allreduce and
     # reduce_scatter merges agree bit-for-bit).  "on" = resolved to
-    # "int16" by resolve_auto_config.
+    # "int16" by resolve_auto_config.  How many levels a channel has comes
+    # from num_grad_quant_bins below (ops.histogram.quantize_levels):
+    # gradients in [-bins/2, bins/2], hessians in [0, bins], the count's
+    # bucket 1; not given, 127 a side and the count's bucket 64.
     # Winning splits get an f32 refinement pass, and leaf values come
     # from exact f32 sums, so AUC holds parity with the f32 path.
     hist_quantize: str = "off"
+    # LightGBM's names for the same (Parameters.rst).  use_quantized_grad
+    # is hist_quantize on or off: from_params sets the one from the other
+    # where only one is given, and a disagreement raises.
+    # num_grad_quant_bins: the levels (LightGBM's default is 4; 0 = not
+    # given, the engine's 127 a side).  quant_train_renew_leaf: leaf values
+    # from the exact float32 sums of the rows, which is what this engine
+    # always does, so False is refused.  stochastic_rounding=False rounds
+    # to nearest.
+    use_quantized_grad: Optional[bool] = None
+    num_grad_quant_bins: int = 0
+    quant_train_renew_leaf: bool = True
+    stochastic_rounding: bool = True
     # Histogram resolution of the process_local (device-eval) AUC: its
     # ~1/bins quantization can flip improvement comparisons near a plateau,
     # so distributed early stopping on metric="auc" may stop at a different
@@ -251,6 +270,8 @@ class TrainConfig:
         if unknown:
             # LightGBM logs "Unknown parameter"; surface typos the same way.
             warnings.warn(f"Unknown training parameter(s) ignored: {sorted(unknown)}")
+        if "hist_quantize" not in kwargs and kwargs.get("use_quantized_grad") is not None:
+            kwargs["hist_quantize"] = "on" if kwargs["use_quantized_grad"] else "off"
         return cls(**kwargs)
 
     def objective_params(self) -> dict:
@@ -1360,6 +1381,21 @@ def resolve_auto_config(
             f"hist_quantize must be 'off', 'on', 'int16' or 'int32', got "
             f"{cfg.hist_quantize!r}"
         )
+    if (
+        cfg.use_quantized_grad is not None
+        and bool(cfg.use_quantized_grad) != (cfg.hist_quantize != "off")
+    ):
+        raise ValueError(
+            f"use_quantized_grad={cfg.use_quantized_grad!r} and "
+            f"hist_quantize={cfg.hist_quantize!r} disagree: they are two "
+            "names of one switch, give one or make them agree"
+        )
+    if not cfg.quant_train_renew_leaf:
+        raise ValueError(
+            "quant_train_renew_leaf=false is not supported: leaf values "
+            "always come from the exact float32 sums of the rows' gradients"
+        )
+    quantize_levels(cfg.num_grad_quant_bins)  # raises outside [2, 127]
     if cfg.hist_quantize != "off":
         if cfg.tree_learner in (
             "voting", "voting_parallel", "feature", "feature_parallel"
@@ -2309,15 +2345,17 @@ def _train_impl(
         # pass reproduces LightGBM's exact leaf-wise sequence there.
         split_batch = 1
     quantize_on = cfg.hist_quantize != "off"
+    qlevels = quantize_levels(cfg.num_grad_quant_bins)
     if quantize_on:
         # Wire plan from the PADDED GLOBAL row count (the worst-case row
         # total any merged bin can see): picks the pre-wire right-shift
         # that fits partial sums in the wire dtype, and raises on int32
-        # ACCUMULATOR overflow (per-shard rows × 127 must fit 2³¹) —
-        # trips at config time, never silently wraps on device.
+        # ACCUMULATOR overflow (per-shard rows × each channel's largest
+        # bucket must fit 2³¹) — trips at config time, never silently
+        # wraps on device.
         quantize_shift = quantize_wire_plan(
             n + n_pad, cfg.hist_quantize,
-            num_shards=D if mesh is not None else 1,
+            num_shards=D if mesh is not None else 1, levels=qlevels,
         )
     else:
         quantize_shift = 0
@@ -2341,6 +2379,8 @@ def _train_impl(
         ),
         hist_quantize=cfg.hist_quantize,
         quantize_shift=quantize_shift,
+        quantize_levels=qlevels,
+        quantize_stochastic=bool(cfg.stochastic_rounding),
         grow_policy=grow_policy,
         split_batch=split_batch,
         categorical_features=tuple(int(f) for f in cfg.categorical_feature),
@@ -2466,9 +2506,10 @@ def _train_impl(
         # a fixed tag so the stochastic-rounding stream is decoupled from
         # the bagging/feature-sampling streams (same-seed reruns are
         # bitwise identical; unrelated knobs don't perturb rounding).
-        qscales = jax.vmap(
-            lambda g, h: quantize_channel_scales(g, h, bag)
-        )(grad, hess)  # (K, 2)
+        with jax.named_scope("quant_round"):
+            qscales = jax.vmap(
+                lambda g, h: quantize_channel_scales(g, h, bag, qlevels)
+            )(grad, hess)  # (K, 2)
         qkeys = jax.random.split(jax.random.fold_in(key, 0x51AB), K)
         return qkeys, qscales
 
@@ -3059,6 +3100,18 @@ def _train_impl(
                     grow, gcfg, K, bins_dev, F, quantize_on
                 )
             merge_ledger = program_notes["merge_ledger"]
+        quant_ledger = None
+        if quantize_on and obs.enabled():
+            # the bucket builds and refinement passes of one iteration, read
+            # off the grower's jaxpr and kept with the program like the
+            # merge's ledger
+            if "quant_ledger" not in program_notes:
+                program_notes["quant_ledger"] = _quant_ledger(
+                    grow, gcfg, K, bins_dev, F
+                )
+            quant_ledger = program_notes["quant_ledger"]
+            for channel, level in zip(("grad", "hess", "count"), qlevels):
+                obs.inc("train.quant_levels", float(level), channel=channel)
 
         if (
             n * n_iter >= _TRACE_CACHE_MIN_WORK
@@ -3225,6 +3278,11 @@ def _train_impl(
             scan_cache_hit=scan_cache_hit, devices=D,
             hist_merge=gcfg.hist_merge if mesh is not None and D > 1 else "none",
         )
+        if quantize_on:
+            sp_program.set(
+                quant_levels="x".join(str(v) for v in qlevels),
+                quant_wire=cfg.hist_quantize,
+            )
         phases.close()  # the dispatches are booster.train's own children
         while n_done < n_iter and stop_at is None:
             t_chunk = time.perf_counter()
@@ -3254,6 +3312,11 @@ def _train_impl(
                 obs.inc("train.merge_bytes", float(nbytes * c), op=op)
             for name, per_iter in (rank_counts or {}).items():
                 obs.inc(name, float(per_iter * c))
+            for kind, per_iter in (quant_ledger or {}).items():
+                if kind == "refine_cols":
+                    obs.inc("train.quant_refine_cols", float(per_iter * c))
+                else:
+                    obs.inc("train.quant_passes", float(per_iter * c), kind=kind)
             if quantize_on:
                 trees_c, vsnap_c, qsc_c = scan_ys
             else:
@@ -3534,20 +3597,11 @@ def _placement(arr) -> dict:
     }
 
 
-def _grow_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
-                 quantized: bool) -> dict:
-    """The bytes each device receives in ONE boosting iteration's
-    collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`
-    over the sharded grower's jaxpr: an abstract trace, made once a
-    program, with recording off so the trace-time ``collective.*`` counters
-    do not tick for it).  The windowed grower's loop counts as the passes
-    of a full tree (``full_tree_passes``; ``tests/test_dp_resident.py``
-    holds it to the loop's own trips): the program does not carry its trip
-    count out, so a tree that runs out of valid splits early is counted
-    high, and ``train.merge_bytes`` is the bytes of full trees."""
-    from mmlspark_tpu.engine.tree import full_tree_passes
+def _grow_jaxpr(grow, K: int, bins_dev, F_mask: int, quantized: bool):
+    """The grower's jaxpr at the fit's shapes: an abstract trace, made once
+    a program, with recording off so the trace-time ``collective.*``
+    counters do not tick for it."""
     from mmlspark_tpu.obs import _state as obs_state
-    from mmlspark_tpu.parallel.distributed import collective_ledger
 
     n = bins_dev.shape[0]
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
@@ -3559,10 +3613,89 @@ def _grow_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
         args += [jax.ShapeDtypeStruct((K, 2), jnp.uint32), f32(K, 2)]
     was, obs_state.enabled = obs_state.enabled, False
     try:
-        jaxpr = jax.make_jaxpr(grow)(*args)
+        return jax.make_jaxpr(grow)(*args)
     finally:
         obs_state.enabled = was
+
+
+def _grow_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
+                 quantized: bool) -> dict:
+    """The bytes each device receives in ONE boosting iteration's
+    collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`
+    over the sharded grower's jaxpr).  The windowed grower's loop counts as
+    the passes of a full tree (``full_tree_passes``;
+    ``tests/test_dp_resident.py`` holds it to the loop's own trips): the
+    program does not carry its trip count out, so a tree that runs out of
+    valid splits early is counted high, and ``train.merge_bytes`` is the
+    bytes of full trees."""
+    from mmlspark_tpu.engine.tree import full_tree_passes
+    from mmlspark_tpu.parallel.distributed import collective_ledger
+
+    jaxpr = _grow_jaxpr(grow, K, bins_dev, F_mask, quantized)
     return collective_ledger(jaxpr, while_trips=full_tree_passes(gcfg))
+
+
+def scope_entries(jaxpr, scopes, while_trips: int = 1) -> dict:
+    """``{scope: entries}``: how often one execution of a traced program
+    enters each ``jax.named_scope`` of ``scopes``.  Equations that follow
+    one another under a scope, at one level of the program, are one entry;
+    a ``scan`` multiplies by its length, a ``while`` loop by
+    ``while_trips`` (its count is not in the program: see
+    ``collective_ledger``), ``cond`` counts its largest branch."""
+    out = dict.fromkeys(scopes, 0)
+
+    def walk(jp, mult, dst):
+        inside = None
+        for eqn in getattr(jp, "jaxpr", jp).eqns:
+            names = str(eqn.source_info.name_stack).split("/")
+            here = next((s for s in scopes if s in names), None)
+            if here is not None:
+                if here != inside:
+                    dst[here] += mult
+                inside = here
+                continue
+            inside = None
+            name = eqn.primitive.name
+            inner = mult
+            if name == "scan":
+                inner = mult * int(eqn.params["length"])
+            elif name == "while":
+                inner = mult * int(while_trips)
+            if name == "cond":
+                branches = [dict.fromkeys(scopes, 0) for _ in eqn.params["branches"]]
+                for br, cur in zip(eqn.params["branches"], branches):
+                    walk(br, mult, cur)
+                best = max(branches, key=lambda d: sum(d.values()))
+                for s_, v in best.items():
+                    dst[s_] += v
+                continue
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        walk(sub, inner, dst)
+
+    walk(jaxpr, 1, out)
+    return out
+
+
+def _quant_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int) -> dict:
+    """What ONE boosting iteration of a quantized fit passes over the rows:
+    ``bucket`` histogram builds (the ``quant_hist`` scope), float32
+    ``refine`` passes (``quant_refine``) and the winner columns those
+    re-accumulate (``refine_cols``: a window's slots a pass of the windowed
+    grower, one a step of the lossguide grower).  Counted like the merge's
+    ledger, full trees: a tree that stops early is counted high."""
+    from mmlspark_tpu.engine.tree import full_tree_passes, windowed_grower
+
+    runs = scope_entries(
+        _grow_jaxpr(grow, K, bins_dev, F_mask, True),
+        ("quant_hist", "quant_refine"), while_trips=full_tree_passes(gcfg),
+    )
+    cols = gcfg.level_window if windowed_grower(gcfg) else 1
+    return {
+        "bucket": runs["quant_hist"], "refine": runs["quant_refine"],
+        "refine_cols": runs["quant_refine"] * cols,
+    }
 
 
 def _fold_bias(stacked: Tree, init) -> Tree:

@@ -36,6 +36,7 @@ exactly LightGBM's numbering, which makes the exported model string's
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import NamedTuple, Optional, Tuple
 
@@ -46,11 +47,12 @@ from jax import lax
 
 from mmlspark_tpu.ops.binpack import hist_transpose
 from mmlspark_tpu.ops.histogram import (
-    COUNT_SCALE,
+    DEFAULT_LEVELS,
     HistQuantize,
     build_histogram,
     build_histogram_by_leaf,
     quantize_hist_vals,
+    quantize_scales3,
 )
 
 
@@ -171,6 +173,11 @@ class GrowConfig:
     # Static pre-wire right-shift from ops.histogram.quantize_wire_plan
     # (0 when the worst-case global bin total already fits the wire).
     quantize_shift: int = 0
+    # Largest bucket of (gradient, hessian, count), from
+    # ops.histogram.quantize_levels(num_grad_quant_bins), and whether the
+    # rounding draws (LightGBM's stochastic_rounding) or rounds to nearest.
+    quantize_levels: Tuple[int, int, int] = DEFAULT_LEVELS
+    quantize_stochastic: bool = True
     # A backend switch for the windowed grower's final per-leaf stats
     # (_leaf_totals): the chunked one-hot contraction, what the booster
     # sets on a TPU, or the scatter-add, which sums in row order and so
@@ -865,6 +872,44 @@ def _leaf_totals(vals: jnp.ndarray, leaf_ids: jnp.ndarray, L: int,
     )[0]
 
 
+def _quantize_rows(cfg: GrowConfig, vals, qkey, qscale):
+    """The quantized growers' row values: ``vals`` (3, n) rounded ONCE a
+    tree to int16 buckets at the configuration's levels (the booster
+    computes the per-iteration max-abs scales over the GLOBAL batch
+    pre-shard), and the plan the builders dequantize by.  They accumulate
+    int32 and dequantize right after the merge, so everything downstream of
+    a histogram stays f32 and unchanged.  ``(vals, None)`` with
+    quantization off."""
+    if not cfg.quantize_active:
+        return vals, None
+    with jax.named_scope("quant_round"):
+        scales3 = quantize_scales3(qscale, cfg.quantize_levels)
+        if cfg.axis_name is not None:
+            # decorrelate the SR draws across shards: with one key every
+            # shard would reuse the SAME uniform pattern, correlating
+            # rounding errors across shards instead of letting them cancel
+            qkey = jax.random.fold_in(qkey, lax.axis_index(cfg.axis_name))
+        qvals = quantize_hist_vals(
+            vals, scales3, qkey, cfg.quantize_levels, cfg.quantize_stochastic
+        )
+    return qvals, HistQuantize(cfg.hist_quantize, cfg.quantize_shift, scales3)
+
+
+def _hist_scope(cfg: GrowConfig):
+    """The named scope of a grower's histogram builds: ``quant_hist`` for
+    bucket builds, ``hist_build`` for float ones."""
+    return jax.named_scope("quant_hist" if cfg.quantize_active else "hist_build")
+
+
+def _refine_scope(cfg: GrowConfig):
+    """``quant_refine`` around quantized training's float32 refinement pass
+    (the hierarchical merge's has no scope of its own)."""
+    return (
+        jax.named_scope("quant_refine") if cfg.quantize_active
+        else contextlib.nullcontext()
+    )
+
+
 def grow_tree(
     cfg: GrowConfig,
     bins: jnp.ndarray,  # (n, F) integer bins (uint8/int32)
@@ -892,34 +937,16 @@ def grow_tree(
     vals = jnp.stack(
         [grad * bag_weight, hess * bag_weight, in_bag], axis=0
     ).astype(jnp.float32)  # (3, n) channel-major
-    if cfg.quantize_active:
-        # ISSUE 9 quantized path: ONE stochastic-rounding quantization of
-        # the (3, n) value rows per tree (the booster computes the
-        # per-iteration max-abs scales over the GLOBAL batch pre-shard).
-        # Builders accumulate int32 and dequantize right after the merge,
-        # so everything downstream of hist() stays f32 and unchanged.
-        scales3 = jnp.concatenate(
-            [qscale.astype(jnp.float32),
-             jnp.asarray([COUNT_SCALE], jnp.float32)]
-        )  # (3,)
-        if cfg.axis_name is not None:
-            # decorrelate the SR draws across shards: with one key every
-            # shard would reuse the SAME uniform pattern, correlating
-            # rounding errors across shards instead of letting them cancel
-            qkey = jax.random.fold_in(qkey, lax.axis_index(cfg.axis_name))
-        qvals = quantize_hist_vals(vals, scales3, qkey)
-        hq = HistQuantize(cfg.hist_quantize, cfg.quantize_shift, scales3)
-    else:
-        qvals, hq = vals, None
+    qvals, hq = _quantize_rows(cfg, vals, qkey, qscale)
 
-    @jax.named_scope("hist_build")
     def hist(mask):
-        return build_histogram(
-            bins_t, qvals, mask, B,
-            backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=cfg.axis_name,
-            precision=cfg.hist_precision,
-            quantize=hq,
-        )
+        with _hist_scope(cfg):
+            return build_histogram(
+                bins_t, qvals, mask, B,
+                backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=cfg.axis_name,
+                precision=cfg.hist_precision,
+                quantize=hq,
+            )
 
     root_hist = hist(jnp.ones(n, bool))  # (3, F, B)
     hists = jnp.zeros((3, L, F, B), jnp.float32).at[:, 0].set(root_hist)
@@ -938,25 +965,26 @@ def grow_tree(
             cfg, hists, leaf_stats, leaf_depth, tree.num_leaves, feat_mask
         )
         if cfg.quantize_active:
-            # f32 winner refinement (ISSUE 9): quantized histograms picked
-            # the winner; its ONE column is re-accumulated exactly and
-            # re-scored, so the recorded threshold/gain — and the
-            # membership set below — carry no quantization error.  A tiny
-            # (3, 1, B) allreduce vs the full quantized pass.
-            wcol = lax.dynamic_index_in_dim(bins_t, f, axis=0, keepdims=True)
-            ref = build_histogram(
-                wcol, vals, leaf_ids == l, B,
-                backend=cfg.hist_backend, chunk=cfg.hist_chunk,
-                axis_name=cfg.axis_name, precision=cfg.hist_precision,
-                merge="allreduce_exact",  # recorded gains: layout-invariant
-            )[:, None]  # (3, 1, 1, B)
-            ref_col = ref[:, 0, 0]  # (3, B) exact winner column
-            ref_stats = ref_col.sum(axis=-1)[:, None]  # (3, 1)
-            rg, rt, rd = _refine_candidates(cfg, ref, ref_stats, is_cat[None])
-            ok = rg[0] > -jnp.inf
-            gain = jnp.where(ok, rg[0], gain)
-            t = jnp.where(ok, rt[0], t)
-            dleft = jnp.where(ok, rd[0], dleft)
+            with _refine_scope(cfg):
+                # f32 winner refinement (ISSUE 9): quantized histograms picked
+                # the winner; its ONE column is re-accumulated exactly and
+                # re-scored, so the recorded threshold/gain — and the
+                # membership set below — carry no quantization error.  A tiny
+                # (3, 1, B) allreduce vs the full quantized pass.
+                wcol = lax.dynamic_index_in_dim(bins_t, f, axis=0, keepdims=True)
+                ref = build_histogram(
+                    wcol, vals, leaf_ids == l, B,
+                    backend=cfg.hist_backend, chunk=cfg.hist_chunk,
+                    axis_name=cfg.axis_name, precision=cfg.hist_precision,
+                    merge="allreduce_exact",  # recorded gains: layout-invariant
+                )[:, None]  # (3, 1, 1, B)
+                ref_col = ref[:, 0, 0]  # (3, B) exact winner column
+                ref_stats = ref_col.sum(axis=-1)[:, None]  # (3, 1)
+                rg, rt, rd = _refine_candidates(cfg, ref, ref_stats, is_cat[None])
+                ok = rg[0] > -jnp.inf
+                gain = jnp.where(ok, rg[0], gain)
+                t = jnp.where(ok, rt[0], t)
+                dleft = jnp.where(ok, rd[0], dleft)
         do = (gain > cfg.min_gain_to_split) & ~stopped
 
         fcol = lax.dynamic_index_in_dim(bins_t, f, axis=0, keepdims=False)
@@ -1088,31 +1116,17 @@ def grow_tree_depthwise(
         "hierarchical" if hier
         else ("reduce_scatter" if rs else "allreduce")
     )
-    if cfg.quantize_active:
-        # ISSUE 9 quantized path (see grow_tree): one SR quantization per
-        # tree; the windowed builder accumulates int32, merges over the
-        # integer wire, and dequantizes — downstream stays f32.
-        scales3 = jnp.concatenate(
-            [qscale.astype(jnp.float32),
-             jnp.asarray([COUNT_SCALE], jnp.float32)]
-        )  # (3,)
-        if cfg.axis_name is not None:
-            # decorrelate SR draws across shards (see grow_tree)
-            qkey = jax.random.fold_in(qkey, lax.axis_index(cfg.axis_name))
-        qvals = quantize_hist_vals(vals, scales3, qkey)
-        hq = HistQuantize(cfg.hist_quantize, cfg.quantize_shift, scales3)
-    else:
-        qvals, hq = vals, None
+    qvals, hq = _quantize_rows(cfg, vals, qkey, qscale)
 
-    @jax.named_scope("hist_build")
     def window_hist(win_leaf):
-        return build_histogram_by_leaf(
-            bins_t, qvals, win_leaf, W, B,
-            backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=hist_axis,
-            precision=cfg.hist_precision,
-            merge=merge_mode,
-            quantize=hq,
-        )
+        with _hist_scope(cfg):
+            return build_histogram_by_leaf(
+                bins_t, qvals, win_leaf, W, B,
+                backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=hist_axis,
+                precision=cfg.hist_precision,
+                merge=merge_mode,
+                quantize=hq,
+            )
 
     # Root histogram through the SAME windowed kernel (all rows in slot 0):
     # the plain per-feature kernel's M=3 matmuls cost 2.8ms/pass at the
@@ -1281,55 +1295,56 @@ def grow_tree_depthwise(
         # -- f32 winner refinement (ISSUE 9 quantized path; ISSUE 14
         # hierarchical merge) ---------------------------------------------
         if cfg.refine_active:
-            # Approximate statistics picked the level's ≤W winners
-            # (quantized histograms, or the hierarchical merge's
-            # host-local slices); ONE windowed f32 pass re-accumulates
-            # just their winning COLUMNS (composed into a single per-row
-            # column: each row reads its own leaf's winning feature) and
-            # re-scores them exactly, so recorded thresholds/gains and
-            # the membership sets below carry no quantization or
-            # host-bias error.  Rides the same small-allreduce structure
-            # as the membership owner-broadcast: (3, W, 1, B) ≪ the full
-            # (3, W, F, B) pass — and replicates the whole winner column
-            # even when the merge itself scatters (rows are sharded,
-            # features are not, so every shard holds every column
-            # locally).  Under hierarchical this allreduce spans the FULL
-            # (slow × fast) mesh: it is, with the winner exchange, the
-            # only inter-host traffic of the pass.
-            win_col = jnp.zeros(n, jnp.int32)
-            for w in range(W):
-                l_w = slot_leaves[w]
-                col_w = lax.dynamic_slice(
-                    bins_t, (f[l_w], jnp.int32(0)), (1, n)
-                )[0]
-                win_col = jnp.where(leaf_ids == l_w, col_w, win_col)
-            warange_r = jnp.arange(W, dtype=jnp.int32)
-            slot_of_leaf = jnp.full(L, W, jnp.int32).at[slot_leaves].set(
-                jnp.where(selected[slot_leaves], warange_r, W)
-            )
-            row_slot = slot_of_leaf[leaf_ids]  # non-winners park at W
-            ref_hist = build_histogram_by_leaf(
-                win_col[None, :], vals, row_slot, W, B,
-                backend=cfg.hist_backend, chunk=cfg.hist_chunk,
-                axis_name=hist_axis, precision=cfg.hist_precision,
-                # exact AND process-layout-invariant: the refined
-                # gains/thresholds are recorded in the model, so their
-                # f32 sum order must not depend on how many processes
-                # the mesh spans (multihost bitwise-parity gate)
-                merge="allreduce_exact",
-            )  # (3, W, 1, B) exact winner columns
-            stats_w = ref_hist[:, :, 0, :].sum(axis=-1)  # (3, W)
-            rg, rt, rd = _refine_candidates(
-                cfg, ref_hist, stats_w, is_cat[slot_leaves]
-            )
-            ok_w = selected[slot_leaves] & (rg > -jnp.inf)
-            gain = gain.at[slot_leaves].set(
-                jnp.where(ok_w, rg, gain[slot_leaves])
-            )
-            t = t.at[slot_leaves].set(jnp.where(ok_w, rt, t[slot_leaves]))
-            dleft = dleft.at[slot_leaves].set(
-                jnp.where(ok_w, rd, dleft[slot_leaves])
-            )
+            with _refine_scope(cfg):
+                # Approximate statistics picked the level's ≤W winners
+                # (quantized histograms, or the hierarchical merge's
+                # host-local slices); ONE windowed f32 pass re-accumulates
+                # just their winning COLUMNS (composed into a single per-row
+                # column: each row reads its own leaf's winning feature) and
+                # re-scores them exactly, so recorded thresholds/gains and
+                # the membership sets below carry no quantization or
+                # host-bias error.  Rides the same small-allreduce structure
+                # as the membership owner-broadcast: (3, W, 1, B) ≪ the full
+                # (3, W, F, B) pass — and replicates the whole winner column
+                # even when the merge itself scatters (rows are sharded,
+                # features are not, so every shard holds every column
+                # locally).  Under hierarchical this allreduce spans the FULL
+                # (slow × fast) mesh: it is, with the winner exchange, the
+                # only inter-host traffic of the pass.
+                win_col = jnp.zeros(n, jnp.int32)
+                for w in range(W):
+                    l_w = slot_leaves[w]
+                    col_w = lax.dynamic_slice(
+                        bins_t, (f[l_w], jnp.int32(0)), (1, n)
+                    )[0]
+                    win_col = jnp.where(leaf_ids == l_w, col_w, win_col)
+                warange_r = jnp.arange(W, dtype=jnp.int32)
+                slot_of_leaf = jnp.full(L, W, jnp.int32).at[slot_leaves].set(
+                    jnp.where(selected[slot_leaves], warange_r, W)
+                )
+                row_slot = slot_of_leaf[leaf_ids]  # non-winners park at W
+                ref_hist = build_histogram_by_leaf(
+                    win_col[None, :], vals, row_slot, W, B,
+                    backend=cfg.hist_backend, chunk=cfg.hist_chunk,
+                    axis_name=hist_axis, precision=cfg.hist_precision,
+                    # exact AND process-layout-invariant: the refined
+                    # gains/thresholds are recorded in the model, so their
+                    # f32 sum order must not depend on how many processes
+                    # the mesh spans (multihost bitwise-parity gate)
+                    merge="allreduce_exact",
+                )  # (3, W, 1, B) exact winner columns
+                stats_w = ref_hist[:, :, 0, :].sum(axis=-1)  # (3, W)
+                rg, rt, rd = _refine_candidates(
+                    cfg, ref_hist, stats_w, is_cat[slot_leaves]
+                )
+                ok_w = selected[slot_leaves] & (rg > -jnp.inf)
+                gain = gain.at[slot_leaves].set(
+                    jnp.where(ok_w, rg, gain[slot_leaves])
+                )
+                t = t.at[slot_leaves].set(jnp.where(ok_w, rt, t[slot_leaves]))
+                dleft = dleft.at[slot_leaves].set(
+                    jnp.where(ok_w, rd, dleft[slot_leaves])
+                )
 
         # -- categorical membership sets for the level's winners ----------
         if cfg.has_categoricals:
@@ -1537,18 +1552,23 @@ def grow_tree_depthwise(
     return tree, leaf_ids
 
 
-def grow_tree_auto(cfg: GrowConfig, *args):
-    # split_batch routes lossguide through the windowed grower too (k
-    # best-first splits per windowed pass; k=1 reproduces grow_tree's split
-    # sequence exactly — see GrowConfig.split_batch).  Feature-parallel's
-    # winner exchange only exists in the windowed grower.
-    if (
+def windowed_grower(cfg: GrowConfig) -> bool:
+    """Whether :func:`grow_tree_auto` takes the windowed grower:
+    split_batch routes lossguide through it too (k best-first splits per
+    windowed pass; k=1 reproduces grow_tree's split sequence exactly — see
+    GrowConfig.split_batch), and feature-parallel's winner exchange only
+    exists there."""
+    return (
         cfg.grow_policy == "depthwise"
         or cfg.split_batch > 0
         or cfg.feature_parallel_active
         or cfg.reduce_scatter_active
         or cfg.hierarchical_active
-    ):
+    )
+
+
+def grow_tree_auto(cfg: GrowConfig, *args):
+    if windowed_grower(cfg):
         return grow_tree_depthwise(cfg, *args)
     return grow_tree(cfg, *args)
 

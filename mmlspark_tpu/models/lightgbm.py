@@ -82,12 +82,39 @@ class _LightGBMExecutionParams(Params):
         "histQuantize",
         "Quantized training wire/accumulator: off (default — bitwise the "
         "f32 path) | on (resolved to int16) | int16 | int32.  Quantizes "
-        "per-row grad/hess to ±127 buckets with seeded stochastic "
+        "per-row grad/hess to integer buckets (numGradQuantBins levels; "
+        "±127 where that is not set) with seeded stochastic "
         "rounding, accumulates int32 histograms and merges shards over an "
         "integer collective wire (f32 winner refinement keeps AUC "
         "parity)",
         default="off", dtype=str,
         validator=ParamValidators.inList(["off", "on", "int16", "int32"]),
+    )
+    useQuantizedGrad = Param(
+        "useQuantizedGrad",
+        "LightGBM's use_quantized_grad: quantized training on or off, the "
+        "same switch as histQuantize (set one, or make them agree)",
+        default=False, dtype=bool,
+    )
+    numGradQuantBins = Param(
+        "numGradQuantBins",
+        "LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] "
+        "and hessians in [0, bins] integer levels (LightGBM's default is "
+        "4); 0 = not given, the engine's 127 a side",
+        default=0, dtype=int,
+    )
+    quantTrainRenewLeaf = Param(
+        "quantTrainRenewLeaf",
+        "LightGBM's quant_train_renew_leaf: leaf values from the rows' "
+        "exact float32 gradient sums, which this engine always does; "
+        "False is refused",
+        default=True, dtype=bool,
+    )
+    stochasticRounding = Param(
+        "stochasticRounding",
+        "LightGBM's stochastic_rounding: False rounds gradients to the "
+        "nearest level",
+        default=True, dtype=bool,
     )
     useBarrierExecutionMode = Param(
         "useBarrierExecutionMode",
@@ -237,7 +264,15 @@ class _LightGBMParams(
         p["tree_learner"] = learner
         p["top_k"] = self.getTopK()
         p["hist_merge"] = self.getHistMerge()
-        p["hist_quantize"] = self.getHistQuantize()
+        # two names of one switch: hand over what was set, so that the
+        # engine derives the other or refuses a disagreement
+        if self.isSet("histQuantize") or not self.isSet("useQuantizedGrad"):
+            p["hist_quantize"] = self.getHistQuantize()
+        if self.isSet("useQuantizedGrad"):
+            p["use_quantized_grad"] = self.getUseQuantizedGrad()
+        p["num_grad_quant_bins"] = self.getNumGradQuantBins()
+        p["quant_train_renew_leaf"] = self.getQuantTrainRenewLeaf()
+        p["stochastic_rounding"] = self.getStochasticRounding()
         p["grow_policy"] = self.getGrowPolicy()
         p["split_batch"] = self.getSplitBatch()
         p["predict_backend"] = self.getPredictBackend()

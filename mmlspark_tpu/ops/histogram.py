@@ -28,7 +28,8 @@ Backends:
 
 Every chunk body sums in the accumulator its ``vals`` ask for, read from
 their dtype at trace time: float32, or int32 for the int16 buckets of
-quantized training.
+quantized training (gradient, hessian and in-bag count each rounded to its
+own number of integer levels: "Quantized accumulation" below).
 
 Both are row-chunked with ``lax.scan`` so peak memory is bounded by the chunk,
 not the dataset (HBM holds only the uint8 binned matrix — SURVEY.md §7.2).
@@ -61,21 +62,56 @@ def _row_chunk(x, i, size: int, axis: int):
 # Quantized accumulation (ISSUE 9 — LightGBM quantized training,
 # "Quantized Training of Gradient Boosting Decision Trees", NeurIPS 2022)
 # ---------------------------------------------------------------------------
-# Per-row grad/hess quantize to signed buckets in [-QMAX, QMAX] with
-# per-iteration max-abs scales and seeded stochastic rounding; histograms
-# then accumulate as int32 adds and cross the mesh on an integer wire.
-# QMAX = 127 keeps every quantized row one int8 of information (int16 on
-# the row array for scatter/matmul convenience) and leaves the int32
-# accumulator headroom for n·QMAX row sums up to n ≈ 16.9M rows — the
-# worst case is REAL (iteration 0 of binary logloss: every |grad| equal).
+# Per-row gradients, hessians and the in-bag count are rounded to signed
+# integer buckets, each channel up to its own LARGEST BUCKET (its "level"),
+# with per-iteration max-abs scales and seeded stochastic rounding;
+# histograms then accumulate as int32 adds and cross the mesh on an integer
+# wire.  The levels are static and come from the configuration
+# (:func:`quantize_levels`):
+#
+# - ``num_grad_quant_bins`` given (LightGBM's parameter, default 4 there):
+#   gradients in ``[-bins/2, bins/2]`` with scale ``max|g| / (bins/2)``,
+#   hessians in ``[0, bins]`` with scale ``max h / bins`` (LightGBM's
+#   gradient_discretizer rule), and the count's bucket 1: the count is
+#   ``1[w > 0]``, always 0 or 1, so its bucket holds it exactly at scale 1.
+# - absent: ``DEFAULT_LEVELS``, 127 a side for gradients and hessians
+#   (QMAX: every row value one int8 of information) and the count's bucket
+#   64 at the fixed scale 2⁻⁶ (COUNT_SCALE) — what the engine did before
+#   the levels became a parameter, kept so that those fits, their integer
+#   wire's dynamic shift included, give the same model to the bit.
+#
+# The row array is int16 whatever the levels (scatter/matmul convenience).
+# The int32 accumulator holds ``rows × largest bucket`` (every row of a shard
+# in one bin at full magnitude, and that worst case is REAL: iteration 0 of
+# binary logloss has every |grad| equal), so :func:`quantize_wire_plan`
+# refuses a fit where that product, channel by channel, reaches 2³¹.
 QMAX = 127
 
-# The count channel uses a FIXED power-of-two scale instead of a max-abs
-# scale: an in-bag row quantizes to exactly 1/COUNT_SCALE = 64 and
-# dequantizes to exactly 1.0 (64 · 2⁻⁶ is exact in f32), so quantized
-# leaf counts are EXACT and `count >= min_data_in_leaf` comparisons can
-# never flip versus the f32 path.
+# The default count channel: an in-bag row quantizes to exactly
+# 1/COUNT_SCALE = 64 and dequantizes to exactly 1.0 (64 · 2⁻⁶ is exact in
+# f32).  Whatever the count's bucket (64 here, 1 under
+# ``num_grad_quant_bins``), its scale is the bucket's reciprocal, a power of
+# two, so quantized leaf counts are EXACT and ``count >= min_data_in_leaf``
+# comparisons can never flip versus the f32 path.
 COUNT_SCALE = 2.0 ** -6
+
+# Largest bucket of (gradient, hessian, count) with no level count given.
+DEFAULT_LEVELS = (QMAX, QMAX, 64)
+_CHANNELS = ("gradient", "hessian", "count")
+
+
+def quantize_levels(num_grad_quant_bins: int = 0) -> tuple:
+    """Largest bucket of ``(gradient, hessian, count)`` for LightGBM's
+    ``num_grad_quant_bins`` (0 = not given: ``DEFAULT_LEVELS``)."""
+    bins = int(num_grad_quant_bins)
+    if bins == 0:
+        return DEFAULT_LEVELS
+    if not 2 <= bins <= QMAX:
+        raise ValueError(
+            f"num_grad_quant_bins must lie in [2, {QMAX}] (LightGBM's "
+            f"default is 4), got {num_grad_quant_bins!r}"
+        )
+    return (bins // 2, bins, 1)
 
 
 class HistQuantize(NamedTuple):
@@ -86,7 +122,7 @@ class HistQuantize(NamedTuple):
                  partial sums before the wire (0 when the worst-case sum
                  already fits; see :func:`quantize_wire_plan`).
     ``scales`` — ``(3,)`` f32 per-channel dequantization scales
-                 (grad, hess, count).
+                 (grad, hess, count), each its own: :func:`quantize_scales3`.
     """
 
     wire: str
@@ -94,18 +130,21 @@ class HistQuantize(NamedTuple):
     scales: jnp.ndarray
 
 
-def quantize_wire_plan(n_rows: int, wire: str, num_shards: int = 1) -> int:
+def quantize_wire_plan(n_rows: int, wire: str, num_shards: int = 1,
+                       levels: tuple = DEFAULT_LEVELS) -> int:
     """Static integer-wire plan: the pre-merge right-shift for ``wire``.
 
-    The worst-case bin total is ``n_rows × QMAX`` (every row in one bin at
-    max magnitude).  The plan guarantees, by construction:
+    The worst-case bin total of a channel is ``n_rows × its largest
+    bucket`` (every row in one bin at max magnitude; ``levels`` holds the
+    three).  The plan guarantees, by construction:
 
-    - the LOCAL int32 accumulator never wraps: ``ceil(n/D) × QMAX < 2³¹``
-      (raises ``ValueError`` otherwise — quantize is unsupported at that
-      scale rather than silently wrong);
+    - the LOCAL int32 accumulator never wraps: ``ceil(n/D) × bucket <
+      2³¹`` for each channel (raises ``ValueError`` naming the channel
+      otherwise — quantize is unsupported at that scale rather than
+      silently wrong);
     - the WIRE value fits its dtype: partial sums are right-shifted by
       ``s`` with round-half-up, so each shifted magnitude is at most
-      ``(n·QMAX)/2^s + 1/2`` and the D-shard sum stays under
+      ``(n·bucket)/2^s + 1/2`` and the D-shard sum stays under
       ``2^cap + D/2`` with cap = 14 (int16) / 30 (int32) — comfortably
       inside the signed range.  Dequantization multiplies by ``2^s``.
 
@@ -120,42 +159,69 @@ def quantize_wire_plan(n_rows: int, wire: str, num_shards: int = 1) -> int:
             f"unknown quantize wire {wire!r}; expected int16|int32"
         )
     n_local = -(-int(n_rows) // max(int(num_shards), 1))
-    if n_local * QMAX >= 2 ** 31:
-        raise ValueError(
-            f"hist_quantize overflow guard: {n_local} rows/shard × "
-            f"QMAX={QMAX} exceeds int32 accumulator headroom (2³¹); "
-            "quantized training is unsupported at this per-shard scale"
-        )
+    for channel, bucket in zip(_CHANNELS, levels):
+        if n_local * int(bucket) >= 2 ** 31:
+            raise ValueError(
+                f"hist_quantize overflow guard: {n_local} rows/shard × the "
+                f"{channel} channel's largest bucket {bucket} exceeds the "
+                "int32 accumulator's headroom (2³¹); set num_grad_quant_bins "
+                f"(LightGBM's default is 4) below {2 ** 31 // n_local + 1} "
+                "or shard the rows further"
+            )
     cap_bits = 14 if wire == "int16" else 30
-    return max(0, (int(n_rows) * QMAX).bit_length() - cap_bits)
+    return max(0, (int(n_rows) * max(int(b) for b in levels)).bit_length() - cap_bits)
 
 
-def quantize_channel_scales(grad, hess, bag_weight) -> jnp.ndarray:
+def quantize_channel_scales(grad, hess, bag_weight,
+                            levels: tuple = DEFAULT_LEVELS) -> jnp.ndarray:
     """Per-iteration (grad, hess) quantization scales for ONE class:
-    max-abs over the bagged batch divided by QMAX (LightGBM quantized
-    training's per-iteration gradient scale).  Zero-gradient batches get
-    scale 1.0 so dequantization never divides by zero."""
+    max-abs over the bagged batch divided by the channel's largest bucket
+    (LightGBM quantized training's per-iteration gradient scale:
+    ``max|g| / (bins/2)`` and ``max h / bins`` under
+    ``num_grad_quant_bins``).  Zero-gradient batches get scale 1.0 so
+    dequantization never divides by zero."""
     gmax = jnp.max(jnp.abs(grad * bag_weight))
     hmax = jnp.max(jnp.abs(hess * bag_weight))
     one = jnp.float32(1.0)
     return jnp.stack([
-        jnp.where(gmax > 0, gmax / QMAX, one),
-        jnp.where(hmax > 0, hmax / QMAX, one),
+        jnp.where(gmax > 0, gmax / levels[0], one),
+        jnp.where(hmax > 0, hmax / levels[1], one),
     ]).astype(jnp.float32)
 
 
-def quantize_hist_vals(vals, scales, key) -> jnp.ndarray:
-    """Stochastically round ``vals`` (3, n) f32 to int16 buckets.
+def quantize_scales3(qscale, levels: tuple = DEFAULT_LEVELS) -> jnp.ndarray:
+    """``(3,)`` dequantization scales: the iteration's (grad, hess) scales
+    and the count's, the reciprocal of its bucket (a power of two)."""
+    return jnp.concatenate([
+        qscale.astype(jnp.float32),
+        jnp.asarray([1.0 / levels[2]], jnp.float32),
+    ])
+
+
+def quantize_draw(key, shape) -> jnp.ndarray:
+    """The stochastic rounding's ``u ~ U[0, 1)``, one a value."""
+    return jax.random.uniform(key, shape, dtype=jnp.float32)
+
+
+def quantize_hist_vals(vals, scales, key, levels: tuple = (QMAX, QMAX, QMAX),
+                       stochastic: bool = True) -> jnp.ndarray:
+    """Round ``vals`` (3, n) f32 to int16 buckets, channel ``c`` clipped to
+    ``±levels[c]`` (the largest bucket of gradient, hessian and count:
+    :func:`quantize_levels`; the count channel holds ``1[w > 0]``; with no
+    levels given every channel is clipped at QMAX).
 
     ``q = floor(v / scale + u)`` with ``u ~ U[0, 1)`` — unbiased
     (E[q·scale] = v), and EXACT whenever ``v/scale`` is integral, which
-    the count channel always is (fixed 2⁻⁶ scale).  Seeded by ``key``:
-    the same (seed, iteration, class) key reproduces the same buckets
-    bitwise, making quantized training run-to-run deterministic."""
+    the count channel always is (its scale is its bucket's reciprocal).
+    Seeded by ``key``: the same (seed, iteration, class) key reproduces
+    the same buckets bitwise, making quantized training run-to-run
+    deterministic.  ``stochastic=False`` (LightGBM's
+    ``stochastic_rounding=false``) rounds to nearest, ``u = 1/2``."""
     x = vals / scales[:, None]
-    u = jax.random.uniform(key, vals.shape, dtype=jnp.float32)
-    # clip: f32 division rounding can land x a hair above ±QMAX
-    return jnp.clip(jnp.floor(x + u), -QMAX, QMAX).astype(jnp.int16)
+    u = quantize_draw(key, vals.shape) if stochastic else jnp.float32(0.5)
+    # clip: f32 division rounding can land x a hair above the largest bucket
+    top = jnp.asarray(levels, jnp.float32)[:, None]
+    return jnp.clip(jnp.floor(x + u), -top, top).astype(jnp.int16)
 
 
 def merge_shard_histograms(
@@ -250,9 +316,9 @@ def merge_shard_histograms_quantized(
     The wire shift is sized DYNAMICALLY per merge: a scalar ``pmax`` of
     the largest local ``|partial|`` agrees a global bit length, and the
     shift is just what squeezes the D-shard sum under the wire cap.  On
-    real data the largest bin magnitude sits far below the static
-    worst case ``n·QMAX``, so the int16 wire usually ships at shift 0–3
-    where the static plan would demand ~7 — enough rounding noise to
+    real data the largest bin magnitude sits far below the static worst
+    case ``rows × largest bucket``, so the int16 wire usually ships at shift
+    0–3 where the static plan would demand ~7 — enough rounding noise to
     corrupt split selection (the AUC-parity gates in
     ``tests/test_quantize.py`` fail on the static plan at 16k rows).
     ``shift`` (the static ceiling from :func:`quantize_wire_plan`) is
@@ -323,9 +389,9 @@ def _is_bucket(vals_dtype) -> bool:
 
 def _scatter_hist_chunk(bins_c, vals_c, num_bins: int):
     """(F, C) int bins, (3, C) vals → (3, F, B) via scatter-add: float32
-    sums, or int32 sums of int16 bucket ``vals_c``.  headroom: |bucket| ≤
-    QMAX, so C·QMAX row sums fit int32 for any chunk ≤ 16.9M rows
-    (quantize_wire_plan)."""
+    sums, or int32 sums of int16 bucket ``vals_c``.  headroom: a chunk is
+    rows of one shard, and quantize_wire_plan refuses a fit whose rows ×
+    largest bucket, channel by channel, reach 2³¹."""
     F, C = bins_c.shape
     acc = jnp.int32 if _is_bucket(vals_c.dtype) else jnp.float32
     idx = bins_c.T.astype(jnp.int32) + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
@@ -340,7 +406,7 @@ def _scatter_hist_chunk(bins_c, vals_c, num_bins: int):
 def _scatter_hist_by_leaf_chunk(bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int):
     """(F, C) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B) scatter-add:
     float32 sums, or int32 sums of int16 bucket ``vals_c``.  headroom:
-    |bucket| ≤ QMAX keeps C·QMAX sums inside int32 (quantize_wire_plan).
+    rows × largest bucket stays inside int32 (quantize_wire_plan).
 
     Rows parked outside ``[0, num_leaves)`` (including NEGATIVE ids from the
     windowed depthwise pass) are routed to a scratch slot and sliced off —
@@ -459,8 +525,8 @@ def build_histogram(
     if quantize is None:
         vals = vals.astype(jnp.float32)
     vals = jnp.where(mask[None, :], vals, vals.dtype.type(0))
-    # headroom: n·QMAX bin sums of buckets fit the int32 accumulator for
-    # any n ≤ 16.9M rows/shard — guarded statically by quantize_wire_plan
+    # headroom: bin sums of buckets fit the int32 accumulator while rows ×
+    # largest bucket < 2³¹ a shard — guarded statically by quantize_wire_plan
     acc0 = jnp.zeros(
         (3, F, num_bins), jnp.int32 if _is_bucket(vals.dtype) else jnp.float32
     )
@@ -528,8 +594,8 @@ def build_histogram_by_leaf(
         raise ValueError(
             f"unknown hist backend {backend!r}; expected scatter|pallas"
         )
-    # headroom: n·QMAX bin sums of buckets fit the int32 accumulator for
-    # any n ≤ 16.9M rows/shard — guarded statically by quantize_wire_plan
+    # headroom: bin sums of buckets fit the int32 accumulator while rows ×
+    # largest bucket < 2³¹ a shard — guarded statically by quantize_wire_plan
     acc0 = jnp.zeros(
         (3, num_leaves, F, num_bins), jnp.int32 if quant else jnp.float32
     )

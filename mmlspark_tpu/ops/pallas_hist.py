@@ -78,14 +78,17 @@ def _pow2_floor(x: int) -> int:
 # same (3·L on sublanes, bf·B on lanes) orientation as the float build.
 # The per-row-block contraction itself stays an f32 MXU matmul — there is
 # no native int32 MXU path to lower to, and none is needed for exactness:
-# both operands are small integers (one-hot ∈ {0,1}, |vals| ≤ QMAX = 127,
-# exact even as bf16 under precision="default"), so every partial sum is
+# both operands are small integers (one-hot ∈ {0,1}, |vals| at most a
+# channel's largest bucket, never over QMAX = 127 — 2 for the gradients of
+# LightGBM's default num_grad_quant_bins=4 — exact even as bf16 under
+# precision="default"), so every partial sum is
 # an integer ≤ bm·QMAX ≈ 2.1M ≪ 2²⁴, exactly representable in the f32
 # accumulator; the cast to int32 after each row block is therefore exact,
 # and int32 grid accumulation across row blocks is associative — the
 # whole build is bit-reproducible regardless of precision mode, chunking,
-# or merge order.  headroom: n·QMAX ≤ 2³¹ per shard is attested
-# statically by ops.histogram.quantize_wire_plan before any kernel runs.
+# or merge order.  headroom: rows × largest bucket < 2³¹ per shard is
+# attested statically by ops.histogram.quantize_wire_plan before any
+# kernel runs.
 # ---------------------------------------------------------------------------
 def _tile_dtype(vals_dtype):
     """The dtype row values cross the DMA in: int16 buckets, else f32."""
@@ -155,8 +158,8 @@ def _pallas_hist(
         # shape's last two dims (3, bf·B) satisfy TPU tiling by equalling
         # the array dims; the bin unflatten happens outside the kernel.
         out_specs=pl.BlockSpec((1, 3, bf * num_bins), lambda j, i: (j, 0, 0)),
-        # headroom: the int32 grid accumulator of a bucket build — n·QMAX
-        # per shard is attested statically by
+        # headroom: the int32 grid accumulator of a bucket build — rows ×
+        # largest bucket per shard is attested statically by
         # ops.histogram.quantize_wire_plan before kernels run
         out_shape=jax.ShapeDtypeStruct(
             (F // bf, 3, bf * num_bins),
@@ -282,7 +285,7 @@ def _hist_leaf_kernel(
     part = jax.lax.fori_loop(
         0, bm // rm, sub,
         # headroom: bm·QMAX ≪ 2³¹ per block of buckets; the cross-block
-        # int32 total is bounded by quantize_wire_plan's static n·QMAX check
+        # int32 total is bounded by quantize_wire_plan's static rows × bucket check
         jnp.zeros(
             (3 * num_leaves, bf * num_bins),
             jnp.int32 if quant else jnp.float32,
@@ -323,8 +326,8 @@ def _pallas_hist_by_leaf(
         out_specs=pl.BlockSpec(
             (1, num_leaves * 3, bf * num_bins), lambda j, i: (j, 0, 0)
         ),
-        # headroom: the int32 grid accumulator of a bucket build — n·QMAX
-        # per shard is attested statically by
+        # headroom: the int32 grid accumulator of a bucket build — rows ×
+        # largest bucket per shard is attested statically by
         # ops.histogram.quantize_wire_plan before kernels run
         out_shape=jax.ShapeDtypeStruct(
             (F // bf, num_leaves * 3, bf * num_bins),
