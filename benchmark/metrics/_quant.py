@@ -3,9 +3,10 @@ quantized, the seconds of their bucket builds, of the float32 refinement and
 of the rounding, and the least work a tree's bucket histograms need.
 
 A reader gets seconds by op name only, and a name there is the op's HLO name
-and the shape it produces (``fusion.9 s16[3,39845888]``).  The three parts are
-told apart by what they PRODUCE, which follows the arithmetic and not the
-implementation:
+and the shape it produces (``fusion.9 s32[1,39845888]``; of an op with several
+results the first, ``pad_maximum_fusion.6 (f32[3,39845888]``).  The three
+parts are told apart by what they PRODUCE, which follows the arithmetic and
+not the implementation:
 
 - a bucket build is a histogram kernel (``_hist.KERNELS``: any kernel of
   ``ops/pallas_hist.py``, whatever its body) whose result is integer
@@ -13,14 +14,17 @@ implementation:
   values (``s16[3,chunk]`` or ``s8[3,chunk]``: only bucket builds read them);
 - the refinement is every histogram kernel with a float result in a fit that
   is quantized (every full pass of such a fit is a bucket build, so the float
-  kernels that are left are the winners' columns), with the slices of the
-  composed winner column (``[1,chunk]``) and of the float32 row values
-  (``f32[3,chunk]``) that only it reads;
+  kernels that are left are the winners' columns), with what only it makes
+  and reads: the winners' columns composed into one (``s32[1,rows]``), its
+  chunks and their padding (``s32[k,chunk]``: the bins themselves are ``u8``)
+  and the chunks of the float32 row values (``f32[3,chunk]``);
 - the rounding is whatever produces an array of the row values' shape
-  ``[3,rows]`` in an integer or ``u32`` type (the buckets and the draw's
-  bits).  Not found this way, so not counted: the two reductions of the
-  scales (scalars), and a draw that the compiler fuses into another op's
-  result; the share reads a little low, never high.
+  ``[3,rows]``: on the chip one fusion stacks the three channels, draws and
+  rounds (``(f32[3,rows], s16[3,rows])``).  Not found this way, so not
+  counted: the two reductions of the scales (scalars, 3 ms a fit).
+
+The row masks, the leaf delta and the chunk loop's copies of the bins are the
+float fit's too and belong to none of the three.
 
 Whether a fit was quantized is read from the program's counter
 ``train.quant_levels``; a program without it gives ``None`` everywhere.
@@ -31,7 +35,7 @@ import re
 from benchmark.metrics import _hist, _program
 
 LEVELS = "train.quant_levels"
-_SHAPE = re.compile(r" ([a-z]+[0-9]*)\[([0-9,]*)\]")
+_SHAPE = re.compile(r"[ (]([a-z]+[0-9]*)\[([0-9,]*)\]")
 
 
 def levels(ctx):
@@ -75,20 +79,20 @@ def split_seconds(ctx):
             out["bucket" if dtype == "s32" else "refine"] += s
         elif dims == (3, chunk) and _integer(dtype):
             out["bucket"] += s
-        elif dims in ((3, chunk), (1, chunk)) and (dtype == "f32" or dims[0] == 1):
+        elif dims == (3, chunk) or (dtype == "s32" and (dims == (1, rows) or (len(dims) == 2 and dims[1] == chunk))):
             out["refine"] += s
-        elif dims == (3, rows) and (_integer(dtype) or dtype == "u32"):
+        elif dims == (3, rows):
             out["round"] += s
     return out
 
 
 def value_bytes(ctx) -> int:
     """Bytes of one bucket as the program keeps it: the width of the integer
-    row values in the trace (``s8`` 1, else ``s16``'s 2)."""
-    rows = int(ctx["rows"])
+    row values the bucket builds read, chunk by chunk, in the trace (``s8``
+    1, else ``s16``'s 2)."""
+    chunk = int(ctx["cfg"]["chunk_rows"])
     for name in ctx["trace"]["op_s"]:
-        dtype, dims = _produces(name)
-        if dims == (3, rows) and dtype == "s8":
+        if _produces(name) == ("s8", (3, chunk)):
             return 1
     return 2
 

@@ -112,17 +112,21 @@ ROWS, CHUNK = 8192, 4096
 
 
 def _ctx(quantized=True, bucket="s16"):
-    ops = {
-        "_pallas_hist_by_leaf.3 s32[1,24,10240]": 4.0,
-        "_pallas_hist_by_leaf.2 s32[1,24,10240]": 0.5,
-        f"dynamic-slice.7 {bucket}[3,{CHUNK}]": 0.25,
-        "_pallas_hist_by_leaf_nibble.5 f32[1,48,1024]": 1.0,
-        f"dynamic-slice.9 f32[3,{CHUNK}]": 0.125,
-        f"dynamic_slice_fusion.4 s32[1,{CHUNK}]": 0.125,
-        f"fusion.11 {bucket}[3,{ROWS}]": 0.75,
-        f"fusion.12 u32[3,{ROWS}]": 0.25,
-        f"not_reduce_fusion.1 pred[{ROWS}]": 2.0,
-        f"compare_select_fusion.2 f32[1,{ROWS}]": 1.0,
+    ops = {  # names as the v5e's trace gives them (my chip run, PR 34), at small sizes
+        "_pallas_hist_by_leaf.15 s32[1,24,10240]": 4.0,
+        "_pallas_hist_by_leaf.14 s32[1,24,10240]": 0.5,
+        f"constant_dynamic-slice_fusion.31 {bucket}[3,{CHUNK}]": 0.25,
+        "_pallas_hist_by_leaf_nibble.4 f32[1,48,1024]": 1.0,
+        f"constant_dynamic-slice_fusion.29 f32[3,{CHUNK}]": 0.125,
+        f"constant_dynamic-slice_fusion.28 s32[1,{CHUNK}]": 0.0625,
+        f"pad.456 s32[8,{CHUNK}]": 0.03125,
+        f"dynamic-slice_convert_fusion.9 (s32[1,{ROWS}]": 0.03125,
+        f"pad_maximum_fusion.6 (f32[3,{ROWS}]": 1.0,
+        f"pad.457 u8[40,{CHUNK}]": 0.5,  # the bins' own copies, the row masks, the leaf delta, the
+        f"dynamic_slice.891 s32[{CHUNK}]": 0.25,  # leaf ids' slices and the scorer: the float fit's too
+        f"compare_reduce_fusion.46 pred[{ROWS}]": 2.0,
+        f"compare_select_fusion.131 f32[1,{ROWS}]": 1.0,
+        "fusion.33 s32[1,6144]": 0.25,
     }
     after = {"train.quant_levels{channel=grad}": 6.0, "train.quant_levels{channel=hess}": 12.0, "train.quant_levels{channel=count}": 3.0}
     before = {k: v / 3 for k, v in after.items()}  # set-up's fit counted once, the window's two fits twice more

@@ -3641,17 +3641,17 @@ def scope_entries(jaxpr, scopes, while_trips: int = 1) -> dict:
     one another under a scope, at one level of the program, are one entry;
     a ``scan`` multiplies by its length, a ``while`` loop by
     ``while_trips`` (its count is not in the program: see
-    ``collective_ledger``), ``cond`` counts its largest branch."""
+    ``collective_ledger``)."""
     out = dict.fromkeys(scopes, 0)
 
-    def walk(jp, mult, dst):
+    def walk(jp, mult):
         inside = None
         for eqn in getattr(jp, "jaxpr", jp).eqns:
             names = str(eqn.source_info.name_stack).split("/")
             here = next((s for s in scopes if s in names), None)
             if here is not None:
                 if here != inside:
-                    dst[here] += mult
+                    out[here] += mult
                 inside = here
                 continue
             inside = None
@@ -3661,20 +3661,12 @@ def scope_entries(jaxpr, scopes, while_trips: int = 1) -> dict:
                 inner = mult * int(eqn.params["length"])
             elif name == "while":
                 inner = mult * int(while_trips)
-            if name == "cond":
-                branches = [dict.fromkeys(scopes, 0) for _ in eqn.params["branches"]]
-                for br, cur in zip(eqn.params["branches"], branches):
-                    walk(br, mult, cur)
-                best = max(branches, key=lambda d: sum(d.values()))
-                for s_, v in best.items():
-                    dst[s_] += v
-                continue
             for v in eqn.params.values():
                 for sub in v if isinstance(v, (tuple, list)) else (v,):
                     if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
-                        walk(sub, inner, dst)
+                        walk(sub, inner)
 
-    walk(jaxpr, 1, out)
+    walk(jaxpr, 1)
     return out
 
 

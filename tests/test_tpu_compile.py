@@ -1,0 +1,73 @@
+"""The chip's own compiler on the histogram kernels of the benchmark's cells,
+at the cells' shapes, with no chip: libtpu compiles for a v5e that is described
+and not attached (Mosaic's tiling and VMEM limits, which interpret mode does
+not see).  Nothing runs, so nothing here says a result or a time.
+
+All of it lives in this one file and behind fixtures: the process that
+describes the topology holds libtpu until it exits, so no module may do it at
+import, and under ``pytest -n`` only the worker given this file does it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+CHUNK = 2_097_152  # rows of one histogram chunk in every cell
+W, B = 8, 256  # leaf slots a pass (split_batch 8) and bins
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1", chips_per_host_bounds=(1, 1, 1)
+        )
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    # a deviceless compile is written to the persistent cache but cannot be
+    # read back without a chip: the next one would warn and compile again
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "kernel, vals_dtype, cols, out_dtype",
+    [
+        # criteo_quant_train_1chip: every pass's bucket build, int16 row values into int32
+        pytest.param("_pallas_hist_by_leaf", jnp.int16, 40, "s32", id="bucket-build-int16"),
+        # the same body should the buckets become one byte wide
+        pytest.param("_pallas_hist_by_leaf", jnp.int8, 40, "s32", id="bucket-build-int8"),
+        # its float32 refinement: one composed winner column, padded to 8
+        pytest.param("_pallas_hist_by_leaf_nibble", jnp.float32, 8, "f32", id="refine-column"),
+        # the float Criteo cells' pass: 39 columns padded to 40
+        pytest.param("_pallas_hist_by_leaf_nibble", jnp.float32, 40, "f32", id="float-nibble"),
+    ],
+)
+def test_histogram_kernel_compiles_for_the_chip(one_chip, no_persistent_cache, kernel, vals_dtype, cols, out_dtype):
+    from mmlspark_tpu.ops import pallas_hist
+
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    compiled = getattr(pallas_hist, kernel).lower(
+        struct((cols, CHUNK), jnp.uint8), struct((3, CHUNK), vals_dtype), struct((1, CHUNK), jnp.int32),
+        num_leaves=W, num_bins=B, bm=16384, bf=cols, rm=1024, interpret=False, precision="default",
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"{out_dtype}[3,{W},{cols},{B}]" in text  # (3, W, F, B) in the accumulator the values ask for
