@@ -86,6 +86,17 @@ def split_seconds(ctx):
     return out
 
 
+def share_pct(ctx, part: str, metric: str):
+    """One part's seconds / busy seconds, in percent; ``None`` where the part
+    has nothing in the trace."""
+    s = split_seconds(ctx)
+    if not s or s[part] <= 0:
+        return None
+    busy = ctx["trace"]["busy_s"]
+    _program.say(metric, **{part + "_s": s[part], "busy_s": busy})
+    return 100.0 * s[part] / busy
+
+
 def value_bytes(ctx) -> int:
     """Bytes of one bucket as the program keeps it: the width of the integer
     row values the bucket builds read, chunk by chunk, in the trace (``s8``

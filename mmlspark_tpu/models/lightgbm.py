@@ -266,7 +266,7 @@ class _LightGBMParams(
         p["hist_merge"] = self.getHistMerge()
         # two names of one switch: hand over what was set, so that the
         # engine derives the other or refuses a disagreement
-        if self.isSet("histQuantize") or not self.isSet("useQuantizedGrad"):
+        if self.isSet("histQuantize"):
             p["hist_quantize"] = self.getHistQuantize()
         if self.isSet("useQuantizedGrad"):
             p["use_quantized_grad"] = self.getUseQuantizedGrad()

@@ -96,7 +96,7 @@ QMAX = 127
 COUNT_SCALE = 2.0 ** -6
 
 # Largest bucket of (gradient, hessian, count) with no level count given.
-DEFAULT_LEVELS = (QMAX, QMAX, 64)
+DEFAULT_LEVELS = (QMAX, QMAX, int(1 / COUNT_SCALE))
 _CHANNELS = ("gradient", "hessian", "count")
 
 
