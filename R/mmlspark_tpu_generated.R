@@ -1883,7 +1883,7 @@ ml_k_n_n_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -1900,6 +1900,7 @@ ml_k_n_n_model <- function(
 #' @param min_sum_hessian_in_leaf Min leaf hessian sum
 #' @param model_string Warm-start model string
 #' @param num_batches Split training into sequential batches (continuation-trained)
+#' @param num_grad_quant_bins LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
 #' @param num_iterations Number of boosting iterations
 #' @param num_leaves Max leaves per tree
 #' @param num_tasks Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1909,14 +1910,17 @@ ml_k_n_n_model <- function(
 #' @param predict_backend Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
 #' @param prediction_col The name of the prediction column
 #' @param probability_col Class probability output column
+#' @param quant_train_renew_leaf LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
 #' @param raw_prediction_col Raw margin output column
 #' @param seed Master random seed
 #' @param slot_names Feature vector slot names
 #' @param split_batch k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+#' @param stochastic_rounding LightGBM's stochastic_rounding: False rounds gradients to the nearest level
 #' @param thresholds Per-class prediction thresholds
 #' @param timeout Distributed initialization timeout in seconds
 #' @param top_k Top-k features voted per worker in voting_parallel
 #' @param use_barrier_execution_mode Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+#' @param use_quantized_grad LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
 #' @param validation_indicator_col Boolean column marking validation rows
 #' @param verbosity Native verbosity
 #' @param weight_col The name of the sample-weight column
@@ -1955,6 +1959,7 @@ ml_light_g_b_m_classification_model <- function(
     min_sum_hessian_in_leaf = 0.001,
     model_string = "",
     num_batches = 0L,
+    num_grad_quant_bins = 0L,
     num_iterations = 100L,
     num_leaves = 31L,
     num_tasks = 0L,
@@ -1964,14 +1969,17 @@ ml_light_g_b_m_classification_model <- function(
     predict_backend = "auto",
     prediction_col = "prediction",
     probability_col = "probability",
+    quant_train_renew_leaf = TRUE,
     raw_prediction_col = "rawPrediction",
     seed = 0L,
     slot_names = NULL,
     split_batch = 0L,
+    stochastic_rounding = TRUE,
     thresholds = NULL,
     timeout = 1200.0,
     top_k = 20L,
     use_barrier_execution_mode = FALSE,
+    use_quantized_grad = FALSE,
     validation_indicator_col = NULL,
     verbosity = 1L,
     weight_col = NULL) {
@@ -2009,6 +2017,7 @@ ml_light_g_b_m_classification_model <- function(
     min_sum_hessian_in_leaf = "minSumHessianInLeaf",
     model_string = "modelString",
     num_batches = "numBatches",
+    num_grad_quant_bins = "numGradQuantBins",
     num_iterations = "numIterations",
     num_leaves = "numLeaves",
     num_tasks = "numTasks",
@@ -2018,14 +2027,17 @@ ml_light_g_b_m_classification_model <- function(
     predict_backend = "predictBackend",
     prediction_col = "predictionCol",
     probability_col = "probabilityCol",
+    quant_train_renew_leaf = "quantTrainRenewLeaf",
     raw_prediction_col = "rawPredictionCol",
     seed = "seed",
     slot_names = "slotNames",
     split_batch = "splitBatch",
+    stochastic_rounding = "stochasticRounding",
     thresholds = "thresholds",
     timeout = "timeout",
     top_k = "topK",
     use_barrier_execution_mode = "useBarrierExecutionMode",
+    use_quantized_grad = "useQuantizedGrad",
     validation_indicator_col = "validationIndicatorCol",
     verbosity = "verbosity",
     weight_col = "weightCol")
@@ -2053,7 +2065,7 @@ ml_light_g_b_m_classification_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2070,6 +2082,7 @@ ml_light_g_b_m_classification_model <- function(
 #' @param min_sum_hessian_in_leaf Min leaf hessian sum
 #' @param model_string Warm-start model string
 #' @param num_batches Split training into sequential batches (continuation-trained)
+#' @param num_grad_quant_bins LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
 #' @param num_iterations Number of boosting iterations
 #' @param num_leaves Max leaves per tree
 #' @param num_tasks Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -2079,14 +2092,17 @@ ml_light_g_b_m_classification_model <- function(
 #' @param predict_backend Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
 #' @param prediction_col The name of the prediction column
 #' @param probability_col Class probability output column
+#' @param quant_train_renew_leaf LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
 #' @param raw_prediction_col Raw margin output column
 #' @param seed Master random seed
 #' @param slot_names Feature vector slot names
 #' @param split_batch k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+#' @param stochastic_rounding LightGBM's stochastic_rounding: False rounds gradients to the nearest level
 #' @param thresholds Per-class prediction thresholds
 #' @param timeout Distributed initialization timeout in seconds
 #' @param top_k Top-k features voted per worker in voting_parallel
 #' @param use_barrier_execution_mode Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+#' @param use_quantized_grad LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
 #' @param validation_indicator_col Boolean column marking validation rows
 #' @param verbosity Native verbosity
 #' @param weight_col The name of the sample-weight column
@@ -2124,6 +2140,7 @@ ml_light_g_b_m_classifier <- function(
     min_sum_hessian_in_leaf = 0.001,
     model_string = "",
     num_batches = 0L,
+    num_grad_quant_bins = 0L,
     num_iterations = 100L,
     num_leaves = 31L,
     num_tasks = 0L,
@@ -2133,14 +2150,17 @@ ml_light_g_b_m_classifier <- function(
     predict_backend = "auto",
     prediction_col = "prediction",
     probability_col = "probability",
+    quant_train_renew_leaf = TRUE,
     raw_prediction_col = "rawPrediction",
     seed = 0L,
     slot_names = NULL,
     split_batch = 0L,
+    stochastic_rounding = TRUE,
     thresholds = NULL,
     timeout = 1200.0,
     top_k = 20L,
     use_barrier_execution_mode = FALSE,
+    use_quantized_grad = FALSE,
     validation_indicator_col = NULL,
     verbosity = 1L,
     weight_col = NULL) {
@@ -2177,6 +2197,7 @@ ml_light_g_b_m_classifier <- function(
     min_sum_hessian_in_leaf = "minSumHessianInLeaf",
     model_string = "modelString",
     num_batches = "numBatches",
+    num_grad_quant_bins = "numGradQuantBins",
     num_iterations = "numIterations",
     num_leaves = "numLeaves",
     num_tasks = "numTasks",
@@ -2186,14 +2207,17 @@ ml_light_g_b_m_classifier <- function(
     predict_backend = "predictBackend",
     prediction_col = "predictionCol",
     probability_col = "probabilityCol",
+    quant_train_renew_leaf = "quantTrainRenewLeaf",
     raw_prediction_col = "rawPredictionCol",
     seed = "seed",
     slot_names = "slotNames",
     split_batch = "splitBatch",
+    stochastic_rounding = "stochasticRounding",
     thresholds = "thresholds",
     timeout = "timeout",
     top_k = "topK",
     use_barrier_execution_mode = "useBarrierExecutionMode",
+    use_quantized_grad = "useQuantizedGrad",
     validation_indicator_col = "validationIndicatorCol",
     verbosity = "verbosity",
     weight_col = "weightCol")
@@ -2223,7 +2247,7 @@ ml_light_g_b_m_classifier <- function(
 #' @param group_col Query group column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2242,6 +2266,7 @@ ml_light_g_b_m_classifier <- function(
 #' @param min_sum_hessian_in_leaf Min leaf hessian sum
 #' @param model_string Warm-start model string
 #' @param num_batches Split training into sequential batches (continuation-trained)
+#' @param num_grad_quant_bins LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
 #' @param num_iterations Number of boosting iterations
 #' @param num_leaves Max leaves per tree
 #' @param num_tasks Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -2250,13 +2275,16 @@ ml_light_g_b_m_classifier <- function(
 #' @param parallelism Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
 #' @param predict_backend Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
 #' @param prediction_col The name of the prediction column
+#' @param quant_train_renew_leaf LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
 #' @param repartition_by_grouping_column Keep each query group within one worker shard
 #' @param seed Master random seed
 #' @param slot_names Feature vector slot names
 #' @param split_batch k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+#' @param stochastic_rounding LightGBM's stochastic_rounding: False rounds gradients to the nearest level
 #' @param timeout Distributed initialization timeout in seconds
 #' @param top_k Top-k features voted per worker in voting_parallel
 #' @param use_barrier_execution_mode Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+#' @param use_quantized_grad LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
 #' @param validation_indicator_col Boolean column marking validation rows
 #' @param verbosity Native verbosity
 #' @param weight_col The name of the sample-weight column
@@ -2298,6 +2326,7 @@ ml_light_g_b_m_ranker <- function(
     min_sum_hessian_in_leaf = 0.001,
     model_string = "",
     num_batches = 0L,
+    num_grad_quant_bins = 0L,
     num_iterations = 100L,
     num_leaves = 31L,
     num_tasks = 0L,
@@ -2306,13 +2335,16 @@ ml_light_g_b_m_ranker <- function(
     parallelism = "data_parallel",
     predict_backend = "auto",
     prediction_col = "prediction",
+    quant_train_renew_leaf = TRUE,
     repartition_by_grouping_column = TRUE,
     seed = 0L,
     slot_names = NULL,
     split_batch = 0L,
+    stochastic_rounding = TRUE,
     timeout = 1200.0,
     top_k = 20L,
     use_barrier_execution_mode = FALSE,
+    use_quantized_grad = FALSE,
     validation_indicator_col = NULL,
     verbosity = 1L,
     weight_col = NULL) {
@@ -2353,6 +2385,7 @@ ml_light_g_b_m_ranker <- function(
     min_sum_hessian_in_leaf = "minSumHessianInLeaf",
     model_string = "modelString",
     num_batches = "numBatches",
+    num_grad_quant_bins = "numGradQuantBins",
     num_iterations = "numIterations",
     num_leaves = "numLeaves",
     num_tasks = "numTasks",
@@ -2361,13 +2394,16 @@ ml_light_g_b_m_ranker <- function(
     parallelism = "parallelism",
     predict_backend = "predictBackend",
     prediction_col = "predictionCol",
+    quant_train_renew_leaf = "quantTrainRenewLeaf",
     repartition_by_grouping_column = "repartitionByGroupingColumn",
     seed = "seed",
     slot_names = "slotNames",
     split_batch = "splitBatch",
+    stochastic_rounding = "stochasticRounding",
     timeout = "timeout",
     top_k = "topK",
     use_barrier_execution_mode = "useBarrierExecutionMode",
+    use_quantized_grad = "useQuantizedGrad",
     validation_indicator_col = "validationIndicatorCol",
     verbosity = "verbosity",
     weight_col = "weightCol")
@@ -2396,7 +2432,7 @@ ml_light_g_b_m_ranker <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2413,6 +2449,7 @@ ml_light_g_b_m_ranker <- function(
 #' @param min_sum_hessian_in_leaf Min leaf hessian sum
 #' @param model_string Warm-start model string
 #' @param num_batches Split training into sequential batches (continuation-trained)
+#' @param num_grad_quant_bins LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
 #' @param num_iterations Number of boosting iterations
 #' @param num_leaves Max leaves per tree
 #' @param num_tasks Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -2421,12 +2458,15 @@ ml_light_g_b_m_ranker <- function(
 #' @param parallelism Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
 #' @param predict_backend Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
 #' @param prediction_col The name of the prediction column
+#' @param quant_train_renew_leaf LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
 #' @param seed Master random seed
 #' @param slot_names Feature vector slot names
 #' @param split_batch k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+#' @param stochastic_rounding LightGBM's stochastic_rounding: False rounds gradients to the nearest level
 #' @param timeout Distributed initialization timeout in seconds
 #' @param top_k Top-k features voted per worker in voting_parallel
 #' @param use_barrier_execution_mode Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+#' @param use_quantized_grad LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
 #' @param validation_indicator_col Boolean column marking validation rows
 #' @param verbosity Native verbosity
 #' @param weight_col The name of the sample-weight column
@@ -2465,6 +2505,7 @@ ml_light_g_b_m_ranker_model <- function(
     min_sum_hessian_in_leaf = 0.001,
     model_string = "",
     num_batches = 0L,
+    num_grad_quant_bins = 0L,
     num_iterations = 100L,
     num_leaves = 31L,
     num_tasks = 0L,
@@ -2473,12 +2514,15 @@ ml_light_g_b_m_ranker_model <- function(
     parallelism = "data_parallel",
     predict_backend = "auto",
     prediction_col = "prediction",
+    quant_train_renew_leaf = TRUE,
     seed = 0L,
     slot_names = NULL,
     split_batch = 0L,
+    stochastic_rounding = TRUE,
     timeout = 1200.0,
     top_k = 20L,
     use_barrier_execution_mode = FALSE,
+    use_quantized_grad = FALSE,
     validation_indicator_col = NULL,
     verbosity = 1L,
     weight_col = NULL) {
@@ -2516,6 +2560,7 @@ ml_light_g_b_m_ranker_model <- function(
     min_sum_hessian_in_leaf = "minSumHessianInLeaf",
     model_string = "modelString",
     num_batches = "numBatches",
+    num_grad_quant_bins = "numGradQuantBins",
     num_iterations = "numIterations",
     num_leaves = "numLeaves",
     num_tasks = "numTasks",
@@ -2524,12 +2569,15 @@ ml_light_g_b_m_ranker_model <- function(
     parallelism = "parallelism",
     predict_backend = "predictBackend",
     prediction_col = "predictionCol",
+    quant_train_renew_leaf = "quantTrainRenewLeaf",
     seed = "seed",
     slot_names = "slotNames",
     split_batch = "splitBatch",
+    stochastic_rounding = "stochasticRounding",
     timeout = "timeout",
     top_k = "topK",
     use_barrier_execution_mode = "useBarrierExecutionMode",
+    use_quantized_grad = "useQuantizedGrad",
     validation_indicator_col = "validationIndicatorCol",
     verbosity = "verbosity",
     weight_col = "weightCol")
@@ -2558,7 +2606,7 @@ ml_light_g_b_m_ranker_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2575,6 +2623,7 @@ ml_light_g_b_m_ranker_model <- function(
 #' @param min_sum_hessian_in_leaf Min leaf hessian sum
 #' @param model_string Warm-start model string
 #' @param num_batches Split training into sequential batches (continuation-trained)
+#' @param num_grad_quant_bins LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
 #' @param num_iterations Number of boosting iterations
 #' @param num_leaves Max leaves per tree
 #' @param num_tasks Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -2583,12 +2632,15 @@ ml_light_g_b_m_ranker_model <- function(
 #' @param parallelism Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
 #' @param predict_backend Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
 #' @param prediction_col The name of the prediction column
+#' @param quant_train_renew_leaf LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
 #' @param seed Master random seed
 #' @param slot_names Feature vector slot names
 #' @param split_batch k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+#' @param stochastic_rounding LightGBM's stochastic_rounding: False rounds gradients to the nearest level
 #' @param timeout Distributed initialization timeout in seconds
 #' @param top_k Top-k features voted per worker in voting_parallel
 #' @param use_barrier_execution_mode Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+#' @param use_quantized_grad LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
 #' @param validation_indicator_col Boolean column marking validation rows
 #' @param verbosity Native verbosity
 #' @param weight_col The name of the sample-weight column
@@ -2627,6 +2679,7 @@ ml_light_g_b_m_regression_model <- function(
     min_sum_hessian_in_leaf = 0.001,
     model_string = "",
     num_batches = 0L,
+    num_grad_quant_bins = 0L,
     num_iterations = 100L,
     num_leaves = 31L,
     num_tasks = 0L,
@@ -2635,12 +2688,15 @@ ml_light_g_b_m_regression_model <- function(
     parallelism = "data_parallel",
     predict_backend = "auto",
     prediction_col = "prediction",
+    quant_train_renew_leaf = TRUE,
     seed = 0L,
     slot_names = NULL,
     split_batch = 0L,
+    stochastic_rounding = TRUE,
     timeout = 1200.0,
     top_k = 20L,
     use_barrier_execution_mode = FALSE,
+    use_quantized_grad = FALSE,
     validation_indicator_col = NULL,
     verbosity = 1L,
     weight_col = NULL) {
@@ -2678,6 +2734,7 @@ ml_light_g_b_m_regression_model <- function(
     min_sum_hessian_in_leaf = "minSumHessianInLeaf",
     model_string = "modelString",
     num_batches = "numBatches",
+    num_grad_quant_bins = "numGradQuantBins",
     num_iterations = "numIterations",
     num_leaves = "numLeaves",
     num_tasks = "numTasks",
@@ -2686,12 +2743,15 @@ ml_light_g_b_m_regression_model <- function(
     parallelism = "parallelism",
     predict_backend = "predictBackend",
     prediction_col = "predictionCol",
+    quant_train_renew_leaf = "quantTrainRenewLeaf",
     seed = "seed",
     slot_names = "slotNames",
     split_batch = "splitBatch",
+    stochastic_rounding = "stochasticRounding",
     timeout = "timeout",
     top_k = "topK",
     use_barrier_execution_mode = "useBarrierExecutionMode",
+    use_quantized_grad = "useQuantizedGrad",
     validation_indicator_col = "validationIndicatorCol",
     verbosity = "verbosity",
     weight_col = "weightCol")
@@ -2720,7 +2780,7 @@ ml_light_g_b_m_regression_model <- function(
 #' @param features_col The name of the features column
 #' @param grow_policy lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
 #' @param hist_merge Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+#' @param hist_quantize Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
 #' @param init_score_col Initial (margin) score column
 #' @param is_provide_training_metric Record metrics on training data too
 #' @param is_unbalance Reweight unbalanced binary labels
@@ -2737,6 +2797,7 @@ ml_light_g_b_m_regression_model <- function(
 #' @param min_sum_hessian_in_leaf Min leaf hessian sum
 #' @param model_string Warm-start model string
 #' @param num_batches Split training into sequential batches (continuation-trained)
+#' @param num_grad_quant_bins LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
 #' @param num_iterations Number of boosting iterations
 #' @param num_leaves Max leaves per tree
 #' @param num_tasks Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -2745,13 +2806,16 @@ ml_light_g_b_m_regression_model <- function(
 #' @param parallelism Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
 #' @param predict_backend Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
 #' @param prediction_col The name of the prediction column
+#' @param quant_train_renew_leaf LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
 #' @param seed Master random seed
 #' @param slot_names Feature vector slot names
 #' @param split_batch k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+#' @param stochastic_rounding LightGBM's stochastic_rounding: False rounds gradients to the nearest level
 #' @param timeout Distributed initialization timeout in seconds
 #' @param top_k Top-k features voted per worker in voting_parallel
 #' @param tweedie_variance_power Tweedie variance power (1..2)
 #' @param use_barrier_execution_mode Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+#' @param use_quantized_grad LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
 #' @param validation_indicator_col Boolean column marking validation rows
 #' @param verbosity Native verbosity
 #' @param weight_col The name of the sample-weight column
@@ -2790,6 +2854,7 @@ ml_light_g_b_m_regressor <- function(
     min_sum_hessian_in_leaf = 0.001,
     model_string = "",
     num_batches = 0L,
+    num_grad_quant_bins = 0L,
     num_iterations = 100L,
     num_leaves = 31L,
     num_tasks = 0L,
@@ -2798,13 +2863,16 @@ ml_light_g_b_m_regressor <- function(
     parallelism = "data_parallel",
     predict_backend = "auto",
     prediction_col = "prediction",
+    quant_train_renew_leaf = TRUE,
     seed = 0L,
     slot_names = NULL,
     split_batch = 0L,
+    stochastic_rounding = TRUE,
     timeout = 1200.0,
     top_k = 20L,
     tweedie_variance_power = 1.5,
     use_barrier_execution_mode = FALSE,
+    use_quantized_grad = FALSE,
     validation_indicator_col = NULL,
     verbosity = 1L,
     weight_col = NULL) {
@@ -2842,6 +2910,7 @@ ml_light_g_b_m_regressor <- function(
     min_sum_hessian_in_leaf = "minSumHessianInLeaf",
     model_string = "modelString",
     num_batches = "numBatches",
+    num_grad_quant_bins = "numGradQuantBins",
     num_iterations = "numIterations",
     num_leaves = "numLeaves",
     num_tasks = "numTasks",
@@ -2850,13 +2919,16 @@ ml_light_g_b_m_regressor <- function(
     parallelism = "parallelism",
     predict_backend = "predictBackend",
     prediction_col = "predictionCol",
+    quant_train_renew_leaf = "quantTrainRenewLeaf",
     seed = "seed",
     slot_names = "slotNames",
     split_batch = "splitBatch",
+    stochastic_rounding = "stochasticRounding",
     timeout = "timeout",
     top_k = "topK",
     tweedie_variance_power = "tweedieVariancePower",
     use_barrier_execution_mode = "useBarrierExecutionMode",
+    use_quantized_grad = "useQuantizedGrad",
     validation_indicator_col = "validationIndicatorCol",
     verbosity = "verbosity",
     weight_col = "weightCol")

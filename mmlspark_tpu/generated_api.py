@@ -1156,7 +1156,7 @@ class LightGBMClassificationModel(_LightGBMClassificationModel):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1173,6 +1173,7 @@ class LightGBMClassificationModel(_LightGBMClassificationModel):
       minSumHessianInLeaf: Min leaf hessian sum
       modelString: Warm-start model string
       numBatches: Split training into sequential batches (continuation-trained)
+      numGradQuantBins: LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
       numIterations: Number of boosting iterations
       numLeaves: Max leaves per tree
       numTasks: Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1182,20 +1183,23 @@ class LightGBMClassificationModel(_LightGBMClassificationModel):
       predictBackend: Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
       predictionCol: The name of the prediction column
       probabilityCol: Class probability output column
+      quantTrainRenewLeaf: LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
       rawPredictionCol: Raw margin output column
       seed: Master random seed
       slotNames: Feature vector slot names
       splitBatch: k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+      stochasticRounding: LightGBM's stochastic_rounding: False rounds gradients to the nearest level
       thresholds: Per-class prediction thresholds
       timeout: Distributed initialization timeout in seconds
       topK: Top-k features voted per worker in voting_parallel
       useBarrierExecutionMode: Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+      useQuantizedGrad: LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
       validationIndicatorCol: Boolean column marking validation rows
       verbosity: Native verbosity
       weightCol: The name of the sample-weight column
     """
 
-    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, booster=_UNSET, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', probabilityCol='probability', rawPredictionCol='rawPrediction', seed=0, slotNames=None, splitBatch=0, thresholds=None, timeout=1200.0, topK=20, useBarrierExecutionMode=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
+    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, booster=_UNSET, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numGradQuantBins=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', probabilityCol='probability', quantTrainRenewLeaf=True, rawPredictionCol='rawPrediction', seed=0, slotNames=None, splitBatch=0, stochasticRounding=True, thresholds=None, timeout=1200.0, topK=20, useBarrierExecutionMode=False, useQuantizedGrad=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
         kw = {k: v for k, v in locals().items()
               if k not in ('self', '__class__') and v is not _UNSET}
         super().__init__(**kw)
@@ -1220,7 +1224,7 @@ class LightGBMClassifier(_LightGBMClassifier):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1237,6 +1241,7 @@ class LightGBMClassifier(_LightGBMClassifier):
       minSumHessianInLeaf: Min leaf hessian sum
       modelString: Warm-start model string
       numBatches: Split training into sequential batches (continuation-trained)
+      numGradQuantBins: LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
       numIterations: Number of boosting iterations
       numLeaves: Max leaves per tree
       numTasks: Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1246,20 +1251,23 @@ class LightGBMClassifier(_LightGBMClassifier):
       predictBackend: Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
       predictionCol: The name of the prediction column
       probabilityCol: Class probability output column
+      quantTrainRenewLeaf: LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
       rawPredictionCol: Raw margin output column
       seed: Master random seed
       slotNames: Feature vector slot names
       splitBatch: k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+      stochasticRounding: LightGBM's stochastic_rounding: False rounds gradients to the nearest level
       thresholds: Per-class prediction thresholds
       timeout: Distributed initialization timeout in seconds
       topK: Top-k features voted per worker in voting_parallel
       useBarrierExecutionMode: Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+      useQuantizedGrad: LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
       validationIndicatorCol: Boolean column marking validation rows
       verbosity: Native verbosity
       weightCol: The name of the sample-weight column
     """
 
-    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='binary', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', probabilityCol='probability', rawPredictionCol='rawPrediction', seed=0, slotNames=None, splitBatch=0, thresholds=None, timeout=1200.0, topK=20, useBarrierExecutionMode=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
+    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numGradQuantBins=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='binary', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', probabilityCol='probability', quantTrainRenewLeaf=True, rawPredictionCol='rawPrediction', seed=0, slotNames=None, splitBatch=0, stochasticRounding=True, thresholds=None, timeout=1200.0, topK=20, useBarrierExecutionMode=False, useQuantizedGrad=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
         kw = {k: v for k, v in locals().items()
               if k not in ('self', '__class__') and v is not _UNSET}
         super().__init__(**kw)
@@ -1286,7 +1294,7 @@ class LightGBMRanker(_LightGBMRanker):
       groupCol: Query group column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1305,6 +1313,7 @@ class LightGBMRanker(_LightGBMRanker):
       minSumHessianInLeaf: Min leaf hessian sum
       modelString: Warm-start model string
       numBatches: Split training into sequential batches (continuation-trained)
+      numGradQuantBins: LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
       numIterations: Number of boosting iterations
       numLeaves: Max leaves per tree
       numTasks: Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1313,19 +1322,22 @@ class LightGBMRanker(_LightGBMRanker):
       parallelism: Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
       predictBackend: Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
       predictionCol: The name of the prediction column
+      quantTrainRenewLeaf: LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
       repartitionByGroupingColumn: Keep each query group within one worker shard
       seed: Master random seed
       slotNames: Feature vector slot names
       splitBatch: k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+      stochasticRounding: LightGBM's stochastic_rounding: False rounds gradients to the nearest level
       timeout: Distributed initialization timeout in seconds
       topK: Top-k features voted per worker in voting_parallel
       useBarrierExecutionMode: Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+      useQuantizedGrad: LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
       validationIndicatorCol: Boolean column marking validation rows
       verbosity: Native verbosity
       weightCol: The name of the sample-weight column
     """
 
-    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, evalAt=[1, 2, 3, 4, 5], featureFraction=1.0, featuresCol='features', groupCol='group', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', labelGain=None, lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, maxPosition=20, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='lambdarank', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', repartitionByGroupingColumn=True, seed=0, slotNames=None, splitBatch=0, timeout=1200.0, topK=20, useBarrierExecutionMode=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
+    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, evalAt=[1, 2, 3, 4, 5], featureFraction=1.0, featuresCol='features', groupCol='group', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', labelGain=None, lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, maxPosition=20, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numGradQuantBins=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='lambdarank', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', quantTrainRenewLeaf=True, repartitionByGroupingColumn=True, seed=0, slotNames=None, splitBatch=0, stochasticRounding=True, timeout=1200.0, topK=20, useBarrierExecutionMode=False, useQuantizedGrad=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
         kw = {k: v for k, v in locals().items()
               if k not in ('self', '__class__') and v is not _UNSET}
         super().__init__(**kw)
@@ -1351,7 +1363,7 @@ class LightGBMRankerModel(_LightGBMRankerModel):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1368,6 +1380,7 @@ class LightGBMRankerModel(_LightGBMRankerModel):
       minSumHessianInLeaf: Min leaf hessian sum
       modelString: Warm-start model string
       numBatches: Split training into sequential batches (continuation-trained)
+      numGradQuantBins: LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
       numIterations: Number of boosting iterations
       numLeaves: Max leaves per tree
       numTasks: Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1376,18 +1389,21 @@ class LightGBMRankerModel(_LightGBMRankerModel):
       parallelism: Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
       predictBackend: Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
       predictionCol: The name of the prediction column
+      quantTrainRenewLeaf: LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
       seed: Master random seed
       slotNames: Feature vector slot names
       splitBatch: k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+      stochasticRounding: LightGBM's stochastic_rounding: False rounds gradients to the nearest level
       timeout: Distributed initialization timeout in seconds
       topK: Top-k features voted per worker in voting_parallel
       useBarrierExecutionMode: Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+      useQuantizedGrad: LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
       validationIndicatorCol: Boolean column marking validation rows
       verbosity: Native verbosity
       weightCol: The name of the sample-weight column
     """
 
-    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, booster=_UNSET, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', seed=0, slotNames=None, splitBatch=0, timeout=1200.0, topK=20, useBarrierExecutionMode=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
+    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, booster=_UNSET, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numGradQuantBins=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', quantTrainRenewLeaf=True, seed=0, slotNames=None, splitBatch=0, stochasticRounding=True, timeout=1200.0, topK=20, useBarrierExecutionMode=False, useQuantizedGrad=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
         kw = {k: v for k, v in locals().items()
               if k not in ('self', '__class__') and v is not _UNSET}
         super().__init__(**kw)
@@ -1413,7 +1429,7 @@ class LightGBMRegressionModel(_LightGBMRegressionModel):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1430,6 +1446,7 @@ class LightGBMRegressionModel(_LightGBMRegressionModel):
       minSumHessianInLeaf: Min leaf hessian sum
       modelString: Warm-start model string
       numBatches: Split training into sequential batches (continuation-trained)
+      numGradQuantBins: LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
       numIterations: Number of boosting iterations
       numLeaves: Max leaves per tree
       numTasks: Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1438,18 +1455,21 @@ class LightGBMRegressionModel(_LightGBMRegressionModel):
       parallelism: Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
       predictBackend: Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
       predictionCol: The name of the prediction column
+      quantTrainRenewLeaf: LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
       seed: Master random seed
       slotNames: Feature vector slot names
       splitBatch: k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+      stochasticRounding: LightGBM's stochastic_rounding: False rounds gradients to the nearest level
       timeout: Distributed initialization timeout in seconds
       topK: Top-k features voted per worker in voting_parallel
       useBarrierExecutionMode: Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+      useQuantizedGrad: LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
       validationIndicatorCol: Boolean column marking validation rows
       verbosity: Native verbosity
       weightCol: The name of the sample-weight column
     """
 
-    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, booster=_UNSET, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', seed=0, slotNames=None, splitBatch=0, timeout=1200.0, topK=20, useBarrierExecutionMode=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
+    def __init__(self, *, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, booster=_UNSET, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numGradQuantBins=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', quantTrainRenewLeaf=True, seed=0, slotNames=None, splitBatch=0, stochasticRounding=True, timeout=1200.0, topK=20, useBarrierExecutionMode=False, useQuantizedGrad=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
         kw = {k: v for k, v in locals().items()
               if k not in ('self', '__class__') and v is not _UNSET}
         super().__init__(**kw)
@@ -1475,7 +1495,7 @@ class LightGBMRegressor(_LightGBMRegressor):
       featuresCol: The name of the features column
       growPolicy: lossguide (leaf-wise; auto-batches splits on TPU — see splitBatch) | lossguide_exact (LightGBM's one-split-per-pass sequence, never batched) | depthwise (level-batched histograms, one pass per level)
       histMerge: Distributed histogram-merge strategy: auto (reduce_scatter when the mesh/feature shape profits — the benchmarked default, see BASELINE.md) | allreduce (every device receives the full merged histogram) | reduce_scatter (each device receives only its feature slice + a best-split allgather)
-      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to ±127 buckets with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
+      histQuantize: Quantized training wire/accumulator: off (default — bitwise the f32 path) | on (resolved to int16) | int16 | int32.  Quantizes per-row grad/hess to integer buckets (numGradQuantBins levels; ±127 where that is not set) with seeded stochastic rounding, accumulates int32 histograms and merges shards over an integer collective wire (f32 winner refinement keeps AUC parity)
       initScoreCol: Initial (margin) score column
       isProvideTrainingMetric: Record metrics on training data too
       isUnbalance: Reweight unbalanced binary labels
@@ -1492,6 +1512,7 @@ class LightGBMRegressor(_LightGBMRegressor):
       minSumHessianInLeaf: Min leaf hessian sum
       modelString: Warm-start model string
       numBatches: Split training into sequential batches (continuation-trained)
+      numGradQuantBins: LightGBM's num_grad_quant_bins: gradients in [-bins/2, bins/2] and hessians in [0, bins] integer levels (LightGBM's default is 4); 0 = not given, the engine's 127 a side
       numIterations: Number of boosting iterations
       numLeaves: Max leaves per tree
       numTasks: Cap on parallel workers; 0 = one per DataFrame partition (reference: numWorkers = min(numTasks, partitions))
@@ -1500,19 +1521,22 @@ class LightGBMRegressor(_LightGBMRegressor):
       parallelism: Tree learner parallelism: data_parallel|voting_parallel|serial|feature_parallel
       predictBackend: Predict traversal backend: auto (pallas on TPU, packed elsewhere; re-resolved against the backend each predict runs on) | packed (depth-stepped device-resident node table) | pallas (fused VMEM row-tile kernel, TPU) | pallas_interpret (that kernel interpreted on CPU — tests/parity) | scan (legacy sequential per-tree lax.scan).  All backends score bitwise-identically.
       predictionCol: The name of the prediction column
+      quantTrainRenewLeaf: LightGBM's quant_train_renew_leaf: leaf values from the rows' exact float32 gradient sums, which this engine always does; False is refused
       seed: Master random seed
       slotNames: Feature vector slot names
       splitBatch: k-batched best-first growth: apply up to k best splits per histogram pass (0 = auto: 8 on the TPU lossguide path — the benchmarked default, see BASELINE.md — policy default elsewhere; 1 = exact lossguide; -1 = never batch)
+      stochasticRounding: LightGBM's stochastic_rounding: False rounds gradients to the nearest level
       timeout: Distributed initialization timeout in seconds
       topK: Top-k features voted per worker in voting_parallel
       tweedieVariancePower: Tweedie variance power (1..2)
       useBarrierExecutionMode: Gang-schedule training (the SPMD program launch is inherently gang-scheduled on TPU; kept for API parity)
+      useQuantizedGrad: LightGBM's use_quantized_grad: quantized training on or off, the same switch as histQuantize (set one, or make them agree)
       validationIndicatorCol: Boolean column marking validation rows
       verbosity: Native verbosity
       weightCol: The name of the sample-weight column
     """
 
-    def __init__(self, *, alpha=0.9, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', seed=0, slotNames=None, splitBatch=0, timeout=1200.0, topK=20, tweedieVariancePower=1.5, useBarrierExecutionMode=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
+    def __init__(self, *, alpha=0.9, baggingFraction=1.0, baggingFreq=0, baggingSeed=3, boostFromAverage=True, boostingType='gbdt', categoricalSlotIndexes=None, categoricalSlotNames=None, defaultListenPort=12400, deviceType='tpu', driverListenPort=0, earlyStoppingRound=0, featureFraction=1.0, featuresCol='features', growPolicy='lossguide', histMerge='auto', histQuantize='off', initScoreCol=_UNSET, isProvideTrainingMetric=False, isUnbalance=False, labelCol='label', lambdaL1=0.0, lambdaL2=0.0, leafPredictionCol='', learningRate=0.1, matrixType='auto', maxBin=255, maxDepth=-1, metric='', minDataInLeaf=20, minSumHessianInLeaf=0.001, modelString='', numBatches=0, numGradQuantBins=0, numIterations=100, numLeaves=31, numTasks=0, numThreads=0, objective='regression', parallelism='data_parallel', predictBackend='auto', predictionCol='prediction', quantTrainRenewLeaf=True, seed=0, slotNames=None, splitBatch=0, stochasticRounding=True, timeout=1200.0, topK=20, tweedieVariancePower=1.5, useBarrierExecutionMode=False, useQuantizedGrad=False, validationIndicatorCol=_UNSET, verbosity=1, weightCol=_UNSET):
         kw = {k: v for k, v in locals().items()
               if k not in ('self', '__class__') and v is not _UNSET}
         super().__init__(**kw)
