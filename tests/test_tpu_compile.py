@@ -33,20 +33,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def no_persistent_cache():
-    # a deviceless compile is written to the persistent cache but cannot be
-    # read back without a chip: the next one would warn and compile again
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
+# A deviceless compile is written to the persistent cache and cannot be read
+# back without a chip: the next run warns and compiles again, which is all
+# this asks of it.  (Turning the cache off around the compiles means a
+# ``reset_cache()``, after which later tests of the same worker pay the
+# cache's set-up inside what they measure: tests/test_streaming.py's host
+# memory test then fails.)
+@pytest.mark.filterwarnings("ignore:Error reading persistent compilation cache entry")
 @pytest.mark.parametrize(
     "kernel, vals_dtype, cols, out_dtype",
     [
@@ -60,7 +53,7 @@ def no_persistent_cache():
         pytest.param("_pallas_hist_by_leaf_nibble", jnp.float32, 40, "f32", id="float-nibble"),
     ],
 )
-def test_histogram_kernel_compiles_for_the_chip(one_chip, no_persistent_cache, kernel, vals_dtype, cols, out_dtype):
+def test_histogram_kernel_compiles_for_the_chip(one_chip, kernel, vals_dtype, cols, out_dtype):
     from mmlspark_tpu.ops import pallas_hist
 
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
