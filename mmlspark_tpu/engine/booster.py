@@ -19,6 +19,7 @@ upstream C++ ``src/boosting/gbdt.cpp``).  Differences by design:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import os
 import time
@@ -3283,6 +3284,8 @@ def _train_impl(
                 quant_levels="x".join(str(v) for v in qlevels),
                 quant_wire=cfg.hist_quantize,
             )
+            if quant_ledger:
+                sp_program.set(quant_bucket_body="+".join(quant_ledger["bodies"]) or "scatter")
         phases.close()  # the dispatches are booster.train's own children
         while n_done < n_iter and stop_at is None:
             t_chunk = time.perf_counter()
@@ -3312,11 +3315,12 @@ def _train_impl(
                 obs.inc("train.merge_bytes", float(nbytes * c), op=op)
             for name, per_iter in (rank_counts or {}).items():
                 obs.inc(name, float(per_iter * c))
-            for kind, per_iter in (quant_ledger or {}).items():
-                if kind == "refine_cols":
-                    obs.inc("train.quant_refine_cols", float(per_iter * c))
-                else:
-                    obs.inc("train.quant_passes", float(per_iter * c), kind=kind)
+            if quant_ledger:
+                for kind in ("bucket", "refine"):
+                    obs.inc("train.quant_passes", float(quant_ledger[kind] * c), kind=kind)
+                obs.inc("train.quant_refine_cols", float(quant_ledger["refine_cols"] * c))
+                for body, per_iter in quant_ledger["bodies"].items():
+                    obs.inc("train.quant_bucket_body", float(per_iter * c), body=body)
             if quantize_on:
                 trees_c, vsnap_c, qsc_c = scan_ys
             else:
@@ -3635,39 +3639,52 @@ def _grow_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
     return collective_ledger(jaxpr, while_trips=full_tree_passes(gcfg))
 
 
-def scope_entries(jaxpr, scopes, while_trips: int = 1) -> dict:
+def scope_entries(jaxpr, scopes, while_trips: int = 1, callees=()) -> dict:
     """``{scope: entries}``: how often one execution of a traced program
     enters each ``jax.named_scope`` of ``scopes``.  Equations that follow
     one another under a scope, at one level of the program, are one entry;
     a ``scan`` multiplies by its length, a ``while`` loop by
     ``while_trips`` (its count is not in the program: see
-    ``collective_ledger``)."""
+    ``collective_ledger``).  An entry that calls, at any depth, a jitted
+    function named in ``callees`` also counts under ``(scope, name)``."""
     out = dict.fromkeys(scopes, 0)
 
-    def walk(jp, mult):
-        inside = None
-        for eqn in getattr(jp, "jaxpr", jp).eqns:
-            names = str(eqn.source_info.name_stack).split("/")
-            here = next((s for s in scopes if s in names), None)
-            if here is not None:
-                if here != inside:
-                    out[here] += mult
-                inside = here
-                continue
-            inside = None
-            name = eqn.primitive.name
-            inner = mult
-            if name == "scan":
-                inner = mult * int(eqn.params["length"])
-            elif name == "while":
-                inner = mult * int(while_trips)
-            for v in eqn.params.values():
-                for sub in v if isinstance(v, (tuple, list)) else (v,):
-                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
-                        walk(sub, inner)
+    def scope_of(eqn):
+        names = str(eqn.source_info.name_stack).split("/")
+        return next((s for s in scopes if s in names), None)
 
-    walk(jaxpr, 1)
+    def called(eqns, found):
+        for eqn in eqns:
+            if eqn.primitive.name in ("jit", "pjit") and eqn.params.get("name") in callees:
+                found.add(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                called(sub.eqns, found)
+        return found
+
+    def walk(jp, mult):
+        for here, run in itertools.groupby(jp.eqns, scope_of):
+            if here is not None:
+                out[here] += mult
+                for name in called(run, set()) if callees else ():
+                    out[here, name] = out.get((here, name), 0) + mult
+                continue
+            for eqn in run:
+                name = eqn.primitive.name
+                inner = mult
+                if name == "scan":
+                    inner = mult * int(eqn.params["length"])
+                elif name == "while":
+                    inner = mult * int(while_trips)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, inner)
+
+    walk(jaxpr.jaxpr, 1)
     return out
+
+
+# the Pallas wrappers a by-leaf bucket build can reach (ops/pallas_hist.py),
+# under the names the counter and the span give them
+_BUCKET_BODIES = {"_pallas_hist_by_leaf_nibble": "nibble", "_pallas_hist_by_leaf": "by_leaf"}
 
 
 def _quant_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int) -> dict:
@@ -3675,18 +3692,25 @@ def _quant_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int) -> dict
     ``bucket`` histogram builds (the ``quant_hist`` scope), float32
     ``refine`` passes (``quant_refine``) and the winner columns those
     re-accumulate (``refine_cols``: a window's slots a pass of the windowed
-    grower, one a step of the lossguide grower).  Counted like the merge's
-    ledger, full trees: a tree that stops early is counted high."""
+    grower, one a step of the lossguide grower), and under ``bodies`` the
+    bucket builds by the kernel body they reached (``nibble``, ``by_leaf``;
+    none on the scatter backend).  Counted like the merge's ledger, full
+    trees: a tree that stops early is counted high."""
     from mmlspark_tpu.engine.tree import full_tree_passes, windowed_grower
 
     runs = scope_entries(
         _grow_jaxpr(grow, K, bins_dev, F_mask, True),
         ("quant_hist", "quant_refine"), while_trips=full_tree_passes(gcfg),
+        callees=tuple(_BUCKET_BODIES),
     )
     cols = gcfg.level_window if windowed_grower(gcfg) else 1
     return {
         "bucket": runs["quant_hist"], "refine": runs["quant_refine"],
         "refine_cols": runs["quant_refine"] * cols,
+        "bodies": {
+            body: runs["quant_hist", name]
+            for name, body in _BUCKET_BODIES.items() if ("quant_hist", name) in runs
+        },
     }
 
 

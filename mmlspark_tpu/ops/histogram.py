@@ -575,13 +575,13 @@ def build_histogram_by_leaf(
 
         # Small windows starve the plain kernel's matmul M = 3·W; the
         # factorized hi/lo variant doubles M (same results to float-summation
-        # ulps — parity tested) and wins measurably up to M ≈ 128 (W≤21 at B=256:
-        # 7.5 → 4.9 ms/pass at W=12, 262k×64 on v5e).  Bucket builds take
-        # the plain kernel whatever the window: the nibble factorization's
-        # hi/lo recombination is a float trick with no int32 twin (and the
-        # int path is already exact, so there is nothing for it to tighten).
+        # ulps, and of buckets the same int32 sums bit for bit — parity
+        # tested) and wins measurably up to M ≈ 128 (W≤21 at B=256:
+        # 7.5 → 4.9 ms/pass at W=12, 262k×64 on v5e).  One rule on shapes
+        # for both value dtypes: the factoring splits a one-hot and its
+        # un-factoring is a reshape, neither cares what is summed.
         h = (num_bins + 127) // 128
-        nibble = not quant and num_bins > 128 and 3 * num_leaves * h <= 128
+        nibble = num_bins > 128 and 3 * num_leaves * h <= 128
         fn = functools.partial(
             pallas_hist_by_leaf_nibble_chunk if nibble else pallas_hist_by_leaf_chunk,
             num_leaves=num_leaves, num_bins=num_bins, precision=precision,

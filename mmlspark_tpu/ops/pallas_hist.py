@@ -22,7 +22,8 @@ Layout choices (TPU tiling wants the last dim lane-sized):
   int32 immediately after the block load — 1-byte indices in HBM and on
   the DMA, int32 only in VMEM;
 - vals arrive channel-major (3, rows) — rows on lanes — float32, or the
-  int16 buckets of quantized training (see "Value dtype" below);
+  int16 buckets of quantized training: all three bodies serve both (see
+  "Value dtype" below);
 - bin one-hots are built PER FEATURE as clean 2-D (B, rows) iota-compares:
   a fused (bf, B, rows)→(bf·B, rows) one-hot needs a Mosaic lane relayout
   that traced at ~10x the matmul cost;
@@ -68,8 +69,9 @@ def _pow2_floor(x: int) -> int:
 
 # ---------------------------------------------------------------------------
 # Value dtype (ISSUE 9 — quantized training).  One body per kernel serves
-# both: the dtype of ``vals`` is static at trace time and picks the
-# accumulator, so the float build's traced body holds no integer op.
+# both, in all three kernels (plain, by-leaf, factorized by-leaf): the
+# dtype of ``vals`` is static at trace time and picks the accumulator, so
+# the float build's traced body holds no integer op.
 #
 # Integer ``vals`` are the int16 buckets of ops.histogram.quantize_hist_vals.
 # Layout note, int accumulator tile: the row values arrive as an int16
@@ -86,9 +88,13 @@ def _pow2_floor(x: int) -> int:
 # accumulator; the cast to int32 after each row block is therefore exact,
 # and int32 grid accumulation across row blocks is associative — the
 # whole build is bit-reproducible regardless of precision mode, chunking,
-# or merge order.  headroom: rows × largest bucket < 2³¹ per shard is
-# attested statically by ops.histogram.quantize_wire_plan before any
-# kernel runs.
+# merge order, or body: the factorized kernel's operands are the same
+# one-hots and buckets with the bin one-hot split in two (hi into M, lo
+# on N), its sub-block sums the same whole numbers ≤ rm·QMAX, and its
+# un-factoring a reshape, so a small window's bucket build takes it like
+# the float build (ops/histogram.py routes by shape alone).  headroom:
+# rows × largest bucket < 2³¹ per shard is attested statically by
+# ops.histogram.quantize_wire_plan before any kernel runs.
 # ---------------------------------------------------------------------------
 def _tile_dtype(vals_dtype):
     """The dtype row values cross the DMA in: int16 buckets, else f32."""
@@ -454,6 +460,9 @@ def pallas_hist_by_leaf_chunk(
 # the MXU utilization at W≤16, and the (B, rm) one-hot build shrinks to
 # (W·H, rm) + (LO, rm).  Only pays when W is small: at W=32 the plain
 # kernel is already M-saturated and the per-feature lhs build dominates.
+# Nothing in it is float-specific: int16 buckets go through the same
+# products into an int32 accumulator (see "Value dtype"), where the plain
+# kernel at W=8 took 2.57x the time a row and pass (PERF.md §5).
 # ---------------------------------------------------------------------------
 _NIBBLE_LO = 128
 
@@ -466,13 +475,16 @@ def _hist_leaf_nibble_kernel(
     bf, bm = bins_ref.shape
     H = (num_bins + _NIBBLE_LO - 1) // _NIBBLE_LO
     M = 3 * num_leaves * H
+    quant = _is_bucket(vals_ref.dtype)
 
     def sub(s, acc):
         sl = pl.ds(s * rm, rm)
         # uint8 at ≤256 bins: 1-byte DMA, widened in VMEM (the >>/& bit
         # ops below need the widening anyway — hi spans [0, 2) at B=256)
         bins = bins_ref[:, sl].astype(jnp.int32)  # (bf, rm)
-        vals = vals_ref[:, sl]  # (3, rm) f32
+        vals = vals_ref[:, sl]  # (3, rm) f32 | int16 buckets
+        if quant:
+            vals = vals.astype(jnp.float32)
         leaf = leaf_ref[0, sl]  # (rm,) int32
         # All operands keep ROWS ON LANES (rm trailing) — mixed-orientation
         # tiles with a 24-wide trailing dim crashed the Mosaic compile.
@@ -500,10 +512,17 @@ def _hist_leaf_nibble_kernel(
                     precision=precision,
                 )  # (3·W·H, LO)
             )
-        return acc + jnp.concatenate(parts, axis=1)  # (M, bf·LO)
+        part = jnp.concatenate(parts, axis=1)  # (M, bf·LO)
+        if quant:
+            # integer-valued f32 partial sums ≤ rm·QMAX ≪ 2²⁴ → exact cast
+            part = part.astype(jnp.int32)
+        return acc + part
 
     part = jax.lax.fori_loop(
-        0, bm // rm, sub, jnp.zeros((M, bf * _NIBBLE_LO), jnp.float32)
+        0, bm // rm, sub,
+        # headroom: bm·QMAX ≪ 2³¹ per block of buckets; the cross-block
+        # int32 total is bounded by quantize_wire_plan's static rows × bucket check
+        jnp.zeros((M, bf * _NIBBLE_LO), jnp.int32 if quant else jnp.float32),
     )
 
     @pl.when(i == 0)
@@ -540,7 +559,12 @@ def _pallas_hist_by_leaf_nibble(
             pl.BlockSpec((1, bm), lambda j, i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, M, bf * _NIBBLE_LO), lambda j, i: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F // bf, M, bf * _NIBBLE_LO), jnp.float32),
+        # headroom: the int32 grid accumulator of a bucket build, as in
+        # _pallas_hist_by_leaf (quantize_wire_plan's static check)
+        out_shape=jax.ShapeDtypeStruct(
+            (F // bf, M, bf * _NIBBLE_LO),
+            jnp.int32 if _is_bucket(vals.dtype) else jnp.float32,
+        ),
         compiler_params=_by_leaf_compiler_params(
             num_leaves, bf, H * _NIBBLE_LO
         ),
@@ -559,10 +583,11 @@ def pallas_hist_by_leaf_nibble_chunk(
     bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
 ) -> jnp.ndarray:
     """Factorized-bin variant of :func:`pallas_hist_by_leaf_chunk` — same
-    contract for float ``vals_c``, intended for small windows (see module
-    comment above).  The hi/lo recombination is a float trick with no
-    integer form: ops/histogram.py sends bucket builds to the plain
-    kernel, whose int32 sums are exact already."""
+    contract, float32 sums of float ``vals_c`` and int32 sums of int16
+    bucket ``vals_c``, intended for small windows (see module comment
+    above).  The hi/lo factoring is a one-hot split and its un-factoring a
+    reshape: of buckets it gives the plain kernel's int32 sums bit for
+    bit."""
     bins_t, vals_c, leaf_row, bm, bf, rm, F, interp = _prep_by_leaf_chunk(
         bins_t, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm
     )

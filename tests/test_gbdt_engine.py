@@ -267,20 +267,24 @@ class TestSharedChunkBodies:
         walk(jax.make_jaxpr(fn)(*args).jaxpr)
         return found
 
-    @pytest.mark.parametrize("dtype", ["float32", "int16"])
-    @pytest.mark.parametrize("backend", ["scatter", "pallas"])
-    @pytest.mark.parametrize("kind", ["plain", "by_leaf"])
-    def test_body_sums_in_the_accumulator_its_vals_ask_for(self, kind, backend, dtype):
+    @pytest.mark.parametrize(
+        "kind,backend,dtype,B,W",
+        [(k, b, d, 256, 8) for k in ("plain", "by_leaf") for b in ("scatter", "pallas") for d in ("float32", "int16")]
+        # a wider window (M = 3*W*2 over 128) and 128 bins (nothing to factor) keep the plain by-leaf body
+        + [("by_leaf", "pallas", d, B, W) for d in ("float32", "int16") for B, W in ((256, 32), (128, 8))],
+    )
+    def test_body_sums_in_the_accumulator_its_vals_ask_for(self, kind, backend, dtype, B, W):
         """The shared chunk body gives numpy's sums: int16 buckets exactly
         and as int32, float32 values to 1e-4 and as float32.  Through the
-        builder, a bucket by-leaf build at 256 bins, W = 8 stays off the
-        float-only nibble kernel that the same float build takes."""
+        builder, the by-leaf build takes the body its SHAPES ask for,
+        whatever it sums: the factorized kernel at 256 bins, W = 8, the
+        plain one at W = 32 and at 128 bins."""
         import jax.numpy as jnp
 
         from mmlspark_tpu.ops import histogram as H
 
         rng = np.random.default_rng(29)
-        n, F, B, W = self._N, self._F, self._B, self._W
+        n, F = self._N, self._F
         bins_t = rng.integers(0, B, size=(F, n)).astype(np.uint8)
         leaf = rng.integers(-2, W + 2, size=n).astype(np.int32)
         if dtype == "int16":
@@ -304,14 +308,14 @@ class TestSharedChunkBodies:
                 lambda b, v, l: H.build_histogram_by_leaf(b, v, l, W, B, backend="pallas", quantize=hq),
                 bins_t, vals, leaf,
             )
-            assert reached == ({"_pallas_hist_by_leaf"} if dtype == "int16" else {"_pallas_hist_by_leaf_nibble"})
+            assert reached == ({"_pallas_hist_by_leaf_nibble"} if (B, W) == (256, 8) else {"_pallas_hist_by_leaf"})
 
     @pytest.mark.parametrize("kernel", ["_pallas_hist", "_pallas_hist_by_leaf", "_pallas_hist_by_leaf_nibble"])
     def test_float_kernel_bodies_hold_no_int32_accumulator(self, kernel):
         """The dtype branch is a Python ``if``: a float build's traced
         kernel casts no float to int32 and holds no int32 array as wide as
-        an accumulator tile, where the bucket build of the same body (the
-        nibble kernel has none) holds both."""
+        an accumulator tile, where the bucket build of the same body holds
+        both, in all three kernels."""
         import jax
         import jax.numpy as jnp
 
@@ -349,9 +353,8 @@ class TestSharedChunkBodies:
 
         hits, out = int32_accumulation(jnp.float32)
         assert not hits and out == jnp.float32, hits[:1]
-        if kernel != "_pallas_hist_by_leaf_nibble":
-            hits, out = int32_accumulation(jnp.int16)
-            assert hits and out == jnp.int32
+        hits, out = int32_accumulation(jnp.int16)
+        assert hits and out == jnp.int32
 
     @pytest.mark.parametrize(
         "builder,rows",
@@ -401,6 +404,41 @@ class TestByLeafKernels:
         a = np.asarray(pallas_hist_by_leaf_chunk(bins, vals, leaf, W, B))
         b = np.asarray(pallas_hist_by_leaf_nibble_chunk(bins, vals, leaf, W, B))
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("vals", ["random", "extreme"])
+    @pytest.mark.parametrize("precision", ["default", "highest"])
+    @pytest.mark.parametrize("B", [255, 256])
+    def test_nibble_kernel_sums_buckets_bit_for_bit(self, B, precision, vals):
+        """int16 buckets through the factorized body: int32 sums equal to
+        numpy's and to the plain body's bit for bit, with parked leaf ids
+        on both sides of the window, rows that are no whole ``rm`` block,
+        and every value at +-QMAX (a sub-block's sum at its largest)."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops.histogram import QMAX
+        from mmlspark_tpu.ops.pallas_hist import (
+            pallas_hist_by_leaf_chunk,
+            pallas_hist_by_leaf_nibble_chunk,
+        )
+
+        rng = np.random.default_rng(B)
+        n, F, W = 2048 + 37, 9, 8
+        bins = rng.integers(0, B, size=(F, n)).astype(np.uint8)
+        bins[:, :300] = B - 1  # the top bin, 300 rows deep: the hi plane and the H*128 -> num_bins slice
+        if vals == "extreme":
+            q = rng.choice(np.array([-QMAX, QMAX], np.int16), size=(3, n))
+            q[:, :300] = QMAX  # one bin's sum well past what bf16 holds: 300 * 127
+        else:
+            q = rng.integers(-QMAX, QMAX + 1, size=(3, n)).astype(np.int16)
+        leaf = rng.integers(-3, W + 2, size=n).astype(np.int32)
+        args = (jnp.asarray(bins), jnp.asarray(q), jnp.asarray(leaf), W, B)
+        got = pallas_hist_by_leaf_nibble_chunk(*args, rm=256, precision=precision)
+        assert got.dtype == jnp.int32 and got.shape == (3, W, F, B)
+        keep = np.flatnonzero((leaf >= 0) & (leaf < W))
+        want = _numpy_hist("by_leaf", bins, q.astype(np.int64), leaf, keep, W, B)
+        np.testing.assert_array_equal(np.asarray(got), want.astype(np.int32))
+        plain = pallas_hist_by_leaf_chunk(*args, rm=256, precision=precision)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
 
     @pytest.mark.parametrize("F", [39, 64, 136])
     @pytest.mark.parametrize("dtype,B", [("uint8", 256), ("int32", 512)])

@@ -11,6 +11,7 @@ with the program's drawn ``u`` handed over, integer sums, dequantize, and the
 float32 re-accumulation of a winner's column.
 """
 
+import contextlib
 import hashlib
 
 import jax
@@ -289,13 +290,18 @@ _PARENT = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(_PARENT))
-def test_levels_absent_give_the_parents_model_string(name):
+def _pinned_rows(n):
+    """The rows of the fits whose model strings are pinned: column 7 categorical."""
     rng = np.random.default_rng(0)
-    n = 4096
     X = rng.normal(size=(n, 8))
     X[:, 7] = rng.integers(0, 12, size=n)
     y = ((X[:, 0] * 1.5 - X[:, 1] + np.where(X[:, 7] % 3 == 0, 1.0, -0.5) + rng.normal(scale=.5, size=n)) > 0).astype(float)
+    return X, y
+
+
+@pytest.mark.parametrize("name", sorted(_PARENT))
+def test_levels_absent_give_the_parents_model_string(name):
+    X, y = _pinned_rows(4096)
     extra, want = _PARENT[name]
     m = train(dict(objective="binary", num_iterations=5, num_leaves=15, learning_rate=0.2, seed=11, verbosity=0,
                    categorical_feature=[7], **extra), Dataset(X, y)).save_model_string()
@@ -303,13 +309,22 @@ def test_levels_absent_give_the_parents_model_string(name):
 
 
 # ---- what a fit counts and says ---------------------------------------------
-@pytest.fixture(scope="module")
-def counted():
-    X, y = _binary()
+@contextlib.contextmanager
+def _recording():
     obs.reset()
     obs.flight.reset()
     obs.enable()
     try:
+        yield
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+@pytest.fixture(scope="module")
+def counted():
+    X, y = _binary()
+    with _recording():
         before = dict(obs.snapshot()["counters"])
         windowed = train(dict(_COMMON, num_leaves=15, use_quantized_grad=True, num_grad_quant_bins=4, split_batch=4), Dataset(X, y))
         mid = dict(obs.snapshot()["counters"])
@@ -319,9 +334,6 @@ def counted():
         train(dict(_COMMON), Dataset(X, y))
         floats = dict(obs.snapshot()["counters"])
         spans = obs.flight.spans("booster.program")
-    finally:
-        obs.disable()
-        obs.reset()
     rise = lambda a, b: {k: b[k] - a.get(k, 0.0) for k in b if b[k] != a.get(k, 0.0)}  # noqa: E731
     return {"windowed": rise(before, mid), "lossguide": rise(mid, after), "float": rise(after, floats),
             "spans": [s["attrs"] for s in spans], "gauges": gauges, "booster": windowed, "y": y}
@@ -355,6 +367,9 @@ def test_program_span_says_levels_and_wire(counted):
     assert (a["quant_levels"], a["quant_wire"]) == ("2x4x1", "int16")
     assert (b["quant_levels"], b["quant_wire"]) == ("127x127x64", "int32")
     assert "quant_levels" not in c and "quant_wire" not in c
+    # the CPU's default backend sums by scatter-add: no kernel body is reached
+    assert a["quant_bucket_body"] == b["quant_bucket_body"] == "scatter" and "quant_bucket_body" not in c
+    assert not any(k.startswith("train.quant_bucket_body") for k in counted["windowed"])
 
 
 def test_first_iterations_scales_are_lightgbms(counted):
@@ -365,3 +380,32 @@ def test_first_iterations_scales_are_lightgbms(counted):
     key = lambda name: next(k for k in g if k.startswith(name) and "it=0" in k)  # noqa: E731
     np.testing.assert_allclose(g[key("train.grad_scale")], max(p0, 1 - p0) / 2, rtol=1e-6)
     np.testing.assert_allclose(g[key("train.hess_scale")], p0 * (1 - p0) / 4, rtol=1e-6)
+
+
+# ---- which kernel body the bucket builds reach (ISSUE 35) ----------------------
+# sha256 of the model strings that commit 2fbaca0 (the parent of the PR that
+# sent a small window's bucket builds to the factorized body) gives for these
+# fits through the interpreted Pallas kernels: integer sums are the same in
+# either body, so the trees may not move by a bit
+_BODIES = {
+    "window8": (dict(num_leaves=31, split_batch=8), "nibble",
+                "61d563703c9d1d03f67a37f69dd9d4b4579c6bda558f5893a2ff0cbee679f39a"),
+    "depthwise32": (dict(num_leaves=63, grow_policy="depthwise", min_data_in_leaf=5), "by_leaf",
+                    "6e123370b33e916297f92961a502c886e0d745f14dec57d71a1d0c98beb3f424"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BODIES))
+def test_bucket_builds_take_the_body_their_window_asks_for_and_the_parents_trees(name):
+    X, y = _pinned_rows(2048)
+    extra, body, want = _BODIES[name]
+    with _recording():
+        m = train(dict(objective="binary", num_iterations=3, learning_rate=0.2, seed=11, verbosity=0, categorical_feature=[7],
+                       use_quantized_grad=True, num_grad_quant_bins=4, hist_backend="pallas", max_bin=255, **extra), Dataset(X, y))
+        counters = dict(obs.snapshot()["counters"])
+        (span,) = (s["attrs"] for s in obs.flight.spans("booster.program"))
+    assert hashlib.sha256(m.save_model_string().encode()).hexdigest() == want
+    bodies = {k: v for k, v in counters.items() if k.startswith("train.quant_bucket_body")}
+    assert bodies == {f"train.quant_bucket_body{{body={body}}}": counters["train.quant_passes{kind=bucket}"]}
+    assert counters["train.quant_passes{kind=bucket}"] > 0
+    assert span["quant_bucket_body"] == body

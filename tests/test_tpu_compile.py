@@ -43,7 +43,10 @@ def one_chip():
 @pytest.mark.parametrize(
     "kernel, vals_dtype, cols, out_dtype",
     [
-        # criteo_quant_train_1chip: every pass's bucket build, int16 row values into int32
+        # criteo_quant_train_1chip: every pass's bucket build, int16 row values into an
+        # int32 (48, 5120) accumulator in the factorized body (W = 8: M = 3*W*2)
+        pytest.param("_pallas_hist_by_leaf_nibble", jnp.int16, 40, "s32", id="bucket-build-nibble-int16"),
+        # the plain body's bucket build, which wider windows (W = 32) and <= 128 bins still take
         pytest.param("_pallas_hist_by_leaf", jnp.int16, 40, "s32", id="bucket-build-int16"),
         # the same body should the buckets become one byte wide
         pytest.param("_pallas_hist_by_leaf", jnp.int8, 40, "s32", id="bucket-build-int8"),
