@@ -43,7 +43,9 @@ The artifacts this module reads itself are touched explicitly
 
 What jit itself costs rides jax's duration events the same way
 (:func:`_on_jit_duration`): ``jit.traces`` / ``jit.trace_s`` from
-``/jax/core/compile/jaxpr_trace_duration``, ``jit.lower_s`` from
+``/jax/core/compile/jaxpr_trace_duration`` (a total, and the same again
+under ``span=<the innermost obs span open on the thread, or none>``: what
+was being done when something was traced), ``jit.lower_s`` from
 ``/jax/core/compile/jaxpr_to_mlir_module_duration``, ``jit.backend_s``
 from ``/jax/core/compile/backend_compile_duration`` (the backend's compile
 OR its load from the persistent cache).  Listeners are registered whether
@@ -206,6 +208,12 @@ def _on_jit_duration(event: str, duration: float, **_kwargs) -> None:
         if n == 0:
             obs.inc("jit.traces")
             obs.inc("jit.trace_s", duration)
+            # the same again by what was being done: the innermost obs
+            # span open on this thread (a new Booster's first evaluation
+            # traces its scorer under ``booster.score_binned``)
+            span = obs.tracing.current_span_name() or "none"
+            obs.inc("jit.traces", span=span)
+            obs.inc("jit.trace_s", duration, span=span)
             # Unified compile-event ledger (obs/device.py): a Python
             # (re-)trace, wherever it happens (a trace-cache miss's
             # export included).

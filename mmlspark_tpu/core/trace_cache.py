@@ -293,4 +293,23 @@ def wrap_aot(
         state[sig] = exp
         return out
 
+    def lower(*args):
+        """``jitted.lower`` for the program ``call`` dispatches on ``args``
+        (arrays or ``ShapeDtypeStruct``s): the exported program as eager
+        dispatch lowers it, flat arguments and results under the module
+        name ``jit_call_exported``, so that its compile is a load of the
+        executable that ran; ``jitted`` itself where the cache is off for
+        it (``obs.device.regions`` asks)."""
+        exp = state.get(_arg_signature(args))
+        if exp is None:
+            return jitted.lower(*args)
+        leaves, tree = jax.tree_util.tree_flatten(args)
+
+        def call_exported(*flat):
+            return jax.tree_util.tree_leaves(exp.call(*jax.tree_util.tree_unflatten(tree, flat)))
+
+        return jax.jit(call_exported).lower(*leaves)
+
+    call.lower = lower
+    call.jitted = jitted
     return call

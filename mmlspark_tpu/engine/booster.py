@@ -850,7 +850,11 @@ class Booster:
         (trees in serial order), so outputs are bitwise-equal."""
         if backend == "scan":
             trees, weights = self._dev_forest(T)
-            return self._forest_fn(T, "raw")(trees, weights, bins)
+            fn = self._forest_fn(T, "raw")
+            obs.device.note_program(
+                "booster.scorer", self.bin_mapper.num_bins, fn, (trees, weights, bins)
+            )
+            return fn(trees, weights, bins)
         if backend in ("pallas", "pallas_interpret"):
             from mmlspark_tpu.ops.pallas_predict import pallas_raw_scores
 
@@ -1218,7 +1222,7 @@ _PARALLEL_LEARNERS = (
 # Jitted whole-run scan programs cached ACROSS train() calls (bounded FIFO):
 # jax.jit caches per function object, so without this every fit (AutoML
 # candidate, CV fold, steady-state run) re-traces the scan body for seconds.
-# An entry is ``(program, notes)``; ``notes["merge_ledger"]``: _grow_ledger.
+# An entry is ``(program, notes)``; the notes are :func:`_grow_ledgers`'.
 _SCAN_CACHE: Dict[Tuple, tuple] = {}
 _SCAN_CACHE_MAX = 16
 
@@ -3090,27 +3094,21 @@ def _train_impl(
                     _SCAN_CACHE.pop(next(iter(_SCAN_CACHE)))
                 _SCAN_CACHE[cache_key] = entry
             scan_chunk, program_notes = entry
-        merge_ledger = None
-        if mesh is not None and D > 1 and obs.enabled():
-            # what one iteration's collectives bring each device, read off
-            # the grower's jaxpr once a program and kept with it (a program
-            # that is not cached across calls reads it anew each fit): the
-            # dispatches below count it once for every iteration that RAN
-            if "merge_ledger" not in program_notes:
-                program_notes["merge_ledger"] = _grow_ledger(
-                    grow, gcfg, K, bins_dev, F, quantize_on
-                )
+        merge_ledger = hist_ledger = None
+        if obs.enabled():
+            # what one iteration's collectives bring each device and what
+            # its histogram passes issue, read off ONE abstract trace of the
+            # grower, made once a program and kept with it (a program that
+            # is not cached across calls reads it anew each fit): the
+            # dispatches below count both once for every iteration that RAN
+            if "hist_ledger" not in program_notes:
+                program_notes.update(_grow_ledgers(
+                    grow, gcfg, K, bins_dev, F, quantize_on,
+                    merges=mesh is not None and D > 1,
+                ))
             merge_ledger = program_notes["merge_ledger"]
-        quant_ledger = None
+            hist_ledger = program_notes["hist_ledger"]
         if quantize_on and obs.enabled():
-            # the bucket builds and refinement passes of one iteration, read
-            # off the grower's jaxpr and kept with the program like the
-            # merge's ledger
-            if "quant_ledger" not in program_notes:
-                program_notes["quant_ledger"] = _quant_ledger(
-                    grow, gcfg, K, bins_dev, F
-                )
-            quant_ledger = program_notes["quant_ledger"]
             for channel, level in zip(("grad", "hess", "count"), qlevels):
                 obs.inc("train.quant_levels", float(level), channel=channel)
 
@@ -3284,8 +3282,10 @@ def _train_impl(
                 quant_levels="x".join(str(v) for v in qlevels),
                 quant_wire=cfg.hist_quantize,
             )
-            if quant_ledger:
-                sp_program.set(quant_bucket_body="+".join(quant_ledger["bodies"]) or "scatter")
+            if hist_ledger:
+                sp_program.set(quant_bucket_body="+".join(sorted(
+                    {body for body, _, scope in hist_ledger if scope == "quant_hist"}
+                )))
         phases.close()  # the dispatches are booster.train's own children
         while n_done < n_iter and stop_at is None:
             t_chunk = time.perf_counter()
@@ -3299,28 +3299,35 @@ def _train_impl(
             # cold=True marks the chunk whose dispatch blocks on Python
             # tracing + XLA compile (or trace/compile-cache loads); later
             # chunks measure pure async-dispatch cost.
+            scan_args = (
+                bins_dev, y_dev, w_dev, valid_mask, obj.device_state(),
+                init_scores_dev, vbins_t, vaux_t, carry,
+                jax.lax.slice(xs_dev, (n_done, 0), (n_done + c, 5))
+                if c < n_iter else xs_dev,
+                *dart_xs,
+            )
+            # what obs.device.regions() needs to ask for this program again
+            # (shapes and shardings; nothing is lowered here)
+            obs.device.note_program(
+                "booster.fit", id(getattr(scan_chunk, "jitted", scan_chunk)),
+                scan_chunk, scan_args,
+            )
             with obs.span(
                 "booster.scan_dispatch",
                 chunk=chunk_idx, iters=c, cold=(chunk_idx == 0),
             ):
-                carry, scan_ys = scan_chunk(
-                    bins_dev, y_dev, w_dev, valid_mask, obj.device_state(),
-                    init_scores_dev, vbins_t, vaux_t, carry,
-                    jax.lax.slice(xs_dev, (n_done, 0), (n_done + c, 5))
-                    if c < n_iter else xs_dev,
-                    *dart_xs,
-                )
+                carry, scan_ys = scan_chunk(*scan_args)
+            del scan_args  # let go of the carry that went in
             for op, (calls, nbytes) in (merge_ledger or {}).items():
                 obs.inc("train.merge_calls", float(calls * c), op=op)
                 obs.inc("train.merge_bytes", float(nbytes * c), op=op)
             for name, per_iter in (rank_counts or {}).items():
                 obs.inc(name, float(per_iter * c))
-            if quant_ledger:
-                for kind in ("bucket", "refine"):
-                    obs.inc("train.quant_passes", float(quant_ledger[kind] * c), kind=kind)
-                obs.inc("train.quant_refine_cols", float(quant_ledger["refine_cols"] * c))
-                for body, per_iter in quant_ledger["bodies"].items():
-                    obs.inc("train.quant_bucket_body", float(per_iter * c), body=body)
+            for (body, vals_kind, scope), work in (hist_ledger or {}).items():
+                for name, per_iter in work.items():
+                    obs.inc("hist." + name, float(per_iter * c), body=body, vals=vals_kind, scope=scope)
+            if quantize_on and hist_ledger:
+                obs.inc("train.quant_refine_cols", float(program_notes["quant_refine_cols"] * c))
             if quantize_on:
                 trees_c, vsnap_c, qsc_c = scan_ys
             else:
@@ -3622,51 +3629,54 @@ def _grow_jaxpr(grow, K: int, bins_dev, F_mask: int, quantized: bool):
         obs_state.enabled = was
 
 
-def _grow_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
-                 quantized: bool) -> dict:
-    """The bytes each device receives in ONE boosting iteration's
-    collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`
-    over the sharded grower's jaxpr).  The windowed grower's loop counts as
-    the passes of a full tree (``full_tree_passes``;
-    ``tests/test_dp_resident.py`` holds it to the loop's own trips): the
-    program does not carry its trip count out, so a tree that runs out of
-    valid splits early is counted high, and ``train.merge_bytes`` is the
-    bytes of full trees."""
-    from mmlspark_tpu.engine.tree import full_tree_passes
+def _grow_ledgers(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
+                  quantized: bool, merges: bool) -> dict:
+    """The ``program_notes`` of ONE boosting iteration, from one abstract
+    trace of the grower: ``merge_ledger``, the bytes each device receives in
+    its collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`;
+    ``None`` off a mesh), and ``hist_ledger`` (:func:`hist_ledger`).  The
+    windowed grower's loop counts as the passes of a full tree
+    (``full_tree_passes``; ``tests/test_dp_resident.py`` holds it to the
+    loop's own trips): the program does not carry its trip count out, so a
+    tree that runs out of valid splits early is counted high, and
+    ``train.merge_bytes`` and ``hist.*`` are the work of full trees.
+    ``quant_refine_cols``: the winner columns the refinement passes
+    re-accumulate, a window's slots a pass of the windowed grower, one a
+    step of the lossguide grower."""
+    from mmlspark_tpu.engine.tree import full_tree_passes, windowed_grower
     from mmlspark_tpu.parallel.distributed import collective_ledger
 
     jaxpr = _grow_jaxpr(grow, K, bins_dev, F_mask, quantized)
-    return collective_ledger(jaxpr, while_trips=full_tree_passes(gcfg))
+    trips = full_tree_passes(gcfg)
+    hists = hist_ledger(jaxpr, while_trips=trips)
+    refines = sum(work["passes"] for (_, _, scope), work in hists.items() if scope == "quant_refine")
+    return {
+        "merge_ledger": collective_ledger(jaxpr, while_trips=trips) if merges else None,
+        "hist_ledger": hists,
+        "quant_refine_cols": refines * (gcfg.level_window if windowed_grower(gcfg) else 1),
+    }
 
 
-def scope_entries(jaxpr, scopes, while_trips: int = 1, callees=()) -> dict:
+def scope_entries(jaxpr, scopes, while_trips: int = 1, visit=None) -> dict:
     """``{scope: entries}``: how often one execution of a traced program
     enters each ``jax.named_scope`` of ``scopes``.  Equations that follow
     one another under a scope, at one level of the program, are one entry;
     a ``scan`` multiplies by its length, a ``while`` loop by
     ``while_trips`` (its count is not in the program: see
-    ``collective_ledger``).  An entry that calls, at any depth, a jitted
-    function named in ``callees`` also counts under ``(scope, name)``."""
+    ``collective_ledger``).  ``visit(scope, equations, times)`` is called
+    for each entry."""
     out = dict.fromkeys(scopes, 0)
 
     def scope_of(eqn):
         names = str(eqn.source_info.name_stack).split("/")
         return next((s for s in scopes if s in names), None)
 
-    def called(eqns, found):
-        for eqn in eqns:
-            if eqn.primitive.name in ("jit", "pjit") and eqn.params.get("name") in callees:
-                found.add(eqn.params["name"])
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                called(sub.eqns, found)
-        return found
-
     def walk(jp, mult):
         for here, run in itertools.groupby(jp.eqns, scope_of):
             if here is not None:
                 out[here] += mult
-                for name in called(run, set()) if callees else ():
-                    out[here, name] = out.get((here, name), 0) + mult
+                if visit is not None:
+                    visit(here, list(run), mult)
                 continue
             for eqn in run:
                 name = eqn.primitive.name
@@ -3682,36 +3692,60 @@ def scope_entries(jaxpr, scopes, while_trips: int = 1, callees=()) -> dict:
     return out
 
 
-# the Pallas wrappers a by-leaf bucket build can reach (ops/pallas_hist.py),
-# under the names the counter and the span give them
-_BUCKET_BODIES = {"_pallas_hist_by_leaf_nibble": "nibble", "_pallas_hist_by_leaf": "by_leaf"}
+# the scopes a grower builds its histograms under (engine/tree.py)
+HIST_SCOPES = ("hist_build", "quant_hist", "quant_refine")
 
 
-def _quant_ledger(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int) -> dict:
-    """What ONE boosting iteration of a quantized fit passes over the rows:
-    ``bucket`` histogram builds (the ``quant_hist`` scope), float32
-    ``refine`` passes (``quant_refine``) and the winner columns those
-    re-accumulate (``refine_cols``: a window's slots a pass of the windowed
-    grower, one a step of the lossguide grower), and under ``bodies`` the
-    bucket builds by the kernel body they reached (``nibble``, ``by_leaf``;
-    none on the scatter backend).  Counted like the merge's ledger, full
-    trees: a tree that stops early is counted high."""
-    from mmlspark_tpu.engine.tree import full_tree_passes, windowed_grower
+def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
+    """What the histogram passes of one execution of a traced grower issue:
+    ``{(body, vals, scope): {"passes", "rowcols", "mxu_flops",
+    "vpu_elems"}}``.  A pass is one entry of a scope of :data:`HIST_SCOPES`
+    (:func:`scope_entries`: full trees), labelled by the kernel body its
+    calls reach (``plain``, ``by_leaf``, ``nibble``: the Pallas wrappers of
+    ``ops/pallas_hist.py``, found by the name of their ``jit`` equation;
+    ``scatter``: the other backend's scatter-add) and by the row values'
+    dtype (``f32``, or ``i16`` buckets).  ``rowcols`` are the rows × padded
+    columns those calls read, a chunk loop's calls times its length;
+    ``mxu_flops`` and ``vpu_elems`` what the bodies issue for them, as each
+    body states beside its kernel (``pallas_hist.call_work``), and 0 on the
+    scatter backend."""
+    from mmlspark_tpu.ops.pallas_hist import WRAPPERS, call_work
 
-    runs = scope_entries(
-        _grow_jaxpr(grow, K, bins_dev, F_mask, True),
-        ("quant_hist", "quant_refine"), while_trips=full_tree_passes(gcfg),
-        callees=tuple(_BUCKET_BODIES),
-    )
-    cols = gcfg.level_window if windowed_grower(gcfg) else 1
-    return {
-        "bucket": runs["quant_hist"], "refine": runs["quant_refine"],
-        "refine_cols": runs["quant_refine"] * cols,
-        "bodies": {
-            body: runs["quant_hist", name]
-            for name, body in _BUCKET_BODIES.items() if ("quant_hist", name) in runs
-        },
-    }
+    out: dict = {}
+
+    def calls(eqns, mult, found):
+        for eqn in eqns:
+            name = eqn.primitive.name
+            if name in ("jit", "pjit") and eqn.params.get("name") in WRAPPERS:
+                found.append((mult, call_work(eqn)))
+            elif name == "scatter-add":
+                updates = eqn.invars[2].aval  # (3, chunk rows x columns)
+                found.append((mult, {
+                    "body": "scatter", "quant": jnp.issubdtype(updates.dtype, jnp.integer),
+                    "rowcols": int(updates.shape[-1]), "mxu_flops": 0, "vpu_elems": 0,
+                }))
+            else:
+                inner = mult * int(eqn.params["length"]) if name == "scan" else mult
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    calls(sub.eqns, inner, found)
+        return found
+
+    def visit(scope, run, times):
+        found = calls(run, 1, [])
+        if not found:
+            return
+        first = found[0][1]
+        work = out.setdefault(
+            (first["body"], "i16" if first["quant"] else "f32", scope),
+            dict.fromkeys(("passes", "rowcols", "mxu_flops", "vpu_elems"), 0),
+        )
+        work["passes"] += times
+        for mult, call in found:
+            for name in ("rowcols", "mxu_flops", "vpu_elems"):
+                work[name] += times * mult * call[name]
+
+    scope_entries(jaxpr, HIST_SCOPES, while_trips=while_trips, visit=visit)
+    return out
 
 
 def _fold_bias(stacked: Tree, init) -> Tree:

@@ -1437,19 +1437,20 @@ def grow_tree_depthwise(
             # ≥ base > every splittable leaf id, so later slots can never
             # re-match it.  (slot_leaves hoisted above — the refinement
             # pass and the candidate cache share the gain-ranked slots.)
-            for w in range(W):
-                l_w = slot_leaves[w]
-                col = lax.dynamic_slice(
-                    bins_t, (f[l_w], jnp.int32(0)), (1, n)
-                )[0]
-                gl_w = jnp.where(col == (B - 1), dleft[l_w], col <= t[l_w])
-                if cfg.has_categoricals:
-                    memb_w = lax.dynamic_slice(members, (l_w, 0), (1, B))[0]
-                    gl_w = jnp.where(
-                        is_cat[l_w], _member_lookup(memb_w, col, B), gl_w
-                    )
-                moves_w = (leaf_ids == l_w) & selected[l_w] & ~gl_w
-                leaf_ids = jnp.where(moves_w, new_id_of_leaf[l_w], leaf_ids)
+            with jax.named_scope("row_route"):
+                for w in range(W):
+                    l_w = slot_leaves[w]
+                    col = lax.dynamic_slice(
+                        bins_t, (f[l_w], jnp.int32(0)), (1, n)
+                    )[0]
+                    gl_w = jnp.where(col == (B - 1), dleft[l_w], col <= t[l_w])
+                    if cfg.has_categoricals:
+                        memb_w = lax.dynamic_slice(members, (l_w, 0), (1, B))[0]
+                        gl_w = jnp.where(
+                            is_cat[l_w], _member_lookup(memb_w, col, B), gl_w
+                        )
+                    moves_w = (leaf_ids == l_w) & selected[l_w] & ~gl_w
+                    leaf_ids = jnp.where(moves_w, new_id_of_leaf[l_w], leaf_ids)
 
         # -- windowed new-children histograms + parent subtraction --------
         win = window_hist(leaf_ids - base)  # (3, W, F, B); old ids park <0

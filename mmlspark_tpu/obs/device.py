@@ -31,11 +31,22 @@ the matching span/counter —
 
 ``summary()`` folds both families into the ``device`` section rendered
 by ``python -m tools.obs report``.
+
+Device regions by the program's own names: the program marks its regions
+with ``jax.named_scope`` (:data:`SCOPES`), and a compiled module carries
+each instruction's scope path in its ``op_name`` metadata.  A dispatch
+site hands :func:`note_program` the callable and its arguments while obs
+is enabled (their shapes, dtypes and shardings are kept, nothing else);
+:func:`regions` lowers and compiles each noted program WHEN ASKED (the
+compile is a load from the persistent cache of the executable that ran)
+and returns ``{(instruction, result shape): region}`` for it, which a
+reader joins with a device trace's table of seconds by op.
 """
 
 from __future__ import annotations
 
 import os
+import re
 import sys
 import threading
 from typing import Optional
@@ -58,11 +69,13 @@ _peak_seen = 0.0
 
 
 def reset() -> None:
-    """Re-arm the throttle and drop the watermark (test isolation)."""
+    """Re-arm the throttle, drop the watermark and the noted programs
+    (test isolation)."""
     global _poll_seq, _peak_seen
     with _lock:
         _poll_seq = 0
         _peak_seen = 0.0
+        _PROGRAMS.clear()
 
 
 def compile_event(kind: str) -> None:
@@ -140,4 +153,109 @@ def summary(snapshot: Optional[dict] = None) -> dict:
     }
     if compile_events:
         out["compile_events"] = compile_events
+    return out
+
+
+# The named scopes the program marks its device regions with, each where
+# the work is: engine/tree.py (split_scan, hist_build, quant_hist,
+# quant_refine, quant_round, leaf_stats, replay_step, row_route),
+# engine/booster.py (leaf_delta, quant_round), ops/histogram.py and
+# ops/pallas_hist.py (chunk_copy), parallel/distributed.py (hist_merge),
+# ops/objectives.py (rank_grad), ops/rank_plan.py (rank_ndcg).
+SCOPES = (
+    "split_scan", "hist_build", "quant_hist", "quant_refine", "quant_round",
+    "leaf_stats", "leaf_delta", "replay_step", "hist_merge", "rank_grad",
+    "rank_ndcg", "row_route", "chunk_copy",
+)
+
+_PROGRAMS: dict = {}  # (label, same, shapes) -> [callable, abstract args, map]
+_PROGRAMS_MAX = 16
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def note_program(label: str, same, fn, args) -> None:
+    """Keep what :func:`regions` needs to ask for the program that ``fn``
+    dispatches on ``args``: the callable and the arguments' shapes, dtypes
+    and (where committed) shardings.  ``same`` tells one program of a label
+    from another of the same shapes (the jitted program's identity, a
+    scorer's kind); a program noted again keeps its map.  Nothing is
+    lowered here, and nothing is kept with obs off."""
+    if not _state.enabled:
+        return
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+
+    def abstract(x):
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    shapes = jax.tree_util.tree_map(abstract, args)
+    key = (label, same, repr(shapes))
+    with _lock:
+        entry = _PROGRAMS.get(key)
+        if entry is not None:
+            entry[0] = fn
+            return
+        if len(_PROGRAMS) >= _PROGRAMS_MAX:
+            _PROGRAMS.pop(next(iter(_PROGRAMS)))
+        _PROGRAMS[key] = [fn, shapes, None]
+
+
+def module_regions(hlo_text: str) -> dict:
+    """``{(instruction, result shape): region}`` of one compiled module's
+    text: the region is the innermost name of :data:`SCOPES` on the
+    instruction's ``op_name`` path, ``None`` where there is none.  A fusion
+    the compiler left without a scope of its own takes its fused
+    computation's: the root's, else the one most of its instructions carry.
+    The shape is the text up to its layout (of a tuple, its first
+    element's), as a device trace's op names give it."""
+    out, calls, bodies = {}, {}, {}
+    body = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            head = line.split(" ", 2)
+            if line.endswith("{") and len(head) > 1:  # "%name (params) -> shape {", "ENTRY %name ..."
+                body = bodies.setdefault(head[head[0] == "ENTRY"].lstrip("%"), [])
+            continue
+        name, rest = m.groups()
+        path = _OP_NAME.search(rest)
+        region = None
+        for part in reversed(path.group(1).split("/")) if path else ():
+            region = next((t for t in re.findall(r"\w+", part) if t in SCOPES), None)
+            if region is not None:
+                break
+        key = name, rest.split("{", 1)[0].split(" ", 1)[0]
+        out[key] = region
+        if body is not None:
+            body.append((region, line.lstrip().startswith("ROOT ")))
+        called = _CALLS.search(rest)
+        if region is None and called:
+            calls[key] = called.group(1)
+    for key, computation in calls.items():
+        inside = [r for r, _ in bodies.get(computation, ()) if r is not None]
+        root = next((r for r, is_root in bodies.get(computation, ()) if is_root), None)
+        if inside:
+            out[key] = root or max(inside, key=inside.count)
+    return out
+
+
+def regions() -> dict:
+    """``{program: {(instruction, result shape): region}}`` for every
+    program noted under obs (:func:`note_program`: a fit's scan program,
+    a booster's binned scorer), ``program`` being its label and a running
+    number.  Lazy: a program is lowered and compiled at its first call
+    here (a load from the persistent cache where the executable that ran
+    is kept there) and its map memoised; call it after the timed work."""
+    with _lock:
+        noted = list(_PROGRAMS.items())
+    out = {}
+    for i, ((label, _, _), entry) in enumerate(noted):
+        fn, shapes, found = entry
+        if found is None:
+            found = entry[2] = module_regions(fn.lower(*shapes).compile().as_text())
+        out[f"{label}:{i}"] = found
     return out

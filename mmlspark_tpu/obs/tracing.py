@@ -90,6 +90,12 @@ def _stack() -> list:
     return s
 
 
+def current_span_name() -> Optional[str]:
+    """The name of the innermost enabled span open on this thread."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1].name if stack else None
+
+
 _TA_CLS: object = 0  # 0 = unresolved, None = unavailable
 
 

@@ -450,11 +450,11 @@ def _chunked_hist(fn, acc0, bins, rows, chunk: int, axis_name, merge: str,
             raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
 
         def body(acc, i):
-            part = fn(
-                _row_chunk(bins, i, chunk, 1),
-                *(_row_chunk(x, i, chunk, axis) for x, axis in rows),
-            )
-            return acc + part, None
+            with jax.named_scope("chunk_copy"):
+                sliced = [_row_chunk(bins, i, chunk, 1)] + [
+                    _row_chunk(x, i, chunk, axis) for x, axis in rows
+                ]
+            return acc + fn(*sliced), None
 
         hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
     feature_axis = acc0.ndim - 2  # (3, F, B) | (3, L, F, B)

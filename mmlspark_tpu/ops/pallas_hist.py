@@ -143,6 +143,20 @@ def _hist_kernel(bins_ref, vals_ref, out_ref, *, num_bins: int, precision):
         out_ref[...] += part[None, :, :]
 
 
+def _hist_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, quant: bool):
+    """``(MXU flops, VPU element operations)`` one grid cell of
+    :func:`_hist_kernel` issues, as its body stands: ``M`` = 3 channels, ``N``
+    = bins, and no inner loop (``rm`` = ``bm``).  Every element of every
+    elementwise array the body builds counts once (widenings, compares,
+    converts, products, accumulator adds); iotas, slices and concatenations
+    do not."""
+    rows = bf * bm  # the widened bins
+    per_col = 2 * N * bm  # the bin one-hot: a compare and its convert
+    tile = M * bf * N  # the accumulator tile: its add, and of buckets its cast
+    vpu = rows + bf * per_col + tile * (2 if quant else 1) + (3 * bm if quant else 0)
+    return 2 * M * N * bm * bf, vpu
+
+
 @functools.partial(
     jax.jit, static_argnames=("num_bins", "bm", "bf", "interpret", "precision")
 )
@@ -307,6 +321,18 @@ def _hist_leaf_kernel(
         out_ref[...] += part[None]
 
 
+def _hist_leaf_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, quant: bool):
+    """As :func:`_hist_kernel_work`, of :func:`_hist_leaf_kernel`: ``M`` =
+    3·L, ``N`` = bins; a sub-block of ``rm`` rows builds the leaf one-hot
+    (compare, convert), its three products, a bin one-hot a column, and adds
+    its ``(M, bf·N)`` part to the carry; the cell adds the carry to the
+    output tile."""
+    L = M // 3
+    sub = bf * rm + 5 * L * rm + bf * 2 * N * rm + M * bf * N * (2 if quant else 1)
+    sub += 3 * rm if quant else 0
+    return 2 * M * N * bm * bf, (bm // rm) * sub + M * bf * N
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -412,13 +438,14 @@ def _prep_by_leaf_chunk(
     rm = min(rm, bm)
     pad_r = (-C) % bm
     pad_f = (-F) % bf
-    if pad_r:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad_r)))
-        vals_c = jnp.pad(vals_c, ((0, 0), (0, pad_r)))
-        # padded rows park at leaf == num_leaves → no one-hot slot
-        leaf_row = jnp.pad(leaf_row, ((0, 0), (0, pad_r)), constant_values=num_leaves)
-    if pad_f:
-        bins_t = jnp.pad(bins_t, ((0, pad_f), (0, 0)))
+    with jax.named_scope("chunk_copy"):
+        if pad_r:
+            bins_t = jnp.pad(bins_t, ((0, 0), (0, pad_r)))
+            vals_c = jnp.pad(vals_c, ((0, 0), (0, pad_r)))
+            # padded rows park at leaf == num_leaves → no one-hot slot
+            leaf_row = jnp.pad(leaf_row, ((0, 0), (0, pad_r)), constant_values=num_leaves)
+        if pad_f:
+            bins_t = jnp.pad(bins_t, ((0, pad_f), (0, 0)))
     return bins_t, vals_c, leaf_row, bm, bf, rm, F, backend == "cpu"
 
 
@@ -534,6 +561,19 @@ def _hist_leaf_nibble_kernel(
         out_ref[...] += part[None]
 
 
+def _hist_leaf_nibble_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, quant: bool):
+    """As :func:`_hist_kernel_work`, of :func:`_hist_leaf_nibble_kernel`:
+    ``M`` = 3·W·H, ``N`` = LO.  A row and column: ``hi``, ``lo``, the key's
+    multiply and add, the key one-hot (W·H compares and converts), its three
+    products and the ``lo`` one-hot (LO compares and converts); a sub-block
+    adds its ``(M, bf·LO)`` part to the carry, the cell the carry to the
+    output tile."""
+    WH = M // 3
+    sub = bf * rm + bf * (4 + 5 * WH + 2 * N) * rm + M * bf * N * (2 if quant else 1)
+    sub += 3 * rm if quant else 0
+    return 2 * M * N * bm * bf, (bm // rm) * sub + M * bf * N
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -596,3 +636,35 @@ def pallas_hist_by_leaf_nibble_chunk(
         interp, precision,
     )
     return out[:, :, :F]
+
+
+# ---------------------------------------------------------------------------
+# What a traced call issues (the booster's histogram work ledger).  The
+# wrappers by the name their ``jit`` equation carries: the body's label and
+# the function beside it that states a grid cell's work.
+# ---------------------------------------------------------------------------
+WRAPPERS = {
+    "_pallas_hist": ("plain", _hist_kernel_work),
+    "_pallas_hist_by_leaf": ("by_leaf", _hist_leaf_kernel_work),
+    "_pallas_hist_by_leaf_nibble": ("nibble", _hist_leaf_nibble_kernel_work),
+}
+
+
+def call_work(eqn) -> dict:
+    """One call of a wrapper of :data:`WRAPPERS`, read off its ``jit``
+    equation: ``body``, ``quant`` (int16 bucket values), ``rowcols`` (rows ×
+    padded columns the call reads), ``mxu_flops`` and ``vpu_elems``.  The
+    operands' ``(F, n)`` are on the equation; the inner ``pallas_call``'s
+    blocks and result give ``bf``, ``bm``, ``M`` and ``N``, and the length
+    of the body's loop over sub-blocks gives ``rm``."""
+    body, work = WRAPPERS[eqn.params["name"]]
+    F, n = eqn.invars[0].aval.shape
+    quant = _is_bucket(eqn.invars[1].aval.dtype)
+    (call,) = (e for e in eqn.params["jaxpr"].eqns if e.primitive.name == "pallas_call")
+    bf, bm = (int(b.block_size) for b in call.params["grid_mapping"].block_mappings[0].block_shape)
+    _, M, lanes = call.params["out_avals"][0].shape
+    loops = [e.params["length"] for e in call.params["jaxpr"].eqns if e.primitive.name == "scan"]
+    rm = bm // int(loops[0]) if loops else bm
+    flops, elems = work(M, lanes // bf, bf, bm, rm, quant)
+    cells = (F // bf) * (n // bm)
+    return {"body": body, "quant": quant, "rowcols": F * n, "mxu_flops": cells * flops, "vpu_elems": cells * elems}

@@ -353,13 +353,15 @@ def test_fit_counts_its_passes_from_the_growers_program(counted):
     # 15 leaves at 4 splits a pass: 1, 2, 4, 4, 3 -> five passes and the root's build
     passes = full_tree_passes(GrowConfig(num_bins=256, num_leaves=15, split_batch=4))
     assert passes == 5
+    # the CPU's default backend sums by scatter-add: bucket builds of int16 values, float32 refinement
+    bucket, refine = "hist.passes{body=scatter,scope=quant_hist,vals=i16}", "hist.passes{body=scatter,scope=quant_refine,vals=f32}"
     w = counted["windowed"]
-    assert w["train.quant_passes{kind=bucket}"] == iters * (passes + 1)
-    assert w["train.quant_passes{kind=refine}"] == iters * passes
+    assert w[bucket] == iters * (passes + 1)
+    assert w[refine] == iters * passes
     assert w["train.quant_refine_cols"] == iters * passes * 4  # a window's four slots a pass
     lg = counted["lossguide"]  # one split a step: 14 steps, a build and a refined column each, and the root
-    assert lg["train.quant_passes{kind=bucket}"] == iters * 15
-    assert lg["train.quant_passes{kind=refine}"] == lg["train.quant_refine_cols"] == iters * 14
+    assert lg[bucket] == iters * 15
+    assert lg[refine] == lg["train.quant_refine_cols"] == iters * 14
 
 
 def test_program_span_says_levels_and_wire(counted):
@@ -369,7 +371,7 @@ def test_program_span_says_levels_and_wire(counted):
     assert "quant_levels" not in c and "quant_wire" not in c
     # the CPU's default backend sums by scatter-add: no kernel body is reached
     assert a["quant_bucket_body"] == b["quant_bucket_body"] == "scatter" and "quant_bucket_body" not in c
-    assert not any(k.startswith("train.quant_bucket_body") for k in counted["windowed"])
+    assert not any(k.startswith("hist.passes{body=") and "body=scatter" not in k for k in counted["windowed"])
 
 
 def test_first_iterations_scales_are_lightgbms(counted):
@@ -405,7 +407,6 @@ def test_bucket_builds_take_the_body_their_window_asks_for_and_the_parents_trees
         counters = dict(obs.snapshot()["counters"])
         (span,) = (s["attrs"] for s in obs.flight.spans("booster.program"))
     assert hashlib.sha256(m.save_model_string().encode()).hexdigest() == want
-    bodies = {k: v for k, v in counters.items() if k.startswith("train.quant_bucket_body")}
-    assert bodies == {f"train.quant_bucket_body{{body={body}}}": counters["train.quant_passes{kind=bucket}"]}
-    assert counters["train.quant_passes{kind=bucket}"] > 0
+    bodies = {k: v for k, v in counters.items() if k.startswith("hist.passes{") and "scope=quant_hist" in k}
+    assert list(bodies) == [f"hist.passes{{body={body},scope=quant_hist,vals=i16}}"] and all(v > 0 for v in bodies.values())
     assert span["quant_bucket_body"] == body
