@@ -168,7 +168,7 @@ SCOPES = (
     "rank_ndcg", "row_route", "chunk_copy",
 )
 
-_PROGRAMS: dict = {}  # (label, same, shapes) -> [callable, abstract args, map]
+_PROGRAMS: dict = {}  # (label, same, argument tree, shapes) -> [callable, abstract args, map]
 _PROGRAMS_MAX = 16
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -188,12 +188,12 @@ def note_program(label: str, same, fn, args) -> None:
     if jax is None:
         return
 
-    def abstract(x):
-        sharding = x.sharding if getattr(x, "committed", False) else None
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
-
-    shapes = jax.tree_util.tree_map(abstract, args)
-    key = (label, same, repr(shapes))
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    placed = tuple(
+        (x.shape, x.dtype, x.sharding if getattr(x, "committed", False) else None)
+        for x in leaves
+    )
+    key = (label, same, tree, placed)
     with _lock:
         entry = _PROGRAMS.get(key)
         if entry is not None:
@@ -201,6 +201,9 @@ def note_program(label: str, same, fn, args) -> None:
             return
         if len(_PROGRAMS) >= _PROGRAMS_MAX:
             _PROGRAMS.pop(next(iter(_PROGRAMS)))
+        shapes = tree.unflatten(
+            [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding) for shape, dtype, sharding in placed]
+        )
         _PROGRAMS[key] = [fn, shapes, None]
 
 
@@ -253,7 +256,7 @@ def regions() -> dict:
     with _lock:
         noted = list(_PROGRAMS.items())
     out = {}
-    for i, ((label, _, _), entry) in enumerate(noted):
+    for i, ((label, *_), entry) in enumerate(noted):
         fn, shapes, found = entry
         if found is None:
             found = entry[2] = module_regions(fn.lower(*shapes).compile().as_text())

@@ -211,3 +211,30 @@ def test_trace_seconds_carry_the_open_span_and_sum_to_the_total():
     for name in ("jit.trace_s", "jit.traces"):
         by_span = [v for k, v in after.items() if k.startswith(name + "{span=")]
         assert sum(by_span) == pytest.approx(after[name])
+
+
+# ---- over a mesh: a chip's own rows, and the merge's region --------------------
+def test_sharded_fit_counts_a_chips_rowcols_and_maps_the_merge():
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from mmlspark_tpu.data.streaming import StreamedDataset
+    from mmlspark_tpu.ops.binning import BinningAuthority
+    from mmlspark_tpu.parallel.mesh import DATA_AXIS, default_mesh
+
+    D, mesh = 4, default_mesh(4)
+    X, y = _rows(4)
+    authority = BinningAuthority.fit(X, max_bin=255, seed=0)
+    sharding = NamedSharding(mesh, P(DATA_AXIS, None))
+    bins = jax.device_put(authority.mapper.transform(X).astype(np.uint8), sharding)
+    ds = StreamedDataset(authority=authority, binned_dev=bins, packed=False, num_rows=ROWS, num_features=COLS, label=y)
+    with _recording():
+        model = train(dict(PARAMS, tree_learner="data", hist_chunk=256), ds, mesh=mesh)
+        model._raw_scores_binned(bins)
+        got = _counters()
+        maps = obs.device.regions()
+    label = "{body=scatter,scope=hist_build,vals=f32}"
+    assert got["hist.passes" + label] == ITERS * (PASSES + 1)
+    assert got["hist.rowcols" + label] == ITERS * (PASSES + 1) * (ROWS // D) * COLS  # the shard's rows, not the host's
+    fit, scorer = (collections.Counter(maps[name].values()) for name in sorted(maps))
+    assert fit["hist_merge"] > 0 and fit["chunk_copy"] > 0 and fit["row_route"] > 0 and scorer["replay_step"] > 0

@@ -224,9 +224,10 @@ class TestDisabledOverhead:
         snap = obs.snapshot()
         events = sum(s["count"] for s in snap["spans"].values())
         # counters that carry a quantity (nanoseconds, bytes sent, seconds
-        # of tracing) are one event per `inc`, not `value` events
+        # of tracing, the histogram ledger's passes, row-columns, flops and
+        # element operations) are one event per `inc`, not `value` events
         events += sum(
-            1 if k.endswith(("_bytes", "_s")) else v
+            1 if k.endswith(("_bytes", "_s")) or k.startswith(("hist.", "jit.trace_s{")) else v
             for k, v in snap["counters"].items() if ".ns" not in k
         )
         # step telemetry rides the same budget: every histogram sample
