@@ -3704,8 +3704,8 @@ def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
     calls reach (``plain``, ``by_leaf``, ``nibble``: the Pallas wrappers of
     ``ops/pallas_hist.py``, found by the name of their ``jit`` equation;
     ``scatter``: the other backend's scatter-add) and by the row values'
-    dtype (``f32``, or ``i16`` buckets).  ``rowcols`` are the rows × padded
-    columns those calls read, a chunk loop's calls times its length;
+    dtype (``f32``, or ``i16`` buckets).  ``rowcols`` are the rows × columns
+    those calls read, a chunk loop's calls times its length;
     ``mxu_flops`` and ``vpu_elems`` what the bodies issue for them, as each
     body states beside its kernel (``pallas_hist.call_work``), and 0 on the
     scatter backend."""

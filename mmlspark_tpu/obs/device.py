@@ -159,8 +159,11 @@ def summary(snapshot: Optional[dict] = None) -> dict:
 # The named scopes the program marks its device regions with, each where
 # the work is: engine/tree.py (split_scan, hist_build, quant_hist,
 # quant_refine, quant_round, leaf_stats, replay_step, row_route),
-# engine/booster.py (leaf_delta, quant_round), ops/histogram.py and
-# ops/pallas_hist.py (chunk_copy), parallel/distributed.py (hist_merge),
+# engine/booster.py (leaf_delta, quant_round), ops/histogram.py
+# (chunk_copy: the scatter functions' slices of a chunk) and
+# ops/pallas_hist.py (chunk_copy: the slice and pad of a chunk that is no
+# whole number of row blocks; any other is read in place and the region
+# holds nothing), parallel/distributed.py (hist_merge),
 # ops/objectives.py (rank_grad), ops/rank_plan.py (rank_ndcg).
 SCOPES = (
     "split_scan", "hist_build", "quant_hist", "quant_refine", "quant_round",

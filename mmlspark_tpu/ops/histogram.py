@@ -21,7 +21,8 @@ past it: rows on the lane axis, which is what the kernels read.
 Backends:
 
 - ``scatter``  — ``jnp...at[].add`` scatter-add.  Reference semantics; the
-  backend used on the CPU test mesh (it transposes and widens per chunk).
+  backend used on the CPU test mesh (it slices, transposes and widens per
+  chunk).
 - ``pallas``   — Pallas kernels (``mmlspark_tpu.ops.pallas_hist``): the
   histogram as a one-hot × values matmul on the MXU, with the one-hot
   tile living in VMEM only.  The chip's path; interpreted on a CPU.
@@ -33,8 +34,12 @@ own number of integer levels: "Quantized accumulation" below).
 
 Both are row-chunked with ``lax.scan`` so peak memory is bounded by the chunk,
 not the dataset (HBM holds only the uint8 binned matrix — SURVEY.md §7.2).
-The scan walks the chunk INDEX and its body slices chunk ``i`` out of the
-bins, ``vals`` and leaf ids where they lie (``_row_chunk``).  Nothing is
+The scan walks the chunk INDEX and hands every chunk function the whole
+bins, ``vals`` and leaf ids with it: ``fn(bins, *rows, i=i, chunk=chunk)``.
+How chunk ``i`` is reached is the backend's own business.  The Pallas
+wrappers offset their grid by it and read the chunk's blocks where they
+lie, so a pass moves no byte that no histogram needs (PERF.md §6 PR 37);
+the scatter functions slice it out (``_chunk_slices``).  Nothing is
 re-laid-out to put chunks on a leading axis: for the ``(F, n)`` matrix
 that is a copy of the whole data set on every histogram pass, and a
 compile that follows the row count (PERF.md §6 PR 28).
@@ -56,6 +61,16 @@ DEFAULT_CHUNK = 16_384
 def _row_chunk(x, i, size: int, axis: int):
     """Rows ``[i·size, (i+1)·size)`` of ``x`` along its row ``axis``."""
     return lax.dynamic_slice_in_dim(x, i * size, size, axis=axis)
+
+
+def _chunk_slices(arrays, i, chunk: Optional[int]):
+    """Chunk ``i`` of each ``(array, row axis)``, a copy each (the device
+    region ``chunk_copy``); with no ``chunk`` the arrays themselves.  How
+    the scatter functions reach a chunk."""
+    if chunk is None:
+        return [x for x, _ in arrays]
+    with jax.named_scope("chunk_copy"):
+        return [_row_chunk(x, i, chunk, axis) for x, axis in arrays]
 
 
 # ---------------------------------------------------------------------------
@@ -387,11 +402,13 @@ def _is_bucket(vals_dtype) -> bool:
     return jnp.issubdtype(vals_dtype, jnp.integer)
 
 
-def _scatter_hist_chunk(bins_c, vals_c, num_bins: int):
-    """(F, C) int bins, (3, C) vals → (3, F, B) via scatter-add: float32
-    sums, or int32 sums of int16 bucket ``vals_c``.  headroom: a chunk is
+def _scatter_hist_chunk(bins, vals, num_bins: int, i=0, chunk: Optional[int] = None):
+    """(F, n) int bins, (3, n) vals → (3, F, B) of rows ``[i·chunk,
+    (i+1)·chunk)`` (all of them with no ``chunk``) via scatter-add: float32
+    sums, or int32 sums of int16 bucket ``vals``.  headroom: a chunk is
     rows of one shard, and quantize_wire_plan refuses a fit whose rows ×
     largest bucket, channel by channel, reach 2³¹."""
+    bins_c, vals_c = _chunk_slices([(bins, 1), (vals, 1)], i, chunk)
     F, C = bins_c.shape
     acc = jnp.int32 if _is_bucket(vals_c.dtype) else jnp.float32
     idx = bins_c.T.astype(jnp.int32) + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
@@ -403,15 +420,18 @@ def _scatter_hist_chunk(bins_c, vals_c, num_bins: int):
     return flat.reshape(3, F, num_bins)
 
 
-def _scatter_hist_by_leaf_chunk(bins_c, vals_c, leaf_c, num_leaves: int, num_bins: int):
-    """(F, C) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B) scatter-add:
-    float32 sums, or int32 sums of int16 bucket ``vals_c``.  headroom:
+def _scatter_hist_by_leaf_chunk(bins, vals, leaf_ids, num_leaves: int, num_bins: int,
+                                i=0, chunk: Optional[int] = None):
+    """(F, n) bins + (3, n) vals + (n,) leaf ids → (3, L, F, B) of rows
+    ``[i·chunk, (i+1)·chunk)`` (all of them with no ``chunk``) scatter-add:
+    float32 sums, or int32 sums of int16 bucket ``vals``.  headroom:
     rows × largest bucket stays inside int32 (quantize_wire_plan).
 
     Rows parked outside ``[0, num_leaves)`` (including NEGATIVE ids from the
     windowed depthwise pass) are routed to a scratch slot and sliced off —
     negative flat indices would otherwise WRAP in ``.at[].add``.
     """
+    bins_c, vals_c, leaf_c = _chunk_slices([(bins, 1), (vals, 1), (leaf_ids, 0)], i, chunk)
     F, C = bins_c.shape
     acc = jnp.int32 if _is_bucket(vals_c.dtype) else jnp.float32
     leaf_c = leaf_c.astype(jnp.int32)
@@ -429,14 +449,14 @@ def _scatter_hist_by_leaf_chunk(bins_c, vals_c, leaf_c, num_leaves: int, num_bin
 
 def _chunked_hist(fn, acc0, bins, rows, chunk: int, axis_name, merge: str,
                   quantize: Optional[HistQuantize]):
-    """What both builders do with their chunk function ``fn(bins_chunk,
-    *row_chunks)``: sum it over the row chunks of the (F, n) ``bins``
-    into ``acc0``, merge the sum across shards, dequantize.
+    """What both builders do with their chunk function ``fn(bins, *rows,
+    i=, chunk=)``: sum it over the row chunks of the (F, n) ``bins`` into
+    ``acc0``, merge the sum across shards, dequantize.
 
-    ``rows`` holds the per-row arrays as ``(array, row axis)`` pairs, in
-    ``fn``'s argument order.  A chunk is the (F, chunk) column slice of
-    ``bins`` and the matching slice of each, taken inside the scan; the
-    matrix itself is never reshaped or transposed.
+    ``rows`` holds the per-row arrays in ``fn``'s argument order.  Every
+    call is handed all of them whole, and the chunk's index: reaching rows
+    ``[i·chunk, (i+1)·chunk)`` is ``fn``'s to do, each backend its own way
+    (module text).  The loop itself touches no array but its accumulator.
     """
     n = bins.shape[1]
     if quantize is not None and not _is_bucket(acc0.dtype):
@@ -444,17 +464,13 @@ def _chunked_hist(fn, acc0, bins, rows, chunk: int, axis_name, merge: str,
             "quantize needs the int16 buckets of quantize_hist_vals as vals"
         )
     if n <= chunk:
-        hist = fn(bins, *(x for x, _ in rows))
+        hist = fn(bins, *rows)
     else:
         if n % chunk != 0:
             raise ValueError(f"row count {n} not a multiple of chunk {chunk}")
 
         def body(acc, i):
-            with jax.named_scope("chunk_copy"):
-                sliced = [_row_chunk(bins, i, chunk, 1)] + [
-                    _row_chunk(x, i, chunk, axis) for x, axis in rows
-                ]
-            return acc + fn(*sliced), None
+            return acc + fn(bins, *rows, i=i, chunk=chunk), None
 
         hist, _ = lax.scan(body, acc0, jnp.arange(n // chunk))
     feature_axis = acc0.ndim - 2  # (3, F, B) | (3, L, F, B)
@@ -531,7 +547,7 @@ def build_histogram(
         (3, F, num_bins), jnp.int32 if _is_bucket(vals.dtype) else jnp.float32
     )
     return _chunked_hist(
-        fn, acc0, bins, [(vals, 1)], chunk, axis_name, merge, quantize
+        fn, acc0, bins, [vals], chunk, axis_name, merge, quantize
     )
 
 
@@ -600,6 +616,5 @@ def build_histogram_by_leaf(
         (3, num_leaves, F, num_bins), jnp.int32 if quant else jnp.float32
     )
     return _chunked_hist(
-        fn, acc0, bins, [(vals, 1), (leaf_ids, 0)], chunk, axis_name, merge,
-        quantize,
+        fn, acc0, bins, [vals, leaf_ids], chunk, axis_name, merge, quantize,
     )

@@ -33,25 +33,38 @@ Layout choices (TPU tiling wants the last dim lane-sized):
 VMEM budget per grid cell (by-leaf defaults bm=8192, bf=8, rm=1024):
 one-hot (256, 1024) f32 = 1 MiB + rhs/out tiles ≪ 16 MiB/core.
 
+Reading in place (ISSUE 37): a call is handed the growers' arrays WHOLE —
+the (F, n) matrix, the (3, n) row values, the leaf ids — and the index of
+the row chunk it is to sum as a prefetched scalar, which its grid's
+``index_map``s add to the row-block index (:func:`_chunk_grid`).  No chunk
+is sliced out and no column padded: a block is as tall as the matrix where
+the columns fit one block, else the last of ``cdiv(F, bf)`` blocks is
+ragged, and what it reads past column ``F`` lands in output columns the
+wrapper drops (a bin of any value indexes no row of a one-hot it should
+not).  Only rows that are no whole number of ``bm`` blocks are still
+sliced and padded (:func:`_place_chunk`): padded row values must be zero,
+which a ragged row block's are not.
+
 Distributed merge layout (ISSUE 4): the engine keeps features CONTIGUOUS
 on the feature axis of the kernel's output, so the reduce-scatter merge
 (``ops/histogram.py::merge_shard_histograms``) can ``psum_scatter`` that
 axis tiled — block i of the feature axis lands merged on mesh shard i
 with no re-layout between the kernel and the collective.  Feature padding
-for ``F % D != 0`` happens host-side before binning, so the kernel never
-sees a ragged feature axis.
+for ``F % D != 0`` (the mesh's D, not a block's height) happens host-side
+before binning, so the merge never sees a feature axis it cannot tile.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mmlspark_tpu.ops.histogram import _is_bucket
+from mmlspark_tpu.ops.histogram import _is_bucket, _row_chunk
 
 _PRECISIONS = {
     "highest": jax.lax.Precision.HIGHEST,
@@ -101,8 +114,50 @@ def _tile_dtype(vals_dtype):
     return jnp.int16 if _is_bucket(vals_dtype) else jnp.float32
 
 
-def _hist_kernel(bins_ref, vals_ref, out_ref, *, num_bins: int, precision):
-    """One (feature-block j, row-block i) cell: out[j] += vals·onehotᵀ."""
+def _chunk_grid(F: int, bf: int, bm: int, chunk: int, by_leaf: bool, out_block: tuple):
+    """The grid of one call over whole arrays: ``cdiv(F, bf)`` column blocks
+    by the ``chunk // bm`` row blocks of chunk ``c[0]``, the one prefetched
+    scalar: row block ``i`` of the chunk is block ``c[0] · (chunk // bm) + i``
+    of the array, read where it lies.  ``out_block`` is a column block's
+    ``(1, M, lanes)`` tile of the output, resident across the row blocks."""
+    k = chunk // bm
+
+    def rows(j, i, c):
+        return 0, c[0] * k + i
+
+    in_specs = [pl.BlockSpec((bf, bm), lambda j, i, c: (j, c[0] * k + i)), pl.BlockSpec((3, bm), rows)]
+    if by_leaf:
+        in_specs.append(pl.BlockSpec((1, bm), rows))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(pl.cdiv(F, bf), k), in_specs=in_specs,
+        out_specs=pl.BlockSpec(out_block, lambda j, i, c: (j, 0, 0)),
+    )
+
+
+def _place_chunk(bins_t, rows, i, chunk: int, bm: int):
+    """What a wrapper is called with to sum rows ``[i·chunk, (i+1)·chunk)``:
+    ``(bins_t, rows, c, chunk)``.  ``rows`` are the per-row ``(array, pad
+    value)`` pairs, rows last.  A chunk of whole ``bm`` blocks stays where
+    it lies and ``c`` carries its index.  Any other (small fits) is cut out
+    and padded to whole blocks, ``c`` 0: padded rows must carry zero values
+    and a parked leaf id, which the rows a ragged block would read in their
+    place do not."""
+    n = bins_t.shape[1]
+    pad = (-chunk) % bm
+    if not pad:
+        return bins_t, [x for x, _ in rows], jnp.asarray(i, jnp.int32).reshape(1), chunk
+
+    def cut(x, fill):
+        x = x if chunk == n else _row_chunk(x, i, chunk, 1)
+        return jnp.pad(x, ((0, 0), (0, pad)), constant_values=fill)
+
+    with jax.named_scope("chunk_copy"):
+        return cut(bins_t, 0), [cut(x, fill) for x, fill in rows], jnp.asarray([0], jnp.int32), chunk + pad
+
+
+def _hist_kernel(chunk_ref, bins_ref, vals_ref, out_ref, *, num_bins: int, precision):
+    """One (feature-block j, row-block i) cell: out[j] += vals·onehotᵀ.
+    ``chunk_ref`` is the prefetched chunk index, the ``index_map``s' alone."""
     i = pl.program_id(1)  # row block (innermost → accumulation is safe)
     quant = _is_bucket(vals_ref.dtype)
     # bins arrive uint8 at ≤256 bins (byte tier, ops/binpack.py) — the
@@ -158,70 +213,55 @@ def _hist_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, quant: bool):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_bins", "bm", "bf", "interpret", "precision")
+    jax.jit, static_argnames=("num_bins", "bm", "bf", "chunk", "interpret", "precision")
 )
 def _pallas_hist(
-    bins_t, vals, num_bins: int, bm: int, bf: int, interpret: bool, precision: str
+    bins_t, vals, c, num_bins: int, bm: int, bf: int, chunk: int, interpret: bool, precision: str
 ):
-    F, n = bins_t.shape
+    """(3, F, B) of chunk ``c[0]``'s ``chunk`` rows of the whole ``(F, n)``
+    ``bins_t`` and ``(3, n)`` ``vals`` (:func:`_chunk_grid`)."""
+    F = bins_t.shape[0]
+    blocks = pl.cdiv(F, bf)
     kernel = functools.partial(
         _hist_kernel, num_bins=num_bins, precision=_PRECISIONS[precision]
     )
     out = pl.pallas_call(
         kernel,
-        grid=(F // bf, n // bm),
-        in_specs=[
-            pl.BlockSpec((bf, bm), lambda j, i: (j, i)),
-            pl.BlockSpec((3, bm), lambda j, i: (0, i)),
-        ],
         # Output layout (F/bf, 3, bf·B): feature-block leading so the block
         # shape's last two dims (3, bf·B) satisfy TPU tiling by equalling
         # the array dims; the bin unflatten happens outside the kernel.
-        out_specs=pl.BlockSpec((1, 3, bf * num_bins), lambda j, i: (j, 0, 0)),
+        grid_spec=_chunk_grid(F, bf, bm, chunk, False, (1, 3, bf * num_bins)),
         # headroom: the int32 grid accumulator of a bucket build — rows ×
         # largest bucket per shard is attested statically by
         # ops.histogram.quantize_wire_plan before kernels run
         out_shape=jax.ShapeDtypeStruct(
-            (F // bf, 3, bf * num_bins),
+            (blocks, 3, bf * num_bins),
             jnp.int32 if _is_bucket(vals.dtype) else jnp.float32,
         ),
         interpret=interpret,
-    )(bins_t, vals)
-    return out.transpose(1, 0, 2).reshape(3, F, num_bins)
+    )(c, bins_t, vals)
+    # a ragged last block's columns past F are dropped here
+    return out.transpose(1, 0, 2).reshape(3, blocks * bf, num_bins)[:, :F]
 
 
 def pallas_hist_chunk(
-    bins_t, vals_c, num_bins: int, bm: int = 4096, bf: int = 32,
-    precision: str = "highest",
+    bins_t, vals, num_bins: int, bm: int = 4096, bf: int = 32,
+    precision: str = "highest", i=0, chunk: Optional[int] = None,
 ) -> jnp.ndarray:
-    """(F, C) int bins + (3, C) vals → (3, F, B), same contract as the
+    """(F, n) int bins + (3, n) vals → (3, F, B) of rows ``[i·chunk,
+    (i+1)·chunk)`` (all of them with no ``chunk``), same contract as the
     scatter chunk builder in :mod:`mmlspark_tpu.ops.histogram`: float32
-    sums of float ``vals_c``, int32 sums of int16 bucket ``vals_c``.
+    sums of float ``vals``, int32 sums of int16 bucket ``vals``.
 
-    ``bins_t`` is a column slice of the growers' (F, n) matrix — uint8
-    through the byte tier (``num_bins ≤ 256``), int32 past it.  The
-    kernel widens per VMEM block, so uint8 input quarters the per-pass
-    bins DMA.
+    ``bins_t`` is the growers' (F, n) matrix — uint8 through the byte
+    tier (``num_bins ≤ 256``), int32 past it.  The kernel widens per VMEM
+    block, so uint8 input quarters the per-pass bins DMA.
 
-    Pads rows/features up to block multiples (padded rows carry zero vals,
-    padded features are sliced off).
+    The chunk is read in place (:func:`_chunk_grid`).  Up to ``bf``
+    columns are one block as tall as the matrix, more are ``bf``-tall
+    blocks, the last ragged; only a chunk that is no whole number of row
+    blocks is cut out and padded (:func:`_place_chunk`).
     """
-    F, C = bins_t.shape
-    vals_c = vals_c.astype(_tile_dtype(vals_c.dtype))
-    # VMEM guard: the kernel's iota/one-hot tiles are (num_bins, bm); the
-    # defaults were swept at B=256, so scale bm down for bigger bin counts.
-    # Powers of two / 128-multiples only: Pallas requires 128-aligned
-    # trailing block dims (an 8-aligned guard broke num_bins like 712).
-    bm = min(bm, _pow2_floor(max(512, bm * 256 // num_bins)))
-    bm = min(bm, _round_up(C, 128))
-    bf = min(bf, max(8, _round_up(F, 8)))  # don't pad tiny feature counts 4x
-    pad_r = (-C) % bm
-    pad_f = (-F) % bf
-    if pad_r:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad_r)))
-        vals_c = jnp.pad(vals_c, ((0, 0), (0, pad_r)))
-    if pad_f:
-        bins_t = jnp.pad(bins_t, ((0, pad_f), (0, 0)))
     backend = jax.default_backend()
     if backend not in ("cpu", "tpu"):
         # The sequential-innermost-grid accumulation is a TPU contract; on
@@ -231,10 +271,20 @@ def pallas_hist_chunk(
             f"hist_backend='pallas' supports tpu (compiled) and cpu "
             f"(interpret) backends, not {backend!r}; use 'scatter'"
         )
-    out = _pallas_hist(
-        bins_t, vals_c, num_bins, bm, bf, backend == "cpu", precision
+    F, n = bins_t.shape
+    chunk = n if chunk is None else chunk
+    vals = vals.astype(_tile_dtype(vals.dtype))
+    # VMEM guard: the kernel's iota/one-hot tiles are (num_bins, bm); the
+    # defaults were swept at B=256, so scale bm down for bigger bin counts.
+    # Powers of two / 128-multiples only: Pallas requires 128-aligned
+    # trailing block dims (an 8-aligned guard broke num_bins like 712).
+    bm = min(bm, _pow2_floor(max(512, bm * 256 // num_bins)))
+    bm = min(bm, _round_up(chunk, 128))
+    bf = min(bf, F)  # a block as tall as the matrix, or a multiple of 8
+    bins_t, (vals,), c, chunk = _place_chunk(bins_t, [(vals, 0)], i, chunk, bm)
+    return _pallas_hist(
+        bins_t, vals, c, num_bins, bm, bf, chunk, backend == "cpu", precision
     )
-    return out[:, :F, :]  # (3, F, B)
 
 
 # ---------------------------------------------------------------------------
@@ -246,10 +296,11 @@ def pallas_hist_chunk(
 # depthwise window W=32 that is M=96, which feeds the MXU properly.
 # ---------------------------------------------------------------------------
 def _hist_leaf_kernel(
-    bins_ref, vals_ref, leaf_ref, out_ref, *,
+    chunk_ref, bins_ref, vals_ref, leaf_ref, out_ref, *,
     num_bins: int, num_leaves: int, rm: int, precision,
 ):
-    """One (feature-block j, row-block i) cell.
+    """One (feature-block j, row-block i) cell (``chunk_ref``: the
+    prefetched chunk index, the ``index_map``s' alone).
 
     The row block (bm) is deliberately LARGE with an in-kernel
     accumulation loop over ``rm``-row sub-blocks: VMEM tiles are bounded by
@@ -336,41 +387,38 @@ def _hist_leaf_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, quant: boo
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "num_leaves", "num_bins", "bm", "bf", "rm", "interpret", "precision"
+        "num_leaves", "num_bins", "bm", "bf", "rm", "chunk", "interpret", "precision"
     ),
 )
 def _pallas_hist_by_leaf(
-    bins_t, vals, leaf_ids, num_leaves, num_bins, bm, bf, rm, interpret, precision
+    bins_t, vals, leaf_ids, c, num_leaves, num_bins, bm, bf, rm, chunk, interpret, precision
 ):
-    F, n = bins_t.shape
+    """(3, L, F, B) of chunk ``c[0]``'s ``chunk`` rows of the whole ``(F,
+    n)`` ``bins_t``, ``(3, n)`` ``vals`` and ``(1, n)`` ``leaf_ids``
+    (:func:`_chunk_grid`)."""
+    F = bins_t.shape[0]
+    blocks = pl.cdiv(F, bf)
     kernel = functools.partial(
         _hist_leaf_kernel, num_bins=num_bins, num_leaves=num_leaves, rm=rm,
         precision=_PRECISIONS[precision],
     )
     out = pl.pallas_call(
         kernel,
-        grid=(F // bf, n // bm),
-        in_specs=[
-            pl.BlockSpec((bf, bm), lambda j, i: (j, i)),
-            pl.BlockSpec((3, bm), lambda j, i: (0, i)),
-            pl.BlockSpec((1, bm), lambda j, i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, num_leaves * 3, bf * num_bins), lambda j, i: (j, 0, 0)
-        ),
+        grid_spec=_chunk_grid(F, bf, bm, chunk, True, (1, num_leaves * 3, bf * num_bins)),
         # headroom: the int32 grid accumulator of a bucket build — rows ×
         # largest bucket per shard is attested statically by
         # ops.histogram.quantize_wire_plan before kernels run
         out_shape=jax.ShapeDtypeStruct(
-            (F // bf, num_leaves * 3, bf * num_bins),
+            (blocks, num_leaves * 3, bf * num_bins),
             jnp.int32 if _is_bucket(vals.dtype) else jnp.float32,
         ),
         compiler_params=_by_leaf_compiler_params(num_leaves, bf, num_bins),
         interpret=interpret,
-    )(bins_t, vals, leaf_ids)
-    # (F/bf, 3·L, bf·B) channel-major → (3, L, F, B)
-    out = out.reshape(F // bf, 3, num_leaves, bf, num_bins)
-    return out.transpose(1, 2, 0, 3, 4).reshape(3, num_leaves, F, num_bins)
+    )(c, bins_t, vals, leaf_ids)
+    # (F/bf, 3·L, bf·B) channel-major → (3, L, F, B); a ragged last block's
+    # columns past F are dropped here
+    out = out.reshape(blocks, 3, num_leaves, bf, num_bins)
+    return out.transpose(1, 2, 0, 3, 4).reshape(3, num_leaves, blocks * bf, num_bins)[:, :, :F]
 
 
 # Largest by-leaf accumulator (3·W · bf·B f32 elements) the v5e compiler
@@ -395,66 +443,67 @@ def _by_leaf_compiler_params(num_leaves: int, bf: int, num_bins: int):
 
 
 def _prep_by_leaf_chunk(
-    bins_t, vals_c, leaf_c, num_leaves: int, num_bins: int,
-    bm: int, bf: int, rm: int,
+    bins_t, vals, leaf_ids, num_leaves: int, num_bins: int,
+    bm: int, bf: int, rm: int, i=0, chunk: Optional[int] = None,
 ):
     """Shared wrapper prep for the by-leaf kernels: backend check, block
-    clamps, padding of the (F, C) bins slice.  Returns
-    (bins_t, vals, leaf_row, bm, bf, rm, F, interpret).  Float ``vals_c``
-    go in as f32, integer buckets as int16 (the row values DMA at half
-    width)."""
-    import jax as _jax
-
-    backend = _jax.default_backend()
+    choice, the chunk's place (:func:`_place_chunk`).  Returns
+    (bins_t, vals, leaf_row, c, chunk, bm, bf, rm, interpret): the call's
+    operands, ``bins_t`` the very matrix wherever the chunk is whole row
+    blocks.  Float ``vals`` go in as f32, integer buckets as int16 (the row
+    values DMA at half width)."""
+    backend = jax.default_backend()
     if backend not in ("cpu", "tpu"):
         raise NotImplementedError(
             f"hist_backend='pallas' supports tpu/cpu backends, not {backend!r}"
         )
-    F, C = bins_t.shape  # integer, uint8 through the byte tier
-    vals_c = vals_c.astype(_tile_dtype(vals_c.dtype))
-    leaf_row = leaf_c.astype(jnp.int32)[None, :]  # (1, C): lane-friendly
-    bf = min(bf, max(8, _round_up(F, 8)))  # don't pad tiny feature counts 4x
-    # Feature-block choice minimizes PADDED width: bf=32 on F=40 (the
-    # criteo schema) tiles to 64 — 37.5% of every pass histogramming
-    # padding; F=136 (the MSLR schema) tiles to 160 where bf=48 gives 144.
-    # Any multiple of 8 is a legal block height for uint8 as for int32
-    # bins (Mosaic pads the 8-bit (32, 128) tile itself).  The cap is
-    # VMEM: the grid-resident (3·W, bf·B) f32 accumulator, its loop carry
-    # and the double-buffered output block share the 16 MiB scoped default
-    # with the bins block — see _ACC_BUDGET_ELS.  Ties prefer the LARGER
-    # block (fewer grid steps amortize the per-block leaf-side rhs build).
+    F, n = bins_t.shape  # integer, uint8 through the byte tier
+    chunk = n if chunk is None else chunk
+    vals = vals.astype(_tile_dtype(vals.dtype))
+    leaf_row = leaf_ids.astype(jnp.int32)[None, :]  # (1, n): lane-friendly
+    # Feature blocks.  No column is padded: a block is as tall as the
+    # matrix (always a legal block dimension: 39 on the criteo schema, the
+    # refinement's 1) wherever that fits, else a multiple of 8 (legal for
+    # uint8 as for int32 bins: Mosaic pads the 8-bit (32, 128) tile itself)
+    # with the last block ragged.  A ragged block is looped over whole, so
+    # the choice is the FEWEST COLUMNS COMPUTED, blocks × height: bf=32 on
+    # F=220 (the istella schema) computes 224 where bf=48 computes 240; on
+    # F=136 (the MSLR schema) it computes 160 where bf=48 computes 144.  The
+    # cap is VMEM:
+    # the grid-resident (3·W, bf·B) f32 accumulator, its loop carry and the
+    # double-buffered output block share the 16 MiB scoped default with the
+    # bins block — see _ACC_BUDGET_ELS.  Ties prefer the LARGER block
+    # (fewer grid steps amortize the per-block leaf-side rhs build).
     cap = min(48, max(8, _ACC_BUDGET_ELS // (3 * num_leaves * num_bins) // 8 * 8))
-    cands = {bf, 24, 40, 48, max(8, min(48, _round_up(F, 8)))}
-    bf = min(
-        {c for c in cands if c <= cap} or {cap},
-        key=lambda c: (_round_up(F, c), -c),
-    )
+    if F <= cap:
+        bf = F
+    else:
+        bf = min(
+            {c for c in (bf, 24, 40, 48) if c <= cap} or {cap},
+            key=lambda c: (_round_up(F, c), -c),
+        )
     # VMEM guard: (num_bins, rm) one-hot tiles were swept at B=256.  rm
     # must stay a power of two ≥ 256: pl.ds offsets need 128 alignment and
     # the in-kernel loop needs rm | bm (an 8-aligned guard silently dropped
     # rows on the interpret path for num_bins like 304).
     rm = min(rm, _pow2_floor(max(256, rm * 256 // num_bins)))
-    bm = min(bm, _round_up(C, rm))
+    bm = min(bm, _round_up(chunk, rm))
     rm = min(rm, bm)
-    pad_r = (-C) % bm
-    pad_f = (-F) % bf
-    with jax.named_scope("chunk_copy"):
-        if pad_r:
-            bins_t = jnp.pad(bins_t, ((0, 0), (0, pad_r)))
-            vals_c = jnp.pad(vals_c, ((0, 0), (0, pad_r)))
-            # padded rows park at leaf == num_leaves → no one-hot slot
-            leaf_row = jnp.pad(leaf_row, ((0, 0), (0, pad_r)), constant_values=num_leaves)
-        if pad_f:
-            bins_t = jnp.pad(bins_t, ((0, pad_f), (0, 0)))
-    return bins_t, vals_c, leaf_row, bm, bf, rm, F, backend == "cpu"
+    # padded rows park at leaf == num_leaves → no one-hot slot
+    bins_t, (vals, leaf_row), c, chunk = _place_chunk(
+        bins_t, [(vals, 0), (leaf_row, num_leaves)], i, chunk, bm
+    )
+    return bins_t, vals, leaf_row, c, chunk, bm, bf, rm, backend == "cpu"
 
 
 def pallas_hist_by_leaf_chunk(
-    bins_t, vals_c, leaf_c, num_leaves: int, num_bins: int,
+    bins_t, vals, leaf_ids, num_leaves: int, num_bins: int,
     bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
+    i=0, chunk: Optional[int] = None,
 ) -> jnp.ndarray:
-    """(F, C) bins + (3, C) vals + (C,) leaf ids → (3, L, F, B): float32
-    sums of float ``vals_c``, int32 sums of int16 bucket ``vals_c`` (see
+    """(F, n) bins + (3, n) vals + (n,) leaf ids → (3, L, F, B) of rows
+    ``[i·chunk, (i+1)·chunk)``, read in place: float32 sums of float
+    ``vals``, int32 sums of int16 bucket ``vals`` (see
     :func:`pallas_hist_chunk`).
 
     ``rm`` bounds the VMEM one-hot tile AND sets the matmul contraction
@@ -463,14 +512,13 @@ def pallas_hist_by_leaf_chunk(
     leaf-side rhs build over 4x more matmul work (10.3 → 6.0 ms/pass);
     bf=64 and bm=32k×rm=2k blow the remote-compile VMEM budget.
     """
-    bins_t, vals_c, leaf_row, bm, bf, rm, F, interp = _prep_by_leaf_chunk(
-        bins_t, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm
+    bins_t, vals, leaf_row, c, chunk, bm, bf, rm, interp = _prep_by_leaf_chunk(
+        bins_t, vals, leaf_ids, num_leaves, num_bins, bm, bf, rm, i, chunk
     )
-    out = _pallas_hist_by_leaf(
-        bins_t, vals_c, leaf_row, num_leaves, num_bins, bm, bf, rm,
+    return _pallas_hist_by_leaf(
+        bins_t, vals, leaf_row, c, num_leaves, num_bins, bm, bf, rm, chunk,
         interp, precision,
     )
-    return out[:, :, :F]
 
 
 # ---------------------------------------------------------------------------
@@ -495,7 +543,7 @@ _NIBBLE_LO = 128
 
 
 def _hist_leaf_nibble_kernel(
-    bins_ref, vals_ref, leaf_ref, out_ref, *,
+    chunk_ref, bins_ref, vals_ref, leaf_ref, out_ref, *,
     num_bins: int, num_leaves: int, rm: int, precision,
 ):
     i = pl.program_id(1)  # row block, innermost → accumulation is safe
@@ -577,13 +625,15 @@ def _hist_leaf_nibble_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, qua
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "num_leaves", "num_bins", "bm", "bf", "rm", "interpret", "precision"
+        "num_leaves", "num_bins", "bm", "bf", "rm", "chunk", "interpret", "precision"
     ),
 )
 def _pallas_hist_by_leaf_nibble(
-    bins_t, vals, leaf_ids, num_leaves, num_bins, bm, bf, rm, interpret, precision
+    bins_t, vals, leaf_ids, c, num_leaves, num_bins, bm, bf, rm, chunk, interpret, precision
 ):
-    F, n = bins_t.shape
+    """As :func:`_pallas_hist_by_leaf`, through the factorized body."""
+    F = bins_t.shape[0]
+    blocks = pl.cdiv(F, bf)
     H = (num_bins + _NIBBLE_LO - 1) // _NIBBLE_LO
     M = 3 * num_leaves * H
     kernel = functools.partial(
@@ -592,50 +642,45 @@ def _pallas_hist_by_leaf_nibble(
     )
     out = pl.pallas_call(
         kernel,
-        grid=(F // bf, n // bm),
-        in_specs=[
-            pl.BlockSpec((bf, bm), lambda j, i: (j, i)),
-            pl.BlockSpec((3, bm), lambda j, i: (0, i)),
-            pl.BlockSpec((1, bm), lambda j, i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, M, bf * _NIBBLE_LO), lambda j, i: (j, 0, 0)),
+        grid_spec=_chunk_grid(F, bf, bm, chunk, True, (1, M, bf * _NIBBLE_LO)),
         # headroom: the int32 grid accumulator of a bucket build, as in
         # _pallas_hist_by_leaf (quantize_wire_plan's static check)
         out_shape=jax.ShapeDtypeStruct(
-            (F // bf, M, bf * _NIBBLE_LO),
+            (blocks, M, bf * _NIBBLE_LO),
             jnp.int32 if _is_bucket(vals.dtype) else jnp.float32,
         ),
         compiler_params=_by_leaf_compiler_params(
             num_leaves, bf, H * _NIBBLE_LO
         ),
         interpret=interpret,
-    )(bins_t, vals, leaf_ids)
-    # (F/bf, 3·W·H, bf·LO) → (3, W, F, H·LO) → slice the real bin range
-    out = out.reshape(F // bf, 3, num_leaves, H, bf, _NIBBLE_LO)
+    )(c, bins_t, vals, leaf_ids)
+    # (F/bf, 3·W·H, bf·LO) → (3, W, F, H·LO) → slice the real columns (a
+    # ragged last block's run past F) and the real bin range
+    out = out.reshape(blocks, 3, num_leaves, H, bf, _NIBBLE_LO)
     out = out.transpose(1, 2, 0, 4, 3, 5).reshape(
-        3, num_leaves, F, H * _NIBBLE_LO
+        3, num_leaves, blocks * bf, H * _NIBBLE_LO
     )
-    return out[:, :, :, :num_bins]
+    return out[:, :, :F, :num_bins]
 
 
 def pallas_hist_by_leaf_nibble_chunk(
-    bins_t, vals_c, leaf_c, num_leaves: int, num_bins: int,
+    bins_t, vals, leaf_ids, num_leaves: int, num_bins: int,
     bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
+    i=0, chunk: Optional[int] = None,
 ) -> jnp.ndarray:
     """Factorized-bin variant of :func:`pallas_hist_by_leaf_chunk` — same
-    contract, float32 sums of float ``vals_c`` and int32 sums of int16
-    bucket ``vals_c``, intended for small windows (see module comment
+    contract, float32 sums of float ``vals`` and int32 sums of int16
+    bucket ``vals``, intended for small windows (see module comment
     above).  The hi/lo factoring is a one-hot split and its un-factoring a
     reshape: of buckets it gives the plain kernel's int32 sums bit for
     bit."""
-    bins_t, vals_c, leaf_row, bm, bf, rm, F, interp = _prep_by_leaf_chunk(
-        bins_t, vals_c, leaf_c, num_leaves, num_bins, bm, bf, rm
+    bins_t, vals, leaf_row, c, chunk, bm, bf, rm, interp = _prep_by_leaf_chunk(
+        bins_t, vals, leaf_ids, num_leaves, num_bins, bm, bf, rm, i, chunk
     )
-    out = _pallas_hist_by_leaf_nibble(
-        bins_t, vals_c, leaf_row, num_leaves, num_bins, bm, bf, rm,
+    return _pallas_hist_by_leaf_nibble(
+        bins_t, vals, leaf_row, c, num_leaves, num_bins, bm, bf, rm, chunk,
         interp, precision,
     )
-    return out[:, :, :F]
 
 
 # ---------------------------------------------------------------------------
@@ -653,18 +698,19 @@ WRAPPERS = {
 def call_work(eqn) -> dict:
     """One call of a wrapper of :data:`WRAPPERS`, read off its ``jit``
     equation: ``body``, ``quant`` (int16 bucket values), ``rowcols`` (rows ×
-    padded columns the call reads), ``mxu_flops`` and ``vpu_elems``.  The
-    operands' ``(F, n)`` are on the equation; the inner ``pallas_call``'s
+    columns the call reads: its grid's cells times a block), ``mxu_flops``
+    and ``vpu_elems``.  All of it is the inner ``pallas_call``'s: the
+    operands are the growers' whole arrays, the grid is one chunk's.  Its
     blocks and result give ``bf``, ``bm``, ``M`` and ``N``, and the length
     of the body's loop over sub-blocks gives ``rm``."""
     body, work = WRAPPERS[eqn.params["name"]]
-    F, n = eqn.invars[0].aval.shape
     quant = _is_bucket(eqn.invars[1].aval.dtype)
     (call,) = (e for e in eqn.params["jaxpr"].eqns if e.primitive.name == "pallas_call")
-    bf, bm = (int(b.block_size) for b in call.params["grid_mapping"].block_mappings[0].block_shape)
+    grid = call.params["grid_mapping"]
+    bf, bm = (int(b.block_size) for b in grid.block_mappings[0].block_shape)
     _, M, lanes = call.params["out_avals"][0].shape
     loops = [e.params["length"] for e in call.params["jaxpr"].eqns if e.primitive.name == "scan"]
     rm = bm // int(loops[0]) if loops else bm
     flops, elems = work(M, lanes // bf, bf, bm, rm, quant)
-    cells = (F // bf) * (n // bm)
-    return {"body": body, "quant": quant, "rowcols": F * n, "mxu_flops": cells * flops, "vpu_elems": cells * elems}
+    cells = int(grid.grid[0]) * int(grid.grid[1])
+    return {"body": body, "quant": quant, "rowcols": cells * bf * bm, "mxu_flops": cells * flops, "vpu_elems": cells * elems}

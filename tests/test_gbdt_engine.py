@@ -203,8 +203,11 @@ class TestHistogram:
     def test_chunk_loop_relays_no_whole_array(self, kind, backend, F):
         """The relayout cannot come back unseen on a CPU: the chunked
         call's jaxpr holds no transpose or reshape of the whole
-        integer bins matrix and no transpose of the whole ``vals``; the scan's
-        body slices the matrix itself."""
+        integer bins matrix and no transpose of the whole ``vals``; a chunk
+        function that slices (the scatter functions; the by-leaf kernels
+        here, whose 1,024-row blocks a chunk of 256 rows does not fill)
+        slices the matrix itself, and the plain kernel, whose blocks the
+        chunk does fill, slices nothing."""
         import jax
 
         bins, *rest = self._chunk_inputs(F, seed=0)
@@ -226,7 +229,87 @@ class TestHistogram:
                     walk(sub)
 
         walk(jaxpr.jaxpr)
-        assert sliced == [(F, self._N)]
+        assert sliced == ([] if (kind, backend) == ("plain", "pallas") else [(F, self._N)])
+
+    # -- the kernels read the matrix in place: 3 chunks of whole row blocks --
+    _bodies = {"plain": "_pallas_hist", "by_leaf": "_pallas_hist_by_leaf", "nibble": "_pallas_hist_by_leaf_nibble"}
+
+    def _in_place_build(self, body, bins_t, vals, leaf, chunk):
+        """The builder whose router takes ``body`` at 256 bins: the plain
+        kernel, the by-leaf kernel at W = 32, the factorized one at W = 8."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops import histogram as H
+
+        hq = H.HistQuantize("int32", 0, jnp.ones(3, jnp.float32)) if vals.dtype == np.int16 else None
+        kw = dict(backend="pallas", chunk=chunk, quantize=hq)
+        if body == "plain":
+            return H.build_histogram(bins_t, vals, jnp.ones(bins_t.shape[1], bool), self._B, **kw)
+        return H.build_histogram_by_leaf(bins_t, vals, leaf, 32 if body == "by_leaf" else 8, self._B, **kw)
+
+    @pytest.mark.parametrize("F", [13, 39, 1])
+    @pytest.mark.parametrize("dtype", ["float32", "int16"])
+    @pytest.mark.parametrize("body", ["plain", "by_leaf", "nibble"])
+    def test_reading_in_place_sums_what_each_chunks_own_slice_sums(self, body, dtype, F):
+        """The whole matrix with a chunk index, against the parent's path
+        written out: the same body on each chunk's own host slice, added in
+        order.  Equal to the bit, at column counts that are no whole
+        blocks of 8, with rows parked on both sides of the window."""
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.ops import pallas_hist as PH
+
+        rng = np.random.default_rng(F)
+        chunk, W = 1024, 32 if body == "by_leaf" else 8
+        n = 3 * chunk
+        bins_t = rng.integers(0, self._B, size=(F, n)).astype(np.uint8)
+        if dtype == "int16":
+            vals = rng.integers(-127, 128, size=(3, n)).astype(np.int16)
+        else:
+            vals = rng.normal(size=(3, n)).astype(np.float32)
+        leaf = rng.integers(-2, W + 2, size=n).astype(np.int32)
+        leaf[chunk:chunk + 300] = np.where(rng.random(300) < 0.5, -1, W)
+        got = self._in_place_build(body, jnp.asarray(bins_t), jnp.asarray(vals), jnp.asarray(leaf), chunk)
+        fn = {"plain": PH.pallas_hist_chunk, "by_leaf": PH.pallas_hist_by_leaf_chunk, "nibble": PH.pallas_hist_by_leaf_nibble_chunk}[body]
+        want = 0
+        for i in range(n // chunk):
+            sl = slice(i * chunk, (i + 1) * chunk)
+            rows = (vals[:, sl],) if body == "plain" else (vals[:, sl], leaf[sl], W)
+            want = want + fn(jnp.asarray(bins_t[:, sl]), *rows, self._B)
+        assert want.dtype == (jnp.int32 if dtype == "int16" else jnp.float32)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want).astype(np.float32))
+
+    def test_pallas_chunk_loop_touches_no_array_as_long_as_the_matrix(self):
+        """Traced under ``backend="pallas"`` with chunks of whole row blocks,
+        the scan's body holds the kernel's call on the whole arrays and no
+        ``pad``, ``dynamic_slice`` or ``convert_element_type`` of anything
+        with the matrix's row count, in any of the three bodies."""
+        import jax
+        import jax.numpy as jnp
+
+        chunk, F = 1024, 39
+        n = 3 * chunk
+        args = (jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), jnp.float32), jnp.zeros(n, jnp.int32))
+        for body, wrapper in self._bodies.items():
+            jaxpr = jax.make_jaxpr(lambda *a, body=body: self._in_place_build(body, *a, chunk))(*args)
+            bodies, calls = [], []
+
+            def walk(jp, in_scan):
+                for eqn in jp.eqns:
+                    name = eqn.primitive.name
+                    if in_scan and name in ("pad", "dynamic_slice", "convert_element_type"):
+                        assert all(n not in v.aval.shape for v in eqn.invars if hasattr(v.aval, "shape")), (body, eqn)
+                    if in_scan and eqn.params.get("name") == wrapper:
+                        calls.append([v.aval.shape for v in eqn.invars])
+                    if name == "scan":
+                        bodies.append(eqn.params["length"])
+                    for sub in jax.core.jaxprs_in_params(eqn.params):
+                        walk(sub, in_scan or name == "scan")
+
+            walk(jaxpr.jaxpr, False)
+            assert bodies[0] == 3
+            rows = [(3, n)] if body == "plain" else [(3, n), (1, n)]
+            assert calls == [[(F, n), *rows, (1,)]], (body, calls)
 
     def test_pallas_matches_scatter(self):
         import jax.numpy as jnp
@@ -323,10 +406,10 @@ class TestSharedChunkBodies:
 
         n, F, B, W, bf = 1536, 8, 256, 8, 8
         acc_lanes = {bf * B, bf * PH._NIBBLE_LO}  # no other array here is this wide
-        static = dict(num_bins=B, bm=512, bf=bf, interpret=True, precision="highest")
-        rows = []
+        static = dict(num_bins=B, bm=512, bf=bf, chunk=n, interpret=True, precision="highest")
+        rows = [jnp.zeros(1, jnp.int32)]  # the chunk's index
         if kernel != "_pallas_hist":
-            rows = [jnp.zeros((1, n), jnp.int32)]
+            rows.insert(0, jnp.zeros((1, n), jnp.int32))
             static.update(num_leaves=W, rm=256)
 
         def int32_accumulation(val_dtype):
@@ -444,11 +527,13 @@ class TestByLeafKernels:
     @pytest.mark.parametrize("dtype,B", [("uint8", 256), ("int32", 512)])
     def test_block_shapes_obey_what_the_v5e_compile_taught(self, F, dtype, B):
         """The block choice of ``_prep_by_leaf_chunk``, against the rules
-        the Mosaic compile for v5e established (PR 21): (a) a 1-byte bins
-        block needs no more than the 8-row alignment 4-byte bins need —
-        bf=24/40/48 all lower; (b) the limit is scoped VMEM, reached when
-        the (3·W, bf·B) accumulator passes ``_ACC_BUDGET_ELS``; (c) the
-        bins stay at their HBM width (the kernel widens in VMEM)."""
+        the Mosaic compile for v5e established (PR 21, PR 37): (a) a block
+        is as tall as the matrix or a multiple of 8, for 1-byte bins as for
+        4-byte bins, and no column is padded: the operand is the matrix
+        handed in; (b) the limit is scoped VMEM, reached when the (3·W,
+        bf·B) accumulator passes ``_ACC_BUDGET_ELS``; (c) the bins stay at
+        their HBM width (the kernel widens in VMEM); (d) ``rm`` a power of
+        two, ``bm`` whole ``rm`` blocks and the chunk whole ``bm`` blocks."""
         import jax.numpy as jnp
 
         from mmlspark_tpu.ops.pallas_hist import (
@@ -457,22 +542,22 @@ class TestByLeafKernels:
         )
 
         n = 4096
+        matrix = jnp.zeros((F, n), dtype)
         for W in (8, 32):
-            bins_t, _, leaf_row, bm, bf, rm, F_out, _ = _prep_by_leaf_chunk(
-                jnp.zeros((F, n), dtype), jnp.zeros((3, n)),
-                jnp.zeros((n,), jnp.int32), W, B, 16384, 32, 1024,
+            bins_t, _, leaf_row, c, chunk, bm, bf, rm, _ = _prep_by_leaf_chunk(
+                matrix, jnp.zeros((3, n)), jnp.zeros((n,), jnp.int32), W, B, 16384, 32, 1024,
             )
-            Fp, n_pad = bins_t.shape
-            assert F_out == F and bins_t.dtype == jnp.dtype(dtype)
-            assert bf % 8 == 0 and 8 <= bf <= 48
-            assert Fp % bf == 0 and Fp - F < bf
+            assert bins_t is matrix and c.shape == (1,) and chunk == n
+            assert bf == F or (bf % 8 == 0 and 8 <= bf <= 48)
+            assert -(-F // bf) * bf - F < bf  # the last block holds a real column
             assert 3 * W * bf * B <= _ACC_BUDGET_ELS
             assert rm >= 256 and rm & (rm - 1) == 0
-            assert bm % rm == 0 and n_pad % bm == 0
-            assert leaf_row.shape == (1, n_pad)
+            assert bm % rm == 0 and chunk % bm == 0
+            assert leaf_row.shape == (1, n)
         if B == 256:
-            # the swept blocks of the benchmarked schemas are unchanged
-            assert bf == {39: 40, 64: 32, 136: 48}[F]
+            # the swept blocks of the benchmarked schemas: criteo's 39 columns
+            # one block of their own height, the wider ones as they were
+            assert bf == {39: 39, 64: 32, 136: 48}[F]
 
     def test_by_leaf_dispatch_through_build_histogram(self):
         """build_histogram_by_leaf's pallas dispatch (nibble for small W at

@@ -54,13 +54,14 @@ def _rise(before, after):
 PASSES = full_tree_passes(GrowConfig(num_bins=256, num_leaves=63, split_batch=8))  # 1, 2, 4, then 8 a pass: ten
 M, N = 3 * 8 * 2, 128  # the nibble body's tile at W = 8 and 256 bins
 CASES = {
-    # what one fit of ITERS trees passes over, by (body, vals, scope): passes and the padded columns a pass reads
+    # what one fit of ITERS trees passes over, by (body, vals, scope): passes and the columns a pass reads (a
+    # kernel's block is as tall as the matrix: six columns, and the refinement's one composed column)
     "float_scatter": (dict(), {("scatter", "f32", "hist_build"): (ITERS * (PASSES + 1), COLS)}),
-    "float_pallas": (dict(hist_backend="pallas"), {("nibble", "f32", "hist_build"): (ITERS * (PASSES + 1), 8)}),
+    "float_pallas": (dict(hist_backend="pallas"), {("nibble", "f32", "hist_build"): (ITERS * (PASSES + 1), COLS)}),
     "quant_scatter": (dict(use_quantized_grad=True, num_grad_quant_bins=4), {
         ("scatter", "i16", "quant_hist"): (ITERS * (PASSES + 1), COLS), ("scatter", "f32", "quant_refine"): (ITERS * PASSES, 1)}),
     "quant_pallas": (dict(use_quantized_grad=True, num_grad_quant_bins=4, hist_backend="pallas"), {
-        ("nibble", "i16", "quant_hist"): (ITERS * (PASSES + 1), 8), ("nibble", "f32", "quant_refine"): (ITERS * PASSES, 8)}),
+        ("nibble", "i16", "quant_hist"): (ITERS * (PASSES + 1), COLS), ("nibble", "f32", "quant_refine"): (ITERS * PASSES, 1)}),
 }
 
 
@@ -107,10 +108,11 @@ def _walk(jaxpr, times, acc):
 def test_stated_work_of_a_body_is_what_its_traced_body_builds(wrapper, leaves, vals_dtype):
     F, n = 16, 4096
     args = [jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), vals_dtype)]
-    kw = dict(num_bins=256, bm=2048, bf=8, interpret=True, precision="default")
+    kw = dict(num_bins=256, bm=2048, bf=8, chunk=n, interpret=True, precision="default")
     if leaves:
         args.append(jnp.zeros((1, n), jnp.int32))
         kw |= dict(num_leaves=leaves, rm=1024)
+    args.append(jnp.zeros(1, jnp.int32))  # the chunk's index
     jaxpr = jax.make_jaxpr(lambda *a: getattr(pallas_hist, wrapper)(*a, **kw))(*args)
     (eqn,) = (e for e in jaxpr.eqns if e.primitive.name in ("jit", "pjit"))
     work = pallas_hist.call_work(eqn)
@@ -120,6 +122,30 @@ def test_stated_work_of_a_body_is_what_its_traced_body_builds(wrapper, leaves, v
     assert (work["rowcols"], work["quant"]) == (F * n, vals_dtype == jnp.int16)
     assert work["vpu_elems"] == cells * body["vpu"]
     assert work["mxu_flops"] == cells * body["mxu"] == 2 * (3 * (leaves or 1)) * 256 * F * n
+
+
+def test_a_call_on_the_whole_matrix_counts_one_chunks_rowcols():
+    """The chunk loop hands every call the whole matrix: the ledger reads a
+    call's rows and columns off the inner grid and blocks, one chunk's, and a
+    pass over ``chunks`` calls counts the matrix once."""
+    from mmlspark_tpu.ops.histogram import build_histogram_by_leaf
+
+    F, chunk, chunks, W = 39, 2048, 3, 8
+    n = chunks * chunk
+    args = (jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), jnp.float32), jnp.zeros(n, jnp.int32))
+
+    def one_pass(*a):
+        with jax.named_scope("hist_build"):
+            return build_histogram_by_leaf(*a, W, 256, backend="pallas", chunk=chunk)
+
+    jaxpr = jax.make_jaxpr(one_pass)(*args)
+    (loop,) = (e for e in jaxpr.eqns if e.primitive.name == "scan")
+    (eqn,) = (e for e in loop.params["jaxpr"].eqns if e.params.get("name") == "_pallas_hist_by_leaf_nibble")
+    assert eqn.invars[0].aval.shape == (F, n)  # the operand is the matrix, not a chunk of it
+    work = pallas_hist.call_work(eqn)
+    assert work["rowcols"] == F * chunk and work["mxu_flops"] == 2 * M * N * F * chunk  # 12,288 a row-column
+    (ledger,) = booster_mod.hist_ledger(jaxpr).values()
+    assert ledger["passes"] == 1 and ledger["rowcols"] == F * n and ledger["mxu_flops"] == 12_288 * F * n
 
 
 # ---- the counters at each dispatch, and one grower trace a program -------------
