@@ -1872,7 +1872,7 @@ ml_k_n_n_model <- function(
 #' @param bagging_seed Bagging random seed
 #' @param boost_from_average Seed scores at the label average
 #' @param booster The trained booster
-#' @param boosting_type gbdt|rf|dart|goss
+#' @param boosting_type gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
 #' @param categorical_slot_indexes Categorical feature indices
 #' @param categorical_slot_names Categorical feature names
 #' @param default_listen_port Legacy socket-allreduce base port (no-op on TPU)
@@ -2054,7 +2054,7 @@ ml_light_g_b_m_classification_model <- function(
 #' @param bagging_freq Resample bag every k iterations (0 = off)
 #' @param bagging_seed Bagging random seed
 #' @param boost_from_average Seed scores at the label average
-#' @param boosting_type gbdt|rf|dart|goss
+#' @param boosting_type gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
 #' @param categorical_slot_indexes Categorical feature indices
 #' @param categorical_slot_names Categorical feature names
 #' @param default_listen_port Legacy socket-allreduce base port (no-op on TPU)
@@ -2234,7 +2234,7 @@ ml_light_g_b_m_classifier <- function(
 #' @param bagging_freq Resample bag every k iterations (0 = off)
 #' @param bagging_seed Bagging random seed
 #' @param boost_from_average Seed scores at the label average
-#' @param boosting_type gbdt|rf|dart|goss
+#' @param boosting_type gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
 #' @param categorical_slot_indexes Categorical feature indices
 #' @param categorical_slot_names Categorical feature names
 #' @param default_listen_port Legacy socket-allreduce base port (no-op on TPU)
@@ -2421,7 +2421,7 @@ ml_light_g_b_m_ranker <- function(
 #' @param bagging_seed Bagging random seed
 #' @param boost_from_average Seed scores at the label average
 #' @param booster The trained booster
-#' @param boosting_type gbdt|rf|dart|goss
+#' @param boosting_type gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
 #' @param categorical_slot_indexes Categorical feature indices
 #' @param categorical_slot_names Categorical feature names
 #' @param default_listen_port Legacy socket-allreduce base port (no-op on TPU)
@@ -2595,7 +2595,7 @@ ml_light_g_b_m_ranker_model <- function(
 #' @param bagging_seed Bagging random seed
 #' @param boost_from_average Seed scores at the label average
 #' @param booster The trained booster
-#' @param boosting_type gbdt|rf|dart|goss
+#' @param boosting_type gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
 #' @param categorical_slot_indexes Categorical feature indices
 #' @param categorical_slot_names Categorical feature names
 #' @param default_listen_port Legacy socket-allreduce base port (no-op on TPU)
@@ -2769,7 +2769,7 @@ ml_light_g_b_m_regression_model <- function(
 #' @param bagging_freq Resample bag every k iterations (0 = off)
 #' @param bagging_seed Bagging random seed
 #' @param boost_from_average Seed scores at the label average
-#' @param boosting_type gbdt|rf|dart|goss
+#' @param boosting_type gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
 #' @param categorical_slot_indexes Categorical feature indices
 #' @param categorical_slot_names Categorical feature names
 #' @param default_listen_port Legacy socket-allreduce base port (no-op on TPU)

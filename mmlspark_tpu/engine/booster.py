@@ -10,7 +10,10 @@ upstream C++ ``src/boosting/gbdt.cpp``).  Differences by design:
   is control only (early stopping, metric records, DART bookkeeping) —
   mirroring how the reference keeps its loop in Scala but the work native.
 - Boosting modes: ``gbdt``, ``rf``, ``dart``, ``goss`` (SURVEY.md §2.3.1
-  ``boostingType``).
+  ``boostingType``).  ``goss`` draws an exact-count sample every iteration
+  (:func:`goss_sample`: no sort) and, on one device, grows the tree from
+  the sample's rows alone (:func:`goss_compact`), then routes every row
+  through it.
 - ``boost_from_average`` folds the initial score into tree 0's leaf values
   (LightGBM's ``Tree::AddBias`` behavior) so saved models predict
   identically without a separate init-score field.
@@ -36,6 +39,7 @@ from mmlspark_tpu.engine.tree import (
     GrowConfig,
     Tree,
     _leaf_lookup,
+    _replay_leaf_ids,
     grow_tree_auto,
     predict_tree_binned,
     predict_tree_leaf_binned,
@@ -1097,32 +1101,105 @@ class Booster:
 # ---------------------------------------------------------------------------
 # Sampling helpers (bagging / GOSS / feature_fraction)
 # ---------------------------------------------------------------------------
-def _bag_weights(key, cfg: TrainConfig, valid_mask, grad_abs):
-    """Per-row bag weight for this iteration (0 = excluded).
-
-    GOSS (``boosting="goss"``): keep the top ``top_rate`` fraction by
-    |gradient|, sample ``other_rate`` of the rest amplified by
-    (1-top_rate)/other_rate — LightGBM's gradient one-side sampling.
-    """
-    n = valid_mask.shape[0]
-    n_valid = jnp.sum(valid_mask)
-    if cfg.boosting == "goss":
-        a, b = cfg.top_rate, cfg.other_rate
-        k_top = jnp.maximum((n_valid * a).astype(jnp.int32), 1)
-        g = jnp.where(valid_mask, grad_abs, -1.0)
-        order = jnp.argsort(-g)
-        rank = jnp.argsort(order)
-        top = rank < k_top
-        rest = valid_mask & ~top
-        u = jax.random.uniform(key, (n,))
-        sampled = rest & (u < b)
-        amp = (1.0 - a) / max(b, 1e-12)
-        return jnp.where(top, 1.0, jnp.where(sampled, amp, 0.0))
+def _bag_weights(key, cfg: TrainConfig, valid_mask):
+    """Per-row bag weight of LightGBM's bagging for this iteration: 1 on the
+    rows drawn (each valid row with probability ``bagging_fraction``), 0
+    elsewhere.  GOSS draws its own sample from the iteration's gradients
+    (:func:`goss_sample`)."""
     frac = cfg.bagging_fraction
     if frac < 1.0:
-        u = jax.random.uniform(key, (n,))
+        u = jax.random.uniform(key, valid_mask.shape)
         return (valid_mask & (u < frac)).astype(jnp.float32)
     return valid_mask.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Gradient one-side sampling (GOSS: Ke et al. 2017, Algorithm 2)
+# ---------------------------------------------------------------------------
+GOSS_TAG = 0x6055  # folded into an iteration's sampling key for the rest's draw
+
+
+def goss_counts(n_valid: int, top_rate: float, other_rate: float) -> Tuple[int, int]:
+    """``(top, rest)``: the rows of a GOSS sample of ``n_valid`` rows, fixed
+    by the row count alone: ``⌊top_rate·n⌋`` (at least one) of largest
+    gradient, and ``⌊other_rate·n⌋`` of the others."""
+    top = min(max(1, math.floor(top_rate * n_valid)), n_valid)
+    return top, min(math.floor(other_rate * n_valid), n_valid - top)
+
+
+def goss_amplification(top_rate: float, other_rate: float) -> float:
+    """The weight of a rest row, ``(1 - top_rate) / other_rate`` in float32."""
+    return float(np.float32((1.0 - top_rate) / max(other_rate, 1e-12)))
+
+
+def _largest(keys, eligible, k):
+    """The ``k`` eligible rows of largest ``uint32`` key, ties to the lower
+    row index, with no sort: the k-th largest key is found bit by bit from
+    32 counts over the rows, and the rows AT it are taken in row order by a
+    running count."""
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        return jnp.where(jnp.sum(eligible & (keys >= cand)) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, bit, jnp.uint32(0))
+    above = eligible & (keys > t)
+    at = eligible & (keys == t)
+    return above | (at & (jnp.cumsum(at) <= k - jnp.sum(above)))
+
+
+def goss_sample(grad, valid_mask, key, k_top: int, k_rest: int, amp: float):
+    """One iteration's GOSS weights, ``(n,)`` float32: 1 on the ``k_top``
+    valid rows of largest ``Σ_k |g_k|`` (``grad`` is ``(K, n)``), ``amp``
+    on ``k_rest`` of the other valid rows, those of smallest
+    ``u = uniform(fold_in(key, GOSS_TAG))``, 0 elsewhere; ties go to the
+    lower row index on both sides, so the counts are exact."""
+    with jax.named_scope("goss_select"):
+        s = jnp.sum(jnp.abs(grad), axis=0)  # >= 0: its bits order as its values
+        top = _largest(jax.lax.bitcast_convert_type(s, jnp.uint32), valid_mask, k_top)
+        u = jax.random.uniform(jax.random.fold_in(key, GOSS_TAG), valid_mask.shape)
+        rest = _largest(~jax.lax.bitcast_convert_type(u, jnp.uint32), valid_mask & ~top, k_rest)
+        return jnp.where(top, 1.0, jnp.where(rest, amp, 0.0)).astype(jnp.float32)
+
+
+def goss_rows(m: int, chunk: int) -> int:
+    """Rows of the buffer a sample of ``m`` rows is gathered into: whole
+    histogram chunks where it spans more than one (the chunk loop's shape)."""
+    return m if m <= chunk else -(-m // chunk) * chunk
+
+
+def goss_compact(bins, grad, hess, bag, rows: int, backend: str = "scatter"):
+    """The sample (rows of weight > 0) gathered in row order into buffers of
+    ``rows`` rows: ``(bins (rows, F), grad (K, rows), hess (K, rows), bag
+    (rows,))``; the buffer's rows past the sample hold weight 0.  On the
+    ``pallas`` backend one streaming kernel does it
+    (``ops/pallas_compact.py``: a TPU gathers at 30-45 ns an index);
+    elsewhere XLA's scatter and gathers."""
+    with jax.named_scope("goss_compact"):
+        if backend == "pallas":
+            from mmlspark_tpu.ops.pallas_compact import compact_rows
+
+            K = grad.shape[0]
+            bins_c, vals_c = compact_rows(
+                bins.T, jnp.concatenate([grad, hess, bag[None, :]]), rows
+            )
+            return bins_c.T, vals_c[:K], vals_c[K : 2 * K], vals_c[2 * K]
+        n = bag.shape[0]
+        take = bag > 0
+        rows_n = jnp.arange(n, dtype=jnp.int32)
+        # each row its own slot: the sample's in order, the others past the end
+        slot = jnp.where(take, jnp.cumsum(take) - 1, rows + rows_n)
+        idx = jnp.zeros(rows, jnp.int32).at[slot].set(rows_n, mode="drop", unique_indices=True)
+        held = jnp.arange(rows) < jnp.sum(take)
+        idx = jnp.where(held, idx, n - 1)  # the pad reads the last row: idx stays sorted
+
+        def gather(x, axis):
+            return jnp.take(x, idx, axis=axis, mode="clip", indices_are_sorted=True)
+
+        return (
+            gather(bins, 0), gather(grad, 1), gather(hess, 1),
+            jnp.where(held, gather(bag, 0), 0.0),
+        )
 
 
 def _feature_mask(key, F: int, fraction: float):
@@ -2364,6 +2441,21 @@ def _train_impl(
         )
     else:
         quantize_shift = 0
+    # ---- GOSS: an exact-count sample every iteration -------------------
+    # Counts follow the real rows alone, so they are known here.  On one
+    # device the trees grow from the sample alone, gathered into a buffer of
+    # goss_buf rows (goss_compact), and every row is routed through the new
+    # tree after; over a mesh the rows stay where they lie and the sample
+    # rides the bag weights.
+    goss = cfg.boosting == "goss"
+    goss_top = goss_rest = 0
+    goss_buf = None
+    if goss:
+        n_real = int(np.sum(proc_counts)) if process_local else n
+        goss_top, goss_rest = goss_counts(n_real, cfg.top_rate, cfg.other_rate)
+        if mesh is None:
+            goss_buf = goss_rows(goss_top + goss_rest, chunk)
+    goss_amp = goss_amplification(cfg.top_rate, cfg.other_rate)
     gcfg = GrowConfig(
         num_bins=B,
         num_leaves=cfg.num_leaves,
@@ -2529,16 +2621,12 @@ def _train_impl(
         )
         if K == 1:
             grad, hess = grad[None, :], hess[None, :]
-        gkey, fkey = jax.random.split(key)
+        _, fkey = jax.random.split(key)
         # Decouple the feature-sampling stream from bagging (LightGBM has
-        # independent feature_fraction_seed / bagging_seed streams).
+        # independent feature_fraction_seed / bagging_seed streams).  The
+        # per-iteration loop is DART's alone (GOSS always takes the scan).
         fkey = jax.random.fold_in(fkey, cfg.feature_fraction_seed)
-        if cfg.boosting == "goss":
-            # GOSS resamples every iteration from the current gradients.
-            grad_abs = jnp.sum(jnp.abs(grad), axis=0)
-            bag = _bag_weights(gkey, cfg, vmask_a, grad_abs)
-        else:
-            bag = bag_in
+        bag = bag_in
         fmask = jax.vmap(_fmask_one)(jax.random.split(fkey, K))
         if quantize_on:
             qkeys, qscales = _quantize_inputs(grad, hess, bag, key)
@@ -2551,11 +2639,7 @@ def _train_impl(
 
     # LightGBM bagging semantics: a bag is drawn at iterations where
     # ``it % bagging_freq == 0`` and *reused* until the next draw.
-    resample_bag = jax.jit(
-        lambda key, vmask_a: _bag_weights(
-            key, cfg, vmask_a, jnp.zeros(vmask_a.shape[0])
-        )
-    )
+    resample_bag = jax.jit(lambda key, vmask_a: _bag_weights(key, cfg, vmask_a))
     do_bagging = cfg.bagging_fraction < 1.0 and cfg.bagging_freq > 0
     full_bag = valid_mask.astype(jnp.float32)
     current_bag = full_bag
@@ -2952,26 +3036,30 @@ def _train_impl(
                         grad, hess = grad[None, :], hess[None, :]
                     gkey, fkey = jax.random.split(key)
                     fkey = jax.random.fold_in(fkey, cfg.feature_fraction_seed)
-                    if cfg.boosting == "goss":
-                        grad_abs = jnp.sum(jnp.abs(grad), axis=0)
-                        bag = _bag_weights(gkey, cfg, vmask_a, grad_abs)
+                    if goss:
+                        bag = goss_sample(grad, vmask_a, gkey, goss_top,
+                                          goss_rest, goss_amp)
                     elif do_bagging:
-                        bag = _bag_weights(
-                            bag_key, cfg, vmask_a, jnp.zeros(vmask_a.shape[0])
-                        )
+                        bag = _bag_weights(bag_key, cfg, vmask_a)
                     else:
                         bag = vmask_a.astype(jnp.float32)
                     fmask = jax.vmap(_fmask_one)(
                         jax.random.split(fkey, K)
                     )
+                    rows_g = (bins_a, grad, hess, bag)
+                    if goss_buf is not None:
+                        rows_g = goss_compact(*rows_g, goss_buf,
+                                              backend=gcfg.hist_backend)
                     if quantize_on:
-                        qkeys, qscales = _quantize_inputs(
-                            grad, hess, bag, key
-                        )
-                        tree, leaf_ids = grow(bins_a, grad, hess, bag,
-                                              fmask, qkeys, qscales)
+                        qkeys, qscales = _quantize_inputs(*rows_g[1:], key)
+                        tree, leaf_ids = grow(*rows_g, fmask, qkeys, qscales)
                     else:
-                        tree, leaf_ids = grow(bins_a, grad, hess, bag, fmask)
+                        tree, leaf_ids = grow(*rows_g, fmask)
+                    if goss_buf is not None:
+                        # the grower's leaf ids are the buffer's rows: the
+                        # score update routes every row through the tree
+                        leaf_ids = jax.vmap(lambda t: _replay_leaf_ids(
+                            t, bins_a, B, scope="goss_route"))(tree)
                     delta = _leaf_delta(tree, leaf_ids)
                     if dart_scan:
                         # DART normalization (legacy-loop semantics): new
@@ -3085,6 +3173,7 @@ def _train_impl(
             cache_key = (
                 _cfg_cache_key(cfg), K, F, F_real, B, _mesh_cache_key(mesh),
                 type(obj).__name__, state_key, gcfg,
+                (goss_top, goss_rest, goss_buf),  # follow the row count
             )
             entry = _SCAN_CACHE.get(cache_key)
             scan_cache_hit = entry is not None
@@ -3104,7 +3193,7 @@ def _train_impl(
             if "hist_ledger" not in program_notes:
                 program_notes.update(_grow_ledgers(
                     grow, gcfg, K, bins_dev, F, quantize_on,
-                    merges=mesh is not None and D > 1,
+                    merges=mesh is not None and D > 1, rows=goss_buf,
                 ))
             merge_ledger = program_notes["merge_ledger"]
             hist_ledger = program_notes["hist_ledger"]
@@ -3143,6 +3232,7 @@ def _train_impl(
                         len(vsets), cfg.is_provide_training_metric,
                         tuple(metric_names) if device_eval else None,
                         gcfg,  # data-derived statics (cat_value_bins, ...)
+                        (goss_top, goss_rest, goss_buf),
                         mesh_trace_key(mesh), process_local, feature_par,
                     )),
                     # Load-vs-export agreement only for programs every rank
@@ -3277,6 +3367,11 @@ def _train_impl(
             scan_cache_hit=scan_cache_hit, devices=D,
             hist_merge=gcfg.hist_merge if mesh is not None and D > 1 else "none",
         )
+        if goss:
+            sp_program.set(goss=(
+                f"a={cfg.top_rate} b={cfg.other_rate} m={goss_top + goss_rest}"
+                f" buffer={goss_buf or 0}"
+            ))
         if quantize_on:
             sp_program.set(
                 quant_levels="x".join(str(v) for v in qlevels),
@@ -3328,6 +3423,9 @@ def _train_impl(
                     obs.inc("hist." + name, float(per_iter * c), body=body, vals=vals_kind, scope=scope)
             if quantize_on and hist_ledger:
                 obs.inc("train.quant_refine_cols", float(program_notes["quant_refine_cols"] * c))
+            if goss:
+                for name, per_iter in (("top_rows", goss_top), ("rest_rows", goss_rest), ("sample_rows", goss_top + goss_rest)):
+                    obs.inc("goss." + name, float(per_iter * c))
             if quantize_on:
                 trees_c, vsnap_c, qsc_c = scan_ys
             else:
@@ -3608,16 +3706,18 @@ def _placement(arr) -> dict:
     }
 
 
-def _grow_jaxpr(grow, K: int, bins_dev, F_mask: int, quantized: bool):
-    """The grower's jaxpr at the fit's shapes: an abstract trace, made once
+def _grow_jaxpr(grow, K: int, bins_dev, F_mask: int, quantized: bool,
+                rows: Optional[int] = None):
+    """The grower's jaxpr at the fit's shapes (``rows`` rows where it grows
+    from a buffer of its own, GOSS's sample): an abstract trace, made once
     a program, with recording off so the trace-time ``collective.*``
     counters do not tick for it."""
     from mmlspark_tpu.obs import _state as obs_state
 
-    n = bins_dev.shape[0]
+    n = rows or bins_dev.shape[0]
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
     args = [
-        jax.ShapeDtypeStruct(bins_dev.shape, bins_dev.dtype), f32(K, n),
+        jax.ShapeDtypeStruct((n,) + bins_dev.shape[1:], bins_dev.dtype), f32(K, n),
         f32(K, n), f32(n), jax.ShapeDtypeStruct((K, F_mask), jnp.bool_),
     ]
     if quantized:
@@ -3630,7 +3730,7 @@ def _grow_jaxpr(grow, K: int, bins_dev, F_mask: int, quantized: bool):
 
 
 def _grow_ledgers(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
-                  quantized: bool, merges: bool) -> dict:
+                  quantized: bool, merges: bool, rows: Optional[int] = None) -> dict:
     """The ``program_notes`` of ONE boosting iteration, from one abstract
     trace of the grower: ``merge_ledger``, the bytes each device receives in
     its collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`;
@@ -3646,7 +3746,7 @@ def _grow_ledgers(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
     from mmlspark_tpu.engine.tree import full_tree_passes, windowed_grower
     from mmlspark_tpu.parallel.distributed import collective_ledger
 
-    jaxpr = _grow_jaxpr(grow, K, bins_dev, F_mask, quantized)
+    jaxpr = _grow_jaxpr(grow, K, bins_dev, F_mask, quantized, rows)
     trips = full_tree_passes(gcfg)
     hists = hist_ledger(jaxpr, while_trips=trips)
     refines = sum(work["passes"] for (_, _, scope), work in hists.items() if scope == "quant_refine")

@@ -165,8 +165,9 @@ def _validate_job(cfg: TrainConfig, job: MultiTrainJob, i: int) -> None:
     if cfg.boosting != "gbdt":
         raise ValueError(
             f"multi_train supports boosting='gbdt' only; {tag} asked for "
-            f"{cfg.boosting!r} (dart/rf/goss reshape the per-iteration "
-            "loop and cannot share the stacked program)"
+            f"{cfg.boosting!r} (dart/rf reshape the per-iteration loop, and "
+            "goss grows each tree from a sample of its own model's rows: "
+            "none can share the stacked program)"
         )
     if cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0:
         raise ValueError(

@@ -1574,14 +1574,18 @@ def grow_tree_auto(cfg: GrowConfig, *args):
     return grow_tree(cfg, *args)
 
 
-def _replay_leaf_ids(tree: Tree, bins: jnp.ndarray, num_bins: int) -> jnp.ndarray:
+def _replay_leaf_ids(tree: Tree, bins: jnp.ndarray, num_bins: int,
+                     scope: str = "replay_step") -> jnp.ndarray:
     """Replay a tree's splits over binned rows → per-row leaf ids.
 
     Split replay keeps prediction gather-free over tree topology: rows start
     in leaf 0 and each recorded split moves the affected rows, mirroring the
     growth procedure exactly (same arithmetic ⇒ train/predict parity): a
     step reads one column and tests set membership with the grower's
-    bit-packed :func:`_member_lookup`, never a per-row table entry.
+    bit-packed :func:`_member_lookup`, never a per-row table entry.  The
+    steps run under the named ``scope``: the scorers' ``replay_step``, or a
+    GOSS fit's ``goss_route`` (every training row routed through the tree
+    grown from the sample).
     """
     n = bins.shape[0]
     S = tree.split_leaf.shape[0]
@@ -1602,7 +1606,7 @@ def _replay_leaf_ids(tree: Tree, bins: jnp.ndarray, num_bins: int) -> jnp.ndarra
         move = active & (leaf_ids == tree.split_leaf[s]) & ~goes_left
         return jnp.where(move, s + 1, leaf_ids)
 
-    with jax.named_scope("replay_step"):
+    with jax.named_scope(scope):
         return lax.fori_loop(0, S, step, jnp.zeros(n, jnp.int32))
 
 

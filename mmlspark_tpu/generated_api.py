@@ -1145,7 +1145,7 @@ class LightGBMClassificationModel(_LightGBMClassificationModel):
       baggingSeed: Bagging random seed
       boostFromAverage: Seed scores at the label average
       booster: The trained booster
-      boostingType: gbdt|rf|dart|goss
+      boostingType: gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
       categoricalSlotIndexes: Categorical feature indices
       categoricalSlotNames: Categorical feature names
       defaultListenPort: Legacy socket-allreduce base port (no-op on TPU)
@@ -1213,7 +1213,7 @@ class LightGBMClassifier(_LightGBMClassifier):
       baggingFreq: Resample bag every k iterations (0 = off)
       baggingSeed: Bagging random seed
       boostFromAverage: Seed scores at the label average
-      boostingType: gbdt|rf|dart|goss
+      boostingType: gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
       categoricalSlotIndexes: Categorical feature indices
       categoricalSlotNames: Categorical feature names
       defaultListenPort: Legacy socket-allreduce base port (no-op on TPU)
@@ -1281,7 +1281,7 @@ class LightGBMRanker(_LightGBMRanker):
       baggingFreq: Resample bag every k iterations (0 = off)
       baggingSeed: Bagging random seed
       boostFromAverage: Seed scores at the label average
-      boostingType: gbdt|rf|dart|goss
+      boostingType: gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
       categoricalSlotIndexes: Categorical feature indices
       categoricalSlotNames: Categorical feature names
       defaultListenPort: Legacy socket-allreduce base port (no-op on TPU)
@@ -1352,7 +1352,7 @@ class LightGBMRankerModel(_LightGBMRankerModel):
       baggingSeed: Bagging random seed
       boostFromAverage: Seed scores at the label average
       booster: The trained booster
-      boostingType: gbdt|rf|dart|goss
+      boostingType: gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
       categoricalSlotIndexes: Categorical feature indices
       categoricalSlotNames: Categorical feature names
       defaultListenPort: Legacy socket-allreduce base port (no-op on TPU)
@@ -1418,7 +1418,7 @@ class LightGBMRegressionModel(_LightGBMRegressionModel):
       baggingSeed: Bagging random seed
       boostFromAverage: Seed scores at the label average
       booster: The trained booster
-      boostingType: gbdt|rf|dart|goss
+      boostingType: gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
       categoricalSlotIndexes: Categorical feature indices
       categoricalSlotNames: Categorical feature names
       defaultListenPort: Legacy socket-allreduce base port (no-op on TPU)
@@ -1484,7 +1484,7 @@ class LightGBMRegressor(_LightGBMRegressor):
       baggingFreq: Resample bag every k iterations (0 = off)
       baggingSeed: Bagging random seed
       boostFromAverage: Seed scores at the label average
-      boostingType: gbdt|rf|dart|goss
+      boostingType: gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)
       categoricalSlotIndexes: Categorical feature indices
       categoricalSlotNames: Categorical feature names
       defaultListenPort: Legacy socket-allreduce base port (no-op on TPU)

@@ -167,7 +167,10 @@ class _LightGBMParams(
     lambdaL1 = Param("lambdaL1", "L1 regularization", default=0.0, dtype=float)
     lambdaL2 = Param("lambdaL2", "L2 regularization", default=0.0, dtype=float)
     boostingType = Param(
-        "boostingType", "gbdt|rf|dart|goss", default="gbdt", dtype=str,
+        "boostingType",
+        "gbdt|rf|dart|goss (goss: each tree grown from an exact-count sample, the 20% of rows "
+        "of largest gradient and 10% of the others at weight 8, LightGBM's top_rate/other_rate)",
+        default="gbdt", dtype=str,
         validator=ParamValidators.inList(["gbdt", "rf", "dart", "goss"]),
     )
     objective = Param("objective", "Training objective", default="regression", dtype=str)

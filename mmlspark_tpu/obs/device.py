@@ -168,7 +168,8 @@ def summary(snapshot: Optional[dict] = None) -> dict:
 SCOPES = (
     "split_scan", "hist_build", "quant_hist", "quant_refine", "quant_round",
     "leaf_stats", "leaf_delta", "replay_step", "hist_merge", "rank_grad",
-    "rank_ndcg", "row_route", "chunk_copy",
+    "rank_ndcg", "row_route", "chunk_copy", "goss_select", "goss_compact",
+    "goss_route",
 )
 
 _PROGRAMS: dict = {}  # (label, same, argument tree, shapes) -> [callable, abstract args, map]
