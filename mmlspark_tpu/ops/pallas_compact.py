@@ -8,16 +8,19 @@ A TPU gathers one index at a time, 30-45 ns however few bytes it fetches
 rows takes seconds.  This kernel streams the rows instead.  Each grid step
 reads ``SUB`` consecutive rows; the prefetched scalar ``off[s]`` is the
 number of sampled rows before them.  Their ranks within the step come
-from one small matmul against a triangular matrix, and a one-hot
-``(WIN, SUB)`` matmul places each sampled row at its rank inside a window
-of ``WIN`` output lanes that starts at the 128-lane tile holding rank
+from a doubling scan of the take mask across the lanes (ten rolls), and a
+one-hot ``(WIN, SUB)`` matmul places each sampled row at its rank inside a
+window of ``WIN`` output lanes that starts at the 128-lane tile holding rank
 ``off[s]``.  The window is merged with what the previous step left in the
 same lanes (a VMEM carry, shifted by whole tiles) and written to HBM by
 one DMA per output; a later step rewrites the lanes it shares with this
 one, and the DMAs run one after the other, so the last write of every lane
-holds all of its rows.  The one-hot is exact in float32: every output lane
-sums at most one row, the bins as bfloat16 integers below 256 and the row
-values at ``Precision.HIGHEST``.
+holds all of its rows.  A step makes one default-precision pass of the
+MXU: the bins and the four bytes of each float32 row value (bit-cast to
+int32) are stacked into one bfloat16 operand, every one an integer below
+256 and so exact, and every output lane sums at most one row, so the
+placed bytes are exact in float32 and put back together give each value
+to the bit (-0.0, subnormals and every exponent included).
 """
 
 from __future__ import annotations
@@ -34,8 +37,7 @@ WIN = SUB + 128  # output lanes a step reaches: its ranks start anywhere in a ti
 _NT = (((1,), (1,)), ((), ()))  # contract the lanes of both operands
 
 
-def _compact_kernel(off_ref, tri_ref, bins_ref, vals_ref, _zb, _zv, ob_ref, ov_ref,
-                    accb, accv, winb, winv, sem, *, w: int):
+def _compact_kernel(off_ref, bins_ref, vals_ref, _zb, _zv, ob_ref, ov_ref, acc, winb, winv, sem, *, w: int):
     s = pl.program_id(0)
     off = off_ref[s]
     a = pl.multiple_of((off // 128) * 128, 128)
@@ -44,32 +46,32 @@ def _compact_kernel(off_ref, tri_ref, bins_ref, vals_ref, _zb, _zv, ob_ref, ov_r
 
     @pl.when(s == 0)
     def _init():
-        accb[...] = jnp.zeros_like(accb)
-        accv[...] = jnp.zeros_like(accv)
+        acc[...] = jnp.zeros_like(acc)
 
     vals = vals_ref[...]  # (Rp, SUB) f32: row w the weight, rows past it the ragged block's
-    take = vals[w : w + 1, :] > 0
-    prefix = jnp.dot(
-        take.astype(jnp.bfloat16), tri_ref[...], preferred_element_type=jnp.float32
-    ).astype(jnp.int32)  # (1, SUB): sampled rows up to and including each
-    dest = jnp.where(take, (off - a) + prefix - 1, -1)  # lane in the window; -1 for none
-    onehot = jax.lax.broadcasted_iota(jnp.int32, (WIN, SUB), 0) == dest
-    bins = bins_ref[...].astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
-    placed_b = jax.lax.dot_general(
-        bins, onehot.astype(jnp.bfloat16), _NT, preferred_element_type=jnp.float32
-    )  # (Fp, WIN)
-    placed_v = jax.lax.dot_general(
-        vals, onehot.astype(jnp.float32), _NT, preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )  # (Rp, WIN): a row past the weight holds what its ragged block read
-    nb = accb[:, pl.ds(d, WIN)] + placed_b
-    nv = accv[:, pl.ds(d, WIN)] + placed_v
-    accb[:, :WIN] = nb
-    accb[:, WIN:] = jnp.zeros((nb.shape[0], WIN), jnp.float32)
-    accv[:, :WIN] = nv
-    accv[:, WIN:] = jnp.zeros((nv.shape[0], WIN), jnp.float32)
-    winb[...] = nb.astype(jnp.int32).astype(jnp.uint8)
-    winv[...] = nv
+    take = (vals[w : w + 1, :] > 0).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
+    prefix, k = take, 1
+    while k < SUB:  # (1, SUB): sampled rows up to and including each
+        prefix = prefix + jnp.where(lane >= k, pltpu.roll(prefix, k, 1), 0)
+        k *= 2
+    dest = jnp.where(take > 0, (off - a) + prefix - 1, -1)  # lane in the window; -1 for none
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (WIN, SUB), 0) == dest).astype(jnp.bfloat16)
+    Fp, Rp = bins_ref.shape[0], vals.shape[0]
+    bits = jax.lax.bitcast_convert_type(vals, jnp.int32)
+    planes = [bins_ref[...].astype(jnp.int32)] + [(bits >> (8 * b)) & 255 for b in range(4)]
+    if acc.shape[0] > Fp + 4 * Rp:  # the operand in whole bfloat16 sublane tiles
+        planes.append(jnp.zeros((acc.shape[0] - Fp - 4 * Rp, SUB), jnp.int32))
+    lhs = jnp.concatenate(planes).astype(jnp.float32).astype(jnp.bfloat16)
+    placed = jax.lax.dot_general(lhs, onehot, _NT, preferred_element_type=jnp.float32)  # (M, WIN)
+    nb = acc[:, pl.ds(d, WIN)] + placed
+    acc[:, :WIN] = nb
+    acc[:, WIN:] = jnp.zeros_like(nb)
+    q = nb.astype(jnp.int32)
+    winb[...] = q[:Fp].astype(jnp.uint8)
+    byte = [q[Fp + b * Rp : Fp + (b + 1) * Rp] << (8 * b) for b in range(4)]
+    # a row past the weight holds what its ragged block read
+    winv[...] = jax.lax.bitcast_convert_type(byte[0] | byte[1] | byte[2] | byte[3], jnp.float32)
     out_b = pltpu.make_async_copy(winb, ob_ref.at[:, pl.ds(a, WIN)], sem.at[0])
     out_v = pltpu.make_async_copy(winv, ov_ref.at[:, pl.ds(a, WIN)], sem.at[1])
     out_b.start()
@@ -95,22 +97,20 @@ def _pallas_compact(bins_t, vals, rows: int, interpret: bool):
     # sampled rows before each step's block; past ``rows`` (more rows taken
     # than the buffer holds) the windows stay in bounds and land past the end
     off = jnp.minimum(jnp.cumsum(counts) - counts, rows)
-    i = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
-    tri = (i <= jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)).astype(jnp.bfloat16)
     lanes = rows + WIN  # a window starting at the last rank stays inside
+    M = -(-(Fp + 4 * Rp) // 16) * 16  # the bins' rows and four byte rows a value row
     any_ = pl.BlockSpec(memory_space=pl.ANY)
     grid = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(steps,),
         in_specs=[
-            pl.BlockSpec((SUB, SUB), lambda s, off: (0, 0)),
             pl.BlockSpec((Fp, SUB), lambda s, off: (0, s)),
             pl.BlockSpec((Rp, SUB), lambda s, off: (0, s)),
             any_, any_,
         ],
         out_specs=[any_, any_],
         scratch_shapes=[
-            pltpu.VMEM((Fp, 2 * WIN), jnp.float32), pltpu.VMEM((Rp, 2 * WIN), jnp.float32),
+            pltpu.VMEM((M, 2 * WIN), jnp.float32),
             pltpu.VMEM((Fp, WIN), jnp.uint8), pltpu.VMEM((Rp, WIN), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
@@ -120,9 +120,9 @@ def _pallas_compact(bins_t, vals, rows: int, interpret: bool):
         grid_spec=grid,
         out_shape=[jax.ShapeDtypeStruct((Fp, lanes), jnp.uint8), jax.ShapeDtypeStruct((Rp, lanes), jnp.float32)],
         # the outputs start as zeros: lanes past the last sampled row stay 0
-        input_output_aliases={4: 0, 5: 1},
+        input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
-    )(off, tri, bins_t, vals, jnp.zeros((Fp, lanes), jnp.uint8), jnp.zeros((Rp, lanes), jnp.float32))
+    )(off, bins_t, vals, jnp.zeros((Fp, lanes), jnp.uint8), jnp.zeros((Rp, lanes), jnp.float32))
     return out_b[:F, :rows], out_v[:R, :rows]
 
 

@@ -72,31 +72,75 @@ def test_counts_and_buffer_rows():
     assert goss_rows(2457, 2_097_152) == 2457  # one chunk: the sample itself
 
 
-@pytest.mark.parametrize("n, rows, share", [
-    (5000, 2048, 0.3),  # a ragged last block, padding past the sample
-    (4096, 4096, 1.0),  # every row taken: the windows carry whole blocks
-    (10240, 700, 0.05),  # sparse: many steps add nothing
-    (3000, 100, 0.5),  # more rows taken than the buffer holds: the first rows kept
+def _hard_values(rng, shape):
+    """Float32 values a rounding product would not carry: signed zeros,
+    subnormals, the largest exponents, the smallest normals with full
+    mantissas (whose lower bf16 parts would be subnormal), and random bit
+    patterns over every finite exponent."""
+    bits = rng.integers(0, 2**32, shape, dtype=np.uint64).astype(np.uint32).view(np.float32)
+    bits = np.where(np.isfinite(bits), bits, np.float32(-0.0))
+    tiny = (np.float32(1.1754944e-38) * (1 + rng.random(shape))).astype(np.float32)
+    special = np.float32([-0.0, 0.0, 1e-40, -1e-40, 3.0e38, -3.0e38, 1 + 2**-23, -(1 + 2**-8 + 2**-16 + 2**-23)])
+    pick = rng.integers(0, 3, shape)
+    return np.where(pick == 0, rng.choice(special, shape), np.where(pick == 1, tiny, bits)).astype(np.float32)
+
+
+@pytest.mark.parametrize("n, rows, share, F, hard", [
+    pytest.param(5000, 2048, 0.3, 7, False, id="5000-2048-0.3"),  # a ragged last block, padding past the sample
+    pytest.param(4096, 4096, 1.0, 7, False, id="4096-4096-1.0"),  # every row taken: the windows carry whole blocks
+    pytest.param(10240, 700, 0.05, 7, False, id="10240-700-0.05"),  # sparse: many steps add nothing
+    pytest.param(3000, 100, 0.5, 7, False, id="3000-100-0.5"),  # more rows taken than the buffer holds: the first rows kept
+    pytest.param(5000, 2048, 0.3, 7, True, id="hard-values"),  # every byte of every value placed and put back
+    pytest.param(4096, 4096, 1.0, 7, True, id="hard-values-every-row"),
+    pytest.param(6500, 2500, 0.3, 39, True, id="cell-width-ragged"),  # the cell's 39 columns, a ragged last block
 ])
-def test_streaming_compaction_matches_the_gathers(n, rows, share):
+def test_streaming_compaction_matches_the_gathers(n, rows, share, F, hard):
     """The Pallas kernel (interpreted here) against XLA's scatter and
     gathers, to the bit: the sample in row order, then rows of weight 0."""
     from mmlspark_tpu.engine.booster import goss_compact
 
-    rng = np.random.default_rng(n)
-    bins = jnp.asarray(rng.integers(0, 256, (n, 7)).astype(np.uint8))
+    rng = np.random.default_rng(n + F)
+    bins = jnp.asarray(rng.integers(0, 256, (n, F)).astype(np.uint8))
     w = np.where(rng.random(n) < share, rng.choice(np.float32([1.0, 8.0]), n), 0.0).astype(np.float32)
-    g, h = jnp.asarray(rng.normal(size=(2, n)), jnp.float32), jnp.asarray(rng.random((2, n)), jnp.float32)
+    if hard:
+        g, h = jnp.asarray(_hard_values(rng, (2, n))), jnp.asarray(_hard_values(rng, (2, n)))
+    else:
+        g, h = jnp.asarray(rng.normal(size=(2, n)), jnp.float32), jnp.asarray(rng.random((2, n)), jnp.float32)
     plain = goss_compact(bins, g, h, jnp.asarray(w), rows, backend="scatter")
     kernel = goss_compact(bins, g, h, jnp.asarray(w), rows, backend="pallas")
     for a, b in zip(plain, kernel):
         assert a.shape == b.shape and a.dtype == b.dtype
     held = min(int((w > 0).sum()), rows)
     np.testing.assert_array_equal(np.asarray(kernel[0])[:held], np.asarray(plain[0])[:held])
-    for a, b in zip(plain[1:3], kernel[1:3]):
-        np.testing.assert_array_equal(np.asarray(b)[:, :held], np.asarray(a)[:, :held])
-    np.testing.assert_array_equal(np.asarray(kernel[3]), np.asarray(plain[3]))  # weights 0 past the sample
+    for a, b in zip(plain[1:3], kernel[1:3]):  # the bits: -0.0 is not 0.0 here
+        np.testing.assert_array_equal(np.asarray(b)[:, :held].view(np.uint32), np.asarray(a)[:, :held].view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(kernel[3]).view(np.uint32), np.asarray(plain[3]).view(np.uint32))
     assert not np.asarray(kernel[0])[held:].any()
+
+
+def test_compaction_step_is_one_default_precision_pass():
+    """A grid step's MXU work: one ``dot_general`` against the ``(WIN, SUB)``
+    one-hot, at default precision with a bfloat16 left operand (the bins and
+    the values' bytes stacked), and no other matmul operand larger than one
+    128 x 128 tile (the rank takes no triangular ``(SUB, SUB)`` matrix)."""
+    import functools
+
+    from mmlspark_tpu.ops.pallas_compact import SUB, WIN, _pallas_compact
+
+    n, F, R = 5000, 39, 3
+    fn = functools.partial(_pallas_compact, rows=2048, interpret=True)
+    jaxpr = jax.make_jaxpr(fn)(jnp.zeros((F, n), jnp.uint8), jnp.zeros((R, n), jnp.float32)).jaxpr
+    (call,) = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+    dots = [e for e in _eqns(call.params["jaxpr"]) if e.primitive.name == "dot_general"]
+    onehot = [e for e in dots if (WIN, SUB) in [v.aval.shape for v in e.invars]]
+    assert len(onehot) == 1, [[v.aval.shape for v in e.invars] for e in dots]
+    (dot,) = onehot
+    lhs, rhs = (v.aval for v in dot.invars)
+    assert lhs.dtype == rhs.dtype == jnp.bfloat16 and rhs.shape == (WIN, SUB)
+    assert lhs.shape[0] >= F + 4 * R and lhs.shape[0] % 16 == 0  # the bins' rows and four bytes a value row
+    assert dot.params["precision"] in (None, jax.lax.Precision.DEFAULT, (jax.lax.Precision.DEFAULT,) * 2)
+    others = [v.aval.shape for e in dots if e is not dot for v in e.invars]
+    assert all(np.prod(s) <= 128 * 128 for s in others), others
 
 
 def _data(n, F, seed):

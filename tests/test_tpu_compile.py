@@ -79,3 +79,21 @@ def test_histogram_kernel_compiles_for_the_chip(one_chip, kernel, vals_dtype, bi
     moved = "u8" if bins_dtype == jnp.uint8 else r"s32\[1,"
     made = re.findall(rf"= ({moved}\S*) (pad|copy|dynamic-slice)\(", text)
     assert not made, made
+
+
+@pytest.mark.filterwarnings("ignore:Error reading persistent compilation cache entry")
+def test_compaction_kernel_compiles_for_the_chip(one_chip):
+    """``criteo_goss_train_1chip``'s compaction: 132,120,576 rows of 39 binned
+    columns and three row values (gradient, hessian, weight) into a
+    39,845,888-row buffer, 129,024 grid steps whose offsets fit the SMEM.
+    Mosaic takes the byte-plane step (bit casts, shifts, the lane scan's rolls)
+    and XLA adds no sort, gather or scatter around the kernel."""
+    from mmlspark_tpu.ops.pallas_compact import _pallas_compact
+
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    n = 63 * CHUNK
+    text = _pallas_compact.lower(
+        struct((39, n), jnp.uint8), struct((3, n), jnp.float32), rows=19 * CHUNK, interpret=False
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [op for op in ("sort(", "gather(", "scatter(") if op in text]
