@@ -21,6 +21,7 @@ upstream C++ ``src/boosting/gbdt.cpp``).  Differences by design:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import math
@@ -1212,13 +1213,22 @@ def _feature_mask(key, F: int, fraction: float):
     return rank < k
 
 
-@jax.named_scope("leaf_delta")
 def _leaf_delta(tree: Tree, leaf_ids: jnp.ndarray) -> jnp.ndarray:
     """delta[k] = leaf_value[k][leaf_ids[k]] for the (K, L) leaf values
     and (K, n) leaf ids of one iteration's trees: the float32 the stored
     model holds, on every backend and at every ``n`` (the gather lowering
-    cost 8.0ns a row on v5e, ``_leaf_lookup``'s select form ~0.5ns)."""
-    return jax.vmap(_leaf_lookup)(tree.leaf_value, leaf_ids)
+    cost 8.0ns a row on v5e, ``_leaf_lookup``'s select form ~0.5ns).
+    Its device region is ``leaf_delta``, ``class_update`` where K > 1."""
+    with jax.named_scope("class_update" if tree.leaf_value.shape[0] > 1 else "leaf_delta"):
+        return jax.vmap(_leaf_lookup)(tree.leaf_value, leaf_ids)
+
+
+def _class_scope(K: int, name: str):
+    """``jax.named_scope(name)`` round what a fit of K > 1 trees an
+    iteration adds (``class_grad``: the objective's (K, n) gradient;
+    ``class_update``: the K-row score update); a K = 1 fit's program is
+    left as it was."""
+    return jax.named_scope(name) if K > 1 else contextlib.nullcontext()
 
 
 # ---------------------------------------------------------------------------
@@ -2616,9 +2626,10 @@ def _train_impl(
     # 8s compile observed at 262k×64).
     @jax.jit
     def iteration(bins_a, y_a, w_a, vmask_a, ostate_a, scores, key, bag_in):
-        grad, hess = obj.grad_hess_from(
-            ostate_a, scores if K > 1 else scores[0], y_a, w_a
-        )
+        with _class_scope(K, "class_grad"):
+            grad, hess = obj.grad_hess_from(
+                ostate_a, scores if K > 1 else scores[0], y_a, w_a
+            )
         if K == 1:
             grad, hess = grad[None, :], hess[None, :]
         _, fkey = jax.random.split(key)
@@ -3028,10 +3039,11 @@ def _train_impl(
                         train_scores = (
                             init_scores_a if cfg.boosting == "rf" else scores_c
                         )
-                    grad, hess = obj.grad_hess_from(
-                        ostate_a,
-                        train_scores if K > 1 else train_scores[0], y_a, w_a,
-                    )
+                    with _class_scope(K, "class_grad"):
+                        grad, hess = obj.grad_hess_from(
+                            ostate_a,
+                            train_scores if K > 1 else train_scores[0], y_a, w_a,
+                        )
                     if K == 1:
                         grad, hess = grad[None, :], hess[None, :]
                     gkey, fkey = jax.random.split(key)
@@ -3079,7 +3091,8 @@ def _train_impl(
                         )
                         wts = wts.at[it_idx].set(w_new)
                     else:
-                        scores_c = scores_c + delta
+                        with _class_scope(K, "class_update"):
+                            scores_c = scores_c + delta
                     nv = len(vbins_a)
                     new_vs = []
                     new_pvs = []
@@ -3423,6 +3436,8 @@ def _train_impl(
                     obs.inc("hist." + name, float(per_iter * c), body=body, vals=vals_kind, scope=scope)
             if quantize_on and hist_ledger:
                 obs.inc("train.quant_refine_cols", float(program_notes["quant_refine_cols"] * c))
+            if K > 1:
+                obs.inc("train.class_trees", float(K * c))
             if goss:
                 for name, per_iter in (("top_rows", goss_top), ("rest_rows", goss_rest), ("sample_rows", goss_top + goss_rest)):
                     obs.inc("goss." + name, float(per_iter * c))

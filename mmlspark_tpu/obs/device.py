@@ -164,12 +164,14 @@ def summary(snapshot: Optional[dict] = None) -> dict:
 # ops/pallas_hist.py (chunk_copy: the slice and pad of a chunk that is no
 # whole number of row blocks; any other is read in place and the region
 # holds nothing), parallel/distributed.py (hist_merge),
-# ops/objectives.py (rank_grad), ops/rank_plan.py (rank_ndcg).
+# ops/objectives.py (rank_grad), ops/rank_plan.py (rank_ndcg); a fit of
+# K > 1 trees an iteration (engine/booster.py) marks its (K, n) gradient
+# class_grad and its K-row score update class_update.
 SCOPES = (
     "split_scan", "hist_build", "quant_hist", "quant_refine", "quant_round",
     "leaf_stats", "leaf_delta", "replay_step", "hist_merge", "rank_grad",
     "rank_ndcg", "row_route", "chunk_copy", "goss_select", "goss_compact",
-    "goss_route",
+    "goss_route", "class_grad", "class_update",
 )
 
 _PROGRAMS: dict = {}  # (label, same, argument tree, shapes) -> [callable, abstract args, map]

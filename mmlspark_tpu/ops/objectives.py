@@ -298,8 +298,9 @@ class MAPE(Objective):
 class Multiclass(Objective):
     """Softmax cross-entropy; one tree per class per iteration.
 
-    ``score``/outputs have shape (K, n).  hess uses LightGBM's 2·p(1−p)
-    diagonal approximation.
+    ``score``/outputs have shape (K, n).  The engine's hessian is the
+    diagonal ``2·p(1−p)``; LightGBM's (recalled, not checked) is
+    ``K/(K−1)·p(1−p)``.
     """
 
     name = "multiclass"
