@@ -41,7 +41,10 @@ from mmlspark_tpu.engine.tree import (
     Tree,
     _leaf_lookup,
     _replay_leaf_ids,
+    class_root_histograms,
+    class_roots_active,
     grow_tree_auto,
+    grow_tree_depthwise,
     predict_tree_binned,
     predict_tree_leaf_binned,
 )
@@ -2539,6 +2542,16 @@ def _train_impl(
             return grow_all_q
 
         def grow_all(bins_a, grad_a, hess_a, bag_a, fmask_a):
+            if class_roots_active(gcfg_, K):
+                # the K roots in ⌈K/8⌉ passes, each handed to its grower
+                roots = class_root_histograms(gcfg_, bins_a, grad_a, hess_a, bag_a)
+
+                def one_rooted(args):
+                    g, h, fm, root = args
+                    return grow_tree_depthwise(gcfg_, bins_a, g, h, bag_a, fm, root_hist=root)
+
+                return jax.lax.map(one_rooted, (grad_a, hess_a, fmask_a, roots))
+
             def one(args):
                 g, h, fm = args
                 return grow_tree_auto(gcfg_, bins_a, g, h, bag_a, fm)
@@ -3815,11 +3828,13 @@ def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
     """What the histogram passes of one execution of a traced grower issue:
     ``{(body, vals, scope): {"passes", "rowcols", "mxu_flops",
     "vpu_elems"}}``.  A pass is one entry of a scope of :data:`HIST_SCOPES`
-    (:func:`scope_entries`: full trees), labelled by the kernel body its
-    calls reach (``plain``, ``by_leaf``, ``nibble``: the Pallas wrappers of
+    (:func:`scope_entries`: full trees), or as many as the entry's first
+    call makes (``passes`` of ``call_work``: the class roots' groups of
+    eight classes), labelled by the kernel body its calls reach (``plain``,
+    ``by_leaf``, ``nibble``, ``class_root``: the Pallas wrappers of
     ``ops/pallas_hist.py``, found by the name of their ``jit`` equation;
-    ``scatter``: the other backend's scatter-add) and by the row values'
-    dtype (``f32``, or ``i16`` buckets).  ``rowcols`` are the rows × columns
+    ``scatter``: the other backend's scatter-add, one pass an entry) and by
+    the row values' dtype (``f32``, or ``i16`` buckets).  ``rowcols`` are the rows × columns
     those calls read, a chunk loop's calls times its length;
     ``mxu_flops`` and ``vpu_elems`` what the bodies issue for them, as each
     body states beside its kernel (``pallas_hist.call_work``), and 0 on the
@@ -3836,7 +3851,7 @@ def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
             elif name == "scatter-add":
                 updates = eqn.invars[2].aval  # (3, chunk rows x columns)
                 found.append((mult, {
-                    "body": "scatter", "quant": jnp.issubdtype(updates.dtype, jnp.integer),
+                    "body": "scatter", "quant": jnp.issubdtype(updates.dtype, jnp.integer), "passes": 1,
                     "rowcols": int(updates.shape[-1]), "mxu_flops": 0, "vpu_elems": 0,
                 }))
             else:
@@ -3854,7 +3869,7 @@ def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
             (first["body"], "i16" if first["quant"] else "f32", scope),
             dict.fromkeys(("passes", "rowcols", "mxu_flops", "vpu_elems"), 0),
         )
-        work["passes"] += times
+        work["passes"] += times * first["passes"]
         for mult, call in found:
             for name in ("rowcols", "mxu_flops", "vpu_elems"):
                 work[name] += times * mult * call[name]

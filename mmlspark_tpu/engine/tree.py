@@ -49,8 +49,10 @@ from mmlspark_tpu.ops.binpack import hist_transpose
 from mmlspark_tpu.ops.histogram import (
     DEFAULT_LEVELS,
     HistQuantize,
+    build_class_root_histograms,
     build_histogram,
     build_histogram_by_leaf,
+    factorized_by_leaf,
     quantize_hist_vals,
     quantize_scales3,
 )
@@ -1057,6 +1059,7 @@ def grow_tree_depthwise(
     feat_mask: jnp.ndarray,
     qkey: Optional[jnp.ndarray] = None,
     qscale: Optional[jnp.ndarray] = None,
+    root_hist: Optional[jnp.ndarray] = None,
 ) -> Tuple[Tree, jnp.ndarray]:
     """Level-synchronous growth with windowed new-children histograms.
 
@@ -1078,6 +1081,10 @@ def grow_tree_depthwise(
     only when the leaf budget runs out mid-level (lossguide can then favor
     a deep chain).  The recorded Tree uses the identical step numbering, so
     prediction replay and model-string export are unchanged.
+
+    ``root_hist``: the tree's merged ``(3, F_loc, B)`` root histogram where
+    the caller built it (:func:`class_root_histograms`, a multiclass
+    iteration's roots together); the grower then makes no root pass.
     """
     n, F = bins.shape
     B, L, S = cfg.num_bins, cfg.num_leaves, cfg.max_steps
@@ -1093,29 +1100,9 @@ def grow_tree_depthwise(
         [grad * bag_weight, hess * bag_weight, in_bag], axis=0
     ).astype(jnp.float32)  # (3, n) channel-major
 
-    # Under voting-parallel the carried histogram buffer stays LOCAL per
-    # shard (votes + elected slices are the only collectives); under
-    # feature-parallel it is local by CONSTRUCTION (each shard owns its
-    # columns outright — no histogram collective exists in the mode);
-    # otherwise the builders merge so the buffer is globally replicated
-    # (hist_merge="allreduce") or feature-sliced per shard
-    # (hist_merge="reduce_scatter").
-    hist_axis = (
-        None if (cfg.voting_active or cfg.feature_parallel_active)
-        else cfg.axis_name
-    )
+    hist_axis, merge_mode = _window_merge(cfg)
     rs = cfg.reduce_scatter_active
-    # Hierarchical (ISSUE 14): the windowed merge scatters over the FAST
-    # intra-host axis only (hist_axis is the (slow, fast) tuple; the merge
-    # routes the scatter to its last element), so the carried buffer holds
-    # HOST-LOCAL feature slices.  Election below is host-biased; the
-    # always-on refinement pass restores global exactness for the winners.
-    hier = cfg.hierarchical_active
-    featsliced = rs or hier
-    merge_mode = (
-        "hierarchical" if hier
-        else ("reduce_scatter" if rs else "allreduce")
-    )
+    featsliced = rs or cfg.hierarchical_active
     qvals, hq = _quantize_rows(cfg, vals, qkey, qscale)
 
     def window_hist(win_leaf):
@@ -1132,7 +1119,8 @@ def grow_tree_depthwise(
     # the plain per-feature kernel's M=3 matmuls cost 2.8ms/pass at the
     # bench shape vs 1.9ms for the factorized windowed kernel, and reusing
     # it drops one compiled kernel from the program.
-    root_hist = window_hist(jnp.zeros(n, jnp.int32))[:, 0]  # (3, F_loc, B)
+    if root_hist is None:
+        root_hist = window_hist(jnp.zeros(n, jnp.int32))[:, 0]  # (3, F_loc, B)
     # Under reduce_scatter the merged buffer holds only THIS shard's
     # contiguous feature slice: F_loc = F/D is STATIC at trace time
     # (psum_scatter's result shape; device_psum_scatter pads the histogram
@@ -1566,6 +1554,61 @@ def windowed_grower(cfg: GrowConfig) -> bool:
         or cfg.reduce_scatter_active
         or cfg.hierarchical_active
     )
+
+
+def _window_merge(cfg: GrowConfig):
+    """``(axis_name, merge)`` of the windowed grower's histogram builds.
+
+    Under voting-parallel the carried histogram buffer stays LOCAL per
+    shard (votes + elected slices are the only collectives); under
+    feature-parallel it is local by CONSTRUCTION (each shard owns its
+    columns outright — no histogram collective exists in the mode);
+    otherwise the histogram builds merge so the buffer is globally replicated
+    (hist_merge="allreduce") or feature-sliced per shard
+    (hist_merge="reduce_scatter").  Hierarchical (the 2-D pod mesh): the merge
+    scatters over the FAST intra-host axis only (the axis is the (slow,
+    fast) tuple; the merge routes the scatter to its last element), so the
+    carried buffer holds HOST-LOCAL feature slices.  Election is then
+    host-biased; the always-on refinement pass restores global exactness
+    for the winners."""
+    if cfg.voting_active or cfg.feature_parallel_active:
+        return None, "allreduce"
+    if cfg.hierarchical_active:
+        return cfg.axis_name, "hierarchical"
+    return cfg.axis_name, "reduce_scatter" if cfg.reduce_scatter_active else "allreduce"
+
+
+def class_roots_active(cfg: GrowConfig, num_class: int) -> bool:
+    """Whether a fit of ``num_class`` trees an iteration builds their roots
+    together (:func:`class_root_histograms`) and hands each windowed grower
+    its own: float values, the windowed grower, one device or a row-sharded
+    mesh whose merge the histogram build performs, and on the Pallas
+    backend a window whose own root takes the factorized body (the class
+    roots' body is its tile with a class in each slot: the same sums in the
+    same order).  Quantized, lossguide, voting and feature-parallel fits,
+    and windows or bin counts that take the plain by-leaf body, build each
+    root in its own tree."""
+    return (
+        num_class > 1
+        and not cfg.quantize_active
+        and windowed_grower(cfg)
+        and not (cfg.voting_active or cfg.feature_parallel_active)
+        and (cfg.hist_backend != "pallas" or factorized_by_leaf(cfg.level_window, cfg.num_bins))
+    )
+
+
+def class_root_histograms(cfg: GrowConfig, bins, grad, hess, bag_weight):
+    """``(K, 3, F_loc, B)``: the merged root histogram of each of the K
+    classes' trees, which :func:`grow_tree_depthwise` builds one tree at a
+    time as its first windowed pass, in ⌈K/8⌉ passes
+    (:func:`~mmlspark_tpu.ops.histogram.build_class_root_histograms`)."""
+    axis_name, merge = _window_merge(cfg)
+    with _hist_scope(cfg):
+        return build_class_root_histograms(
+            hist_transpose(bins, cfg.num_bins), grad, hess, bag_weight, cfg.num_bins,
+            backend=cfg.hist_backend, chunk=cfg.hist_chunk, axis_name=axis_name,
+            precision=cfg.hist_precision, merge=merge,
+        )
 
 
 def grow_tree_auto(cfg: GrowConfig, *args):

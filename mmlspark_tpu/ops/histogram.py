@@ -551,6 +551,21 @@ def build_histogram(
     )
 
 
+def factorized_by_leaf(num_leaves: int, num_bins: int) -> bool:
+    """Whether the Pallas backend builds ``num_leaves`` slots' histograms
+    over ``num_bins`` bins in the factorized (hi/lo) body.
+
+    Small windows starve the plain kernel's matmul M = 3·W; the factorized
+    hi/lo variant doubles M (same results to float-summation ulps, and of
+    buckets the same int32 sums bit for bit — parity tested) and wins
+    measurably up to M ≈ 128 (W≤21 at B=256: 7.5 → 4.9 ms/pass at W=12,
+    262k×64 on v5e).  One rule on shapes for both value dtypes: the
+    factoring splits a one-hot and its un-factoring is a reshape, neither
+    cares what is summed."""
+    h = (num_bins + 127) // 128
+    return num_bins > 128 and 3 * num_leaves * h <= 128
+
+
 def build_histogram_by_leaf(
     bins: jnp.ndarray,
     vals: jnp.ndarray,
@@ -589,17 +604,9 @@ def build_histogram_by_leaf(
             pallas_hist_by_leaf_nibble_chunk,
         )
 
-        # Small windows starve the plain kernel's matmul M = 3·W; the
-        # factorized hi/lo variant doubles M (same results to float-summation
-        # ulps, and of buckets the same int32 sums bit for bit — parity
-        # tested) and wins measurably up to M ≈ 128 (W≤21 at B=256:
-        # 7.5 → 4.9 ms/pass at W=12, 262k×64 on v5e).  One rule on shapes
-        # for both value dtypes: the factoring splits a one-hot and its
-        # un-factoring is a reshape, neither cares what is summed.
-        h = (num_bins + 127) // 128
-        nibble = num_bins > 128 and 3 * num_leaves * h <= 128
         fn = functools.partial(
-            pallas_hist_by_leaf_nibble_chunk if nibble else pallas_hist_by_leaf_chunk,
+            pallas_hist_by_leaf_nibble_chunk if factorized_by_leaf(num_leaves, num_bins)
+            else pallas_hist_by_leaf_chunk,
             num_leaves=num_leaves, num_bins=num_bins, precision=precision,
         )
     elif backend == "scatter":
@@ -618,3 +625,61 @@ def build_histogram_by_leaf(
     return _chunked_hist(
         fn, acc0, bins, [vals, leaf_ids], chunk, axis_name, merge, quantize,
     )
+
+
+def _scatter_class_roots_chunk(bins, grad, hess, bag, num_bins: int, i=0,
+                               chunk: Optional[int] = None):
+    """(F, n) bins + (K, n) gradients and hessians + (n,) bag weights →
+    (K, 3, F, B) of rows ``[i·chunk, (i+1)·chunk)``: each class's root
+    histogram of ``(g·bag, h·bag, 1[bag > 0])``, the scatter-add of
+    :func:`_scatter_hist_chunk` class by class."""
+    bins_c, grad_c, hess_c, bag_c = _chunk_slices(
+        [(bins, 1), (grad, 1), (hess, 1), (bag, 0)], i, chunk
+    )
+    in_bag = (bag_c > 0).astype(jnp.float32)
+    return lax.map(
+        lambda gh: _scatter_hist_chunk(
+            bins_c, jnp.stack([gh[0] * bag_c, gh[1] * bag_c, in_bag]), num_bins
+        ),
+        (grad_c, hess_c),
+    )
+
+
+def build_class_root_histograms(
+    bins: jnp.ndarray,
+    grad: jnp.ndarray,
+    hess: jnp.ndarray,
+    bag: jnp.ndarray,
+    num_bins: int,
+    backend: str = "scatter",
+    chunk: int = DEFAULT_CHUNK,
+    axis_name: Optional[str] = None,
+    precision: str = "highest",
+    merge: str = "allreduce",
+) -> jnp.ndarray:
+    """The root histograms of a multiclass iteration's K trees in one go:
+    ``(K, 3, F, B)`` — or ``(K, 3, F/D, B)`` under
+    ``merge="reduce_scatter"`` — where class ``k``'s is
+    :func:`build_histogram_by_leaf` of ``(grad[k]·bag, hess[k]·bag,
+    1[bag > 0])`` with every row in leaf 0, sum for sum.
+
+    The ``pallas`` backend puts eight classes in the slots of one pass
+    (``ops/pallas_hist.py`` ``pallas_hist_class_roots_chunk``), reading the
+    ``(K, n)`` gradients and hessians where they lie: ⌈K/8⌉ passes over
+    the ``(F, n)`` ``bins`` where the classes' own roots make K.  Float
+    values only; the chunk loop and the merge across shards are
+    :func:`build_histogram_by_leaf`'s."""
+    K, F = grad.shape[0], bins.shape[0]
+    if backend == "pallas":
+        from mmlspark_tpu.ops.pallas_hist import pallas_hist_class_roots_chunk
+
+        fn = functools.partial(pallas_hist_class_roots_chunk, num_bins=num_bins, precision=precision)
+    elif backend == "scatter":
+        fn = functools.partial(_scatter_class_roots_chunk, num_bins=num_bins)
+    else:
+        raise ValueError(
+            f"unknown hist backend {backend!r}; expected scatter|pallas"
+        )
+    acc0 = jnp.zeros((K, 3, F, num_bins), jnp.float32)
+    rows = [grad.astype(jnp.float32), hess.astype(jnp.float32), bag.astype(jnp.float32)]
+    return _chunked_hist(fn, acc0, bins, rows, chunk, axis_name, merge, None)

@@ -57,6 +57,7 @@ before binning, so the merge never sees a feature axis it cannot tile.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -452,15 +453,27 @@ def _prep_by_leaf_chunk(
     operands, ``bins_t`` the very matrix wherever the chunk is whole row
     blocks.  Float ``vals`` go in as f32, integer buckets as int16 (the row
     values DMA at half width)."""
+    F, n = bins_t.shape  # integer, uint8 through the byte tier
+    chunk = n if chunk is None else chunk
+    bm, bf, rm, interpret = _by_leaf_blocks(F, num_leaves, num_bins, bm, bf, rm, chunk)
+    vals = vals.astype(_tile_dtype(vals.dtype))
+    leaf_row = leaf_ids.astype(jnp.int32)[None, :]  # (1, n): lane-friendly
+    # padded rows park at leaf == num_leaves → no one-hot slot
+    bins_t, (vals, leaf_row), c, chunk = _place_chunk(
+        bins_t, [(vals, 0), (leaf_row, num_leaves)], i, chunk, bm
+    )
+    return bins_t, vals, leaf_row, c, chunk, bm, bf, rm, interpret
+
+
+def _by_leaf_blocks(F: int, num_leaves: int, num_bins: int, bm: int, bf: int, rm: int, chunk: int):
+    """The by-leaf bodies' ``(bm, bf, rm, interpret)`` for ``F`` columns,
+    ``num_leaves`` slots and a chunk of ``chunk`` rows, after the backend
+    check."""
     backend = jax.default_backend()
     if backend not in ("cpu", "tpu"):
         raise NotImplementedError(
             f"hist_backend='pallas' supports tpu/cpu backends, not {backend!r}"
         )
-    F, n = bins_t.shape  # integer, uint8 through the byte tier
-    chunk = n if chunk is None else chunk
-    vals = vals.astype(_tile_dtype(vals.dtype))
-    leaf_row = leaf_ids.astype(jnp.int32)[None, :]  # (1, n): lane-friendly
     # Feature blocks.  No column is padded: a block is as tall as the
     # matrix (always a legal block dimension: 39 on the criteo schema, the
     # refinement's 1) wherever that fits, else a multiple of 8 (legal for
@@ -489,11 +502,7 @@ def _prep_by_leaf_chunk(
     rm = min(rm, _pow2_floor(max(256, rm * 256 // num_bins)))
     bm = min(bm, _round_up(chunk, rm))
     rm = min(rm, bm)
-    # padded rows park at leaf == num_leaves → no one-hot slot
-    bins_t, (vals, leaf_row), c, chunk = _place_chunk(
-        bins_t, [(vals, 0), (leaf_row, num_leaves)], i, chunk, bm
-    )
-    return bins_t, vals, leaf_row, c, chunk, bm, bf, rm, backend == "cpu"
+    return bm, bf, rm, backend == "cpu"
 
 
 def pallas_hist_by_leaf_chunk(
@@ -684,6 +693,159 @@ def pallas_hist_by_leaf_nibble_chunk(
 
 
 # ---------------------------------------------------------------------------
+# Class roots: the root histograms of a multiclass iteration's K trees, the
+# factorized body's tile with a CLASS in each slot.  At the root every row is
+# in leaf 0 of every class's tree and only the row values differ, so eight
+# classes share one pass: slot w of group p is class 8p + w, its values read
+# out of the (K, n) gradient and hessian where they lie, an (8, bm) block of
+# each through the index map, and weighted by the shared bag in the body:
+#
+#     lhs[(c, h, w), r] = 1[hi_rf = h] · v[c, w, r]
+#     v[0] = g·bag, v[1] = h·bag, v[2] = 1[bag > 0]
+#
+# The same products as the nibble body's slot 0 (its one-hot times the same
+# float32 values), so each class's sums are the per-class root's; its rows
+# are ordered (channel, half, slot) so that every piece is a whole (8, rm)
+# sublane tile.  Groups are the grid's leading axis; a ragged last group's
+# slots read past K and are dropped by the wrapper.  Its own body beside
+# the nibble body: the operands differ, and the nibble body's compiled
+# program is every other fit's.
+# ---------------------------------------------------------------------------
+_CLASS_SLOTS = 8
+
+
+def _class_roots_kernel(
+    chunk_ref, bins_ref, grad_ref, hess_ref, bag_ref, out_ref, *,
+    num_bins: int, rm: int, precision,
+):
+    i = pl.program_id(2)  # row block, innermost → accumulation is safe
+    bf, bm = bins_ref.shape
+    H = (num_bins + _NIBBLE_LO - 1) // _NIBBLE_LO
+    G = grad_ref.shape[0]
+
+    def sub(s, acc):
+        sl = pl.ds(s * rm, rm)
+        bins = bins_ref[:, sl].astype(jnp.int32)  # (bf, rm), see _hist_kernel
+        bag = bag_ref[:, sl]  # (1, rm)
+        in_bag = jnp.broadcast_to((bag > 0).astype(jnp.float32), (G, rm))
+        vals = (grad_ref[:, sl] * bag, hess_ref[:, sl] * bag, in_bag)  # (G, rm) each
+        iota_lo = jax.lax.broadcasted_iota(jnp.int32, (_NIBBLE_LO, rm), 0)
+        parts = []
+        for f in range(bf):
+            hi = bins[f, :] >> 7  # LO = 128
+            lo = bins[f, :] & (_NIBBLE_LO - 1)
+            halves = [(hi == h).astype(jnp.float32)[None, :] for h in range(H)]
+            lhs = jnp.concatenate(
+                [half * v for v in vals for half in halves], axis=0
+            )  # (3·H·G, rm)
+            oh_lo = (iota_lo == lo[None, :]).astype(jnp.float32)  # (LO, rm)
+            parts.append(
+                jax.lax.dot_general(
+                    lhs, oh_lo,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=precision,
+                )  # (3·H·G, LO)
+            )
+        return acc + jnp.concatenate(parts, axis=1)  # (M, bf·LO)
+
+    part = jax.lax.fori_loop(
+        0, bm // rm, sub, jnp.zeros((3 * H * G, bf * _NIBBLE_LO), jnp.float32)
+    )
+
+    @pl.when(i == 0)
+    def _init():
+        out_ref[...] = part[None, None]
+
+    @pl.when(i > 0)
+    def _acc():
+        out_ref[...] += part[None, None]
+
+
+def _class_roots_kernel_work(M: int, N: int, bf: int, bm: int, rm: int, quant: bool):
+    """As :func:`_hist_kernel_work`, of :func:`_class_roots_kernel`: ``M`` =
+    3·H·G, ``N`` = LO.  A sub-block widens its bins, weighs the G slots'
+    gradients and hessians by the bag and takes the in-bag flag (compare,
+    convert); a column takes ``hi``, ``lo``, H half masks (compare,
+    convert), their products with the three channels' G rows and the ``lo``
+    one-hot (LO compares and converts); the sub-block adds its ``(M,
+    bf·LO)`` part to the carry, the cell the carry to the output tile.
+    Float values only (``quant`` is always False)."""
+    G = _CLASS_SLOTS
+    H = M // (3 * G)
+    sub = bf * rm + 2 * G * rm + 2 * rm + bf * (2 + 2 * H + M + 2 * N) * rm + M * bf * N
+    return 2 * M * N * bm * bf, (bm // rm) * sub + M * bf * N
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_bins", "bm", "bf", "rm", "chunk", "interpret", "precision"),
+)
+def _pallas_hist_class_roots(
+    bins_t, grad, hess, bag, c, num_bins, bm, bf, rm, chunk, interpret, precision
+):
+    """(K, 3, F, B) root histograms of chunk ``c[0]``'s ``chunk`` rows of the
+    whole ``(F, n)`` ``bins_t``, ``(K, n)`` ``grad`` and ``hess`` and ``(1,
+    n)`` ``bag``: ⌈K/8⌉ groups of eight classes on the grid's leading axis,
+    each a pass over the chunk's bins."""
+    F = bins_t.shape[0]
+    K = grad.shape[0]
+    G = _CLASS_SLOTS
+    groups, blocks, k = pl.cdiv(K, G), pl.cdiv(F, bf), chunk // bm
+    H = (num_bins + _NIBBLE_LO - 1) // _NIBBLE_LO
+    M = 3 * H * G
+    kernel = functools.partial(
+        _class_roots_kernel, num_bins=num_bins, rm=rm, precision=_PRECISIONS[precision],
+    )
+    vals_spec = pl.BlockSpec((G, bm), lambda p, j, i, c: (p, c[0] * k + i))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(groups, blocks, k),
+            in_specs=[
+                pl.BlockSpec((bf, bm), lambda p, j, i, c: (j, c[0] * k + i)),
+                vals_spec, vals_spec,
+                pl.BlockSpec((1, bm), lambda p, j, i, c: (0, c[0] * k + i)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, M, bf * _NIBBLE_LO), lambda p, j, i, c: (p, j, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((groups, blocks, M, bf * _NIBBLE_LO), jnp.float32),
+        compiler_params=_by_leaf_compiler_params(G, bf, H * _NIBBLE_LO),
+        interpret=interpret,
+    )(c, bins_t, grad, hess, bag)
+    # (P, F/bf, 3·H·G, bf·LO) → (P·G, 3, F, H·LO) → the real classes, columns
+    # (a ragged last block's run past F) and bin range
+    out = out.reshape(groups, blocks, 3, H, G, bf, _NIBBLE_LO)
+    out = out.transpose(0, 4, 2, 1, 5, 3, 6).reshape(
+        groups * G, 3, blocks * bf, H * _NIBBLE_LO
+    )
+    return out[:K, :, :F, :num_bins]
+
+
+def pallas_hist_class_roots_chunk(
+    bins_t, grad, hess, bag, num_bins: int,
+    bm: int = 16384, bf: int = 32, rm: int = 1024, precision: str = "highest",
+    i=0, chunk: Optional[int] = None,
+) -> jnp.ndarray:
+    """(F, n) bins + (K, n) gradients and hessians + (n,) bag weights →
+    (K, 3, F, B) of rows ``[i·chunk, (i+1)·chunk)``, read in place: each
+    class's root histogram of ``(g·bag, h·bag, 1[bag > 0])``, float32 sums,
+    equal to the nibble body's slot 0 at eight slots over the same values.
+    Blocks as :func:`_by_leaf_blocks` chooses them for eight slots."""
+    F, n = bins_t.shape
+    chunk = n if chunk is None else chunk
+    bm, bf, rm, interp = _by_leaf_blocks(F, _CLASS_SLOTS, num_bins, bm, bf, rm, chunk)
+    bag_row = bag[None, :]  # (1, n): lane-friendly
+    # padded rows carry a zero bag: no value and no count
+    bins_t, (grad, hess, bag_row), c, chunk = _place_chunk(
+        bins_t, [(grad, 0), (hess, 0), (bag_row, 0)], i, chunk, bm
+    )
+    return _pallas_hist_class_roots(
+        bins_t, grad, hess, bag_row, c, num_bins, bm, bf, rm, chunk, interp, precision,
+    )
+
+
+# ---------------------------------------------------------------------------
 # What a traced call issues (the booster's histogram work ledger).  The
 # wrappers by the name their ``jit`` equation carries: the body's label and
 # the function beside it that states a grid cell's work.
@@ -692,25 +854,31 @@ WRAPPERS = {
     "_pallas_hist": ("plain", _hist_kernel_work),
     "_pallas_hist_by_leaf": ("by_leaf", _hist_leaf_kernel_work),
     "_pallas_hist_by_leaf_nibble": ("nibble", _hist_leaf_nibble_kernel_work),
+    "_pallas_hist_class_roots": ("class_root", _class_roots_kernel_work),
 }
 
 
 def call_work(eqn) -> dict:
     """One call of a wrapper of :data:`WRAPPERS`, read off its ``jit``
-    equation: ``body``, ``quant`` (int16 bucket values), ``rowcols`` (rows ×
-    columns the call reads: its grid's cells times a block), ``mxu_flops``
-    and ``vpu_elems``.  All of it is the inner ``pallas_call``'s: the
-    operands are the growers' whole arrays, the grid is one chunk's.  Its
-    blocks and result give ``bf``, ``bm``, ``M`` and ``N``, and the length
-    of the body's loop over sub-blocks gives ``rm``."""
+    equation: ``body``, ``quant`` (int16 bucket values), ``passes`` (the
+    passes over the chunk's rows it makes: the grid's axes before the column
+    and row axes, the class roots' groups; 1 for the others), ``rowcols``
+    (rows × columns the call reads: its grid's cells times a block),
+    ``mxu_flops`` and ``vpu_elems``.  All of it is the inner
+    ``pallas_call``'s: the operands are the growers' whole arrays, the grid
+    is one chunk's.  Its blocks and result give ``bf``, ``bm``, ``M`` and
+    ``N``, and the length of the body's loop over sub-blocks gives ``rm``."""
     body, work = WRAPPERS[eqn.params["name"]]
     quant = _is_bucket(eqn.invars[1].aval.dtype)
     (call,) = (e for e in eqn.params["jaxpr"].eqns if e.primitive.name == "pallas_call")
     grid = call.params["grid_mapping"]
     bf, bm = (int(b.block_size) for b in grid.block_mappings[0].block_shape)
-    _, M, lanes = call.params["out_avals"][0].shape
+    M, lanes = call.params["out_avals"][0].shape[-2:]
     loops = [e.params["length"] for e in call.params["jaxpr"].eqns if e.primitive.name == "scan"]
     rm = bm // int(loops[0]) if loops else bm
     flops, elems = work(M, lanes // bf, bf, bm, rm, quant)
-    cells = int(grid.grid[0]) * int(grid.grid[1])
-    return {"body": body, "quant": quant, "rowcols": cells * bf * bm, "mxu_flops": cells * flops, "vpu_elems": cells * elems}
+    cells = math.prod(int(d) for d in grid.grid)
+    return {
+        "body": body, "quant": quant, "passes": math.prod(int(d) for d in grid.grid[:-2]),
+        "rowcols": cells * bf * bm, "mxu_flops": cells * flops, "vpu_elems": cells * elems,
+    }

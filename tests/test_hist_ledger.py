@@ -83,7 +83,7 @@ def test_ledger_counts_a_windowed_fits_passes_rowcols_and_flops(name):
 
 
 # ---- each body's stated work against its own traced body ------------------------
-_ELEMENTWISE = {"convert_element_type", "eq", "mul", "add", "shift_right_arithmetic", "and"}
+_ELEMENTWISE = {"convert_element_type", "eq", "gt", "mul", "add", "shift_right_arithmetic", "and"}
 
 
 def _walk(jaxpr, times, acc):
@@ -124,6 +124,25 @@ def test_stated_work_of_a_body_is_what_its_traced_body_builds(wrapper, leaves, v
     assert work["mxu_flops"] == cells * body["mxu"] == 2 * (3 * (leaves or 1)) * 256 * F * n
 
 
+def test_stated_work_of_the_class_roots_body_is_what_its_traced_body_builds():
+    """Twelve classes: a whole group of eight and a ragged one of four, each
+    a pass over the chunk's rows at the nibble body's tile (M = 48, 12,288
+    MXU flops a row-column)."""
+    F, n, K = 16, 4096, 12
+    args = [jnp.zeros((F, n), jnp.uint8), jnp.zeros((K, n), jnp.float32), jnp.zeros((K, n), jnp.float32),
+            jnp.zeros((1, n), jnp.float32), jnp.zeros(1, jnp.int32)]
+    kw = dict(num_bins=256, bm=2048, bf=8, rm=1024, chunk=n, interpret=True, precision="default")
+    jaxpr = jax.make_jaxpr(lambda *a: pallas_hist._pallas_hist_class_roots(*a, **kw))(*args)
+    (eqn,) = (e for e in jaxpr.eqns if e.primitive.name in ("jit", "pjit"))
+    work = pallas_hist.call_work(eqn)
+    (call,) = (e for e in eqn.params["jaxpr"].eqns if e.primitive.name == "pallas_call")
+    cells = int(np.prod(call.params["grid_mapping"].grid))
+    body = _walk(call.params["jaxpr"], 1, collections.Counter())
+    assert (work["body"], work["passes"], work["rowcols"], work["quant"]) == ("class_root", 2, 2 * F * n, False)
+    assert work["vpu_elems"] == cells * body["vpu"]
+    assert work["mxu_flops"] == cells * body["mxu"] == 2 * M * N * 2 * F * n
+
+
 def test_a_call_on_the_whole_matrix_counts_one_chunks_rowcols():
     """The chunk loop hands every call the whole matrix: the ledger reads a
     call's rows and columns off the inner grid and blocks, one chunk's, and a
@@ -146,6 +165,42 @@ def test_a_call_on_the_whole_matrix_counts_one_chunks_rowcols():
     assert work["rowcols"] == F * chunk and work["mxu_flops"] == 2 * M * N * F * chunk  # 12,288 a row-column
     (ledger,) = booster_mod.hist_ledger(jaxpr).values()
     assert ledger["passes"] == 1 and ledger["rowcols"] == F * n and ledger["mxu_flops"] == 12_288 * F * n
+
+
+# ---- a multiclass fit's roots, and a fit of one tree an iteration ---------------
+def test_a_multiclass_fit_counts_its_roots_as_a_pass_for_eight_classes():
+    """K = 100: the roots in ⌈100/8⌉ = 13 passes an iteration, each over every
+    row and column, and every class tree's windowed passes without a root
+    pass of its own."""
+    K = 100
+    X, _ = _rows(5)
+    y = np.floor((X[:, 0] - X[:, 0].min()) / np.ptp(X[:, 0]) * (K - 1e-9))
+    params = dict(PARAMS, objective="multiclass", num_class=K, num_leaves=4, split_batch=2, hist_backend="pallas")
+    passes = full_tree_passes(GrowConfig(num_bins=256, num_leaves=4, split_batch=2))
+    with _recording():
+        train(params, Dataset(X, y))
+        got = _counters()
+    roots, trees = "{body=class_root,scope=hist_build,vals=f32}", "{body=nibble,scope=hist_build,vals=f32}"
+    assert got["hist.passes" + roots] == ITERS * 13
+    assert got["hist.rowcols" + roots] == ITERS * 13 * ROWS * COLS
+    assert got["hist.mxu_flops" + roots] == 2 * M * N * ITERS * 13 * ROWS * COLS
+    assert got["hist.passes" + trees] == ITERS * K * passes
+    assert {k for k in got if k.startswith("hist.passes{")} == {"hist.passes" + roots, "hist.passes" + trees}
+
+
+def test_a_fit_of_one_tree_an_iteration_builds_its_own_root():
+    """K = 1 at the Criteo cells' shape (39 columns, 63 leaves at 8 splits a
+    pass): no class-root pass, and 11 passes a tree, the root's among them."""
+    rng = np.random.default_rng(6)
+    X = rng.normal(size=(ROWS, 39))
+    y = (X[:, 0] - X[:, 1] > 0).astype(np.float64)
+    with _recording():
+        train(dict(PARAMS, hist_backend="pallas"), Dataset(X, y))
+        got = _counters()
+    assert not [k for k in got if "class_root" in k]
+    label = "{body=nibble,scope=hist_build,vals=f32}"
+    assert got["hist.passes" + label] / ITERS == PASSES + 1 == 11
+    assert got["hist.rowcols" + label] == ITERS * 11 * ROWS * 39
 
 
 # ---- the counters at each dispatch, and one grower trace a program -------------

@@ -81,6 +81,17 @@ def test_fit_matches_the_teacher_forced_reference(cell, K):
     assert gaps["holdout_logloss_gap"] < 1e-5, gaps
 
 
+def test_windowed_fit_with_its_roots_built_together_matches_the_reference(cell):
+    """The chip's grower (8 splits a pass), whose K trees take their roots
+    from one build of every class's root (``class_roots_active``)."""
+    cfg, ds, holdout, params = cell(5)
+    booster = train_loop_multiclass._train(dict(params, split_batch=8), ds)
+    gaps = _gaps(cfg, booster, holdout)
+    assert gaps["leaf_count_gap"] == 0.0 and gaps["holdout_score_gap"] == 0.0, gaps
+    assert all(gaps[k] <= lim for k, lim in LIMITS.items()), gaps
+    assert gaps["holdout_logloss_gap"] < 1e-5, gaps
+
+
 @pytest.mark.parametrize("fault", ["ova_gradient", "class_shift", "hess_halved"])
 def test_a_gradient_fault_fails_the_limits(cell, fault):
     cfg, ds, holdout, params = cell(5)

@@ -97,3 +97,28 @@ def test_compaction_kernel_compiles_for_the_chip(one_chip):
     ).compile().as_text()
     assert "tpu_custom_call" in text
     assert not [op for op in ("sort(", "gather(", "scatter(") if op in text]
+
+
+@pytest.mark.filterwarnings("ignore:Error reading persistent compilation cache entry")
+@pytest.mark.parametrize("K", [100, 5])
+def test_class_roots_kernel_compiles_for_the_chip(one_chip, K):
+    """``expedia_multiclass_train_1chip``'s roots: 100 classes in 13 groups of
+    eight on 4,194,304 x 22 rows, one chunk (and five classes, a group that
+    runs past K).  Mosaic takes the body, and XLA copies no ``(K, n)``
+    gradient, hessian or bag row to feed it: the class slots are read where
+    they lie."""
+    import re
+
+    from mmlspark_tpu.ops import pallas_hist
+
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    F, n = 22, 2 * CHUNK
+    text = pallas_hist._pallas_hist_class_roots.lower(
+        struct((F, n), jnp.uint8), struct((K, n), jnp.float32), struct((K, n), jnp.float32),
+        struct((1, n), jnp.float32), struct((1,), jnp.int32),
+        num_bins=B, bm=16384, bf=F, rm=1024, chunk=n, interpret=False, precision="default",
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[{K},3,{F},{B}]" in text
+    made = re.findall(rf"= ((?:f32|u8)\[\d+,{n}\]\S*) (pad|copy|dynamic-slice)\(", text)
+    assert not made, made
