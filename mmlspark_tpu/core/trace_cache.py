@@ -74,9 +74,10 @@ from mmlspark_tpu import obs
 
 _SRC_HASH: Optional[str] = None
 _REGISTERED = False
-# In-process memo of deserialized/exported programs: repeated train()
-# calls build fresh wrappers, and re-deserializing the scan blob per fit
-# would tax steady-state runs.
+# In-process memo of deserialized/exported programs and the jitted calls
+# that run them: repeated train() calls build fresh wrappers, and
+# re-deserializing the scan blob (or tracing its call anew) per fit would
+# tax steady-state runs.
 _EXP_MEMO: dict = {}
 _EXP_MEMO_MAX = 8
 
@@ -241,7 +242,8 @@ def _load_or_export(jitted, args, digest: str, multi_controller: bool):
 
 
 def wrap_aot(
-    jitted: Callable, key_material: str, multi_controller: bool = False
+    jitted: Callable, key_material: str, multi_controller: bool = False,
+    donate_argnums: tuple = (),
 ) -> Callable:
     """Wrap a jitted function so its traced program persists across
     processes.  First call per argument signature: load the exported
@@ -253,19 +255,39 @@ def wrap_aot(
     booster derives it via :func:`mesh_spans_processes`).  Only then is
     load-vs-export agreed collectively; meshless programs load/export
     purely locally even inside a multi-process job, so a rank-local train
-    (e.g. a rank-0-only serial comparator) can never deadlock here."""
+    (e.g. a rank-0-only serial comparator) can never deadlock here.
+
+    ``donate_argnums``: ``jitted``'s own, so that the exported program
+    overwrites the same arguments."""
     import jax
 
     state: dict = {}
+
+    def runner(exp, args):
+        """The exported program as one jitted call of ``args``' flat
+        leaves, under the module name ``jit_call_exported``, donating the
+        leaves of ``donate_argnums``."""
+        tree = jax.tree_util.tree_structure(args)
+        sizes = [len(jax.tree_util.tree_leaves(a)) for a in args]
+        donate = tuple(j for i in donate_argnums for j in range(sum(sizes[:i]), sum(sizes[: i + 1])))
+
+        def call_exported(*flat):
+            return jax.tree_util.tree_leaves(exp.call(*jax.tree_util.tree_unflatten(tree, flat)))
+
+        return jax.jit(call_exported, donate_argnums=donate)
+
+    def run(entry, args):
+        exp, fn = entry
+        return jax.tree_util.tree_unflatten(exp.out_tree, fn(*jax.tree_util.tree_leaves(args)))
 
     def call(*args):
         if state.get("off"):
             return jitted(*args)
         sig = _arg_signature(args)
-        exp = state.get(sig)
-        if exp is not None:
+        entry = state.get(sig)
+        if entry is not None:
             obs.inc("trace_cache.memo_hit")
-            return exp.call(*args)
+            return run(entry, args)
         _register_trees()
         digest = hashlib.sha256(
             "\x1e".join(
@@ -278,8 +300,8 @@ def wrap_aot(
                 ]
             ).encode()
         ).hexdigest()
-        exp = _EXP_MEMO.get(digest)
-        if exp is not None:
+        entry = _EXP_MEMO.get(digest)
+        if entry is not None:
             obs.inc("trace_cache.memo_hit")
         else:
             exp = _load_or_export(jitted, args, digest, multi_controller)
@@ -288,27 +310,20 @@ def wrap_aot(
                 return jitted(*args)
             if len(_EXP_MEMO) >= _EXP_MEMO_MAX:
                 _EXP_MEMO.pop(next(iter(_EXP_MEMO)))
-            _EXP_MEMO[digest] = exp
-        out = exp.call(*args)
-        state[sig] = exp
-        return out
+            entry = _EXP_MEMO[digest] = (exp, runner(exp, args))
+        state[sig] = entry
+        return run(entry, args)
 
     def lower(*args):
         """``jitted.lower`` for the program ``call`` dispatches on ``args``
-        (arrays or ``ShapeDtypeStruct``s): the exported program as eager
-        dispatch lowers it, flat arguments and results under the module
-        name ``jit_call_exported``, so that its compile is a load of the
+        (arrays or ``ShapeDtypeStruct``s): the jitted call that ran the
+        exported program (``runner``), so that its compile is a load of the
         executable that ran; ``jitted`` itself where the cache is off for
         it (``obs.device.regions`` asks)."""
-        exp = state.get(_arg_signature(args))
-        if exp is None:
+        entry = state.get(_arg_signature(args))
+        if entry is None:
             return jitted.lower(*args)
-        leaves, tree = jax.tree_util.tree_flatten(args)
-
-        def call_exported(*flat):
-            return jax.tree_util.tree_leaves(exp.call(*jax.tree_util.tree_unflatten(tree, flat)))
-
-        return jax.jit(call_exported).lower(*leaves)
+        return entry[1].lower(*jax.tree_util.tree_leaves(args))
 
     call.lower = lower
     call.jitted = jitted

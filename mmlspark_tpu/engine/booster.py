@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import itertools
 import math
 import os
@@ -311,10 +312,10 @@ class Dataset:
     added to: the padded binned matrix (``_dev_bins_cache``, keyed by the
     mapper, the padding and the placement), a ranking fit's query plan
     (``_rank_plan_cache``), and the fit's per-row state
-    (``_row_state_cache``: labels, weights, row mask and init scores, made
-    by ``_fit_row_state``), so a second ``train()`` under the same objective
-    settings and placement computes and sends nothing that follows the
-    rows.  All three go with the data set, and none enters a pickle.  The
+    (``_row_state_cache``: labels, weights, row mask and a set
+    init_score's scores, made by ``_fit_row_state``), so a second
+    ``train()`` under the same objective settings and placement sends
+    nothing that follows the rows.  All three go with the data set, and none enters a pickle.  The
     row state is keyed by the IDENTITY of ``label``, ``weight`` and
     ``init_score``: assigning a new array is seen.  A write into one of
     them cannot be seen, so it is refused: storing the entry makes the three
@@ -1334,6 +1335,10 @@ _DART_SCAN_MAX_ELS = 128_000_000
 # first-ever program, which would tax small fits/test suites for no win.
 _TRACE_CACHE_MIN_WORK = 1 << 21
 
+# The argument of the whole-run scan program (`scan_chunk`) that holds its
+# carry: the running scores and what rides with them.
+_CARRY_ARG = (8,)
+
 # split_batch="auto" (0) resolution on the TPU pallas lossguide path.
 # Swept on BOTH bench shapes (262k rows, 63 leaves, BASELINE.md r5
 # defaults table + k-sweep): k=8 matches k=12's wall inside run variance
@@ -1861,8 +1866,24 @@ class _RowState(NamedTuple):
     y: object  # (n + n_pad,) float32 labels
     w: object  # (n + n_pad,) float32 weights after is_unbalance / scale_pos_weight, or None
     valid_mask: object  # (n + n_pad,) bool, False on padding
-    init_scores: object  # (K, n + n_pad) float32: init score (+ the set's init_score), before any init_model
+    # (K, n + n_pad) float32: init score + the set's init_score, before any
+    # init_model; None where the set has no init_score (each fit then makes
+    # its own rows of ``init`` on the device: :func:`_constant_rows`)
+    init_scores: object
     init: object  # the init score itself: a float, or (K,) for K > 1
+
+
+def _constant_rows(init, K: int, rows: int, mesh, spec):
+    """``(K, rows)`` float32 of ``init`` (a float, or ``(K,)``) on every
+    row, made on the device and placed as ``_placer`` places ``spec``: a
+    fresh buffer each fit, which the fit's program may take over."""
+    v = np.asarray(init, dtype=np.float32).reshape(-1, 1)
+    return _fill_rows(K, rows, None if mesh is None else jax.sharding.NamedSharding(mesh, spec))(v)
+
+
+@functools.lru_cache(maxsize=8)
+def _fill_rows(K: int, rows: int, sharding):
+    return jax.jit(lambda v: jnp.broadcast_to(v, (K, rows)), out_shardings=sharding)
 
 
 def _fit_row_state(
@@ -1873,12 +1894,14 @@ def _fit_row_state(
     """``(row state, found)`` for a fit of ``train_set``: made once for a
     data set and kept on the device with its binned matrix
     (``_row_state_cache``, one entry), so a later fit on the same host
-    arrays, placement and objective settings touches no array of ``n``
-    elements and sends nothing.  The key holds what the arrays depend on;
-    the entry pins the host arrays it was made from, which keeps their ids
-    from being recycled, and makes them read-only, so a write into them
-    between fits raises instead of being trained past (see ``Dataset``).
-    Nothing donates these buffers; a fit that did would need a copy here.
+    arrays, placement and objective settings sends nothing.  The key holds
+    what the arrays depend on; the entry pins the host arrays it was made
+    from, which keeps their ids from being recycled, and makes them
+    read-only, so a write into them between fits raises instead of being
+    trained past (see ``Dataset``).  The fit's program overwrites the
+    scores it starts from, so held init scores go to it as a copy, and
+    scores that are a constant a class are not held: each fit makes its
+    own on the device (:func:`_constant_rows`).
 
     Multi-controller fits always build: their label statistics are
     collectives that every process must enter in every fit."""
@@ -1927,15 +1950,18 @@ def _fit_row_state(
         init = obj.init_score(label, weight)
     else:
         init = np.zeros(K) if K > 1 else 0.0
-    init_arr = np.broadcast_to(np.asarray(init, dtype=np.float32).reshape(-1, 1), (K, n + n_pad)).copy()
-    if init_score is not None:
-        init_arr = init_arr + _pad_rows(init_score.astype(np.float32), n_pad).reshape(1, -1)
+    init_scores = None
+    if init_score is not None or process_local:
+        init_arr = np.broadcast_to(np.asarray(init, dtype=np.float32).reshape(-1, 1), (K, n + n_pad)).copy()
+        if init_score is not None:
+            init_arr = init_arr + _pad_rows(init_score.astype(np.float32), n_pad).reshape(1, -1)
+        init_scores = put(sent(init_arr), krow_spec)
 
     state = _RowState(
         y=put(sent(y.astype(np.float32)), row_spec),
         w=None if w_np is None else put(sent(w_np.astype(np.float32)), row_spec),
         valid_mask=put(sent(valid_mask_np), row_spec),
-        init_scores=put(sent(init_arr), krow_spec),
+        init_scores=init_scores,
         init=init,
     )
     if not process_local:
@@ -2406,6 +2432,12 @@ def _train_impl(
     # Size-1 like the host caches: each entry pins a full-matrix device
     # copy, and sweeps over mesh/chunk configs must not accumulate HBM.
     train_set._dev_bins_cache = {dev_key: bins_dev}
+    # the carry's first scores are a buffer of this fit's own (the program
+    # overwrites it in place: `donate_carry`) unless the data set's entry
+    # holds them
+    scores_held = init_scores_dev is not None and not process_local
+    if init_scores_dev is None:
+        init_scores_dev = _constant_rows(init, K, n + n_pad, mesh, krow_spec)
     if init_model is not None:
         # Replay the base forest over the already-placed binned matrix:
         # under a mesh this runs sharded (bins_dev carries the row sharding
@@ -2413,6 +2445,7 @@ def _train_impl(
         # unsharded full-matrix copy.  Padded rows score garbage, harmlessly
         # — their gradients are zeroed by the bag mask.
         init_scores_dev = init_scores_dev + init_model._raw_scores_binned(bins_dev)
+        scores_held = False
     scores = init_scores_dev
 
     voting = (
@@ -3019,6 +3052,14 @@ def _train_impl(
         # Like `iteration` above: device data enters as ARGUMENTS (valid
         # bins included, eval label/weight/mask/group aux included) so
         # nothing large becomes a jaxpr constant.
+        # The program overwrites the carry that goes in (its scores are the
+        # largest array of a fit: K rows of n), except where a buffer of it
+        # is also handed in elsewhere: the init scores RF fits from, the
+        # training set's scores as its own pseudo-valid set.
+        donate_carry = cfg.boosting != "rf" and not cfg.is_provide_training_metric
+        if donate_carry and scores_held:
+            scores = jnp.copy(scores)
+
         def _build_scan_chunk():
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _PS
@@ -3171,7 +3212,7 @@ def _train_impl(
                     body, carry, (xs_c,) + tuple(dart_xs)
                 )
 
-            return jax.jit(scan_chunk)
+            return jax.jit(scan_chunk, donate_argnums=_CARRY_ARG if donate_carry else ())
 
         # Reuse the jitted program across train() calls when nothing it
         # closes over can differ.  The cached program closes over the FIRST
@@ -3209,7 +3250,7 @@ def _train_impl(
                     _SCAN_CACHE.pop(next(iter(_SCAN_CACHE)))
                 _SCAN_CACHE[cache_key] = entry
             scan_chunk, program_notes = entry
-        merge_ledger = hist_ledger = None
+        merge_ledger = hist_ledger = routes = None
         if obs.enabled():
             # what one iteration's collectives bring each device and what
             # its histogram passes issue, read off ONE abstract trace of the
@@ -3223,6 +3264,7 @@ def _train_impl(
                 ))
             merge_ledger = program_notes["merge_ledger"]
             hist_ledger = program_notes["hist_ledger"]
+            routes = program_notes["route_ledger"]
         if quantize_on and obs.enabled():
             for channel, level in zip(("grad", "hess", "count"), qlevels):
                 obs.inc("train.quant_levels", float(level), channel=channel)
@@ -3269,6 +3311,7 @@ def _train_impl(
                     multi_controller=(
                         process_local or mesh_spans_processes(mesh)
                     ),
+                    donate_argnums=_CARRY_ARG if donate_carry else (),
                 )
 
         if cfg.early_stopping_round > 0 and vsets:
@@ -3422,7 +3465,7 @@ def _train_impl(
             # chunks measure pure async-dispatch cost.
             scan_args = (
                 bins_dev, y_dev, w_dev, valid_mask, obj.device_state(),
-                init_scores_dev, vbins_t, vaux_t, carry,
+                init_scores_dev if cfg.boosting == "rf" else None, vbins_t, vaux_t, carry,
                 jax.lax.slice(xs_dev, (n_done, 0), (n_done + c, 5))
                 if c < n_iter else xs_dev,
                 *dart_xs,
@@ -3447,6 +3490,8 @@ def _train_impl(
             for (body, vals_kind, scope), work in (hist_ledger or {}).items():
                 for name, per_iter in work.items():
                     obs.inc("hist." + name, float(per_iter * c), body=body, vals=vals_kind, scope=scope)
+            for body, per_iter in (routes or {}).items():
+                obs.inc("route.passes", float(per_iter * c), body=body)
             if quantize_on and hist_ledger:
                 obs.inc("train.quant_refine_cols", float(program_notes["quant_refine_cols"] * c))
             if K > 1:
@@ -3762,7 +3807,8 @@ def _grow_ledgers(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
     """The ``program_notes`` of ONE boosting iteration, from one abstract
     trace of the grower: ``merge_ledger``, the bytes each device receives in
     its collectives (:func:`~mmlspark_tpu.parallel.distributed.collective_ledger`;
-    ``None`` off a mesh), and ``hist_ledger`` (:func:`hist_ledger`).  The
+    ``None`` off a mesh), ``hist_ledger`` (:func:`hist_ledger`) and
+    ``route_ledger`` (:func:`route_ledger`).  The
     windowed grower's loop counts as the passes of a full tree
     (``full_tree_passes``; ``tests/test_dp_resident.py`` holds it to the
     loop's own trips): the program does not carry its trip count out, so a
@@ -3781,6 +3827,7 @@ def _grow_ledgers(grow, gcfg: GrowConfig, K: int, bins_dev, F_mask: int,
     return {
         "merge_ledger": collective_ledger(jaxpr, while_trips=trips) if merges else None,
         "hist_ledger": hists,
+        "route_ledger": route_ledger(jaxpr, while_trips=trips),
         "quant_refine_cols": refines * (gcfg.level_window if windowed_grower(gcfg) else 1),
     }
 
@@ -3875,6 +3922,29 @@ def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
                 work[name] += times * mult * call[name]
 
     scope_entries(jaxpr, HIST_SCOPES, while_trips=while_trips, visit=visit)
+    return out
+
+
+def route_ledger(jaxpr, while_trips: int = 1) -> dict:
+    """``{"block": passes, "slots": passes}``: the entries of the scope
+    ``row_route`` one execution of a traced grower makes
+    (:func:`scope_entries`: full trees), by the form that moved the rows:
+    ``block`` where the entry calls the one-pass Pallas body
+    (``ops/pallas_route.py``, its ``jit`` equation ``_pallas_route``),
+    ``slots`` where it is the slot loop."""
+    out = {"block": 0, "slots": 0}
+
+    def calls_kernel(eqns):
+        return any(
+            (eqn.primitive.name in ("jit", "pjit") and eqn.params.get("name") == "_pallas_route")
+            or any(calls_kernel(sub.eqns) for sub in jax.core.jaxprs_in_params(eqn.params))
+            for eqn in eqns
+        )
+
+    def visit(scope, run, times):
+        out["block" if calls_kernel(run) else "slots"] += times
+
+    scope_entries(jaxpr, ("row_route",), while_trips=while_trips, visit=visit)
     return out
 
 

@@ -528,16 +528,76 @@ def _member_lookup(members, col, B: int):
     262k rows on v5e; bit-packing the mask into ≤⌈B/32⌉ uint32 words and
     selecting by word index is a handful of n-sized elementwise ops
     (~0.1ms).  ``members``: (B,) bool; ``col``: (n,) int bins."""
-    nw = (B + 31) // 32
-    bits = jnp.pad(members, (0, nw * 32 - B))
-    words = (
-        bits.reshape(nw, 32).astype(jnp.uint32)
-        << jnp.arange(32, dtype=jnp.uint32)[None, :]
-    ).sum(axis=1)  # (nw,)
+    from mmlspark_tpu.ops.pallas_route import member_words
+
+    words = member_words(members)  # (nw,)
     wsel = jnp.zeros_like(col, dtype=jnp.uint32)
-    for j in range(nw):
+    for j in range(words.shape[0]):
         wsel = jnp.where(col >> 5 == j, words[j], wsel)
     return ((wsel >> (col & 31).astype(jnp.uint32)) & 1) > 0
+
+
+class PassSplits(NamedTuple):
+    """One windowed pass's splits, by leaf: what moves its rows."""
+
+    feat: jnp.ndarray  # (L,) int32 split column
+    thr: jnp.ndarray  # (L,) int32; bins <= thr go left
+    dleft: jnp.ndarray  # (L,) bool; the missing bin goes left
+    is_cat: jnp.ndarray  # (L,) bool; membership split
+    selected: jnp.ndarray  # (L,) bool; the leaf splits this pass
+    new_id: jnp.ndarray  # (L,) int32; its right child
+    members: Optional[jnp.ndarray]  # (L, B) bool bins that go left; None with no categoricals
+
+
+def route_in_block(cfg: GrowConfig, bins_t) -> bool:
+    """Whether the windowed grower moves its rows by the one-pass Pallas
+    body (:func:`_route_block`) rather than the slot loop: on the Pallas
+    backend, over the byte tier's uint8 ``(F, n)`` matrix, up to
+    ``ops/pallas_route.MAX_COLS`` columns, where reading a whole row block
+    costs less than W one-column passes."""
+    from mmlspark_tpu.ops.pallas_route import MAX_COLS
+
+    return cfg.hist_backend == "pallas" and bins_t.dtype == jnp.uint8 and bins_t.shape[0] <= MAX_COLS
+
+
+def _route_slots(bins_t, leaf_ids, slot_leaves, sp: PassSplits, B: int):
+    """``leaf_ids`` after the pass: the slot loop.  Reads the ≤W split
+    columns with W dynamic slices and resolves rows against their leaf's
+    slot with n-sized selects, where a per-row gather of each row's
+    split-column bin out of the (F, n) matrix (a dynamic cross-sublane
+    lookup) cost ~2.7ms/pass at the bench shape.  XLA makes one fusion a
+    slot over every row.  A moved row's new id is ≥ base > every
+    splittable leaf id, so later slots can never re-match it."""
+    n = bins_t.shape[1]
+    for w in range(slot_leaves.shape[0]):
+        l_w = slot_leaves[w]
+        col = lax.dynamic_slice(bins_t, (sp.feat[l_w], jnp.int32(0)), (1, n))[0]
+        gl_w = jnp.where(col == (B - 1), sp.dleft[l_w], col <= sp.thr[l_w])
+        if sp.members is not None:
+            memb_w = lax.dynamic_slice(sp.members, (l_w, 0), (1, B))[0]
+            gl_w = jnp.where(sp.is_cat[l_w], _member_lookup(memb_w, col, B), gl_w)
+        moves_w = (leaf_ids == l_w) & sp.selected[l_w] & ~gl_w
+        leaf_ids = jnp.where(moves_w, sp.new_id[l_w], leaf_ids)
+    return leaf_ids
+
+
+def _route_block(bins_t, leaf_ids, slot_leaves, sp: PassSplits, B: int, **blocks):
+    """What :func:`_route_slots` returns, to the bit, from one read of each
+    row block (``ops/pallas_route.py``; ``blocks``: its ``route_rows``
+    block sizes): the slots' splits packed into one small table a pass."""
+    from mmlspark_tpu.ops.pallas_route import route_rows, slot_table
+
+    W = slot_leaves.shape[0]
+    take = lambda x: x[slot_leaves]  # noqa: E731
+    if sp.members is None:
+        is_cat, members = jnp.zeros(W, bool), jnp.zeros((W, B), bool)
+    else:
+        is_cat, members = take(sp.is_cat), take(sp.members)
+    table = slot_table(
+        slot_leaves, take(sp.feat), take(sp.thr), take(sp.dleft), is_cat,
+        jnp.where(take(sp.selected), take(sp.new_id), slot_leaves), members,
+    )
+    return route_rows(bins_t, leaf_ids, table, **blocks)
 
 
 def _cat_feat_mask(cfg: GrowConfig, F: int) -> np.ndarray:
@@ -1395,12 +1455,10 @@ def grow_tree_depthwise(
                 # sets bit-packed to (L, ⌈B/32⌉) u32 words, one small-table
                 # take per row (the `members` above is already global —
                 # psum-ed from the owner — so every shard agrees)
-                nw = (B + 31) // 32
-                mbits = jnp.pad(members, ((0, 0), (0, nw * 32 - B)))
-                words = (
-                    mbits.reshape(L, nw, 32).astype(jnp.uint32)
-                    << jnp.arange(32, dtype=jnp.uint32)[None, None, :]
-                ).sum(axis=2)  # (L, nw)
+                from mmlspark_tpu.ops.pallas_route import member_words
+
+                words = member_words(members)  # (L, nw)
+                nw = words.shape[1]
                 wsel = jnp.take(
                     words.reshape(-1),
                     leaf_ids * nw + (fcol >> 5).astype(jnp.int32),
@@ -1415,30 +1473,17 @@ def grow_tree_depthwise(
             move = sel_row & ~goes_left
             leaf_ids = jnp.where(move, new_id_of_leaf[leaf_ids], leaf_ids)
         else:
-            # Only the ≤W window leaves split this pass, so instead of a
-            # per-row gather of each row's split-feature bin out of the
-            # (F, n) matrix — a dynamic cross-sublane lookup that cost
-            # ~2.7ms/pass at the bench shape, more than the histogram
-            # kernel itself — read the ≤W split columns with W dynamic
-            # slices and resolve rows against their leaf's slot with
-            # n-sized selects (~0.2ms/pass).  A moved row's new id is
-            # ≥ base > every splittable leaf id, so later slots can never
-            # re-match it.  (slot_leaves hoisted above — the refinement
-            # pass and the candidate cache share the gain-ranked slots.)
+            # Only the ≤W window leaves split this pass: their rows move by
+            # the ≤W split columns alone (slot_leaves hoisted above — the
+            # refinement pass and the candidate cache share the
+            # gain-ranked slots).
+            splits = PassSplits(
+                f, t, dleft, is_cat, selected, new_id_of_leaf,
+                members if cfg.has_categoricals else None,
+            )
+            route = _route_block if route_in_block(cfg, bins_t) else _route_slots
             with jax.named_scope("row_route"):
-                for w in range(W):
-                    l_w = slot_leaves[w]
-                    col = lax.dynamic_slice(
-                        bins_t, (f[l_w], jnp.int32(0)), (1, n)
-                    )[0]
-                    gl_w = jnp.where(col == (B - 1), dleft[l_w], col <= t[l_w])
-                    if cfg.has_categoricals:
-                        memb_w = lax.dynamic_slice(members, (l_w, 0), (1, B))[0]
-                        gl_w = jnp.where(
-                            is_cat[l_w], _member_lookup(memb_w, col, B), gl_w
-                        )
-                    moves_w = (leaf_ids == l_w) & selected[l_w] & ~gl_w
-                    leaf_ids = jnp.where(moves_w, new_id_of_leaf[l_w], leaf_ids)
+                leaf_ids = route(bins_t, leaf_ids, slot_leaves, splits, B)
 
         # -- windowed new-children histograms + parent subtraction --------
         win = window_hist(leaf_ids - base)  # (3, W, F, B); old ids park <0

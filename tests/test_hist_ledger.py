@@ -82,6 +82,22 @@ def test_ledger_counts_a_windowed_fits_passes_rowcols_and_flops(name):
         f"hist.passes{{body={b},scope={s},vals={v}}}" for b, v, s in want}
 
 
+# ---- the row route's form, counted ------------------------------------------------
+@pytest.mark.parametrize("backend, cols, body", [("pallas", 39, "block"), ("pallas", 220, "slots"), ("scatter", 39, "slots")])
+def test_route_passes_count_the_form_that_moved_a_fits_rows(backend, cols, body):
+    """``route.passes{body=block|slots}``: every windowed pass of the fit, under
+    the one-pass Pallas body at 39 columns on the Pallas backend, under the slot
+    loop at 220 (past ``ops/pallas_route.MAX_COLS``) and on the scatter backend."""
+    rng = np.random.default_rng(cols)
+    X = rng.normal(size=(ROWS, cols))
+    y = (X[:, 0] + rng.normal(size=ROWS) > 0).astype(np.float64)
+    with _recording():
+        train(dict(PARAMS, hist_backend=backend), Dataset(X, y))
+        got = _counters("route.")
+    other = {"block": "slots", "slots": "block"}[body]
+    assert got == {f"route.passes{{body={body}}}": ITERS * PASSES, f"route.passes{{body={other}}}": 0.0}
+
+
 # ---- each body's stated work against its own traced body ------------------------
 _ELEMENTWISE = {"convert_element_type", "eq", "gt", "mul", "add", "shift_right_arithmetic", "and"}
 

@@ -232,7 +232,7 @@ def test_upload_bytes_by_hand_and_less_the_matrix_on_a_second_fit():
     first = sent()
     bins = ds.binned(booster.bin_mapper)
     vbins = valid.binned(booster.bin_mapper)
-    rows = n * 4 + n * 1 + n * 4  # labels float32, the valid mask, init scores float32
+    rows = n * 4 + n * 1  # labels float32, the valid mask (the init scores are made on the device)
     valid_rows = vbins.nbytes + 64 * 4  # its binned matrix and its float32 scores
     assert first == bins.nbytes + rows + valid_rows
     (up,) = flight.spans("booster.upload")

@@ -197,9 +197,10 @@ def test_second_train_builds_and_sends_no_plan():
     assert first["attrs"]["cache_hit"] is False and second["attrs"]["cache_hit"] is True
     assert first["parent"] == "booster.prepare"
     assert first["attrs"]["queries"] == len(sizes) and first["attrs"]["buckets"] == 4  # widths 8, 16, 32, 64
-    # the first fit also sends the binned matrix (a byte a cell) and nine bytes
-    # a row of labels, mask and init scores; the second sends nothing
-    assert sent1["train.upload_bytes"] == plan_bytes + X.size + 9 * len(y)
+    # the first fit also sends the binned matrix (a byte a cell) and five bytes
+    # a row of labels and mask (the init scores are made on the device); the
+    # second sends nothing
+    assert sent1["train.upload_bytes"] == plan_bytes + X.size + 5 * len(y)
     assert sent2["train.upload_bytes"] == 0
     plan = build_rank_plan(sizes)
     for fit_counts in (sent1, sent2):

@@ -78,7 +78,8 @@ def _held(ds):
 def test_second_fit_sends_nothing_and_grows_the_same_model(counted, make):
     ds = make()
     first, c1 = counted(PARAMS, ds)
-    assert (c1["hit"], c1["miss"], c1["rows_cached"]) == (0, 1, False) and c1["sent"] >= 9 * N
+    # the labels (4 bytes a row) and the row mask (1); the init scores are made on the device
+    assert (c1["hit"], c1["miss"], c1["rows_cached"]) == (0, 1, False) and c1["sent"] >= 5 * N
     state = _held(ds)
     second, c2 = counted(PARAMS, ds)
     assert c2 == {"sent": 0, "hit": 1, "miss": 0, "rows_cached": True}
@@ -165,8 +166,9 @@ def test_init_model_on_a_hit_is_init_model_on_a_miss(counted):
     on_miss, c1 = counted(PARAMS, ds, init_model=base)  # no bias folding under a warm start: a new entry
     on_hit, c2 = counted(PARAMS, ds, init_model=base)
     assert (c1["miss"], c2["hit"], c2["sent"]) == (1, 1, 0)
-    held = np.asarray(_held(ds).init_scores)
-    assert not held.any()  # the entry holds the scores BEFORE the old forest's are added
+    held = _held(ds)
+    # the entry holds the init score (0.0 under a warm start), not the scores with the old forest's added
+    assert held.init_scores is None and held.init == 0.0
     assert on_hit.num_iterations == 2 * PARAMS["num_iterations"]
     assert on_hit.save_model_string() == on_miss.save_model_string()
     fresh = train(PARAMS, _plain(), init_model=base)
@@ -192,7 +194,7 @@ def test_a_multiclass_fit_hits_and_matches(counted):
     ds = Dataset(X, y)
     first, c1 = counted(params, ds)
     second, c2 = counted(params, ds)
-    assert _held(ds).init_scores.shape == (3, N)
+    assert _held(ds).init_scores is None and np.shape(_held(ds).init) == (3,)
     assert (c1["miss"], c2["hit"], c2["sent"]) == (1, 1, 0)
     assert second.save_model_string() == first.save_model_string()
     assert train(params, Dataset(X, y.copy())).save_model_string() == first.save_model_string()
@@ -207,3 +209,47 @@ def test_a_multi_controller_fit_keeps_nothing(counted):
     assert ds._row_state_cache == {} and ds.label.flags.writeable
     assert (c1["miss"], c2["miss"], c2["hit"], c2["rows_cached"]) == (1, 1, 0, False)
     assert c2["sent"] >= 9 * N
+
+
+def test_a_set_init_score_is_held_and_outlives_the_fits(counted):
+    """With an ``init_score`` the entry holds the ``(K, n)`` scores, and the
+    program, which overwrites the carry that goes in, gets a copy of them."""
+    _, _, _, s = _arrays()
+    ds = _plain(init_score=s)
+    first, c1 = counted(PARAMS, ds)
+    second, c2 = counted(PARAMS, ds)
+    held = _held(ds).init_scores
+    assert (c1["miss"], c2["hit"], c2["sent"]) == (1, 1, 0)
+    assert not held.is_deleted()
+    np.testing.assert_array_equal(np.asarray(held)[0, :N], s.astype(np.float32))
+    assert second.save_model_string() == first.save_model_string()
+
+
+@pytest.mark.parametrize("exported", [False, True], ids=["jit", "exported"])
+def test_the_program_overwrites_the_scores_it_starts_from(monkeypatch, tmp_path, exported):
+    """The whole-run program takes over the fit's first scores, made on the
+    device for it alone: no second ``(K, n)`` buffer beside its result.
+    Through the trace cache's exported program too."""
+    import mmlspark_tpu.engine.booster as bo
+    from mmlspark_tpu.core import trace_cache as tc
+
+    made = []
+    real = bo._constant_rows
+    monkeypatch.setattr(bo, "_constant_rows", lambda *a: made.append(real(*a)) or made[-1])
+    if exported:
+        monkeypatch.delenv("MMLSPARK_TPU_NO_TRACE_CACHE", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "traces"))
+        monkeypatch.setattr(bo, "_TRACE_CACHE_MIN_WORK", 0)
+        tc._EXP_MEMO.clear()
+    else:
+        monkeypatch.setenv("MMLSPARK_TPU_NO_TRACE_CACHE", "1")
+    params = dict(PARAMS, objective="multiclass", num_class=3)
+    X, y, _, _ = _arrays()
+    ds = Dataset(X, np.clip(np.round(X[:, 0] + 1.0), 0, 2))
+    first = train(params, ds)
+    second = train(params, ds)
+    assert len(made) == 2 and all(a.is_deleted() for a in made)
+    assert second.save_model_string() == first.save_model_string()
+    if exported:
+        assert list((tmp_path / "traces").glob("*.jaxexp"))
+

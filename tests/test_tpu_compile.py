@@ -122,3 +122,49 @@ def test_class_roots_kernel_compiles_for_the_chip(one_chip, K):
     assert f"f32[{K},3,{F},{B}]" in text
     made = re.findall(rf"= ((?:f32|u8)\[\d+,{n}\]\S*) (pad|copy|dynamic-slice)\(", text)
     assert not made, made
+
+
+@pytest.mark.filterwarnings("ignore:Error reading persistent compilation cache entry")
+@pytest.mark.parametrize(
+    "cols, rows",
+    [(39, 12 * CHUNK), (22, 2 * CHUNK), (39, 10 * CHUNK), (128, 2 * CHUNK)],
+    ids=["criteo-one-chip", "expedia", "criteo-four-chip-shard", "widest-block"],
+)
+def test_route_kernel_compiles_for_the_chip(one_chip, cols, rows):
+    """The windowed grower's one-pass row route at the cells' shapes: the whole
+    resident matrix and the leaf ids (a chip's shard of them in the four-chip
+    cell), and at the widest block it takes (``MAX_COLS``, the VMEM its
+    double-buffered blocks need at most).  Mosaic takes the body, and XLA puts no gather, scatter or copy of
+    the matrix or the ids around it: the ids are updated in place."""
+    import functools
+    import re
+
+    from mmlspark_tpu.ops import pallas_route
+
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    call = functools.partial(pallas_route._pallas_route, bm=pallas_route.BM, rm=pallas_route.RM, interpret=False)
+    text = jax.jit(call, donate_argnums=1).lower(
+        struct((cols, rows), jnp.uint8), struct((rows,), jnp.int32), struct((W, 3 + B // 32), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [op for op in ("gather(", "scatter(") if op in text]
+    shape = re.compile(rf"(u8\[{cols},|s32\[1,|s32\[){rows}\]")
+    made = [line for line in text.splitlines() if shape.search(line) and re.search(r" (pad|copy|copy-start|dynamic-slice|transpose)\(", line)]
+    assert not made, made
+
+
+def test_the_wide_fit_holds_no_route_kernel(one_chip, monkeypatch):
+    """The grower's program for the chip calls the route kernel at 39 columns
+    and not at 220 (the ranking cell's width, which keeps the slot loop)."""
+    from mmlspark_tpu.engine.tree import GrowConfig, grow_tree_depthwise
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels as the chip runs them
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    n = 16384
+    for cols, engaged in ((39, True), (220, False)):
+        cfg = GrowConfig(num_bins=B, num_leaves=63, split_batch=W, hist_backend="pallas", hist_precision="default")
+        text = jax.jit(lambda *a: grow_tree_depthwise(cfg, *a)).lower(
+            struct((n, cols), jnp.uint8), *[struct((n,), jnp.float32)] * 3, struct((cols,), jnp.bool_),
+        ).as_text()
+        assert "_hist_leaf_nibble_kernel" in text
+        assert ("_route_kernel" in text) == engaged
