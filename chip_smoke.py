@@ -380,7 +380,6 @@ def kernels_phase(sz: dict, booster, X) -> dict:
     import numpy as np
 
     from mmlspark_tpu.ops import histogram as H
-    from mmlspark_tpu.ops import pallas_hist as PH
 
     n, F, B = sz["kernel_rows"], 39, 256
     rng = np.random.default_rng(11)
@@ -399,8 +398,10 @@ def kernels_phase(sz: dict, booster, X) -> dict:
         check(np.array_equal(np.asarray(got), np.asarray(ref)), what)
         done[what] = "ok"
 
-    # W=8 is the default lossguide window (nibble kernel), W=32 the plain
-    # by-leaf kernel of depthwise growth
+    # every build takes the one factorized body: W=8 the default window,
+    # W=32 the window of depthwise growth at 63 leaves, one slot the
+    # per-feature build; the int16 buckets' sums are exact by contract
+    hq = H.HistQuantize("int32", 0, jnp.ones(3, jnp.float32))
     for W, name in ((8, "by_leaf_nibble"), (32, "by_leaf")):
         leaf = jnp.asarray(rng.integers(-1, W + 1, size=n), jnp.int32)
         got, ref = (
@@ -409,22 +410,23 @@ def kernels_phase(sz: dict, booster, X) -> dict:
             for be in ("pallas", "scatter")
         )
         close(got, ref, name, 1e-5)  # test_gbdt_engine.py TestByLeafKernels
-        equal(
-            PH.pallas_hist_by_leaf_chunk(
-                bins_t, qvals, leaf, W, B, precision="default"),
-            H._scatter_hist_by_leaf_chunk(bins_t, qvals, leaf, W, B),
-            f"by_leaf_int_W{W}",
+        got, ref = (
+            H.build_histogram_by_leaf(bins_t, qvals, leaf, W, B, backend=be,
+                                      chunk=n, precision="default", quantize=hq)
+            for be in ("pallas", "scatter")
         )
+        equal(got, ref, f"by_leaf_int_W{W}")
     got, ref = (
         H.build_histogram(bins_t, vals, mask, B, backend=be, chunk=n)
         for be in ("pallas", "scatter")
     )
     close(got, ref, "hist", 1e-4)  # ...::test_pallas_matches_scatter
-    equal(
-        PH.pallas_hist_chunk(bins_t, qvals, B, precision="default"),
-        H._scatter_hist_chunk(bins_t, qvals, B),
-        "hist_int",
+    got, ref = (
+        H.build_histogram(bins_t, qvals, mask, B, backend=be, chunk=n,
+                          precision="default", quantize=hq)
+        for be in ("pallas", "scatter")
     )
+    equal(got, ref, "hist_int")
 
     # the streamed ingest's fused bin+occupancy kernel
     from mmlspark_tpu.ops.device_binning import bin_rows_device

@@ -3877,8 +3877,8 @@ def hist_ledger(jaxpr, while_trips: int = 1) -> dict:
     "vpu_elems"}}``.  A pass is one entry of a scope of :data:`HIST_SCOPES`
     (:func:`scope_entries`: full trees), or as many as the entry's first
     call makes (``passes`` of ``call_work``: the class roots' groups of
-    eight classes), labelled by the kernel body its calls reach (``plain``,
-    ``by_leaf``, ``nibble``, ``class_root``: the Pallas wrappers of
+    eight classes), labelled by the kernel body its calls reach (``nibble``,
+    ``class_root``: the Pallas wrappers of
     ``ops/pallas_hist.py``, found by the name of their ``jit`` equation;
     ``scatter``: the other backend's scatter-add, one pass an entry) and by
     the row values' dtype (``f32``, or ``i16`` buckets).  ``rowcols`` are the rows × columns

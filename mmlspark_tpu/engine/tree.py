@@ -52,7 +52,6 @@ from mmlspark_tpu.ops.histogram import (
     build_class_root_histograms,
     build_histogram,
     build_histogram_by_leaf,
-    factorized_by_leaf,
     quantize_hist_vals,
     quantize_scales3,
 )
@@ -1176,9 +1175,7 @@ def grow_tree_depthwise(
             )
 
     # Root histogram through the SAME windowed kernel (all rows in slot 0):
-    # the plain per-feature kernel's M=3 matmuls cost 2.8ms/pass at the
-    # bench shape vs 1.9ms for the factorized windowed kernel, and reusing
-    # it drops one compiled kernel from the program.
+    # reusing it keeps one compiled kernel in the program.
     if root_hist is None:
         root_hist = window_hist(jnp.zeros(n, jnp.int32))[:, 0]  # (3, F_loc, B)
     # Under reduce_scatter the merged buffer holds only THIS shard's
@@ -1626,19 +1623,17 @@ def _window_merge(cfg: GrowConfig):
 def class_roots_active(cfg: GrowConfig, num_class: int) -> bool:
     """Whether a fit of ``num_class`` trees an iteration builds their roots
     together (:func:`class_root_histograms`) and hands each windowed grower
-    its own: float values, the windowed grower, one device or a row-sharded
-    mesh whose merge the histogram build performs, and on the Pallas
-    backend a window whose own root takes the factorized body (the class
-    roots' body is its tile with a class in each slot: the same sums in the
-    same order).  Quantized, lossguide, voting and feature-parallel fits,
-    and windows or bin counts that take the plain by-leaf body, build each
-    root in its own tree."""
+    its own: float values, the windowed grower, and one device or a
+    row-sharded mesh whose merge the histogram build performs.  On the
+    Pallas backend the class roots' body is the factorized body's tile with
+    a class in each slot: the same sums in the same order as each window's
+    own root.  Quantized, lossguide, voting and feature-parallel fits build
+    each root in its own tree."""
     return (
         num_class > 1
         and not cfg.quantize_active
         and windowed_grower(cfg)
         and not (cfg.voting_active or cfg.feature_parallel_active)
-        and (cfg.hist_backend != "pallas" or factorized_by_leaf(cfg.level_window, cfg.num_bins))
     )
 
 

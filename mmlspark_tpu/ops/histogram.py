@@ -23,7 +23,7 @@ Backends:
 - ``scatter``  — ``jnp...at[].add`` scatter-add.  Reference semantics; the
   backend used on the CPU test mesh (it slices, transposes and widens per
   chunk).
-- ``pallas``   — Pallas kernels (``mmlspark_tpu.ops.pallas_hist``): the
+- ``pallas``   — one Pallas body (``mmlspark_tpu.ops.pallas_hist``): the
   histogram as a one-hot × values matmul on the MXU, with the one-hot
   tile living in VMEM only.  The chip's path; interpreted on a CPU.
 
@@ -521,49 +521,36 @@ def build_histogram(
     transposes back and widens per chunk, it is the small-scale/test
     path).
 
+    On the ``pallas`` backend this is :func:`build_histogram_by_leaf` at
+    one slot, the rows in ``mask`` in slot 0 and the others parked at 1:
+    the one Pallas body (``ops/pallas_hist.py``) serves both builders.
+
     When ``axis_name`` is set (running inside ``shard_map`` over row shards),
     the result is ``psum``-med across the mesh axis — this single line is the
     replacement for LightGBM's socket allreduce of histograms
     (``LGBM_NetworkInit`` + recursive-halving allreduce; SURVEY.md §3.1,
     §5.8 native component N2).
     """
-    F = bins.shape[0]
-    if backend == "pallas":
-        from mmlspark_tpu.ops.pallas_hist import pallas_hist_chunk
-
-        fn = functools.partial(pallas_hist_chunk, num_bins=num_bins, precision=precision)
-    elif backend == "scatter":
-        fn = functools.partial(_scatter_hist_chunk, num_bins=num_bins)
-    else:
-        raise ValueError(
-            f"unknown hist backend {backend!r}; expected scatter|pallas"
-        )
     if quantize is None:
         vals = vals.astype(jnp.float32)
     vals = jnp.where(mask[None, :], vals, vals.dtype.type(0))
+    if backend == "pallas":
+        return build_histogram_by_leaf(
+            bins, vals, (~mask).astype(jnp.int32), 1, num_bins, backend, chunk, axis_name, precision, merge, quantize,
+        )[:, 0]
+    if backend != "scatter":
+        raise ValueError(
+            f"unknown hist backend {backend!r}; expected scatter|pallas"
+        )
     # headroom: bin sums of buckets fit the int32 accumulator while rows ×
     # largest bucket < 2³¹ a shard — guarded statically by quantize_wire_plan
     acc0 = jnp.zeros(
-        (3, F, num_bins), jnp.int32 if _is_bucket(vals.dtype) else jnp.float32
+        (3, bins.shape[0], num_bins), jnp.int32 if _is_bucket(vals.dtype) else jnp.float32
     )
+    fn = functools.partial(_scatter_hist_chunk, num_bins=num_bins)
     return _chunked_hist(
         fn, acc0, bins, [vals], chunk, axis_name, merge, quantize
     )
-
-
-def factorized_by_leaf(num_leaves: int, num_bins: int) -> bool:
-    """Whether the Pallas backend builds ``num_leaves`` slots' histograms
-    over ``num_bins`` bins in the factorized (hi/lo) body.
-
-    Small windows starve the plain kernel's matmul M = 3·W; the factorized
-    hi/lo variant doubles M (same results to float-summation ulps, and of
-    buckets the same int32 sums bit for bit — parity tested) and wins
-    measurably up to M ≈ 128 (W≤21 at B=256: 7.5 → 4.9 ms/pass at W=12,
-    262k×64 on v5e).  One rule on shapes for both value dtypes: the
-    factoring splits a one-hot and its un-factoring is a reshape, neither
-    cares what is summed."""
-    h = (num_bins + 127) // 128
-    return num_bins > 128 and 3 * num_leaves * h <= 128
 
 
 def build_histogram_by_leaf(
@@ -593,20 +580,20 @@ def build_histogram_by_leaf(
     zeroed ``vals``.  With ``axis_name``, the result is psum-med
     across the mesh — the same single-collective structure as
     :func:`build_histogram`.
+
+    The ``pallas`` backend takes the factorized (hi/lo) body at every
+    window and bin count (``ops/pallas_hist.py``
+    ``pallas_hist_by_leaf_nibble_chunk``).
     """
     F = bins.shape[0]
     if quantize is None:
         vals = vals.astype(jnp.float32)
     quant = _is_bucket(vals.dtype)
     if backend == "pallas":
-        from mmlspark_tpu.ops.pallas_hist import (
-            pallas_hist_by_leaf_chunk,
-            pallas_hist_by_leaf_nibble_chunk,
-        )
+        from mmlspark_tpu.ops.pallas_hist import pallas_hist_by_leaf_nibble_chunk
 
         fn = functools.partial(
-            pallas_hist_by_leaf_nibble_chunk if factorized_by_leaf(num_leaves, num_bins)
-            else pallas_hist_by_leaf_chunk,
+            pallas_hist_by_leaf_nibble_chunk,
             num_leaves=num_leaves, num_bins=num_bins, precision=precision,
         )
     elif backend == "scatter":

@@ -89,16 +89,28 @@ def test_a_grower_handed_its_root_grows_the_tree_it_grows_alone(backend):
 
 
 def test_the_roots_are_built_together_where_each_tree_s_own_root_takes_the_factorized_body():
-    """On the Pallas backend the class roots' body is the factorized body's
-    tile, so they replace a tree's own root only where that root takes it:
-    over 128 bins and at most 21 slots at 256 bins."""
+    """On the Pallas backend every window's root takes the factorized body,
+    whose tile the class roots' body is: they replace a tree's own root at
+    any window and bin count, and equal it to the bit at a window of 32
+    slots (depthwise at 63 leaves) and at 64 bins.  Lossguide fits build
+    each root in its own tree."""
     cfg = GrowConfig(num_bins=B, num_leaves=31, split_batch=8, hist_backend="pallas")
     assert class_roots_active(cfg, 100)
-    assert not class_roots_active(GrowConfig(num_bins=64, num_leaves=31, split_batch=8, hist_backend="pallas"), 100)
+    assert class_roots_active(GrowConfig(num_bins=64, num_leaves=31, split_batch=8, hist_backend="pallas"), 100)
     wide = GrowConfig(num_bins=B, num_leaves=63, grow_policy="depthwise", hist_backend="pallas")
-    assert wide.level_window == 32 and not class_roots_active(wide, 100)
+    assert wide.level_window == 32 and class_roots_active(wide, 100)
     assert class_roots_active(GrowConfig(num_bins=64, num_leaves=31, split_batch=8), 100)  # scatter: any shape
     assert not class_roots_active(GrowConfig(num_bins=B, num_leaves=31, hist_backend="pallas"), 100)  # lossguide
+    K, n, F = 5, 2048, 6
+    bins, grad, hess, bag = _rows(K, n, F, seed=2)
+    for W, num_bins in ((32, B), (8, 64)):
+        bins_t = bins.T % num_bins
+        kw = dict(backend="pallas", precision="default")
+        roots = np.asarray(build_class_root_histograms(bins_t, grad, hess, bag, num_bins, **kw))
+        for k in range(K):
+            vals = jnp.stack([grad[k] * bag, hess[k] * bag, (bag > 0).astype(jnp.float32)])
+            own = build_histogram_by_leaf(bins_t, vals, jnp.zeros(n, jnp.int32), W, num_bins, **kw)[:, 0]
+            np.testing.assert_array_equal(roots[k], np.asarray(own))
 
 
 @pytest.mark.parametrize("extra, engaged", [

@@ -68,16 +68,23 @@ class TestBinning:
         np.testing.assert_array_equal(bm.transform(X), bm2.transform(X))
 
 
-def _hist_chunk_fn(kind, backend, nibble=False):
-    """The chunk function a builder hands its chunk loop."""
+def _hist_chunk_fn(kind, backend):
+    """The chunk function a builder hands its chunk loop, called as ``fn(bins_t,
+    vals, leaf, W, B)``.  A plain build reads no leaf ids; on the Pallas
+    backend it is the factorized body at one slot with every row in slot 0
+    (its builder parks the masked-out rows, whose zeroed ``vals`` add
+    nothing there either)."""
+    import jax.numpy as jnp
+
     from mmlspark_tpu.ops import histogram as H
     from mmlspark_tpu.ops import pallas_hist as PH
 
     return {
-        ("plain", "scatter"): H._scatter_hist_chunk,
-        ("plain", "pallas"): PH.pallas_hist_chunk,
+        ("plain", "scatter"): lambda b, v, leaf, W, B: H._scatter_hist_chunk(b, v, B),
+        ("plain", "pallas"): lambda b, v, leaf, W, B: PH.pallas_hist_by_leaf_nibble_chunk(
+            b, v, jnp.zeros(b.shape[1], jnp.int32), 1, B)[:, 0],
         ("by_leaf", "scatter"): H._scatter_hist_by_leaf_chunk,
-        ("by_leaf", "pallas"): PH.pallas_hist_by_leaf_nibble_chunk if nibble else PH.pallas_hist_by_leaf_chunk,
+        ("by_leaf", "pallas"): PH.pallas_hist_by_leaf_nibble_chunk,
     }[kind, backend]
 
 
@@ -175,13 +182,12 @@ class TestHistogram:
         kw = dict(backend=backend, chunk=self._CHUNK)
         t = np.asarray(self._chunk_build(kind, bins.T, vals, leaf, mask, **kw))
         u = np.asarray(self._chunk_build(kind, bins.T, vals, leaf, mask, backend=backend, chunk=self._N))
-        fn = _hist_chunk_fn(kind, backend, nibble=True)  # W = 8 at 256 bins: the router's choice
-        per_row = [np.where(mask[None, :], vals, 0).astype(np.float32)] + ([leaf] if kind == "by_leaf" else [])
-        static = (self._W, self._B) if kind == "by_leaf" else (self._B,)
+        fn = _hist_chunk_fn(kind, backend)
+        per_row = [np.where(mask[None, :], vals, 0).astype(np.float32), leaf]
         want = jnp.zeros(t.shape, jnp.float32)
         for i in range(self._N // self._CHUNK):
             sl = slice(i * self._CHUNK, (i + 1) * self._CHUNK)
-            want = want + fn(jnp.asarray(bins.T[:, sl]), *(jnp.asarray(x[..., sl]) for x in per_row), *static)
+            want = want + fn(jnp.asarray(bins.T[:, sl]), *(jnp.asarray(x[..., sl]) for x in per_row), self._W, self._B)
         np.testing.assert_array_equal(t, np.asarray(want))
         np.testing.assert_allclose(t, u, rtol=1e-5, atol=1e-4)
 
@@ -203,11 +209,10 @@ class TestHistogram:
     def test_chunk_loop_relays_no_whole_array(self, kind, backend, F):
         """The relayout cannot come back unseen on a CPU: the chunked
         call's jaxpr holds no transpose or reshape of the whole
-        integer bins matrix and no transpose of the whole ``vals``; a chunk
-        function that slices (the scatter functions; the by-leaf kernels
-        here, whose 1,024-row blocks a chunk of 256 rows does not fill)
-        slices the matrix itself, and the plain kernel, whose blocks the
-        chunk does fill, slices nothing."""
+        integer bins matrix and no transpose of the whole ``vals``; every
+        chunk function here slices (the scatter functions; the Pallas body,
+        whose 1,024-row blocks a chunk of 256 rows does not fill), and
+        slices the matrix itself."""
         import jax
 
         bins, *rest = self._chunk_inputs(F, seed=0)
@@ -229,14 +234,15 @@ class TestHistogram:
                     walk(sub)
 
         walk(jaxpr.jaxpr)
-        assert sliced == ([] if (kind, backend) == ("plain", "pallas") else [(F, self._N)])
+        assert sliced == [(F, self._N)]
 
     # -- the kernels read the matrix in place: 3 chunks of whole row blocks --
-    _bodies = {"plain": "_pallas_hist", "by_leaf": "_pallas_hist_by_leaf", "nibble": "_pallas_hist_by_leaf_nibble"}
+    _bodies = ("plain", "by_leaf", "nibble")
 
     def _in_place_build(self, body, bins_t, vals, leaf, chunk):
-        """The builder whose router takes ``body`` at 256 bins: the plain
-        kernel, the by-leaf kernel at W = 32, the factorized one at W = 8."""
+        """The route at 256 bins: ``plain`` the per-feature build (the
+        factorized body at one slot), ``by_leaf`` a window of W = 32 and
+        ``nibble`` one of W = 8."""
         import jax.numpy as jnp
 
         from mmlspark_tpu.ops import histogram as H
@@ -260,7 +266,7 @@ class TestHistogram:
         from mmlspark_tpu.ops import pallas_hist as PH
 
         rng = np.random.default_rng(F)
-        chunk, W = 1024, 32 if body == "by_leaf" else 8
+        chunk, W = 1024, {"plain": 1, "by_leaf": 32, "nibble": 8}[body]
         n = 3 * chunk
         bins_t = rng.integers(0, self._B, size=(F, n)).astype(np.uint8)
         if dtype == "int16":
@@ -270,12 +276,11 @@ class TestHistogram:
         leaf = rng.integers(-2, W + 2, size=n).astype(np.int32)
         leaf[chunk:chunk + 300] = np.where(rng.random(300) < 0.5, -1, W)
         got = self._in_place_build(body, jnp.asarray(bins_t), jnp.asarray(vals), jnp.asarray(leaf), chunk)
-        fn = {"plain": PH.pallas_hist_chunk, "by_leaf": PH.pallas_hist_by_leaf_chunk, "nibble": PH.pallas_hist_by_leaf_nibble_chunk}[body]
+        fn = _hist_chunk_fn("plain" if body == "plain" else "by_leaf", "pallas")
         want = 0
         for i in range(n // chunk):
             sl = slice(i * chunk, (i + 1) * chunk)
-            rows = (vals[:, sl],) if body == "plain" else (vals[:, sl], leaf[sl], W)
-            want = want + fn(jnp.asarray(bins_t[:, sl]), *rows, self._B)
+            want = want + fn(jnp.asarray(bins_t[:, sl]), vals[:, sl], leaf[sl], W, self._B)
         assert want.dtype == (jnp.int32 if dtype == "int16" else jnp.float32)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want).astype(np.float32))
 
@@ -283,14 +288,14 @@ class TestHistogram:
         """Traced under ``backend="pallas"`` with chunks of whole row blocks,
         the scan's body holds the kernel's call on the whole arrays and no
         ``pad``, ``dynamic_slice`` or ``convert_element_type`` of anything
-        with the matrix's row count, in any of the three bodies."""
+        with the matrix's row count, on any of the three routes."""
         import jax
         import jax.numpy as jnp
 
         chunk, F = 1024, 39
         n = 3 * chunk
         args = (jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), jnp.float32), jnp.zeros(n, jnp.int32))
-        for body, wrapper in self._bodies.items():
+        for body in self._bodies:
             jaxpr = jax.make_jaxpr(lambda *a, body=body: self._in_place_build(body, *a, chunk))(*args)
             bodies, calls = [], []
 
@@ -299,7 +304,7 @@ class TestHistogram:
                     name = eqn.primitive.name
                     if in_scan and name in ("pad", "dynamic_slice", "convert_element_type"):
                         assert all(n not in v.aval.shape for v in eqn.invars if hasattr(v.aval, "shape")), (body, eqn)
-                    if in_scan and eqn.params.get("name") == wrapper:
+                    if in_scan and eqn.params.get("name") == "_pallas_hist_by_leaf_nibble":
                         calls.append([v.aval.shape for v in eqn.invars])
                     if name == "scan":
                         bodies.append(eqn.params["length"])
@@ -308,8 +313,7 @@ class TestHistogram:
 
             walk(jaxpr.jaxpr, False)
             assert bodies[0] == 3
-            rows = [(3, n)] if body == "plain" else [(3, n), (1, n)]
-            assert calls == [[(F, n), *rows, (1,)]], (body, calls)
+            assert calls == [[(F, n), (3, n), (1, n), (1,)]], (body, calls)
 
     def test_pallas_matches_scatter(self):
         import jax.numpy as jnp
@@ -353,15 +357,14 @@ class TestSharedChunkBodies:
     @pytest.mark.parametrize(
         "kind,backend,dtype,B,W",
         [(k, b, d, 256, 8) for k in ("plain", "by_leaf") for b in ("scatter", "pallas") for d in ("float32", "int16")]
-        # a wider window (M = 3*W*2 over 128) and 128 bins (nothing to factor) keep the plain by-leaf body
+        # a wider window (M = 3*W*2 over 128) and 128 bins (nothing to factor: H = 1) take the same body
         + [("by_leaf", "pallas", d, B, W) for d in ("float32", "int16") for B, W in ((256, 32), (128, 8))],
     )
     def test_body_sums_in_the_accumulator_its_vals_ask_for(self, kind, backend, dtype, B, W):
         """The shared chunk body gives numpy's sums: int16 buckets exactly
-        and as int32, float32 values to 1e-4 and as float32.  Through the
-        builder, the by-leaf build takes the body its SHAPES ask for,
-        whatever it sums: the factorized kernel at 256 bins, W = 8, the
-        plain one at W = 32 and at 128 bins."""
+        and as int32, float32 values to 1e-4 and as float32.  Through
+        either builder, the Pallas backend reaches the factorized body at
+        every shape, whatever it sums."""
         import jax.numpy as jnp
 
         from mmlspark_tpu.ops import histogram as H
@@ -375,8 +378,7 @@ class TestSharedChunkBodies:
         else:
             vals = rng.normal(size=(3, n)).astype(np.float32)
         fn = _hist_chunk_fn(kind, backend)
-        per_row = [vals] if kind == "plain" else [vals, leaf, W]
-        got = fn(jnp.asarray(bins_t), *per_row, B)
+        got = fn(jnp.asarray(bins_t), jnp.asarray(vals), jnp.asarray(leaf), W, B)
         rows = np.flatnonzero((leaf >= 0) & (leaf < W)) if kind == "by_leaf" else np.arange(n)
         want = _numpy_hist(kind, bins_t, vals, leaf, rows, W, B)
         if dtype == "int16":
@@ -385,35 +387,37 @@ class TestSharedChunkBodies:
         else:
             assert got.dtype == jnp.float32
             np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
-        if (kind, backend) == ("by_leaf", "pallas"):
+        if backend == "pallas":
             hq = H.HistQuantize("int32", 0, jnp.ones(3, jnp.float32)) if dtype == "int16" else None
-            reached = self._kernels_reached(
-                lambda b, v, l: H.build_histogram_by_leaf(b, v, l, W, B, backend="pallas", quantize=hq),
-                bins_t, vals, leaf,
+            kw = dict(backend="pallas", quantize=hq)
+            build = (
+                (lambda b, v, l: H.build_histogram_by_leaf(b, v, l, W, B, **kw)) if kind == "by_leaf"
+                else (lambda b, v, l: H.build_histogram(b, v, l >= 0, B, **kw))
             )
-            assert reached == ({"_pallas_hist_by_leaf_nibble"} if (B, W) == (256, 8) else {"_pallas_hist_by_leaf"})
+            assert self._kernels_reached(build, bins_t, vals, leaf) == {"_pallas_hist_by_leaf_nibble"}
 
-    @pytest.mark.parametrize("kernel", ["_pallas_hist", "_pallas_hist_by_leaf", "_pallas_hist_by_leaf_nibble"])
-    def test_float_kernel_bodies_hold_no_int32_accumulator(self, kernel):
+    # the slots of each route: the per-feature build's one, a window of 32, one of 8
+    @pytest.mark.parametrize(
+        "W", [pytest.param(1, id="_pallas_hist"), pytest.param(32, id="_pallas_hist_by_leaf"),
+              pytest.param(8, id="_pallas_hist_by_leaf_nibble")],
+    )
+    def test_float_kernel_bodies_hold_no_int32_accumulator(self, W):
         """The dtype branch is a Python ``if``: a float build's traced
         kernel casts no float to int32 and holds no int32 array as wide as
         an accumulator tile, where the bucket build of the same body holds
-        both, in all three kernels."""
+        both, at every number of slots."""
         import jax
         import jax.numpy as jnp
 
         from mmlspark_tpu.ops import pallas_hist as PH
 
-        n, F, B, W, bf = 1536, 8, 256, 8, 8
-        acc_lanes = {bf * B, bf * PH._NIBBLE_LO}  # no other array here is this wide
-        static = dict(num_bins=B, bm=512, bf=bf, chunk=n, interpret=True, precision="highest")
-        rows = [jnp.zeros(1, jnp.int32)]  # the chunk's index
-        if kernel != "_pallas_hist":
-            rows.insert(0, jnp.zeros((1, n), jnp.int32))
-            static.update(num_leaves=W, rm=256)
+        n, F, B, bf = 1536, 8, 256, 8
+        acc_lanes = {bf * PH._NIBBLE_LO}  # no other array here is this wide
+        static = dict(num_leaves=W, num_bins=B, bm=512, bf=bf, rm=256, chunk=n, interpret=True, precision="highest")
+        rows = [jnp.zeros((1, n), jnp.int32), jnp.zeros(1, jnp.int32)]  # the leaf ids, the chunk's index
 
         def int32_accumulation(val_dtype):
-            jaxpr = jax.make_jaxpr(lambda *a: getattr(PH, kernel)(*a, **static))(
+            jaxpr = jax.make_jaxpr(lambda *a: PH._pallas_hist_by_leaf_nibble(*a, **static))(
                 jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), val_dtype), *rows
             )
             hits, seen = [], set()
@@ -464,45 +468,38 @@ class TestSharedChunkBodies:
 class TestByLeafKernels:
     @pytest.mark.parametrize("B,W", [(256, 12), (255, 12), (129, 5), (256, 1)])
     def test_nibble_kernel_parity(self, B, W):
-        """The factorized hi/lo by-leaf kernel must match the plain kernel
-        to float-summation ulps (the two contractions associate the row sum
-        differently; both run CPU interpret mode here) — it is the
-        auto-selected path for small windows at num_bins > 128 and its
-        output feeds split decisions directly."""
+        """The factorized hi/lo body must match numpy's float64 sums to
+        float-summation ulps (run in CPU interpret mode here) — it is every
+        Pallas build's body and its output feeds split decisions directly."""
         import jax.numpy as jnp
 
-        from mmlspark_tpu.ops.pallas_hist import (
-            pallas_hist_by_leaf_chunk,
-            pallas_hist_by_leaf_nibble_chunk,
-        )
+        from mmlspark_tpu.ops.pallas_hist import pallas_hist_by_leaf_nibble_chunk
 
         rng = np.random.default_rng(B + W)
         n, F = 2048, 9
         # inclusive of bin B-1: the top bin exercises the nibble kernel's
         # hi plane and the H*128 -> num_bins slice at non-power-of-two B
-        bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
-        vals = jnp.asarray(rng.normal(size=(3, n)), dtype=jnp.float32)
+        bins = rng.integers(0, B, size=(F, n))
+        vals = rng.normal(size=(3, n)).astype(np.float32)
         # parked ids on both sides of the window range
-        leaf = jnp.asarray(rng.integers(-3, W + 2, size=(n,)), dtype=jnp.int32)
-        a = np.asarray(pallas_hist_by_leaf_chunk(bins, vals, leaf, W, B))
-        b = np.asarray(pallas_hist_by_leaf_nibble_chunk(bins, vals, leaf, W, B))
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+        leaf = rng.integers(-3, W + 2, size=(n,)).astype(np.int32)
+        got = np.asarray(pallas_hist_by_leaf_nibble_chunk(jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(leaf), W, B))
+        want = _numpy_hist("by_leaf", bins, vals.astype(np.float64), leaf, np.flatnonzero((leaf >= 0) & (leaf < W)), W, B)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
     @pytest.mark.parametrize("vals", ["random", "extreme"])
     @pytest.mark.parametrize("precision", ["default", "highest"])
     @pytest.mark.parametrize("B", [255, 256])
     def test_nibble_kernel_sums_buckets_bit_for_bit(self, B, precision, vals):
         """int16 buckets through the factorized body: int32 sums equal to
-        numpy's and to the plain body's bit for bit, with parked leaf ids
-        on both sides of the window, rows that are no whole ``rm`` block,
-        and every value at +-QMAX (a sub-block's sum at its largest)."""
+        numpy's and to the scatter reference's bit for bit, with parked leaf
+        ids on both sides of the window, rows that are no whole ``rm``
+        block, and every value at +-QMAX (a sub-block's sum at its
+        largest)."""
         import jax.numpy as jnp
 
-        from mmlspark_tpu.ops.histogram import QMAX
-        from mmlspark_tpu.ops.pallas_hist import (
-            pallas_hist_by_leaf_chunk,
-            pallas_hist_by_leaf_nibble_chunk,
-        )
+        from mmlspark_tpu.ops.histogram import QMAX, _scatter_hist_by_leaf_chunk
+        from mmlspark_tpu.ops.pallas_hist import pallas_hist_by_leaf_nibble_chunk
 
         rng = np.random.default_rng(B)
         n, F, W = 2048 + 37, 9, 8
@@ -520,8 +517,7 @@ class TestByLeafKernels:
         keep = np.flatnonzero((leaf >= 0) & (leaf < W))
         want = _numpy_hist("by_leaf", bins, q.astype(np.int64), leaf, keep, W, B)
         np.testing.assert_array_equal(np.asarray(got), want.astype(np.int32))
-        plain = pallas_hist_by_leaf_chunk(*args, rm=256, precision=precision)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(_scatter_hist_by_leaf_chunk(*args)))
 
     @pytest.mark.parametrize("F", [39, 64, 136])
     @pytest.mark.parametrize("dtype,B", [("uint8", 256), ("int32", 512)])
@@ -560,22 +556,24 @@ class TestByLeafKernels:
             assert bf == {39: 39, 64: 32, 136: 48}[F]
 
     def test_by_leaf_dispatch_through_build_histogram(self):
-        """build_histogram_by_leaf's pallas dispatch (nibble for small W at
-        B>128) must agree with the scatter reference backend."""
+        """build_histogram_by_leaf's pallas dispatch (the factorized body at
+        every shape: a small window, a wide one, and 64 bins) must agree
+        with the scatter reference backend."""
         import jax.numpy as jnp
 
         from mmlspark_tpu.ops.histogram import build_histogram_by_leaf
 
         rng = np.random.default_rng(7)
-        n, F, B, W = 1024, 6, 256, 8
-        bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
-        vals = jnp.asarray(rng.normal(size=(3, n)), dtype=jnp.float32)
-        leaf = jnp.asarray(rng.integers(-1, W + 1, size=(n,)), dtype=jnp.int32)
-        ref = np.asarray(build_histogram_by_leaf(bins, vals, leaf, W, B,
-                                                 backend="scatter"))
-        pal = np.asarray(build_histogram_by_leaf(bins, vals, leaf, W, B,
-                                                 backend="pallas"))
-        np.testing.assert_allclose(ref, pal, rtol=1e-5, atol=1e-5)
+        n, F = 1024, 6
+        for B, W in ((256, 8), (256, 32), (64, 8)):
+            bins = jnp.asarray(rng.integers(0, B, size=(F, n)))
+            vals = jnp.asarray(rng.normal(size=(3, n)), dtype=jnp.float32)
+            leaf = jnp.asarray(rng.integers(-1, W + 1, size=(n,)), dtype=jnp.int32)
+            ref = np.asarray(build_histogram_by_leaf(bins, vals, leaf, W, B,
+                                                     backend="scatter"))
+            pal = np.asarray(build_histogram_by_leaf(bins, vals, leaf, W, B,
+                                                     backend="pallas"))
+            np.testing.assert_allclose(ref, pal, rtol=1e-5, atol=1e-5)
 
 
 class TestGrowTree:

@@ -120,24 +120,22 @@ def _walk(jaxpr, times, acc):
 
 
 @pytest.mark.parametrize("vals_dtype", [jnp.float32, jnp.int16], ids=["f32", "i16"])
-@pytest.mark.parametrize("wrapper, leaves", [("_pallas_hist", None), ("_pallas_hist_by_leaf", 32), ("_pallas_hist_by_leaf_nibble", 8)])
+# the factorized body at the slots of each route: the per-feature build's one, a window of 32, one of 8
+@pytest.mark.parametrize("wrapper, leaves", [("_pallas_hist", 1), ("_pallas_hist_by_leaf", 32), ("_pallas_hist_by_leaf_nibble", 8)],
+                         ids=["_pallas_hist-None", "_pallas_hist_by_leaf-32", "_pallas_hist_by_leaf_nibble-8"])
 def test_stated_work_of_a_body_is_what_its_traced_body_builds(wrapper, leaves, vals_dtype):
     F, n = 16, 4096
-    args = [jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), vals_dtype)]
-    kw = dict(num_bins=256, bm=2048, bf=8, chunk=n, interpret=True, precision="default")
-    if leaves:
-        args.append(jnp.zeros((1, n), jnp.int32))
-        kw |= dict(num_leaves=leaves, rm=1024)
-    args.append(jnp.zeros(1, jnp.int32))  # the chunk's index
-    jaxpr = jax.make_jaxpr(lambda *a: getattr(pallas_hist, wrapper)(*a, **kw))(*args)
+    args = [jnp.zeros((F, n), jnp.uint8), jnp.zeros((3, n), vals_dtype), jnp.zeros((1, n), jnp.int32), jnp.zeros(1, jnp.int32)]
+    kw = dict(num_leaves=leaves, num_bins=256, bm=2048, bf=8, rm=1024, chunk=n, interpret=True, precision="default")
+    jaxpr = jax.make_jaxpr(lambda *a: pallas_hist._pallas_hist_by_leaf_nibble(*a, **kw))(*args)
     (eqn,) = (e for e in jaxpr.eqns if e.primitive.name in ("jit", "pjit"))
     work = pallas_hist.call_work(eqn)
     (call,) = (e for e in eqn.params["jaxpr"].eqns if e.primitive.name == "pallas_call")
     cells = int(np.prod(call.params["grid_mapping"].grid))
     body = _walk(call.params["jaxpr"], 1, collections.Counter())
-    assert (work["rowcols"], work["quant"]) == (F * n, vals_dtype == jnp.int16)
+    assert (work["body"], work["rowcols"], work["quant"]) == ("nibble", F * n, vals_dtype == jnp.int16)
     assert work["vpu_elems"] == cells * body["vpu"]
-    assert work["mxu_flops"] == cells * body["mxu"] == 2 * (3 * (leaves or 1)) * 256 * F * n
+    assert work["mxu_flops"] == cells * body["mxu"] == 2 * (3 * leaves) * 256 * F * n
 
 
 def test_stated_work_of_the_class_roots_body_is_what_its_traced_body_builds():

@@ -42,24 +42,31 @@ def one_chip():
 # memory test then fails.)
 @pytest.mark.filterwarnings("ignore:Error reading persistent compilation cache entry")
 @pytest.mark.parametrize(
-    "kernel, vals_dtype, bins_dtype, cols, bf, chunk, out_dtype",
+    "W, B, vals_dtype, bins_dtype, cols, chunk, out_dtype",
     [
         # criteo_quant_train_1chip: every pass's bucket build, int16 row values into an int32
         # (48, 4992) accumulator in the factorized body (W = 8: M = 3*W*2), a block as tall as the 39 columns
-        pytest.param("_pallas_hist_by_leaf_nibble", jnp.int16, jnp.uint8, 39, 39, CHUNK, "s32", id="bucket-build-nibble-int16"),
-        # the plain body's bucket build, which wider windows (W = 32) and <= 128 bins still take
-        pytest.param("_pallas_hist_by_leaf", jnp.int16, jnp.uint8, 39, 39, CHUNK, "s32", id="bucket-build-int16"),
+        pytest.param(W, B, jnp.int16, jnp.uint8, 39, CHUNK, "s32", id="bucket-build-nibble-int16"),
+        # a depthwise window of 32 slots (num_leaves 63): M = 192, the bucket build and the float one
+        pytest.param(32, B, jnp.int16, jnp.uint8, 39, CHUNK, "s32", id="bucket-build-int16"),
+        pytest.param(32, B, jnp.float32, jnp.uint8, 39, CHUNK, "f32", id="float-window32"),
+        # depthwise at 255 leaves: a window of 128 slots (M = 768), eight-column blocks, the fifth ragged
+        pytest.param(128, 255, jnp.float32, jnp.uint8, 39, CHUNK, "f32", id="float-window128"),
+        # max_bin 63: nothing to factor (H = 1), the lo one-hot half used
+        pytest.param(W, 64, jnp.float32, jnp.uint8, 39, CHUNK, "f32", id="float-64-bins"),
+        # 512 bins over int32 bins (H = 4) at a window of 32: 24-column blocks, the second ragged
+        pytest.param(32, 512, jnp.float32, jnp.int32, 39, CHUNK, "f32", id="float-512-bins-int32"),
         # istella_rank_train_1chip: 220 columns under 32-tall blocks, the seventh ragged
-        pytest.param("_pallas_hist_by_leaf_nibble", jnp.float32, jnp.uint8, 220, 32, CHUNK // 2, "f32", id="float-nibble-ragged"),
+        pytest.param(W, B, jnp.float32, jnp.uint8, 220, CHUNK // 2, "f32", id="float-nibble-ragged"),
         # the quantized cell's float32 refinement: one composed winner column of int32, a block one row tall
-        pytest.param("_pallas_hist_by_leaf_nibble", jnp.float32, jnp.int32, 1, 1, CHUNK, "f32", id="refine-column"),
+        pytest.param(W, B, jnp.float32, jnp.int32, 1, CHUNK, "f32", id="refine-column"),
         # the float Criteo cells' pass: 39 columns, one block
-        pytest.param("_pallas_hist_by_leaf_nibble", jnp.float32, jnp.uint8, 39, 39, CHUNK, "f32", id="float-nibble"),
+        pytest.param(W, B, jnp.float32, jnp.uint8, 39, CHUNK, "f32", id="float-nibble"),
     ],
 )
-def test_histogram_kernel_compiles_for_the_chip(one_chip, kernel, vals_dtype, bins_dtype, cols, bf, chunk, out_dtype):
+def test_histogram_kernel_compiles_for_the_chip(one_chip, W, B, vals_dtype, bins_dtype, cols, chunk, out_dtype):
     """The call as the chunk loop makes it: the whole matrix of several chunks,
-    the chunk's index a scalar, the blocks the wrappers choose at these
+    the chunk's index a scalar, the blocks the wrapper chooses at these
     widths.  Mosaic takes each, and XLA puts nothing between the resident
     arrays and the kernel: no ``pad``, ``copy`` or ``dynamic-slice`` that
     produces an array of the matrix's dtype."""
@@ -69,14 +76,15 @@ def test_histogram_kernel_compiles_for_the_chip(one_chip, kernel, vals_dtype, bi
 
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     n = CHUNKS * chunk
-    compiled = getattr(pallas_hist, kernel).lower(
+    bm, bf, rm, _ = pallas_hist._by_leaf_blocks(cols, W, B, 16384, 32, 1024, chunk)
+    compiled = pallas_hist._pallas_hist_by_leaf_nibble.lower(
         struct((cols, n), bins_dtype), struct((3, n), vals_dtype), struct((1, n), jnp.int32), struct((1,), jnp.int32),
-        num_leaves=W, num_bins=B, bm=16384, bf=bf, rm=1024, chunk=chunk, interpret=False, precision="default",
+        num_leaves=W, num_bins=B, bm=bm, bf=bf, rm=rm, chunk=chunk, interpret=False, precision="default",
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert f"{out_dtype}[3,{W},{cols},{B}]" in text  # (3, W, F, B) in the accumulator the values ask for
-    moved = "u8" if bins_dtype == jnp.uint8 else r"s32\[1,"
+    moved = "u8" if bins_dtype == jnp.uint8 else rf"s32\[{cols},"
     made = re.findall(rf"= ({moved}\S*) (pad|copy|dynamic-slice)\(", text)
     assert not made, made
 
